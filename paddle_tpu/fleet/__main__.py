@@ -30,6 +30,9 @@
         (checkpoint-dir deploys included), so the process needs no
         model arguments. SIGTERM = clean leave (deregister, drain);
         SIGKILL = crash, and the launcher's backoff brings it back.
+        The replica serves from the backend JAX gives, and a chip
+        belongs to one process: on a TPU host that is one replica per
+        chip (docs/FLEET.md).
 """
 from __future__ import annotations
 
@@ -39,13 +42,12 @@ import sys
 
 
 def _force_cpu():
+    """Selftest only: toy sizes, no chip taken. Replica mode serves
+    from the backend JAX gives."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    try:
-        import jax
+    import jax
 
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
+    jax.config.update("jax_platforms", "cpu")
 
 
 def run_selftest(verbose: bool = True) -> int:
@@ -305,6 +307,56 @@ def run_selftest(verbose: bool = True) -> int:
     return 0
 
 
+def run_replica(controller_addr: str, replica_id, host: str,
+                port: int) -> int:
+    """Replica mode, on the backend JAX gives (a chip belongs to one
+    process: one replica per chip on a TPU host — docs/FLEET.md)."""
+    import signal
+    import threading
+
+    import jax
+
+    from paddle_tpu.serving import ServingServer
+
+    from . import FleetMember
+
+    platform = jax.devices()[0].platform
+    chost, _, cport = controller_addr.rpartition(":")
+    srv = ServingServer()
+    host, port = srv.serve(host, port)
+    member = FleetMember(srv, (chost or "127.0.0.1", int(cport)),
+                         replica_id=replica_id)
+    done = threading.Event()
+    # SIGTERM is the launcher's polite stop: deregister (the
+    # controller must not count this as an eviction) and drain
+    # in-flight work before exiting. SIGKILL needs no handler —
+    # that is the crash path the launcher resurrects.
+    for s in (signal.SIGTERM, signal.SIGINT):
+        signal.signal(s, lambda *_: done.set())
+    print(f"fleet replica {member.replica_id} on {host}:{port} "
+          f"platform={platform}", flush=True)
+    done.wait()
+    member.stop(deregister=True)
+    srv.shutdown(drain=True)
+    return 0
+
+
+def run_controller(host: str, port: int, lease_ttl) -> int:
+    import time
+
+    from . import FleetController
+
+    ctl = FleetController(lease_ttl=lease_ttl)
+    host, port = ctl.serve(host, port)
+    print(f"fleet controller on {host}:{port} (ctrl-c to stop)")
+    try:
+        while True:
+            time.sleep(3600)
+    except KeyboardInterrupt:
+        ctl.shutdown()
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="python -m paddle_tpu.fleet")
     ap.add_argument("--selftest", action="store_true",
@@ -325,49 +377,14 @@ def main(argv=None) -> int:
     ap.add_argument("--lease-ttl", type=float, default=None)
     args = ap.parse_args(argv)
 
-    _force_cpu()
     if args.replica:
-        import signal
-        import threading
-
-        from paddle_tpu.serving import ServingServer
-
-        from . import FleetMember
-
         if not args.controller_addr:
             ap.error("--replica requires --controller-addr HOST:PORT")
-        chost, _, cport = args.controller_addr.rpartition(":")
-        srv = ServingServer()
-        host, port = srv.serve(args.host, args.port)
-        member = FleetMember(srv, (chost or "127.0.0.1", int(cport)),
-                             replica_id=args.replica_id)
-        done = threading.Event()
-        # SIGTERM is the launcher's polite stop: deregister (the
-        # controller must not count this as an eviction) and drain
-        # in-flight work before exiting. SIGKILL needs no handler —
-        # that is the crash path the launcher resurrects.
-        for s in (signal.SIGTERM, signal.SIGINT):
-            signal.signal(s, lambda *_: done.set())
-        print(f"fleet replica {member.replica_id} on {host}:{port}",
-              flush=True)
-        done.wait()
-        member.stop(deregister=True)
-        srv.shutdown(drain=True)
-        return 0
+        return run_replica(args.controller_addr, args.replica_id,
+                           args.host, args.port)
     if args.controller:
-        from . import FleetController
-
-        ctl = FleetController(lease_ttl=args.lease_ttl)
-        host, port = ctl.serve(args.host, args.port)
-        print(f"fleet controller on {host}:{port} (ctrl-c to stop)")
-        try:
-            import time
-
-            while True:
-                time.sleep(3600)
-        except KeyboardInterrupt:
-            ctl.shutdown()
-        return 0
+        return run_controller(args.host, args.port, args.lease_ttl)
+    _force_cpu()
     return run_selftest()
 
 

@@ -77,11 +77,10 @@ FLAGS: Dict[str, Any] = _Flags({
     "autotune_dir": os.environ.get("PADDLE_TPU_AUTOTUNE_DIR", ""),
     # minimum decode batch (slot count) at which paged attention routes
     # to the Pallas kernel instead of the pure-jax reference when
-    # kernels are enabled. 1 = kernel always (the measured PR 6 answer
-    # on v5e: decode attention is bandwidth-bound, the paged kernel
-    # wins at every batch) — a cold-cache default the tuner overrides
-    # per device kind (Ragged Paged Attention motivates per-chip
-    # routing; a future chip's crossover need not be 1)
+    # kernels are enabled. 1 = kernel always — a cold-cache default
+    # with no chip measurement behind it yet (ROADMAP S2), which the
+    # tuner overrides per device kind (Ragged Paged Attention
+    # motivates per-chip routing)
     "paged_min_slots": 1,
     # mixed precision: bf16 MXU operands with f32 accumulation for
     # conv/matmul (master weights and the rest of the graph stay f32) —

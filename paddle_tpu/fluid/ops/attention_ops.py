@@ -70,7 +70,7 @@ def ring_attention(ctx, ins, attrs):
             # batch/heads, so no collectives are needed.
             from jax.sharding import PartitionSpec as P
 
-            from ...jax_compat import shard_map
+            from jax import shard_map
 
             sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
             b_ax = attrs.get("batch_axis", "") or None

@@ -30,7 +30,7 @@ from .common import one
 # replay), behind FLAGS['count_while_step_evals']. This is the observable
 # the O(T) while-grad contract is tested against: checkpointed replay must
 # evaluate the step ~3T times, where the naive replay-from-zero form is
-# O(T^2) (VERDICT r4 item 5).
+# O(T^2) (round-4 review item 5).
 _STEP_EVALS = {"n": 0}
 
 

@@ -22,8 +22,8 @@ def _ln_kernel(x_ref, scale_ref, bias_ref, y_ref, mean_ref, rstd_ref, *, eps):
     y = xc * rstd
     y = y * scale_ref[:].astype(jnp.float32) + bias_ref[:].astype(jnp.float32)
     y_ref[:] = y.astype(y_ref.dtype)
-    mean_ref[:] = mean[:, 0]
-    rstd_ref[:] = rstd[:, 0]
+    mean_ref[:] = mean
+    rstd_ref[:] = rstd
 
 
 def _round_up(x: int, m: int) -> int:
@@ -49,17 +49,20 @@ def _ln_pallas(x, scale, bias, eps, block_rows, interpret):
         ],
         out_specs=[
             pl.BlockSpec((bn, f), lambda i: (i, 0)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            # stats leave as [bn, 1] column blocks: Mosaic refuses 1-D
+            # (bn,) outputs once n spans several blocks (its T(128) tile
+            # against XLA's T(1024) layout for f32[n])
+            pl.BlockSpec((bn, 1), lambda i: (i, 0)),
+            pl.BlockSpec((bn, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, f), x.dtype),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x, scale.reshape(1, f), bias.reshape(1, f))
-    return y[:n_real], mean[:n_real], rstd[:n_real]
+    return y[:n_real], mean[:n_real, 0], rstd[:n_real, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))

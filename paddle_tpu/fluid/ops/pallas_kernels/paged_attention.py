@@ -56,12 +56,13 @@ chunked layout internally, so the two forms cannot drift.
 ``use_pallas_kernels`` surface that routes flash attention) plus a
 ``flash_min_seq``-style crossover, ``paged_min_slots``: the kernel
 engages at batches of at least that many slots. The cold-cache default
-is 1 — on the measured v5e the paged kernel always wins over
-gather-then-dense, which materializes every page table's worth of K/V
-per step — but the threshold reads through the autotune cache
-(``fluid.flags.effective_flag``), so a device kind where the crossover
-sits elsewhere re-routes without a code change (ISSUE 8; Ragged Paged
-Attention motivates per-chip routing).
+is 1 (kernel at every batch; the kernel-vs-reference crossover has no
+chip measurement yet — ROADMAP S2), and the threshold reads through the
+autotune cache (``fluid.flags.effective_flag``), so a device kind where
+the crossover sits elsewhere re-routes without a code change (ISSUE 8;
+Ragged Paged Attention motivates per-chip routing). A caller whose
+pools are sharded over a mesh names the reference itself
+(``impl="reference"``, see ``paged_route``).
 """
 from __future__ import annotations
 
@@ -77,7 +78,8 @@ from ....observability import metrics as _metrics
 
 NEG_INF = -1e30
 
-__all__ = ["paged_attention", "paged_attention_reference"]
+__all__ = ["paged_attention", "paged_attention_reference",
+           "paged_route"]
 
 # trace-time routing counters (this function body runs once per
 # compiled shape, n_layers times per decoder trace — not per step):
@@ -132,7 +134,11 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
                               scale: Optional[float] = None):
     """Pure-jax oracle: gather the pages, mask causally past each
     query's visibility limit, dense softmax. Same signature/semantics
-    as the kernel. Returns the same rank as ``q``."""
+    as the kernel. Returns the same rank as ``q``. Its two dots run at
+    HIGHEST precision: the kernel multiplies in float32 on the VPU, and
+    a float32 oracle that let the TPU's default single bf16 pass stand
+    in for float32 could not be compared with it (nor serve the same
+    tokens where the engine names it under a mesh)."""
     b, c, hq, d, ps, hkv, w = _check_shapes(q, k_pages, v_pages,
                                             page_tables, kv_lens, q_lens)
     squeeze = q.ndim == 3
@@ -146,7 +152,9 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     qf = q.astype(jnp.float32) * scale
-    s = jnp.einsum("bchd,bthd->bcht", qf, k.astype(jnp.float32))
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("bchd,bthd->bcht", qf, k.astype(jnp.float32),
+                   precision=hi)
     # chunk-causal visibility: query j (absolute position
     # kv_len - q_len + j) sees keys at positions <= its own; dead
     # lanes (j >= q_len) see nothing -> exact-zero rows
@@ -160,7 +168,8 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m) * keep
     l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jnp.einsum("bcht,bthd->bchd", p, v.astype(jnp.float32))
+    o = jnp.einsum("bcht,bthd->bchd", p, v.astype(jnp.float32),
+                   precision=hi)
     o = (o / jnp.maximum(l, jnp.finfo(jnp.float32).tiny)).astype(q.dtype)
     return o[:, 0] if squeeze else o
 
@@ -274,22 +283,39 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
     return out[:, 0] if squeeze else out
 
 
+def paged_route(slots: int, impl: Optional[str] = None) -> str:
+    """Name of the implementation a ``[slots, ...]`` batch takes:
+    ``"paged_kernel"`` or ``"paged_reference"``. ``impl="reference"``
+    is the caller choosing the reference BY NAME — the decode engine
+    does under a mesh, because a Mosaic kernel has no SPMD partitioning
+    rule and must not be handed to GSPMD. ``impl=None`` lets the flags
+    decide: the ``use_pallas_kernels`` surface flash attention uses,
+    plus the ``paged_min_slots`` crossover read through the autotune
+    cache per device kind."""
+    from ...flags import effective_flag, pallas_enabled
+
+    if impl not in (None, "reference"):
+        raise ValueError(f"paged attention impl must be None or "
+                         f"'reference', got {impl!r}")
+    if impl is None and pallas_enabled() and \
+            slots >= int(effective_flag("paged_min_slots")):
+        return "paged_kernel"
+    return "paged_reference"
+
+
 def paged_attention(q, k_pages, v_pages, page_tables, kv_lens,
                     *, q_lens=None, scale: Optional[float] = None,
-                    interpret: Optional[bool] = None):
-    """Route between the Pallas kernel (TPU, or forced via
-    ``use_pallas_kernels=True`` in interpret mode for tests) and the
-    pure-jax reference — the same flags surface flash attention uses
-    (fluid/ops/attention_ops.py), with the ``paged_min_slots``
-    crossover read through the autotune cache per device kind (the
-    hard-coded always-kernel answer survives as the cold default).
-    ``q`` may be ``[B, Hq, D]`` (one token per slot) or
-    ``[B, C, Hq, D]`` with ``q_lens`` (a prefill chunk per slot,
+                    interpret: Optional[bool] = None,
+                    impl: Optional[str] = None):
+    """Route between the Pallas kernel (compiled on TPU; interpret mode
+    off-TPU when forced via ``use_pallas_kernels=True`` for tests) and
+    the pure-jax reference, as ``paged_route`` names it; every trace
+    counts its route. ``q`` may be ``[B, Hq, D]`` (one token per slot)
+    or ``[B, C, Hq, D]`` with ``q_lens`` (a prefill chunk per slot,
     causal within the chunk)."""
-    from ...flags import effective_flag, pallas_enabled, pallas_interpret
+    from ...flags import pallas_interpret
 
-    if pallas_enabled() and \
-            q.shape[0] >= int(effective_flag("paged_min_slots")):
+    if paged_route(q.shape[0], impl) == "paged_kernel":
         _m_route_kernel.inc()
         return _paged_attention_pallas(
             q, k_pages, v_pages, page_tables, kv_lens, q_lens=q_lens,

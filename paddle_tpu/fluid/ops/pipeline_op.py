@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ...jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..registry import exec_op_descs, register_op

@@ -1,30 +1,15 @@
-"""jax version shims. This image ships jax 0.4.37, where shard_map lives
-in jax.experimental and the replication-check kwarg is `check_rep`; newer
-jax exports `jax.shard_map` with `check_vma`. Callers import from here so
-one file owns the skew."""
+"""Thin helpers over the one installed JAX (0.9.0): named-axis mesh
+construction, collective counting over lowered text, and plain-dict
+views of XLA's cost and memory analyses."""
 from __future__ import annotations
-
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check_vma})
 
 
 def make_device_mesh(axes, devices=None):
-    """Named-axis device Mesh construction, one place for any topology
-    skew (ISSUE 15). ``axes``: ordered {name: size}. Uses the first
-    prod(sizes) devices when more are available (tier-1's virtual
-    8-device CPU mesh frequently outnumbers a 2-way test mesh); on TPU
-    prefers ``mesh_utils.create_device_mesh`` for ICI-aware ordering,
+    """Named-axis device Mesh construction (ISSUE 15). ``axes``: ordered
+    {name: size}. Uses the first prod(sizes) devices when more are
+    available (tier-1's virtual 8-device CPU mesh frequently outnumbers
+    a 2-way test mesh); on TPU ``mesh_utils.create_device_mesh`` orders
+    them by ICI topology and a topology it cannot lay out raises;
     off-TPU a plain reshape (virtual CPU devices have no topology).
     Typed error when devices run short."""
     import jax
@@ -41,13 +26,11 @@ def make_device_mesh(axes, devices=None):
             f"{len(devs)} — off-TPU set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N")
     devs = devs[:need]
-    if devices is None and devs and devs[0].platform == "tpu":
-        try:  # ICI-topology-aware ordering where the backend knows one
-            from jax.experimental import mesh_utils
+    if devices is None and devs[0].platform == "tpu":
+        from jax.experimental import mesh_utils
 
-            return Mesh(mesh_utils.create_device_mesh(shape), names)
-        except Exception:  # pragma: no cover - odd topologies fall back
-            pass
+        return Mesh(mesh_utils.create_device_mesh(shape, devices=devs),
+                    names)
     return Mesh(np.asarray(devs).reshape(shape), names)
 
 
@@ -80,21 +63,14 @@ def collective_counts(lowered_text: str) -> dict:
 
 
 def cost_analysis_dict(stage) -> dict:
-    """Normalize `.cost_analysis()` across jax versions and stage kinds.
-
-    On this image (jax 0.4.37) `Lowered.cost_analysis()` returns a flat
-    dict (and costs only an HLO walk — no XLA compile), while
-    `Compiled.cost_analysis()` returns a ONE-ELEMENT LIST of per-device
-    dicts; newer jax returns a dict from both. Returns {} when the
-    backend offers no analysis — callers treat cost accounting as
-    best-effort evidence, never a hard dependency.
-    """
+    """`.cost_analysis()` of a Lowered (an HLO walk, no XLA compile) or
+    Compiled stage as a {str: float} dict. Returns {} when the backend
+    offers no analysis — callers treat cost accounting as best-effort
+    evidence, never a hard dependency."""
     try:
         ca = stage.cost_analysis()
     except Exception:
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     if not isinstance(ca, dict):
         return {}
     return {str(k): float(v) for k, v in ca.items()

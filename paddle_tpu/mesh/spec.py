@@ -382,7 +382,9 @@ def _tree_map_named(tree, fn, prefix: str = ""):
 
 def shard_param_tree(tree, mesh, rules: ShardingRules):
     """device_put every leaf of a param tree per its name-matched rule
-    over ``mesh`` (a built jax Mesh). Indivisible dims replicate when
+    over ``mesh`` (a built jax Mesh). A host (numpy) leaf goes straight
+    to its shards — never whole onto one device — and a jax leaf moves
+    device to device. Indivisible dims replicate when
     ``rules.best_effort`` (else typed error naming the tensor) — the
     ParallelExecutor divisibility discipline applied to serving param
     trees. Returns the same tree structure with sharded jax arrays."""
@@ -401,8 +403,7 @@ def shard_param_tree(tree, mesh, rules: ShardingRules):
                 return False
         return True
 
-    def put(name, leaf):
-        arr = np.asarray(leaf)
+    def put(name, arr):
         spec = rules.spec_for(name, arr.ndim)
         for ax in _spec_axes(spec):
             if ax not in sizes:

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from ..jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -124,12 +124,9 @@ def _ring_fwd_pass(q, k, v, my, axis_name, causal, scale):
 def _ring_attention_impl(q, k, v, my_idx, axis_name, causal, scale):
     """custom_vjp core: ``my_idx`` is an int32[1] array carrying THIS
     shard's ring position. It is a primal input (zero float0 cotangent)
-    rather than ``lax.axis_index(axis_name)`` because axis_index lowers
-    to the ``partition-id`` HLO, which jax-0.4.37's CPU SPMD partitioner
-    rejects whenever jaxpr DCE leaves it alive ("PartitionId instruction
-    is not supported for SPMD partitioning") — the skew that aborted the
-    dryrun's ring phases. A sharded-iota input says the same thing in
-    data, which every backend partitions."""
+    rather than ``lax.axis_index(axis_name)``: a sharded-iota input says
+    the same thing in data, so the shard's position is an ordinary
+    sharded operand and no ``partition-id`` HLO is involved."""
     out, _ = _ring_fwd_pass(q, k, v, my_idx[0], axis_name, causal, scale)
     return out
 
@@ -142,9 +139,8 @@ def ring_attention_shard(q, k, v, axis_name=None, causal=False,
 
     ``my_idx`` (int32[1]): this shard's ring position, normally threaded
     in by ``sequence_parallel_attention`` as a P(seq_axis)-sharded iota.
-    Direct shard_map users on newer jax may omit it (falls back to
-    ``lax.axis_index`` — fine there, but that path lowers to the
-    partition-id HLO the 0.4.x CPU partitioner rejects)."""
+    Direct shard_map users may omit it (falls back to
+    ``lax.axis_index``)."""
     scale = float(scale) if scale is not None else q.shape[-1] ** -0.5
     if my_idx is None:
         if axis_name is None:
@@ -253,8 +249,7 @@ def sequence_parallel_attention(
     spec = P(batch_axis, seq_axis, head_axis, None)
     # the ring index rides in as DATA: a P(seq_axis)-sharded iota hands
     # each shard its own position, so the body never calls
-    # lax.axis_index (whose partition-id lowering the jax-0.4.x CPU
-    # SPMD partitioner rejects — the `PartitionId` dryrun skew)
+    # lax.axis_index
     n_sp = dict(zip(mesh.axis_names, mesh.devices.shape))[seq_axis]
     ring_idx = jnp.arange(n_sp, dtype=jnp.int32)
     fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec, P(seq_axis)),

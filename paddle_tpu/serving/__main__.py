@@ -19,8 +19,9 @@
         serving smoke.
 
     python -m paddle_tpu.serving --serve --load m=/path/to/model_dir
-        Operator mode: start a ServingServer, load the named model
-        directories, print the address, and serve until interrupted.
+        Operator mode: start a ServingServer on the backend JAX gives
+        (the TPU where there is one), load the named model directories,
+        print the address and the device, and serve until interrupted.
 """
 from __future__ import annotations
 
@@ -31,16 +32,14 @@ import tempfile
 
 
 def _force_cpu():
-    """The selftest must not require (or try to dial) a TPU: pin the jax
-    platform before any backend initialization, the same way
+    """The selftest (and only the selftest) pins the CPU: it proves
+    control flow at toy sizes and must not take a chip from a server.
+    Pinned before any backend initialization, the same way
     tests/conftest.py and the analysis CLI do."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    try:
-        import jax
+    import jax
 
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
+    jax.config.update("jax_platforms", "cpu")
 
 
 def make_model_dir(dirname: str, scale: float = 1.0, feature_dim: int = 8,
@@ -534,6 +533,40 @@ def run_selftest(verbose: bool = True) -> int:
     return 0
 
 
+def serve(host: str, port: int, loads) -> int:
+    """Operator mode, on the backend JAX gives: nothing here pins a
+    platform, and the printed line says which one serves."""
+    import time
+
+    import jax
+
+    from . import InferenceEngine, ServingServer
+
+    dev = jax.devices()[0]
+    srv = ServingServer()
+    host, port = srv.serve(host, port)
+    for spec in loads:
+        name, _, dirname = spec.partition("=")
+        if not dirname:
+            print(f"bad --load {spec!r} (want NAME=DIR)")
+            srv.shutdown()
+            return 2
+        eng = srv.registry.deploy(
+            name,
+            lambda d=dirname, n=name:
+                InferenceEngine.from_inference_dir(d, name=n))
+        print(f"loaded {name} v{eng.version} from {dirname}")
+    print(f"serving on {host}:{port} platform={dev.platform} "
+          f"device_kind={dev.device_kind!r} "
+          f"devices={len(jax.devices())} (ctrl-c to stop)", flush=True)
+    try:
+        while True:
+            time.sleep(3600)
+    except KeyboardInterrupt:
+        srv.shutdown()
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="python -m paddle_tpu.serving")
     ap.add_argument("--selftest", action="store_true",
@@ -547,32 +580,10 @@ def main(argv=None) -> int:
                     help="model(s) to load at startup (repeatable)")
     args = ap.parse_args(argv)
 
-    _force_cpu()
     if args.serve:
-        from . import InferenceEngine, ServingServer
-
-        srv = ServingServer()
-        host, port = srv.serve(args.host, args.port)
-        for spec in args.load:
-            name, _, dirname = spec.partition("=")
-            if not dirname:
-                print(f"bad --load {spec!r} (want NAME=DIR)")
-                return 2
-            eng = srv.registry.deploy(
-                name,
-                lambda d=dirname, n=name:
-                    InferenceEngine.from_inference_dir(d, name=n))
-            print(f"loaded {name} v{eng.version} from {dirname}")
-        print(f"serving on {host}:{port} (ctrl-c to stop)")
-        try:
-            import time
-
-            while True:
-                time.sleep(3600)
-        except KeyboardInterrupt:
-            srv.shutdown()
-        return 0
+        return serve(args.host, args.port, args.load)
     # default: selftest
+    _force_cpu()
     return run_selftest()
 
 

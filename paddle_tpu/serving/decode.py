@@ -93,8 +93,9 @@ from .errors import (DeadlineExceeded, EngineRetired, RequestTooLarge,
 from .kv_cache import GARBAGE_PAGE, HostSpillStore, PagedKvCache
 
 __all__ = ["DecoderSpec", "DecodeEngine", "build_decoder_params",
-           "decoder_step", "decoder_step_chunked", "width_ladder",
-           "sample_token", "validate_draft_spec"]
+           "seeded_decoder_arrays", "decoder_step",
+           "decoder_step_chunked", "width_ladder", "sample_token",
+           "validate_draft_spec"]
 
 _log = get_logger("serving")
 
@@ -243,36 +244,42 @@ def validate_draft_spec(target: DecoderSpec, draft: DecoderSpec):
             f"tokens, so the specs must agree on it")
 
 
-def build_decoder_params(spec: DecoderSpec) -> Dict[str, Any]:
-    """Deterministic parameter tree (seeded numpy draws, scaled-normal
-    init) — the test/bench stand-in for loading a checkpoint."""
-    import jax.numpy as jnp
-
+def seeded_decoder_arrays(spec: DecoderSpec) -> Dict[str, Any]:
+    """The deterministic parameter tree as HOST numpy arrays (seeded
+    draws, scaled-normal init). Whoever serves it places it: the
+    engine puts each leaf straight onto its shard of a mesh, so no
+    tensor is first materialized whole on one chip."""
     rng = np.random.RandomState(spec.seed)
     dm, dh = spec.d_model, spec.head_dim
 
     def mat(fan_in, *shape):
-        return jnp.asarray(
-            (rng.randn(*shape) / math.sqrt(fan_in)).astype(np.float32))
+        return (rng.randn(*shape) / math.sqrt(fan_in)).astype(np.float32)
 
-    params: Dict[str, Any] = {
-        "tok_emb": mat(dm, spec.vocab, dm),
-        "lnf": (jnp.ones((dm,), jnp.float32), jnp.zeros((dm,), jnp.float32)),
-    }
+    def ln():
+        return (np.ones((dm,), np.float32), np.zeros((dm,), np.float32))
+
+    params: Dict[str, Any] = {"tok_emb": mat(dm, spec.vocab, dm),
+                              "lnf": ln()}
     for l in range(spec.n_layers):
         params[f"layer{l}"] = {
-            "ln1": (jnp.ones((dm,), jnp.float32),
-                    jnp.zeros((dm,), jnp.float32)),
+            "ln1": ln(),
             "wq": mat(dm, dm, spec.n_heads * dh),
             "wk": mat(dm, dm, spec.n_kv_heads * dh),
             "wv": mat(dm, dm, spec.n_kv_heads * dh),
             "wo": mat(dm, spec.n_heads * dh, dm),
-            "ln2": (jnp.ones((dm,), jnp.float32),
-                    jnp.zeros((dm,), jnp.float32)),
+            "ln2": ln(),
             "w1": mat(dm, dm, 4 * dm),
             "w2": mat(4 * dm, 4 * dm, dm),
         }
     return params
+
+
+def build_decoder_params(spec: DecoderSpec) -> Dict[str, Any]:
+    """``seeded_decoder_arrays`` on the default device — the test/bench
+    stand-in for loading a checkpoint."""
+    import jax
+
+    return jax.device_put(seeded_decoder_arrays(spec))
 
 
 def _ln(x, gb):
@@ -298,7 +305,8 @@ def _pos_encoding(positions, d_model):
 def decoder_step_chunked(params, spec: DecoderSpec, tokens, positions,
                          q_lens, k_pool, v_pool, page_tables, kv_lens,
                          all_lanes: bool = False,
-                         return_hidden: bool = False):
+                         return_hidden: bool = False,
+                         attention_impl: Optional[str] = None):
     """ONE mixed decode/prefill step for a fixed-slot batch
     (ISSUE 10). Each slot carries up to C tokens of ITS sequence — a
     prefill chunk, a single decode token at C lane 0, or nothing —
@@ -335,6 +343,10 @@ def decoder_step_chunked(params, spec: DecoderSpec, tokens, positions,
     every lane's pooled-representation input and its next-token
     distribution (per-token logprobs), so prompt-only scoring requests
     ride the exact prefill path generation uses.
+
+    ``attention_impl`` is handed to ``paged_attention`` as ``impl``:
+    None lets the flags route, ``"reference"`` names the pure-jax path
+    (the engine does under a mesh).
     """
     import jax
     import jax.numpy as jnp
@@ -369,7 +381,8 @@ def decoder_step_chunked(params, spec: DecoderSpec, tokens, positions,
         k_pool = k_pool.at[l, page, off].set(k.astype(k_pool.dtype))
         v_pool = v_pool.at[l, page, off].set(v.astype(v_pool.dtype))
         attn = paged_attention(q, k_pool[l], v_pool[l], page_tables,
-                               kv_lens, q_lens=q_lens)
+                               kv_lens, q_lens=q_lens,
+                               impl=attention_impl)
         x = x + attn.reshape(b, c, spec.n_heads * dh) @ lp["wo"]
         h2 = _ln(x, lp["ln2"])
         x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
@@ -665,13 +678,15 @@ class DecodeEngine:
             note_mesh(self._mesh, label=f"decode:{name}.v{version}")
         # shares _step_mu with the compiled step + shape set: the lock
         # serializes every read-step-rebind against retirement's drop
-        self._params = (build_decoder_params(spec)
-                        if params is None else params)  # guarded-by: _step_mu
-        if self._mesh is not None:
-            from ..mesh import shard_param_tree
-
-            self._params = shard_param_tree(self._params, self._mesh,
-                                            self._mesh_rules)
+        self._params = self._place_params(
+            seeded_decoder_arrays(spec)
+            if params is None else params)  # guarded-by: _step_mu
+        # the Pallas paged kernel has no SPMD form (a shard_map form is
+        # ROADMAP S5), so an engine whose pools are sharded over a mesh
+        # names the pure-jax reference itself; single-chip engines let
+        # the flags route. stats()/load_report show the result.
+        self._attention_impl = ("reference" if self._mesh is not None
+                                else None)
         # slots="auto" resolves through the tuner exactly like the
         # one-shot engine's buckets="auto": a derived ladder from the
         # observed slot-demand histogram (or the cached one), else the
@@ -680,6 +695,16 @@ class DecodeEngine:
             FLAGS["decode_slots"] if slots is None else slots,
             tunable_id="decode_slots", fallback="1,2,4")
         self._max_slots = self._slot_ladder[-1]
+        from ..fluid.ops.pallas_kernels.paged_attention import paged_route
+
+        self._attention_routes = sorted(
+            {paged_route(n, self._attention_impl)
+             for n in self._slot_ladder})
+        if self._mesh is not None:
+            _log.info("decode %s.v%d spans mesh %s: attention takes %s "
+                      "(the Pallas paged kernel has no SPMD form)",
+                      self.name, self.version, self._mesh_spec,
+                      ",".join(self._attention_routes))
         ps = int(FLAGS["kv_page_size"] if page_size is None else page_size)
         npages = int(FLAGS["kv_num_pages"] if num_pages is None
                      else num_pages)
@@ -777,15 +802,10 @@ class DecodeEngine:
             # shadows
             self._draft_chunk_ladder = sorted(
                 {1, 2, self._prefill_chunk})
-            self._draft_params = (
-                build_decoder_params(draft_spec)
+            self._draft_params = self._place_params(
+                seeded_decoder_arrays(draft_spec)
                 if draft_params is None
                 else draft_params)  # guarded-by: _step_mu
-            if self._mesh is not None:
-                from ..mesh import shard_param_tree
-
-                self._draft_params = shard_param_tree(
-                    self._draft_params, self._mesh, self._mesh_rules)
             self._draft_cache = PagedKvCache(
                 draft_spec.n_layers, draft_spec.n_kv_heads,
                 draft_spec.head_dim, page_size=ps, num_pages=npages,
@@ -826,12 +846,14 @@ class DecodeEngine:
         import jax
 
         spec_ref = spec  # closed over; jit retraces only on shape change
+        impl = self._attention_impl
 
         def _step(params, tokens, positions, q_lens, k_pool, v_pool,
                   tables, lens):
             return decoder_step_chunked(params, spec_ref, tokens,
                                         positions, q_lens, k_pool,
-                                        v_pool, tables, lens)
+                                        v_pool, tables, lens,
+                                        attention_impl=impl)
 
         # donate the pools on TPU so XLA updates the KV pages in place
         # (HBM footprint stays the preallocated pool); CPU ignores
@@ -867,13 +889,15 @@ class DecodeEngine:
                 return decoder_step_chunked(params, spec_ref, tokens,
                                             positions, q_lens, k_pool,
                                             v_pool, tables, lens,
-                                            all_lanes=True)
+                                            all_lanes=True,
+                                            attention_impl=impl)
 
             def _draft(params, tokens, positions, q_lens, k_pool,
                        v_pool, tables, lens):
                 return decoder_step_chunked(params, draft_ref, tokens,
                                             positions, q_lens, k_pool,
-                                            v_pool, tables, lens)
+                                            v_pool, tables, lens,
+                                            attention_impl=impl)
 
             _sharded_kw = ({"out_shardings": step_out_shardings}
                            if step_out_shardings is not None else {})
@@ -895,7 +919,8 @@ class DecodeEngine:
                                             positions, q_lens, k_pool,
                                             v_pool, tables, lens,
                                             all_lanes=True,
-                                            return_hidden=True)
+                                            return_hidden=True,
+                                            attention_impl=impl)
 
             embed_out = None
             if step_out_shardings is not None:
@@ -963,6 +988,18 @@ class DecodeEngine:
     def mesh_spec(self):
         """The MeshSpec this engine spans (None = single-chip)."""
         return self._mesh_spec
+
+    def _place_params(self, tree):
+        """Put a param tree (host numpy or jax arrays) where this
+        engine computes: each leaf straight onto its shards of the mesh
+        by its name-matched rule, or onto the one default device."""
+        if self._mesh is not None:
+            from ..mesh import shard_param_tree
+
+            return shard_param_tree(tree, self._mesh, self._mesh_rules)
+        import jax
+
+        return jax.device_put(tree)
 
     @staticmethod
     def _kv_pool_axes(rules):
@@ -1450,6 +1487,7 @@ class DecodeEngine:
                 "spec_k": self._spec_k,
                 "mesh": (dict(self._mesh_spec.axes)
                          if self._mesh_spec is not None else None),
+                "attention_route": self._attention_routes,
                 "draft": (self._draft_spec.to_dict()
                           if self._draft_spec is not None else None),
                 "prefix_cache": self._prefix_on,

@@ -931,22 +931,21 @@ class PagedKvCache:
         self.dtype = jnp.float32 if dtype is None else dtype
         shape = (self.num_layers, int(num_pages), int(page_size),
                  self.num_kv_heads, self.head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
         # mesh-sharded pools (ISSUE 15): one decode replica spans chips
         # with the pool sharded over the kv-head axis — hbm_bytes stays
         # the GLOBAL budget, each chip holds 1/|axis| of it. `sharding`
         # is the pinned NamedSharding every rebind conforms to, so a
         # page-move helper's output can never drift the step's input
-        # sharding (which would mint a post-warm compile).
+        # sharding (which would mint a post-warm compile). The pools
+        # are BORN sharded: a pool sized for the mesh would not fit the
+        # one device a plain jnp.zeros materializes it on.
         self.sharding = None
         if mesh is not None and shard_spec is not None:
-            import jax
             from jax.sharding import NamedSharding
 
             self.sharding = NamedSharding(mesh, shard_spec)
-            self.k = jax.device_put(self.k, self.sharding)
-            self.v = jax.device_put(self.v, self.sharding)
+        self.k = jnp.zeros(shape, self.dtype, device=self.sharding)
+        self.v = jnp.zeros(shape, self.dtype, device=self.sharding)
 
     @property
     def page_size(self) -> int:

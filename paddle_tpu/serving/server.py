@@ -636,6 +636,10 @@ class ServingServer:
                 # replicas are multi-chip after a partial rollout
                 if st.get("mesh"):
                     entry["mesh"] = st["mesh"]
+                # which paged-attention implementation the compiled
+                # steps take — a mesh-spanning engine names the
+                # reference, and that must be visible, not inferred
+                entry["attention_route"] = st["attention_route"]
                 # prefix-cache warmth (ISSUE 13): the MRU depth-1
                 # chain digests let a FleetRouter recognize a replica
                 # whose cache already covers a request's prefix —

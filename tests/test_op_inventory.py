@@ -1,4 +1,4 @@
-"""Op-inventory parity gate (VERDICT r2 item 7): diff the reference's
+"""Op-inventory parity gate (round-2 review item 7): diff the reference's
 REGISTER_OP list (snapshot: tools/reference_op_inventory.txt, extracted from
 /root/reference/paddle/fluid/operators REGISTER_OP* macros, grad ops
 excluded) against this registry. Every gap must be on the explicit,

@@ -2,7 +2,7 @@
 send_op.cc, recv_op.cc, test_recv_op.py:26): the pserver program produced
 by DistributeTranspiler.get_pserver_program actually RUNS behind RPC, with
 trainer-side send/recv ops the Executor executes as host ops around the
-jitted step. Includes the 2-process localhost async-SGD test (VERDICT r2
+jitted step. Includes the 2-process localhost async-SGD test (round-2 review
 item 3's done-bar)."""
 import os
 import socket
@@ -594,7 +594,7 @@ _EMB_PSERVER_PROC = textwrap.dedent("""
 
 
 def test_two_process_distributed_embedding_prefetch():
-    """VERDICT r3 item 3's done-bar: a separate-process pserver owns a
+    """round-3 review item 3's done-bar: a separate-process pserver owns a
     100k-vocab table; the trainer pulls ONLY the batch's rows (prefetch op)
     and pushes SelectedRows grads back; traffic is proportional to batch
     ids, never to the table; loss decreases."""
@@ -700,7 +700,7 @@ _BIG_TRAINER_PROC = textwrap.dedent("""
 
 
 def test_four_trainer_processes_16mb_sync_rounds():
-    """VERDICT r3 item 4's done-bar: four trainer PROCESSES push a 16.8 MB
+    """round-3 review item 4's done-bar: four trainer PROCESSES push a 16.8 MB
     dense grad each, sync rounds merge all four, and the binary framing
     moves it at wire speed (bytes/s reported and sanity-gated)."""
     port = _free_ports(1)[0]

@@ -141,7 +141,7 @@ def test_open_files_multi_shard(tmp_path):
 
 def test_double_buffer_overlaps_decode(tmp_path):
     """The async contract: with a slow decoder, double_buffer hides decode
-    time behind consumer time (VERDICT r2 item 2's 'done' bar, scaled to a
+    time behind consumer time (round-2 review item 2's 'done' bar, scaled to a
     unit test)."""
     from paddle_tpu.fluid.readers import DoubleBufferReader, HostReader
 

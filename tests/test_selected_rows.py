@@ -120,7 +120,7 @@ def test_sparse_grad_is_selected_rows_in_ir_and_at_runtime():
 
 def test_large_vocab_word2vec_style_training():
     """100k-vocab embedding trains sparse: grad stays [N, D] and loss drops
-    (VERDICT item 3's acceptance bar — no dense [V, D] materialization on the
+    (review item 3's acceptance bar — no dense [V, D] materialization on the
     grad path)."""
     V, D, N = 100_000, 64, 64
     prog, startup = Program(), Program()

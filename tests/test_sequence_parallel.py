@@ -189,9 +189,8 @@ def test_ring_attention_layer_parallel_executor():
 
 
 def test_transformer_seq_parallel_trains():
-    # un-gated: the ring shard index now rides in as a P(sp)-sharded
-    # iota input instead of lax.axis_index, so no partition-id HLO
-    # reaches the jax-0.4.x CPU SPMD partitioner (PR 14 shim)
+    # the ring shard index rides in as a P(sp)-sharded iota input
+    # instead of lax.axis_index (PR 14)
     """Flagship model with seq_parallel=True on a dp x sp mesh: loss
     decreases over steps (capability: long-context sharded attention)."""
     import paddle_tpu.fluid as fluid

@@ -224,7 +224,12 @@ def run_fleet_soak(seed: int, smoke: bool, out: str | None,
 
     if REPO not in sys.path:  # `python tools/chaos_soak.py` from anywhere
         sys.path.insert(0, REPO)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # a CPU soak by construction: this parent initialises a backend
+    # (it builds and saves the decoder) and THEN spawns replica
+    # processes. A chip belongs to one process, so on a TPU host the
+    # children would fail or hang behind the parent — the soak tests
+    # the control plane at toy sizes, and says which platform it ran on
+    os.environ["JAX_PLATFORMS"] = "cpu"
     work = tempfile.mkdtemp(prefix="fleet_soak_")
     os.environ["PADDLE_TPU_FLEET_KEY"] = f"soak-key-{seed}"
     os.environ["PADDLE_TPU_FLEET_ALLOW"] = work
@@ -254,7 +259,8 @@ def run_fleet_soak(seed: int, smoke: bool, out: str | None,
 
     checks: list = []
     evidence: dict = {"bench": "fleet_soak", "seed": seed,
-                      "smoke": bool(smoke), "phases": {}}
+                      "platform": "cpu", "smoke": bool(smoke),
+                      "phases": {}}
 
     def check(name: str, ok, detail=""):
         checks.append({"name": name, "ok": bool(ok),

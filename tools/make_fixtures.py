@@ -1,5 +1,5 @@
 """Generate the tiny byte-GENUINE data fixtures under tests/fixtures/
-(VERDICT r4 item 2): real wire formats — gzipped IDX with the 0x803/0x801
+(round-4 review item 2): real wire formats — gzipped IDX with the 0x803/0x801
 magics, a cifar python-pickle tarball, an aclImdb tar fragment, a wmt
 sentence-pair tgz — so the real-format parsers are exercised by CI on
 actual bytes, not synthetic fallbacks.
