@@ -78,6 +78,7 @@ def _fused_gemm(p, wt, scale, shift, relu, block_m, block_f, interpret):
         out_specs=pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, f), p.dtype),
         interpret=interpret,
+        name="conv_bn_relu",
     )(p, wt, scale.reshape(1, f), shift.reshape(1, f))
     return y[:m_real, :f_real]
 

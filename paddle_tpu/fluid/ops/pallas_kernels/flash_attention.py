@@ -125,6 +125,7 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((bh, sq_pad, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return out[:, :sq], lse[:, :sq, 0]
 
@@ -314,6 +315,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, precision,
             jax.ShapeDtypeStruct((bh, sk_pad, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkdv",
     )(q, dout, lse, delta, k, v)
     dk, dv = dkdv
 
@@ -333,6 +335,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, precision,
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq_pad, d), q.dtype),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(k, v, q, dout, lse, delta)
 
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]

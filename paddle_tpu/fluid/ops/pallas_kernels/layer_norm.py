@@ -61,6 +61,7 @@ def _ln_pallas(x, scale, bias, eps, block_rows, interpret):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="layer_norm",
     )(x, scale.reshape(1, f), bias.reshape(1, f))
     return y[:n_real], mean[:n_real, 0], rstd[:n_real, 0]
 

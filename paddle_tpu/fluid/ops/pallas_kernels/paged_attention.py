@@ -279,6 +279,8 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c, hq, d), q.dtype),
         interpret=interpret,
+        # the kernel's name in the compiled program and a device trace
+        name="paged_attention",
     )(tables, kv_l, q_l, q, k_pages, v_pages)
     return out[:, 0] if squeeze else out
 

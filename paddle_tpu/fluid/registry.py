@@ -159,10 +159,14 @@ def exec_op_descs(ctx: EmitCtx, op_descs, env: Dict[str, Any],
             slot: [env.get(n) if n else None for n in names]
             for slot, names in od.inputs.items()
         }
-        if od.type.endswith("_grad") and FWD_META_ATTR in od.attrs:
-            outs = run_grad(ctx, ins, od.attrs)
-        else:
-            outs = run_forward(ctx, od.type, ins, od.attrs)
+        # every device operation this op emits carries its Fluid type in
+        # its op_name (conv2d, batch_norm_grad, momentum...): trace-time
+        # metadata, read off a device trace
+        with jax.named_scope(od.type):
+            if od.type.endswith("_grad") and FWD_META_ATTR in od.attrs:
+                outs = run_grad(ctx, ins, od.attrs)
+            else:
+                outs = run_forward(ctx, od.type, ins, od.attrs)
         for slot, names in od.outputs.items():
             vals = outs.get(slot, [])
             for i, n in enumerate(names):

@@ -8,10 +8,22 @@ and chrome FLOW events ("ph": "s"/"f") link a client RPC span to its
 server handler span so Perfetto draws the client→server arrow across
 process boundaries.
 
+Two clocks at once (ISSUE 27): whenever a `jax.profiler` session is
+collecting (`jax.profiler.start_trace`, or a capture through the
+profiler server), every `span()` also opens a
+`jax.profiler.TraceAnnotation` of the same name with the same args —
+ring enabled or not — so the program's host spans land on the
+profiler's clock, on their thread's line beside the device planes, and
+a device idle gap can be laid against what the host was doing. The
+ring keeps its own `perf_counter` epoch (one pair of clock reads a
+span, as before); the annotation is timed by the profiler. Nobody
+tells the program that a session started: `span()` asks the profiler's
+own trace level (`TraceAnnotation.is_enabled`, one atomic load).
+
 Design constraints:
   - Near-zero cost when disabled: `span()` checks one module-level bool
-    and returns a shared no-op context manager; no allocation, no clock
-    read, no lock, no id minting.
+    and the profiler's trace level and returns a shared no-op context
+    manager; no allocation, no clock read, no lock, no id minting.
   - Thread-safe when enabled: each completed span appends ONE tuple to a
     `collections.deque(maxlen=...)` — an atomic operation under the GIL,
     so concurrent executor / RPC handler / reader worker threads never
@@ -48,6 +60,7 @@ import collections
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -105,6 +118,29 @@ _clock_offset = None  # type: Optional[float]
 from . import metrics as _metrics  # noqa: E402
 
 _g_dropped = _metrics.gauge("tracing.dropped_spans")
+
+
+# the profiler's side of span(): jax.profiler.TraceAnnotation and its
+# is_enabled (true while a profiler session collects), bound the first
+# time span() runs with jax imported. This module imports no jax itself
+# (observability stays importable before it); until jax is in
+# sys.modules no session can exist.
+_annotation = None
+
+
+def _session_unbound() -> bool:
+    """Stand-in for ``TraceAnnotation.is_enabled`` until jax is there."""
+    global _annotation, _session_active
+    if "jax" not in sys.modules:
+        return False
+    from jax.profiler import TraceAnnotation
+
+    _annotation = TraceAnnotation
+    _session_active = TraceAnnotation.is_enabled
+    return _session_active()
+
+
+_session_active = _session_unbound
 
 
 def _env_flag(name: str, default: str = "0") -> bool:
@@ -188,6 +224,7 @@ class _NullSpan:
     nothing, `set_arg` swallows; one instance serves every call site."""
 
     __slots__ = ()
+    live = False  # nothing records: a site skips working out its args
 
     def __enter__(self):
         return self
@@ -203,22 +240,34 @@ _NULL_SPAN = _NullSpan()
 
 
 class Span:
-    """RAII host span. Records a complete event at __exit__ — begin time,
-    duration, thread id, trace context, and optional args — into the ring
-    buffer. While open it is its thread's current span: child spans (and
-    wire_context()) read their parent from it."""
+    """RAII host span, on up to two clocks. ``ring``: records a complete
+    event at __exit__ — begin time, duration, thread id, trace context,
+    and optional args — into the ring buffer, and while open is its
+    thread's current span: child spans (and wire_context()) read their
+    parent from it. ``annotate``: a profiler session is collecting, so
+    the span is also a ``jax.profiler.TraceAnnotation`` of the same name
+    and args (it encloses the ring's interval)."""
 
-    __slots__ = ("name", "args", "_t0", "_prev",
+    __slots__ = ("name", "args", "_t0", "_prev", "_ring", "_ann",
                  "trace_id", "span_id", "parent_id")
+    live = True
 
-    def __init__(self, name: str, args: Optional[Dict[str, Any]] = None):
+    def __init__(self, name: str, args: Optional[Dict[str, Any]] = None,
+                 ring: bool = True, annotate: bool = False):
         self.name = name
         self.args = args
         self._t0 = 0.0
         self._prev = None
+        self._ring = ring
+        self._ann = (_annotation(name, **(args or {})) if annotate
+                     else None)
         self.trace_id = self.span_id = self.parent_id = None
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        if not self._ring:
+            return self
         parent = getattr(_tls, "span", None)
         self._prev = parent
         if parent is not None:
@@ -238,35 +287,43 @@ class Span:
     # per finished span, GIL-atomic by design (see module docstring); _mu
     # here would serialize every instrumented thread on every span
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        _tls.span = self._prev
-        if len(_buf) == _buf.maxlen:
-            _note_drop()
-        _buf.append((
-            self.name,
-            (self._t0 - _EPOCH) * 1e6,      # ts, µs
-            (t1 - self._t0) * 1e6,          # dur, µs
-            threading.get_ident(),
-            self.args,
-            (self.trace_id, self.span_id, self.parent_id),
-        ))
+        if self._ring:
+            t1 = time.perf_counter()
+            _tls.span = self._prev
+            if len(_buf) == _buf.maxlen:
+                _note_drop()
+            _buf.append((
+                self.name,
+                (self._t0 - _EPOCH) * 1e6,      # ts, µs
+                (t1 - self._t0) * 1e6,          # dur, µs
+                threading.get_ident(),
+                self.args,
+                (self.trace_id, self.span_id, self.parent_id),
+            ))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
     def set_arg(self, key, value):
         if self.args is None:
             self.args = {}
         self.args[key] = value
+        if self._ann is not None:
+            self._ann.set_metadata(**{key: value})
 
 
 def span(name: str, **args):
     """`with span("executor.step", step=3): ...` — the one tracing entry
-    point every instrumented layer uses. Disabled: one bool check, a
-    shared no-op object, and (unavoidably) the kwargs dict the caller
-    built; hot paths that can't afford even that should guard with
-    `if trace_enabled():`."""
-    if not _enabled:
+    point every instrumented layer uses; the span lands in the ring
+    (when enabled) and on the profiler's clock (while a session
+    collects). Neither: one bool check, one read of the profiler's trace
+    level, a shared no-op object, and (unavoidably) the kwargs dict the
+    caller built; a site whose args cost something to work out opens the
+    span bare and sets them `if sp.live:`."""
+    annotate = _session_active()
+    if not (_enabled or annotate):
         return _NULL_SPAN
-    return Span(name, args or None)
+    return Span(name, args or None, _enabled, annotate)
 
 
 def current_span() -> Optional[Span]:

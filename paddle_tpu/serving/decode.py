@@ -111,6 +111,16 @@ _m_cancels = _metrics.counter("serving.decode.cancels")
 # warm() this must never move again (the tier-1 churn guard pins it)
 _m_compiles = _metrics.counter("serving.decode.compiles")
 _m_step_ms = _metrics.histogram("serving.decode.step_ms")
+# the rest of a scheduler round on the host clock (ISSUE 27), one
+# observation a step each: sample_ms is the host-side choice of tokens
+# (the serving.decode.sample spans, summed over the step's slots);
+# sched_ms is whatever of the round is neither step_ms's stretch, nor
+# sampling, nor the wait for work — admit + prepare + the answer phase
+# without sampling, and the hand-over of the interpreter lock to the
+# clients a step wakes. A round runs from the last one's end (or from
+# the end of a wait for work), so the three add up to the step period.
+_m_sample_ms = _metrics.histogram("serving.decode.sample_ms")
+_m_sched_ms = _metrics.histogram("serving.decode.sched_ms")
 _m_queue_wait = _metrics.histogram("serving.decode.queue_wait_ms")
 _m_total = _metrics.histogram("serving.decode.total_ms")
 # live slots / slot bucket per step: the continuous-batching win is
@@ -356,52 +366,62 @@ def decoder_step_chunked(params, spec: DecoderSpec, tokens, positions,
     b, c = tokens.shape
     ps = k_pool.shape[2]
     dm, dh = spec.d_model, spec.head_dim
-    lane = jnp.arange(c)[None, :]                      # [1, C]
-    valid = lane < q_lens[:, None]                     # [B, C]
-    x = params["tok_emb"][tokens] * math.sqrt(dm) + \
-        _pos_encoding(positions.reshape(-1), dm).reshape(b, c, dm)
-    page_idx = positions // ps
-    # each lane's physical page: its slot's table row at the token's
-    # page index. Invalid lanes (j >= q_len, padded dead slots) are
-    # FORCED to the garbage page — a live slot's row 0 must never be
-    # clobbered by a dead lane's position-0 write
-    page = jnp.where(valid,
-                     jnp.take_along_axis(page_tables, page_idx, axis=1),
-                     GARBAGE_PAGE)                     # [B, C]
-    off = jnp.where(valid, positions % ps, 0)
+    # the jax.named_scope blocks name the step's device work by role
+    # (ISSUE 27): every operation's op_name in the compiled program, and
+    # so in a device trace, carries decoder.embed / .kv_write / .attn /
+    # .mlp / .head. Trace-time metadata only
+    with jax.named_scope("decoder.embed"):
+        lane = jnp.arange(c)[None, :]                      # [1, C]
+        valid = lane < q_lens[:, None]                     # [B, C]
+        x = params["tok_emb"][tokens] * math.sqrt(dm) + \
+            _pos_encoding(positions.reshape(-1), dm).reshape(b, c, dm)
+        page_idx = positions // ps
+        # each lane's physical page: its slot's table row at the
+        # token's page index. Invalid lanes (j >= q_len, padded dead
+        # slots) are FORCED to the garbage page — a live slot's row 0
+        # must never be clobbered by a dead lane's position-0 write
+        page = jnp.where(
+            valid, jnp.take_along_axis(page_tables, page_idx, axis=1),
+            GARBAGE_PAGE)                                  # [B, C]
+        off = jnp.where(valid, positions % ps, 0)
     for l in range(spec.n_layers):
         lp = params[f"layer{l}"]
-        h = _ln(x, lp["ln1"])
-        q = (h @ lp["wq"]).reshape(b, c, spec.n_heads, dh)
-        k = (h @ lp["wk"]).reshape(b, c, spec.n_kv_heads, dh)
-        v = (h @ lp["wv"]).reshape(b, c, spec.n_kv_heads, dh)
+        with jax.named_scope("decoder.attn"):
+            h = _ln(x, lp["ln1"])
+            q = (h @ lp["wq"]).reshape(b, c, spec.n_heads, dh)
+            k = (h @ lp["wk"]).reshape(b, c, spec.n_kv_heads, dh)
+            v = (h @ lp["wv"]).reshape(b, c, spec.n_kv_heads, dh)
         # write the whole chunk's K/V, THEN attend: within the chunk,
         # query j sees keys i <= j of the same chunk — write-before-
         # attend makes the chunk exactly equal to sequential steps
-        k_pool = k_pool.at[l, page, off].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[l, page, off].set(v.astype(v_pool.dtype))
-        attn = paged_attention(q, k_pool[l], v_pool[l], page_tables,
-                               kv_lens, q_lens=q_lens,
-                               impl=attention_impl)
-        x = x + attn.reshape(b, c, spec.n_heads * dh) @ lp["wo"]
-        h2 = _ln(x, lp["ln2"])
-        x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
-    if all_lanes:
-        # verify form: every lane's logits ([B, C, vocab]) — the
-        # acceptance walk needs the target's distribution at each
-        # proposed position, not just the newest
-        h = _ln(x, params["lnf"])
-        logits = h @ params["tok_emb"].T
-        if return_hidden:
-            return k_pool, v_pool, logits, h
+        with jax.named_scope("decoder.kv_write"):
+            k_pool = k_pool.at[l, page, off].set(k.astype(k_pool.dtype))
+            v_pool = v_pool.at[l, page, off].set(v.astype(v_pool.dtype))
+        with jax.named_scope("decoder.attn"):
+            attn = paged_attention(q, k_pool[l], v_pool[l], page_tables,
+                                   kv_lens, q_lens=q_lens,
+                                   impl=attention_impl)
+            x = x + attn.reshape(b, c, spec.n_heads * dh) @ lp["wo"]
+        with jax.named_scope("decoder.mlp"):
+            h2 = _ln(x, lp["ln2"])
+            x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
+    with jax.named_scope("decoder.head"):
+        if all_lanes:
+            # verify form: every lane's logits ([B, C, vocab]) — the
+            # acceptance walk needs the target's distribution at each
+            # proposed position, not just the newest
+            h = _ln(x, params["lnf"])
+            logits = h @ params["tok_emb"].T
+            if return_hidden:
+                return k_pool, v_pool, logits, h
+            return k_pool, v_pool, logits
+        # unembed only each slot's newest lane (dead slots gather lane
+        # 0 — garbage the scheduler never samples)
+        last = jnp.maximum(q_lens - 1, 0)[:, None, None]   # [B, 1, 1]
+        x_last = jnp.take_along_axis(
+            x, jnp.broadcast_to(last, (b, 1, dm)), axis=1)[:, 0]
+        logits = _ln(x_last, params["lnf"]) @ params["tok_emb"].T
         return k_pool, v_pool, logits
-    # unembed only each slot's newest lane (dead slots gather lane 0 —
-    # garbage the scheduler never samples)
-    last = jnp.maximum(q_lens - 1, 0)[:, None, None]       # [B, 1, 1]
-    x_last = jnp.take_along_axis(
-        x, jnp.broadcast_to(last, (b, 1, dm)), axis=1)[:, 0]
-    logits = _ln(x_last, params["lnf"]) @ params["tok_emb"].T
-    return k_pool, v_pool, logits
 
 
 def decoder_step(params, spec: DecoderSpec, tokens, positions,
@@ -450,6 +470,23 @@ def sample_token(logits_row, temperature: float = 0.0, top_k: int = 0,
     rng = np.random.Generator(np.random.PCG64(np.random.SeedSequence(
         [int(seed) & 0xFFFFFFFF, int(position)])))
     return int(rng.choice(row.size, p=p))
+
+
+def _call_work(slots: int, chunk: int, width: int, q_lens,
+               kv_lens) -> Dict[str, int]:
+    """``serving.decode.device_call``'s args: the compiled buckets of
+    one step call and the three sums its attention work follows from,
+    whatever implements the call — query tokens, keys in view, and
+    causal query-key pairs (a slot's ``q`` newest tokens see ``kv-q+1``
+    up to ``kv`` keys). A layer's attention needs
+    ``4 * heads * head_dim * attn_pairs`` operations and reads
+    ``2 * kv_heads * head_dim * kv_tokens`` K/V elements, beside
+    ``2 * heads * head_dim * q_tokens`` of q and out. Dead slots are
+    0/0 and add nothing."""
+    q, kv = q_lens.astype(np.int64), kv_lens.astype(np.int64)
+    return {"slots": slots, "chunk": chunk, "width": width,
+            "q_tokens": int(q.sum()), "kv_tokens": int(kv.sum()),
+            "attn_pairs": int(((2 * kv - q + 1) * q // 2).sum())}
 
 
 # --- ladders ------------------------------------------------------------
@@ -831,6 +868,10 @@ class DecodeEngine:
         self._seq_counter = 0  # guarded-by: _cond
         self._n_requests = 0  # guarded-by: _cond
         self._n_steps = 0  # guarded-by: _cond
+        # scheduler-thread only: when the round in progress began on the
+        # host clock — where the last one ended, or where a wait for
+        # work did (serving.decode.sched_ms)
+        self._t_round = 0.0
         self._compiled_shapes: set = set()  # guarded-by: _step_mu
         self._g_depth = _metrics.gauge(
             f"serving.decode.queue_depth.{self.name}.v{self.version}")
@@ -1641,12 +1682,20 @@ class DecodeEngine:
 
     def _next_live(self
                    ) -> Optional[Tuple[List[_Slot], List[_EmbedSlot]]]:
+        # the admit span starts BEFORE the condition is taken (so it is
+        # entered and left by hand): getting the condition back from
+        # the clients the last step woke is the scheduler's time. The
+        # round's host clock (_t_round) runs on from the last round's
+        # end; only a wait for work, below, restarts it
+        admit = _tracing.span("serving.decode.admit")
+        admit.__enter__()
         # lint: allow-blocking — Condition.wait on the engine's own
         # condition is the scheduler's idle state by design
         with self._cond:
             while True:
                 self._drop_expired_locked(time.monotonic())
                 self._admit_locked()
+                admit.__exit__(None, None, None)
                 if self._slots or self._embed_slots:
                     return list(self._slots), list(self._embed_slots)
                 if self._stopping and not self._queue \
@@ -1661,8 +1710,13 @@ class DecodeEngine:
                 self._cond.wait(0.05 if (self._queue
                                          or self._embed_queue)
                                 else None)
+                self._t_round = time.perf_counter()
+                admit = _tracing.span("serving.decode.admit")
+                admit.__enter__()
 
-    def _loop(self):
+    def _loop(self):  # lint: allow-unguarded(_t_round) — this thread's
+        # own clock: nobody else reads or writes it
+        self._t_round = time.perf_counter()
         while True:
             nxt = self._next_live()
             if nxt is None:
@@ -1676,6 +1730,8 @@ class DecodeEngine:
                     # round: decode tokens never stall behind scoring,
                     # and a mixed churn interleaves the two lanes 1:1
                     self._embed_step(elive)
+                    # the embed lane's time is no decode round's
+                    self._t_round = time.perf_counter()
             except BaseException as e:  # a broken step fails ITS slots
                 _log.error("decode step on %s v%d failed: %s: %s",
                            self.name, self.version, type(e).__name__, e)
@@ -2182,7 +2238,8 @@ class DecodeEngine:
         _faults.fire("serving.decode.step")
         # restore-before-step, COW copies, demand-mode growth (may
         # preempt/demote — the returned live list is authoritative)
-        live, grants = self._prepare(live)
+        with _tracing.span("serving.decode.prepare"):
+            live, grants = self._prepare(live)
         if not live:
             return
         # split the round: decoding slots with a draft attached ride
@@ -2219,40 +2276,49 @@ class DecodeEngine:
                 # carrying a real chunk pay the chunk-wide compute
                 c_bucket = _bucket_for(self._chunk_ladder,
                                        max(max(ps_grants), 1))
-                tokens = np.zeros((s_bucket, c_bucket), np.int32)
-                positions = np.zeros((s_bucket, c_bucket), np.int32)
-                q_lens = np.zeros(s_bucket, np.int32)
-                lens = np.zeros(s_bucket, np.int32)
-                for i, (s, g) in enumerate(zip(ps_slots, ps_grants)):
-                    plain_row_of[id(s)] = i
-                    for j in range(g):
-                        tokens[i, j] = s.token_at(s.pos + j)
-                        positions[i, j] = s.pos + j
-                    q_lens[i] = g
-                    # keys INCLUDING this chunk; within it, query j
-                    # attends only keys up to its own position
-                    lens[i] = s.pos + g
-                    self._check_reservation(s, int(lens[i]))
-                tables = self.cache.table_array(
-                    [s.req.seq_id for s in ps_slots], w_bucket,
-                    rows=s_bucket)
-                logits = self._run_step_arrays(tokens, positions,
-                                               q_lens, tables, lens)
-                if self._spec_k:
-                    # the draft shadows every prefill chunk so its
-                    # mirrored pool tracks the committed sequence
-                    # (logits discarded; its watermark advances in the
-                    # answer phase with pos)
-                    self._run_draft_arrays(tokens, positions, q_lens,
-                                           tables, lens)
-                logits_np = np.asarray(logits)  # [B, vocab] — newest
+                with _tracing.span("serving.decode.build"):
+                    tokens = np.zeros((s_bucket, c_bucket), np.int32)
+                    positions = np.zeros((s_bucket, c_bucket), np.int32)
+                    q_lens = np.zeros(s_bucket, np.int32)
+                    lens = np.zeros(s_bucket, np.int32)
+                    for i, (s, g) in enumerate(zip(ps_slots, ps_grants)):
+                        plain_row_of[id(s)] = i
+                        for j in range(g):
+                            tokens[i, j] = s.token_at(s.pos + j)
+                            positions[i, j] = s.pos + j
+                        q_lens[i] = g
+                        # keys INCLUDING this chunk; within it, query j
+                        # attends only keys up to its own position
+                        lens[i] = s.pos + g
+                        self._check_reservation(s, int(lens[i]))
+                    tables = self.cache.table_array(
+                        [s.req.seq_id for s in ps_slots], w_bucket,
+                        rows=s_bucket)
+                # dispatch of the jitted step to the logits on the host
+                with _tracing.span("serving.decode.device_call") as sp:
+                    if sp.live:
+                        for key, value in _call_work(
+                                s_bucket, c_bucket, w_bucket, q_lens,
+                                lens).items():
+                            sp.set_arg(key, value)
+                    logits = self._run_step_arrays(tokens, positions,
+                                                   q_lens, tables, lens)
+                    if self._spec_k:
+                        # the draft shadows every prefill chunk so its
+                        # mirrored pool tracks the committed sequence
+                        # (logits discarded; its watermark advances in
+                        # the answer phase with pos)
+                        self._run_draft_arrays(tokens, positions, q_lens,
+                                               tables, lens)
+                    logits_np = np.asarray(logits)  # [B, vocab] — newest
                 # the greedy fast path for the whole batch; per-request
                 # sampling policies resolve per slot below
                 sampled = np.asarray(np.argmax(logits_np, axis=-1))
             if spec_rows:
                 spec_out = self._spec_substep(
                     [live[i] for i in spec_rows], w_bucket)
-        _m_step_ms.observe((time.perf_counter() - t0) * 1e3)
+        t_step_end = time.perf_counter()
+        _m_step_ms.observe((t_step_end - t0) * 1e3)
         _m_steps.inc()
         _m_occupancy.observe(
             len(live) / float(_bucket_for(self._slot_ladder,
@@ -2262,8 +2328,6 @@ class DecodeEngine:
         _m_prefill_per_step.observe(prefill_toks)
         if prefill_toks:
             _m_prefill_tokens.inc(prefill_toks)
-        with self._cond:
-            self._n_steps += 1
         now = time.monotonic()
         done: List[_Slot] = []
         # the whole answer phase holds _cond: stop(drain=False) fails
@@ -2272,7 +2336,11 @@ class DecodeEngine:
         notes: Dict[int, int] = {}
         produced_any = False
         n_proposed = n_accepted = 0
-        with self._cond:
+        sample_s = 0.0
+        # the span opens before the condition is taken: the wait for it
+        # (clients reading their streams hold it) is the phase's time
+        with _tracing.span("serving.decode.answer"), self._cond:
+            self._n_steps += 1
             for i, s in enumerate(live):
                 if s.req.ev.is_set():
                     # already answered — stop(drain=False) raced this
@@ -2353,27 +2421,19 @@ class DecodeEngine:
                         # the (seed, position) pair that makes sampling
                         # independent of batch composition AND chunking
                         row = plain_row_of[id(s)]
-                        if s.req.want_topk and s.req.first_topk is None:
-                            # the beam fork point (ISSUE 20): the FIRST
-                            # generated position's token order by
-                            # logit, stable-sorted so ties break
-                            # deterministically; order[0] == argmax, so
-                            # beam 0 is the greedy continuation
-                            order = np.argsort(
-                                -np.asarray(logits_np[row], np.float64),
-                                kind="stable")
-                            s.req.first_topk = [
-                                int(t) for t in order[:s.req.want_topk]]
-                        if s.req.mask is not None:
-                            tok, mask_done = self._masked_choice(
-                                s.req, logits_np[row], s.pos)
+                        topk = (s.req.want_topk
+                                and s.req.first_topk is None)
+                        if (not topk and s.req.mask is None
+                                and s.req.temperature <= 0.0):
+                            # greedy: the batch argmax already chose
+                            tok = int(sampled[row])
                         else:
-                            tok = (int(sampled[row])
-                                   if s.req.temperature <= 0.0
-                                   else sample_token(
-                                       logits_np[row],
-                                       s.req.temperature,
-                                       s.req.top_k, s.req.seed, s.pos))
+                            t_sample = time.perf_counter()
+                            with _tracing.span("serving.decode.sample"):
+                                tok, mask_done = self._sample(
+                                    s.req, logits_np[row],
+                                    int(sampled[row]), s.pos, topk)
+                            sample_s += time.perf_counter() - t_sample
                         s.req.produced.append(tok)
                         produced_any = True
                         _m_tokens.inc()
@@ -2409,10 +2469,41 @@ class DecodeEngine:
                 # notify lands, ceil(prompt/chunk) steps after
                 # admission, not when the whole sequence finishes
                 self._cond.notify_all()
-        if n_proposed:
-            _m_spec_proposed.inc(n_proposed)
-            _m_spec_accepted.inc(n_accepted)
-            _m_spec_rejected.inc(n_proposed - n_accepted)
+            # the round's last bookkeeping, still inside the span (and
+            # the condition): once it is released the woken clients
+            # run, and what the scheduler then waits belongs to the
+            # next round's admit
+            if n_proposed:
+                _m_spec_proposed.inc(n_proposed)
+                _m_spec_accepted.inc(n_accepted)
+                _m_spec_rejected.inc(n_proposed - n_accepted)
+            t_end = time.perf_counter()
+            _m_sample_ms.observe(sample_s * 1e3)
+            _m_sched_ms.observe((t_end - self._t_round
+                                 - (t_step_end - t0) - sample_s) * 1e3)
+            self._t_round = t_end
+
+    def _sample(self, req: _DecodeRequest, row, greedy: int,
+                position: int, topk: bool) -> Tuple[int, bool]:
+        """The host-side choice of one slot's token from its logits row
+        (the ``serving.decode.sample`` span's body): the first
+        position's token order where the request asked for it, then the
+        masked, sampled or greedy choice. Returns ``(token,
+        mask_exhausted)``."""
+        if topk:
+            # the beam fork point (ISSUE 20): the FIRST generated
+            # position's token order by logit, stable-sorted so ties
+            # break deterministically; order[0] == argmax, so beam 0 is
+            # the greedy continuation
+            order = np.argsort(-np.asarray(row, np.float64),
+                               kind="stable")
+            req.first_topk = [int(t) for t in order[:req.want_topk]]
+        if req.mask is not None:
+            return self._masked_choice(req, row, position)
+        if req.temperature <= 0.0:
+            return greedy, False
+        return sample_token(row, req.temperature, req.top_k, req.seed,
+                            position), False
 
     def _complete(self, s: _Slot):
         self.cache.allocator.free(s.req.seq_id)

@@ -67,3 +67,55 @@ def _observability_isolation():
 
     metrics.reset_all()
     yield
+
+
+class _ProfilerSession:
+    """A ``jax.profiler`` session the way a traced benchmark run starts
+    one (``perf/lib/trace.py``: the Python tracer off), and its host
+    events read back from the ``.xplane.pb``."""
+
+    def __init__(self, trace_dir):
+        self.dir = str(trace_dir)
+        self._open = False
+
+    def start(self):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self.dir, profiler_options=options)
+        self._open = True
+        return self
+
+    def stop(self, prefix=""):
+        """End the session; the host planes' events whose name starts
+        with ``prefix`` as dicts (``line`` is the thread's), by start."""
+        import glob
+
+        from jax.profiler import ProfileData
+
+        if self._open:
+            self._open = False
+            jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(self.dir, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        events = []
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                events += [
+                    {"line": line.name, "name": ev.name,
+                     "start": int(ev.start_ns),
+                     "end": int(ev.start_ns + ev.duration_ns),
+                     "args": {k: v for k, v in ev.stats}}
+                    for ev in line.events if ev.name.startswith(prefix)]
+        return sorted(events, key=lambda e: (e["start"], -e["end"]))
+
+
+@pytest.fixture
+def profiler_session(tmp_path):
+    """An unstarted session writing under the test's tmp_path; a test
+    that fails between start() and stop() still leaves none collecting."""
+    session = _ProfilerSession(tmp_path / "profile")
+    yield session
+    if session._open:
+        jax.profiler.stop_trace()

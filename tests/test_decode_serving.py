@@ -548,6 +548,163 @@ def test_reserve_at_admission_holds_exactly_under_chunking():
     assert a.reserved_tokens(1) == 0
 
 
+# --- the scheduler round's spans and host time (ISSUE 27) ---------------
+
+_ROUND = ("admit", "prepare", "step", "answer")
+
+
+def _drive(eng, requests, together=False):
+    """Submit ``(prompt, kwargs)`` requests one after another (each waits
+    for the last), or — ``together`` — all under the engine's condition,
+    so that one scheduler round admits them all."""
+    if together:
+        with eng._cond:
+            reqs = [eng.submit(p, **kw) for p, kw in requests]
+        for r in reqs:
+            assert r.ev.wait(60)
+        return reqs
+    reqs = []
+    for p, kw in requests:
+        reqs.append(eng.submit(p, **kw))
+        assert reqs[-1].ev.wait(60)
+    return reqs
+
+
+@pytest.mark.parametrize("sampled", [False, True])
+def test_round_histograms_one_observation_a_step_and_inside_the_wall(
+        sampled):
+    """serving.decode.sample_ms and .sched_ms observe once a scheduler
+    step, as step_ms does, and the three are disjoint stretches of the
+    scheduler thread's time: together they never exceed the wall time
+    the rounds took. Greedy slots read no clock for sampling."""
+    eng = _engine(max_seq_len=16)
+    kw = (dict(temperature=1.0, seed=5) if sampled else {})
+    t0 = time.perf_counter()
+    _drive(eng, [([1, 2, 3, 4, 5, 6], dict(max_new_tokens=6, **kw)),
+                 ([7, 8], dict(max_new_tokens=5, topk_first=4 * sampled))])
+    _drive(eng, [([3, 1, 4], dict(max_new_tokens=7, **kw)),
+                 ([9] * 9, dict(max_new_tokens=4))], together=True)
+    eng.stop()      # joins the scheduler: every observation is in
+    wall_ms = (time.perf_counter() - t0) * 1e3
+    snap = metrics.snapshot("serving.decode.")
+    steps = snap["serving.decode.steps"]
+    step, sample, sched = (snap["serving.decode." + n] for n in
+                           ("step_ms", "sample_ms", "sched_ms"))
+    assert steps > 10
+    assert step["count"] == sample["count"] == sched["count"] == steps
+    assert sample["min"] >= 0.0 and sched["min"] > 0.0
+    assert (sample["sum"] > 0.0) == sampled
+    assert step["sum"] + sample["sum"] + sched["sum"] <= wall_ms
+
+
+def _fed(prompt_len, max_new, chunk=4, alone=True):
+    """(query tokens, keys in view summed over the calls, causal pairs)
+    one request puts through the step's device calls: every prompt token
+    and every generated token but the last is fed once; alone, a prompt
+    goes in chunks of ``chunk`` and each call sees the keys up to its
+    chunk's end."""
+    total = prompt_len + max_new - 1
+    ends = list(range(chunk, prompt_len, chunk)) + list(
+        range(prompt_len, total + 1))
+    return total, sum(ends) if alone else None, total * (total + 1) // 2
+
+
+@pytest.mark.parametrize("together", [False, True])
+def test_round_spans_in_order_on_the_profilers_clock(profiler_session,
+                                                     together):
+    """Under a profiler session (the ring off) the scheduler thread's
+    line holds a round's seven spans in order: admit, prepare, step
+    (build then device_call inside it), answer (sample inside it), none
+    overlapping its sibling; device_call's args add up to the work the
+    submitted prompts need."""
+    eng = _engine(max_seq_len=16)
+    requests = [([1, 2, 3, 4, 5, 6], dict(max_new_tokens=3,
+                                          temperature=1.0, seed=3)),
+                ([7, 8], dict(max_new_tokens=4, topk_first=4)),
+                ([5] * 9, dict(max_new_tokens=2))]
+    profiler_session.start()
+    _drive(eng, requests, together)
+    eng.stop()
+    events = profiler_session.stop(prefix="serving.decode.")
+    line, = {e["line"] for e in events}         # the scheduler's thread
+    short = lambda e: e["name"][len("serving.decode."):]
+    top, inside = [], {}
+    for e in events:            # by start, the longer first
+        if top and e["start"] < top[-1]["end"]:
+            assert e["end"] <= top[-1]["end"], (short(e), short(top[-1]))
+            kids = inside.setdefault(id(top[-1]), [])
+            assert not kids or kids[-1]["end"] <= e["start"]
+            kids.append(e)
+        else:
+            top.append(e)
+    # whole rounds; an admit pass that found nothing to run (the engine
+    # idle between two requests, and at the end) is followed by another
+    names = [short(e) for e in top]
+    rounds = [n for i, n in enumerate(names)
+              if not (n == "admit" and names[i + 1:i + 2] != ["prepare"])]
+    n_rounds = names.count("step")
+    assert n_rounds >= 5
+    assert rounds == list(_ROUND) * n_rounds, names
+    calls = []
+    for e in top:
+        kids = [short(k) for k in inside.get(id(e), [])]
+        if short(e) == "step":
+            assert kids == ["build", "device_call"]
+            calls.append(inside[id(e)][1]["args"])
+        elif short(e) == "answer":
+            assert set(kids) <= {"sample"}
+        else:
+            assert kids == []
+    # the sampled request's tokens and the first_topk sort were chosen
+    # inside sample spans; greedy slots open none
+    n_sample = sum(short(e) == "sample" for e in events)
+    assert n_sample == 3 + 1
+    want = [_fed(len(p), kw["max_new_tokens"], alone=not together)
+            for p, kw in requests]
+    assert sum(c["q_tokens"] for c in calls) == sum(w[0] for w in want)
+    assert sum(c["attn_pairs"] for c in calls) == sum(w[2] for w in want)
+    if together:
+        assert max(c["slots"] for c in calls) == 2      # a shared step
+    else:
+        assert sum(c["kv_tokens"] for c in calls) == \
+            sum(w[1] for w in want)
+    assert all(c["slots"] in (1, 2) and c["chunk"] in (1, 4)
+               and c["width"] in (1, 2, 4) for c in calls)
+    from paddle_tpu.observability import tracing
+    assert tracing.trace_events() == []         # the ring stayed off
+
+
+@pytest.mark.parametrize("all_lanes", [False, True])
+def test_decoder_step_names_its_device_work(all_lanes):
+    """decoder_step_chunked's lowered text holds every decoder.* scope,
+    and the Pallas kernel its name inside decoder.attn (interpreted
+    here; Mosaic gets the same name on the chip)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.fluid.flags import FLAGS, set_flags
+    from paddle_tpu.serving.decode import (build_decoder_params,
+                                           decoder_step_chunked)
+
+    spec = _spec()
+    params = build_decoder_params(spec)
+    pool = jnp.zeros((spec.n_layers, 10, 4, spec.n_kv_heads,
+                      spec.head_dim), jnp.float32)
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    was = FLAGS["use_pallas_kernels"]
+    set_flags({"use_pallas_kernels": True})
+    try:
+        text = jax.jit(lambda p, *a: decoder_step_chunked(
+            p, spec, *a, all_lanes=all_lanes)).lower(
+            params, i32(2, 4), i32(2, 4), i32(2), pool, pool, i32(2, 2),
+            i32(2)).as_text(debug_info=True)
+    finally:
+        set_flags({"use_pallas_kernels": was})
+    for scope in ("embed", "kv_write", "attn", "mlp", "head"):
+        assert f"/decoder.{scope}/" in text, scope
+    assert "/decoder.attn/paged_attention/pallas_call" in text
+
+
 # --- admission / deadlines ----------------------------------------------
 
 def test_decode_admission_refusals_are_typed():

@@ -111,6 +111,76 @@ def test_disabled_tracing_records_nothing_and_is_noop():
     assert tracing.trace_events() == []
 
 
+# --- the profiler's clock (ISSUE 27) -------------------------------------
+
+
+@pytest.mark.parametrize("ring", [False, True])
+def test_span_lands_on_the_profilers_clock(profiler_session, ring):
+    """While a jax.profiler session collects, span() is also a
+    TraceAnnotation of the same name and args — ring on or off; with the
+    ring on it records there too, as ever."""
+    if ring:
+        tracing.trace_enable()
+    profiler_session.start()
+    with tracing.span("obs.profiled", k=1) as outer:
+        assert outer.live
+        outer.set_arg("late", 7)        # set after the span opened
+        with tracing.span("obs.profiled.child"):
+            time.sleep(0.001)
+    outer_ev, child_ev = profiler_session.stop(prefix="obs.profiled")
+    assert outer_ev["name"] == "obs.profiled"
+    assert outer_ev["args"] == {"k": 1, "late": 7}
+    assert child_ev["name"] == "obs.profiled.child"
+    assert child_ev["line"] == outer_ev["line"]      # one thread's line
+    assert outer_ev["start"] <= child_ev["start"] \
+        and child_ev["end"] <= outer_ev["end"]
+    assert child_ev["end"] - child_ev["start"] >= 1_000_000   # ns
+    ring_names = [e["name"] for e in tracing.trace_events()]
+    assert ring_names == (["obs.profiled.child", "obs.profiled"]
+                          if ring else [])
+    if ring:
+        child, parent = tracing.trace_events()
+        assert parent["args"]["k"] == 1 and parent["args"]["late"] == 7
+        assert child["args"]["parent_span_id"] == parent["args"]["span_id"]
+
+
+def test_span_is_the_shared_noop_with_no_session_and_the_ring_off(
+        profiler_session):
+    """Neither clock wants the span: the one shared object, before a
+    session, and again after it has stopped."""
+    assert not tracing.trace_enabled()
+    null = tracing.span("obs.nobody", k=1)
+    assert null is tracing.span("obs.nobody_else") and not null.live
+    profiler_session.start()
+    assert tracing.span("obs.somebody") is not null
+    profiler_session.stop()
+    assert tracing.span("obs.nobody", k=1) is null
+    assert tracing.trace_events() == []
+
+
+def test_fluid_op_types_are_scopes_of_the_lowered_program():
+    """exec_op_descs emits each Fluid op under a jax.named_scope of its
+    type: the lowered program's op names (and so a device trace's) say
+    which Fluid op an operation belongs to."""
+    import paddle_tpu.fluid as fluid
+
+    main, startup, loss = _build_sgd_program()
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor()
+        exe.run(startup)
+        jfn, args = exe.lowered(main, feed={"x": np.ones((2, 4),
+                                                           np.float32)},
+                                fetch_list=[loss], scope=scope)
+    text = jfn.lower(*args).as_text(debug_info=True)
+    types = {op.desc.type for op in main.global_block().ops}
+    named = {t for t in types if f"jit(fn)/{t}/" in text}
+    # an op that emits no operation (assign, a folded constant) leaves
+    # no name; every op that computes does
+    assert {"mul", "elementwise_add", "mean", "mean_grad", "mul_grad",
+            "elementwise_add_grad", "sgd"} <= named, (named, types)
+
+
 # --- metrics -----------------------------------------------------------
 
 
