@@ -95,7 +95,7 @@ from .kv_cache import GARBAGE_PAGE, HostSpillStore, PagedKvCache
 __all__ = ["DecoderSpec", "DecodeEngine", "build_decoder_params",
            "seeded_decoder_arrays", "decoder_step",
            "decoder_step_chunked", "width_ladder", "sample_token",
-           "validate_draft_spec"]
+           "choose_tokens", "validate_draft_spec"]
 
 _log = get_logger("serving")
 
@@ -113,7 +113,8 @@ _m_compiles = _metrics.counter("serving.decode.compiles")
 _m_step_ms = _metrics.histogram("serving.decode.step_ms")
 # the rest of a scheduler round on the host clock (ISSUE 27), one
 # observation a step each: sample_ms is the host-side choice of tokens
-# (the serving.decode.sample spans, summed over the step's slots);
+# (the serving.decode.sample spans, summed over the step's slots; 0.0
+# where every token was chosen by the step's program, ISSUE 29);
 # sched_ms is whatever of the round is neither step_ms's stretch, nor
 # sampling, nor the wait for work — admit + prepare + the answer phase
 # without sampling, and the hand-over of the interpreter lock to the
@@ -121,6 +122,15 @@ _m_step_ms = _metrics.histogram("serving.decode.step_ms")
 # the end of a wait for work), so the three add up to the step period.
 _m_sample_ms = _metrics.histogram("serving.decode.sample_ms")
 _m_sched_ms = _metrics.histogram("serving.decode.sched_ms")
+# where a token was chosen (ISSUE 29), one increment a chosen token: by
+# the step's own program (``choose_tokens``: greedy, and temperature > 0
+# with top_k 0) or on the host from one fetched logits row (top_k > 0, a
+# constraint mask, the first position's first_topk order). The histogram
+# observes 100 * device / chosen once a step that chose a token
+_m_device_choices = _metrics.counter("serving.decode.device_choices")
+_m_host_choices = _metrics.counter("serving.decode.host_choices")
+_m_device_choice_pct = _metrics.histogram(
+    "serving.decode.device_choice_pct")
 _m_queue_wait = _metrics.histogram("serving.decode.queue_wait_ms")
 _m_total = _metrics.histogram("serving.decode.total_ms")
 # live slots / slot bucket per step: the continuous-batching win is
@@ -333,7 +343,7 @@ def decoder_step_chunked(params, spec: DecoderSpec, tokens, positions,
     (the chunked-vs-unchunked greedy-equality test pins it).
 
     Logits come back ONLY for each slot's newest lane (``q_len - 1``)
-    — the one position the scheduler ever samples from (a chunk that
+    — the one position a token is ever chosen at (a chunk that
     doesn't finish its prompt uses no logits at all). Unembedding is
     the widest matmul of the step: unembedding all C lanes would waste
     ~(C-1)/C of it plus a C-times-larger device->host transfer on
@@ -444,11 +454,13 @@ def decoder_step(params, spec: DecoderSpec, tokens, positions,
 
 def sample_token(logits_row, temperature: float = 0.0, top_k: int = 0,
                  seed: int = 0, position: int = 0) -> int:
-    """Sampling policy for ONE generated token (the ROADMAP
-    sampling-beyond-greedy residual): greedy argmax at temperature 0
-    (the default — bitwise the PR 6 behavior), else temperature-scaled
-    softmax over the ``top_k`` highest logits (0 = full vocab), drawn
-    from an rng derived ONLY from ``(seed, position)``.
+    """The HOST route's sampling policy for ONE generated token, from
+    its fetched logits row (``top_k`` > 0 and masked requests; the rest
+    are chosen on the device by ``choose_tokens``): greedy argmax at
+    temperature 0 (the default — bitwise the PR 6 behavior), else
+    temperature-scaled softmax over the ``top_k`` highest logits (0 =
+    full vocab), drawn from an rng derived ONLY from ``(seed,
+    position)``.
 
     Deterministic given the request's seed, and — because position is
     the token's absolute index in ITS sequence — independent of batch
@@ -470,6 +482,58 @@ def sample_token(logits_row, temperature: float = 0.0, top_k: int = 0,
     rng = np.random.Generator(np.random.PCG64(np.random.SeedSequence(
         [int(seed) & 0xFFFFFFFF, int(position)])))
     return int(rng.choice(row.size, p=p))
+
+
+def _top_order(row, k: int) -> List[int]:
+    """The first ``k`` lanes of ``row`` in falling order of logit, ties
+    by rising lane: what a stable sort of the whole row gives, from a
+    partition and a sort of the lanes that can be among them (3 ms where
+    the whole row's sort took 32 with the device dry, at vocab 256 008
+    and k 4096)."""
+    row = np.asarray(row)
+    if not 0 < k < row.size or np.isnan(row).any():
+        return [int(t) for t in np.argsort(-row, kind="stable")[:k]]
+    kth = np.partition(row, row.size - k)[row.size - k]
+    lanes = np.flatnonzero(row >= kth)      # rising, so ties stay so
+    return [int(t) for t in
+            lanes[np.argsort(-row[lanes], kind="stable")[:k]]]
+
+
+def choose_tokens(logits, temperature, seed, position):
+    """THE token choice of a step, made by the program that made the
+    logits (ISSUE 29): ``logits [B, V]`` float32, ``temperature [B]``
+    float32, ``seed [B]`` uint32 (the request's seed ``& 0xFFFFFFFF``),
+    ``position [B]`` int32 (the new token's absolute index in ITS
+    sequence) -> ``ids [B]`` int32. Traceable: the plain step, the
+    speculative verify and the draft all fuse this one function, so a
+    committed token is what the non-speculative engine emits whichever
+    program chose it.
+
+    A row with ``temperature == 0`` is ``argmax(logits)``, the first
+    index on ties, bitwise what ``np.argmax`` gives on the host. A row
+    with ``temperature > 0`` is the Gumbel-max draw ``argmax(logits / T
+    + g)``, an exact draw from ``softmax(logits / T)``, with ``g`` from
+    a key folded ONLY from ``(seed, position)``: deterministic given the
+    request's seed and independent of batch composition, slot assignment
+    and admission order, as ``sample_token`` is on the host route (the
+    two draw from different generators: the same distribution, not the
+    same ids). Dead rows draw garbage nobody reads."""
+    import jax
+    import jax.numpy as jnp
+
+    def noise(s, p):
+        # the generator is named, so that no default can change the ids
+        key = jax.random.fold_in(
+            jax.random.key(s, impl="threefry2x32"), p)
+        return jax.random.gumbel(key, logits.shape[-1:], jnp.float32)
+
+    with jax.named_scope("decoder.choose"):
+        drawn = temperature > 0.0
+        t = jnp.where(drawn, temperature, 1.0)[:, None]
+        scores = jnp.where(drawn[:, None],
+                           logits / t + jax.vmap(noise)(seed, position),
+                           logits)
+        return jnp.argmax(scores, axis=-1).astype(jnp.int32)
 
 
 def _call_work(slots: int, chunk: int, width: int, q_lens,
@@ -889,12 +953,19 @@ class DecodeEngine:
         spec_ref = spec  # closed over; jit retraces only on shape change
         impl = self._attention_impl
 
+        # every program that makes logits a token is chosen from also
+        # chooses it (choose_tokens, ISSUE 29) and hands back (pools,
+        # ids, logits): the scheduler fetches the ids, and a logits row
+        # only where a request needs the host (_fetch_row)
         def _step(params, tokens, positions, q_lens, k_pool, v_pool,
-                  tables, lens):
-            return decoder_step_chunked(params, spec_ref, tokens,
-                                        positions, q_lens, k_pool,
-                                        v_pool, tables, lens,
-                                        attention_impl=impl)
+                  tables, lens, temperature, seed):
+            k, v, logits = decoder_step_chunked(
+                params, spec_ref, tokens, positions, q_lens, k_pool,
+                v_pool, tables, lens, attention_impl=impl)
+            # lens (the keys including this chunk) is the new token's
+            # absolute index in its sequence: the position of the draw
+            return k, v, choose_tokens(logits, temperature, seed,
+                                       lens), logits
 
         # donate the pools on TPU so XLA updates the KV pages in place
         # (HBM footprint stays the preallocated pool); CPU ignores
@@ -905,16 +976,18 @@ class DecodeEngine:
         step_out_shardings = None
         if self._mesh is not None:
             # pin the step outputs: pools keep the kv-head sharding they
-            # came in with, logits come back replicated (the scheduler
-            # samples host-side). Without the pin GSPMD may choose a
-            # different output layout per shape and the next step's
-            # input sharding drift would mint a post-warm compile.
+            # came in with, the ids and the logits come back replicated
+            # (the scheduler fetches the ids, and a logits row where a
+            # request is answered host-side). Without the pin GSPMD may
+            # choose a different output layout per shape and the next
+            # step's input sharding drift would mint a post-warm compile.
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as _P
 
             pool_sh = NamedSharding(self._mesh, self._pool_spec())
-            step_out_shardings = (pool_sh, pool_sh,
-                                  NamedSharding(self._mesh, _P()))
+            replicated = NamedSharding(self._mesh, _P())
+            step_out_shardings = (pool_sh, pool_sh, replicated,
+                                  replicated)
         self._step_out_shardings = step_out_shardings
         self._step_fn = jax.jit(
             _step,
@@ -926,19 +999,29 @@ class DecodeEngine:
             draft_ref = self._draft_spec
 
             def _verify(params, tokens, positions, q_lens, k_pool,
-                        v_pool, tables, lens):
-                return decoder_step_chunked(params, spec_ref, tokens,
-                                            positions, q_lens, k_pool,
-                                            v_pool, tables, lens,
-                                            all_lanes=True,
-                                            attention_impl=impl)
+                        v_pool, tables, lens, temperature, seed):
+                import jax.numpy as jnp
+
+                k, v, logits = decoder_step_chunked(
+                    params, spec_ref, tokens, positions, q_lens, k_pool,
+                    v_pool, tables, lens, all_lanes=True,
+                    attention_impl=impl)
+                b, c, vocab = logits.shape
+                # a lane is a row of its own: the slot's temperature
+                # and seed, and the position after the lane's own
+                ids = choose_tokens(
+                    logits.reshape(b * c, vocab),
+                    jnp.repeat(temperature, c), jnp.repeat(seed, c),
+                    (positions + 1).reshape(b * c)).reshape(b, c)
+                return k, v, ids, logits
 
             def _draft(params, tokens, positions, q_lens, k_pool,
-                       v_pool, tables, lens):
-                return decoder_step_chunked(params, draft_ref, tokens,
-                                            positions, q_lens, k_pool,
-                                            v_pool, tables, lens,
-                                            attention_impl=impl)
+                       v_pool, tables, lens, temperature, seed):
+                k, v, logits = decoder_step_chunked(
+                    params, draft_ref, tokens, positions, q_lens,
+                    k_pool, v_pool, tables, lens, attention_impl=impl)
+                return k, v, choose_tokens(logits, temperature, seed,
+                                           lens), logits
 
             _sharded_kw = ({"out_shardings": step_out_shardings}
                            if step_out_shardings is not None else {})
@@ -963,23 +1046,28 @@ class DecodeEngine:
                                             return_hidden=True,
                                             attention_impl=impl)
 
-            embed_out = None
-            if step_out_shardings is not None:
-                from jax.sharding import NamedSharding as _NS
-                from jax.sharding import PartitionSpec as _PS
-
-                # hidden states replicate like logits: pooling and
-                # logprob scoring are host-side
-                embed_out = step_out_shardings + (
-                    _NS(self._mesh, _PS()),)
+            # (pools, logits, hidden): hidden states replicate like
+            # logits (pooling and logprob scoring are host-side), so the
+            # step's pin of two pools and two replicated outputs fits
             self._embed_fn = jax.jit(
                 _embed,
                 donate_argnums=(4, 5) if donate else (),
-                **({"out_shardings": embed_out}
-                   if embed_out is not None
+                **({"out_shardings": step_out_shardings}
+                   if step_out_shardings is not None
                    else {}))  # guarded-by: _step_mu
         else:
             self._embed_fn = None  # guarded-by: _step_mu
+        # one logits row (or one slot's lanes) to the host, for the
+        # requests whose token is chosen there: ONE program a logits
+        # shape with the row index dynamic, so that which slot asks
+        # compiles nothing (warm() runs each; _fetch_row counts them)
+        self._row_fn = jax.jit(
+            lambda logits, i: jax.lax.dynamic_index_in_dim(
+                logits, i, 0, keepdims=False))  # guarded-by: _step_mu
+        self._row_shapes: set = set()  # guarded-by: _step_mu
+        # the ids of a step nobody read a token of, still on the device
+        # (_await_ids); the scheduler thread's own
+        self._unread = None
         # serializes warm() (caller thread) against live steps (the
         # scheduler thread): read-pools -> step -> rebind must be
         # atomic or concurrent rebinds silently drop KV writes
@@ -1104,7 +1192,10 @@ class DecodeEngine:
         entry (the all-lane-logits form) and the draft's own compiled
         ladder ({1, 2, chunk} — singles, the post-full-accept catch-up
         chunk, and the prefill chunks it shadows) warms alongside, so a
-        speculative churn still performs zero post-warm compiles."""
+        speculative churn still performs zero post-warm compiles.
+        Every program chooses its tokens itself (all-greedy here: the
+        sampling arrays are data, not shape), and the row fetch of the
+        host route warms once a slot count."""
         with _tracing.span("serving.decode.warmup", model=self.name,
                            version=self.version):
             for s in self._slot_ladder:
@@ -1117,9 +1208,10 @@ class DecodeEngine:
                                 np.zeros(s, np.int32))
 
                     for c in self._chunk_ladder:
-                        self._run_step_arrays(*dead(c))
+                        _ids, logits = self._run_step_arrays(*dead(c))
                     if self._spec_k:
-                        self._run_verify_arrays(*dead(self._verify_lanes))
+                        _ids, lanes = self._run_verify_arrays(
+                            *dead(self._verify_lanes))
                         for c in self._draft_chunk_ladder:
                             self._run_draft_arrays(*dead(c))
                     if self._embed_on:
@@ -1128,6 +1220,13 @@ class DecodeEngine:
                         # generate + embeddings compiles nothing
                         for c in self._chunk_ladder:
                             self._run_embed_arrays(*dead(c))
+                # the host route's row fetch: one program a logits
+                # shape, [s, vocab] whatever the width and the chunk
+                # (the draft's logits share it: its vocab is the
+                # target's) and the verify's [s, lanes, vocab]
+                self._fetch_row(logits, 0)
+                if self._spec_k:
+                    self._fetch_row(lanes, 0)
 
     def submit(self, prompt: Sequence[int], max_new_tokens: int = 16,
                deadline_ms: Optional[float] = None,
@@ -1139,7 +1238,9 @@ class DecodeEngine:
         pool exhausted), ``RequestTooLarge`` (can't ever fit),
         ``EngineRetired``, ``ValueError`` (bad tokens / bad sampling
         params). ``temperature``/``top_k``/``seed`` select the sampling
-        policy per request (``sample_token``; 0.0 = greedy).
+        policy per request (0.0 = greedy; ``choose_tokens`` inside the
+        step's program over the full vocabulary, ``sample_token`` on
+        the host for ``top_k`` > 0).
 
         ``mask`` (ISSUE 20) constrains generation to a
         ``TokenMaskSpec`` language (spec object or its wire dict): the
@@ -1481,6 +1582,7 @@ class DecodeEngine:
         with self._step_mu:
             self._params = None
             self._step_fn = None
+            self._row_fn = None
             self._embed_fn = None
             self._draft_params = None
             self._verify_fn = None
@@ -1776,13 +1878,42 @@ class DecodeEngine:
                         self._cond.notify_all()
                         return
 
-    def _run_step_arrays(self, tokens, positions, q_lens, tables, lens):
+    @staticmethod
+    def _sampling(temperature, seed, rows: int):
+        """The sampling arrays of one call as the programs take them;
+        all-greedy where the caller gives none (warm(), and a call whose
+        choice nobody reads). They are data: whoever samples, the
+        compiled shape is the same."""
+        if temperature is None:
+            return DecodeEngine._slot_sampling((), rows)
+        return temperature, seed
+
+    @staticmethod
+    def _slot_sampling(slots: Sequence[_Slot], rows: int):
+        """``(temperature [rows] float32, seed [rows] uint32)`` of a
+        call's slots, what ``choose_tokens`` draws each one's token by;
+        padded rows stay 0/0 and choose garbage nobody reads."""
+        temperature = np.zeros(rows, np.float32)
+        seed = np.zeros(rows, np.uint32)
+        for i, s in enumerate(slots):
+            temperature[i] = s.req.temperature
+            seed[i] = s.req.seed & 0xFFFFFFFF
+        return temperature, seed
+
+    def _run_step_arrays(self, tokens, positions, q_lens, tables, lens,
+                         *, temperature=None, seed=None):
         """Shared by warm() and live steps: count a DISTINCT-shape
         compile, run the jitted step, rebind the pools. With a draft
         attached the shape keys carry a model tag ('target'/'verify'/
         'draft') so the three compiled families stay distinct in the
         same churn-pinned set; without one they stay the bare PR 6/9
-        triples."""
+        triples. Returns ``(ids [B] int32, logits [B, vocab])``, both on
+        the device: the program's own choice for each slot's newest
+        lane (``choose_tokens``) from ``[B]`` ``temperature`` float32
+        and ``seed`` uint32, at position ``lens`` (the new token's
+        absolute index). The sampling arrays are keyword-only: the five
+        positional arguments are the call's shapes, which the
+        benchmark's traced runs log by position."""
         with self._step_mu:
             key = (len(tokens), tables.shape[1], tokens.shape[1])
             if self._spec_k or self._embed_on:
@@ -1794,17 +1925,20 @@ class DecodeEngine:
                 self._compiled_shapes.add(key)
                 _m_compiles.inc()
             _m_target_steps.inc()
-            k, v, logits = self._step_fn(
+            k, v, ids, logits = self._step_fn(
                 self._params, tokens, positions, q_lens, self.cache.k,
-                self.cache.v, tables, lens)
+                self.cache.v, tables, lens, *self._sampling(
+                    temperature, seed, len(tokens)))
             self.cache.rebind(k, v)
-            return logits
+            return ids, logits
 
     def _run_verify_arrays(self, tokens, positions, q_lens, tables,
-                           lens):
+                           lens, *, temperature=None, seed=None):
         """The speculative-verify target call: same pools, all-lane
-        logits ``[B, C, vocab]`` (C = spec_k + 1). One target step
-        scores every proposal plus the bonus position."""
+        logits ``[B, C, vocab]`` (C = spec_k + 1) and the choice at
+        every lane, ``ids [B, C]`` (lane j's is the token at
+        ``positions[:, j] + 1``). One target step scores every
+        proposal plus the bonus position."""
         with self._step_mu:
             key = ("verify", len(tokens), tables.shape[1],
                    tokens.shape[1])
@@ -1812,17 +1946,19 @@ class DecodeEngine:
                 self._compiled_shapes.add(key)
                 _m_compiles.inc()
             _m_target_steps.inc()
-            k, v, logits = self._verify_fn(
+            k, v, ids, logits = self._verify_fn(
                 self._params, tokens, positions, q_lens, self.cache.k,
-                self.cache.v, tables, lens)
+                self.cache.v, tables, lens, *self._sampling(
+                    temperature, seed, len(tokens)))
             self.cache.rebind(k, v)
-            return logits
+            return ids, logits
 
     def _run_draft_arrays(self, tokens, positions, q_lens, tables,
-                          lens):
+                          lens, *, temperature=None, seed=None):
         """One DRAFT step (propose singles, catch-up chunks, prefill
         shadowing) against the mirrored draft pool — same page tables
-        as the target, newest-lane logits."""
+        as the target, newest-lane ``(ids, logits)`` like the plain
+        step's."""
         with self._step_mu:
             key = ("draft", len(tokens), tables.shape[1],
                    tokens.shape[1])
@@ -1830,11 +1966,42 @@ class DecodeEngine:
                 self._compiled_shapes.add(key)
                 _m_compiles.inc()
             _m_draft_steps.inc()
-            k, v, logits = self._draft_fn(
+            k, v, ids, logits = self._draft_fn(
                 self._draft_params, tokens, positions, q_lens,
-                self._draft_cache.k, self._draft_cache.v, tables, lens)
+                self._draft_cache.k, self._draft_cache.v, tables, lens,
+                *self._sampling(temperature, seed, len(tokens)))
             self._draft_cache.rebind(k, v)
-            return logits
+            return ids, logits
+
+    def _await_ids(self, ids, chooses: bool):  # lint: allow-unguarded(_unread)
+        """The plain step's chosen ids ``[B]`` on the host, where a slot
+        of the step reads its token from them. A step whose chunks all
+        end inside their prompts chooses nothing anybody reads: the
+        scheduler does not wait for it, so the host's share of the next
+        round hides behind it. It waits for the one before instead, so
+        that at most two steps are ever queued on the device and a
+        cancel or a new request is never more than a step late.
+        ``_unread`` is the scheduler thread's own."""
+        unread, self._unread = self._unread, None
+        if chooses:
+            return np.asarray(ids)
+        if unread is not None:
+            unread.block_until_ready()
+        self._unread = ids
+        return None
+
+    def _fetch_row(self, logits, row: int) -> np.ndarray:
+        """Row ``row`` of a step's logits on the host: ``[vocab]`` of
+        the plain step's and the draft's, ``[lanes, vocab]`` of the
+        verify's. The host route's only transfer: one row, not the
+        batch. Counted like a step shape (``serving.decode.compiles``
+        pins it after warm) but kept out of ``stats()``'s
+        ``compiled_shapes``, which are the steps'."""
+        with self._step_mu:
+            if logits.shape not in self._row_shapes:
+                self._row_shapes.add(logits.shape)
+                _m_compiles.inc()
+            return np.asarray(self._row_fn(logits, np.int32(row)))
 
     def _run_embed_arrays(self, tokens, positions, q_lens, tables,
                           lens):
@@ -2068,15 +2235,29 @@ class DecodeEngine:
             grants.append(g)
         return grants
 
+    @staticmethod
+    def _draws_on_host(req: _DecodeRequest) -> bool:
+        """Where THE deterministic per-(seed, position) choice of a
+        request's tokens is made, read from what the request itself
+        carries (never a flag, a model or the batch, so batch
+        independence holds by construction): on the device by
+        ``choose_tokens`` inside the program that made the logits —
+        greedy, and temperature > 0 over the full vocabulary — or on
+        the host by ``_choose`` from one fetched row: ``top_k > 0``
+        sampling and a constraint mask. The plain step, the draft and
+        the verify acceptance walk all take a request's tokens from the
+        same one of the two, so a committed token is always exactly
+        what the non-speculative engine would have emitted at that
+        position from those logits — spec on/off bitwise equality is
+        structural, not statistical (the rejection-sampling realization
+        is pinned by (seed, position), ISSUE 14)."""
+        return req.mask is not None or (req.temperature > 0.0
+                                        and req.top_k > 0)
+
     def _choose(self, row, req: _DecodeRequest, position: int) -> int:
-        """THE deterministic per-(seed, position) token choice on one
-        logits row: greedy argmax at temperature 0, else the seeded
-        ``sample_token`` draw. Draft proposals AND the verify
-        acceptance walk both use it, so a committed token is always
-        exactly what the non-speculative engine would have emitted at
-        that position from those logits — spec on/off bitwise equality
-        is structural, not statistical (the rejection-sampling
-        realization is pinned by (seed, position), ISSUE 14)."""
+        """The host route's choice on one logits row (masked or not):
+        greedy argmax at temperature 0, else the seeded
+        ``sample_token`` draw."""
         if req.temperature <= 0.0:
             return int(np.argmax(row))
         return sample_token(row, req.temperature, req.top_k, req.seed,
@@ -2085,8 +2266,8 @@ class DecodeEngine:
     def _masked_choice(self, req: _DecodeRequest, row,
                        position: int) -> Tuple[int, bool]:
         """Constrained decode's per-token core (ISSUE 20): zero the
-        disallowed lanes to -inf, make THE SAME deterministic
-        per-(seed, position) choice the unconstrained path makes, then
+        disallowed lanes to -inf, make the host route's deterministic
+        per-(seed, position) choice (``_choose``) on what is left, then
         advance the automaton. Masking composes cleanly with the
         sampler — softmax renormalizes over the survivors — so a
         masked request's tokens are a pure function of (seed, mask,
@@ -2131,7 +2312,8 @@ class DecodeEngine:
         that yields proposal d_1, then k-1 singles — and the target
         verifies all k+1 positions in ONE all-lane chunked call.
         Acceptance is the deterministic walk: lane j's target choice
-        (per-(seed, position)) either equals proposal d_{j+1} (accept,
+        (per-(seed, position), made where ``_draws_on_host`` says the
+        request's are) either equals proposal d_{j+1} (accept,
         continue) or replaces it (the bonus/correction token, stop).
         Returns {id(slot): (committed tokens, k_eff, accepted)} for the
         answer phase; nothing here touches request/slot state."""
@@ -2144,6 +2326,22 @@ class DecodeEngine:
         tables = self.cache.table_array(
             [s.req.seq_id for s in slots], w_bucket, rows=s_bucket)
         proposals: List[List[int]] = [[] for _ in slots]
+        # the slots' sampling arrays, the same through the round; the
+        # positions of the choices are each call's own ``lens``
+        host = [self._draws_on_host(s.req) for s in slots]
+        temperature, seed = self._slot_sampling(slots, s_bucket)
+
+        def propose(ids, logits, j):
+            """Proposal d_j of every slot that still wants one, from a
+            draft call's newest-lane ``(ids, logits)``."""
+            ids = np.asarray(ids)
+            for i, s in enumerate(slots):
+                if keff[i] >= j:
+                    proposals[i].append(
+                        self._choose(self._fetch_row(logits, i), s.req,
+                                     s.pos + j)
+                        if host[i] else int(ids[i]))
+
         with _tracing.span("serving.decode.spec.draft", model=self.name,
                            version=self.version, slots=s_bucket,
                            k=self._spec_k):
@@ -2165,14 +2363,11 @@ class DecodeEngine:
                     tokens[i, j] = s.token_at(s.dpos + j)
                     positions[i, j] = s.dpos + j
                 q_lens[i] = g
-                lens[i] = s.dpos + g        # == s.pos + 1
+                lens[i] = s.dpos + g        # == s.pos + 1, d_1's own
             if int(q_lens.max(initial=0)) > 0:
-                lg = np.asarray(self._run_draft_arrays(
-                    tokens, positions, q_lens, tables, lens))
-                for i, s in enumerate(slots):
-                    if keff[i] >= 1:
-                        proposals[i].append(self._choose(
-                            lg[i], s.req, s.pos + 1))
+                propose(*self._run_draft_arrays(
+                    tokens, positions, q_lens, tables, lens,
+                    temperature=temperature, seed=seed), 1)
                 # singles: feed d_{j-1}, propose d_j
                 for j in range(2, self._spec_k + 1):
                     if not any(ke >= j for ke in keff):
@@ -2186,13 +2381,10 @@ class DecodeEngine:
                             tokens[i, 0] = proposals[i][j - 2]
                             positions[i, 0] = s.pos + j - 1
                             q_lens[i] = 1
-                            lens[i] = s.pos + j
-                    lg = np.asarray(self._run_draft_arrays(
-                        tokens, positions, q_lens, tables, lens))
-                    for i, s in enumerate(slots):
-                        if keff[i] >= j:
-                            proposals[i].append(self._choose(
-                                lg[i], s.req, s.pos + j))
+                            lens[i] = s.pos + j     # d_j's own
+                    propose(*self._run_draft_arrays(
+                        tokens, positions, q_lens, tables, lens,
+                        temperature=temperature, seed=seed), j)
         # verify: ONE target call over [pending, d_1..d_k] at the
         # FIXED spec_k+1 chunk entry; lane j's logits are the target's
         # distribution for position pos+1+j
@@ -2212,14 +2404,19 @@ class DecodeEngine:
                     positions[i, 1 + j] = s.pos + 1 + j
                 q_lens[i] = 1 + keff[i]
                 lens[i] = s.pos + 1 + keff[i]
-            lg = np.asarray(self._run_verify_arrays(
-                tokens, positions, q_lens, tables, lens))  # [B, C, V]
+            ids, lg = self._run_verify_arrays(
+                tokens, positions, q_lens, tables, lens,
+                temperature=temperature, seed=seed)
+            # ids [B, C], lg [B, C, V]
+            ids = np.asarray(ids)
         out: Dict[int, Tuple[List[int], int, int]] = {}
         for i, s in enumerate(slots):
             committed: List[int] = []
             accepted = 0
+            lanes = self._fetch_row(lg, i) if host[i] else None
             for j in range(keff[i] + 1):
-                choice = self._choose(lg[i, j], s.req, s.pos + 1 + j)
+                choice = (self._choose(lanes[j], s.req, s.pos + 1 + j)
+                          if host[i] else int(ids[i, j]))
                 committed.append(choice)
                 if j < keff[i] and proposals[i][j] == choice:
                     accepted += 1      # d_{j+1} accepted — keep going
@@ -2256,7 +2453,7 @@ class DecodeEngine:
         prefill_toks = sum(grants[i] for i in plain_rows
                            if live[i].pos < len(live[i].req.prompt))
         t0 = time.perf_counter()
-        logits_np = sampled = None
+        logits = ids = None
         plain_row_of: Dict[int, int] = {}
         spec_out: Dict[int, Tuple[List[int], int, int]] = {}
         # one decode step joins the OLDEST live request's trace (a span
@@ -2281,8 +2478,14 @@ class DecodeEngine:
                     positions = np.zeros((s_bucket, c_bucket), np.int32)
                     q_lens = np.zeros(s_bucket, np.int32)
                     lens = np.zeros(s_bucket, np.int32)
+                    # a chunk that ends inside its prompt chooses a
+                    # token too: garbage nobody reads
+                    temperature, seed = self._slot_sampling(ps_slots,
+                                                            s_bucket)
+                    chooses = False
                     for i, (s, g) in enumerate(zip(ps_slots, ps_grants)):
                         plain_row_of[id(s)] = i
+                        chooses |= s.pos + g >= len(s.req.prompt)
                         for j in range(g):
                             tokens[i, j] = s.token_at(s.pos + j)
                             positions[i, j] = s.pos + j
@@ -2294,26 +2497,31 @@ class DecodeEngine:
                     tables = self.cache.table_array(
                         [s.req.seq_id for s in ps_slots], w_bucket,
                         rows=s_bucket)
-                # dispatch of the jitted step to the logits on the host
+                # dispatch of the jitted step to the chosen ids on the
+                # host; the logits stay on the device
                 with _tracing.span("serving.decode.device_call") as sp:
                     if sp.live:
                         for key, value in _call_work(
                                 s_bucket, c_bucket, w_bucket, q_lens,
                                 lens).items():
                             sp.set_arg(key, value)
-                    logits = self._run_step_arrays(tokens, positions,
-                                                   q_lens, tables, lens)
+                    # the draw's position is lens, each slot's new
+                    # token's absolute index in its sequence: the (seed,
+                    # position) pair that makes sampling independent of
+                    # batch composition AND chunking
+                    ids, logits = self._run_step_arrays(
+                        tokens, positions, q_lens, tables, lens,
+                        temperature=temperature, seed=seed)
+                    if chooses:
+                        ids.copy_to_host_async()
                     if self._spec_k:
                         # the draft shadows every prefill chunk so its
                         # mirrored pool tracks the committed sequence
-                        # (logits discarded; its watermark advances in
-                        # the answer phase with pos)
+                        # (its choice is discarded; its watermark
+                        # advances in the answer phase with pos)
                         self._run_draft_arrays(tokens, positions, q_lens,
                                                tables, lens)
-                    logits_np = np.asarray(logits)  # [B, vocab] — newest
-                # the greedy fast path for the whole batch; per-request
-                # sampling policies resolve per slot below
-                sampled = np.asarray(np.argmax(logits_np, axis=-1))
+                    ids = self._await_ids(ids, chooses)
             if spec_rows:
                 spec_out = self._spec_substep(
                     [live[i] for i in spec_rows], w_bucket)
@@ -2336,6 +2544,7 @@ class DecodeEngine:
         notes: Dict[int, int] = {}
         produced_any = False
         n_proposed = n_accepted = 0
+        n_device = n_host = 0
         sample_s = 0.0
         # the span opens before the condition is taken: the wait for it
         # (clients reading their streams hold it) is the phase's time
@@ -2357,11 +2566,14 @@ class DecodeEngine:
                     s.req.spec_accepted += acc
                     n_proposed += ke
                     n_accepted += acc
+                    on_host = self._draws_on_host(s.req)
                     for tok in committed:
                         s.pos += 1
                         s.req.produced.append(tok)
                         produced_any = True
                         _m_tokens.inc()
+                        n_host += on_host
+                        n_device += not on_host
                         if s.first_token_steps is None:
                             s.first_token_steps = s.steps
                             _m_first_token_steps.observe(s.steps)
@@ -2413,27 +2625,28 @@ class DecodeEngine:
                     tok = None
                     mask_done = False
                     if s.pos >= len(s.req.prompt):
-                        # logits_np[row] is the slot's newest lane (the
-                        # step unembeds only lane q_len-1): prompt
-                        # token P-1 when the chunk just finished
-                        # prefill, else the decode token. s.pos is the
-                        # new token's absolute index in its sequence —
-                        # the (seed, position) pair that makes sampling
-                        # independent of batch composition AND chunking
+                        # row is the slot's newest lane (the step
+                        # unembeds only lane q_len-1): prompt token P-1
+                        # when the chunk just finished prefill, else
+                        # the decode token; ids[row] is the program's
+                        # own choice there, greedy or drawn by (seed,
+                        # s.pos)
                         row = plain_row_of[id(s)]
+                        tok = int(ids[row])
                         topk = (s.req.want_topk
                                 and s.req.first_topk is None)
-                        if (not topk and s.req.mask is None
-                                and s.req.temperature <= 0.0):
-                            # greedy: the batch argmax already chose
-                            tok = int(sampled[row])
-                        else:
+                        if topk or self._draws_on_host(s.req):
+                            # the host route: this slot's row, fetched
+                            # alone, then today's numpy choice
                             t_sample = time.perf_counter()
                             with _tracing.span("serving.decode.sample"):
                                 tok, mask_done = self._sample(
-                                    s.req, logits_np[row],
-                                    int(sampled[row]), s.pos, topk)
+                                    s.req, self._fetch_row(logits, row),
+                                    tok, s.pos, topk)
                             sample_s += time.perf_counter() - t_sample
+                            n_host += 1
+                        else:
+                            n_device += 1
                         s.req.produced.append(tok)
                         produced_any = True
                         _m_tokens.inc()
@@ -2477,33 +2690,38 @@ class DecodeEngine:
                 _m_spec_proposed.inc(n_proposed)
                 _m_spec_accepted.inc(n_accepted)
                 _m_spec_rejected.inc(n_proposed - n_accepted)
+            if n_device:
+                _m_device_choices.inc(n_device)
+            if n_host:
+                _m_host_choices.inc(n_host)
+            if n_device + n_host:
+                _m_device_choice_pct.observe(
+                    100.0 * n_device / (n_device + n_host))
             t_end = time.perf_counter()
             _m_sample_ms.observe(sample_s * 1e3)
             _m_sched_ms.observe((t_end - self._t_round
                                  - (t_step_end - t0) - sample_s) * 1e3)
             self._t_round = t_end
 
-    def _sample(self, req: _DecodeRequest, row, greedy: int,
+    def _sample(self, req: _DecodeRequest, row, chosen: int,
                 position: int, topk: bool) -> Tuple[int, bool]:
-        """The host-side choice of one slot's token from its logits row
-        (the ``serving.decode.sample`` span's body): the first
+        """The host route of one slot's token, from its fetched logits
+        row (the ``serving.decode.sample`` span's body): the first
         position's token order where the request asked for it, then the
-        masked, sampled or greedy choice. Returns ``(token,
-        mask_exhausted)``."""
+        masked or the ``top_k`` choice; a request that is here for its
+        ``first_topk`` alone keeps ``chosen``, the program's own choice.
+        Returns ``(token, mask_exhausted)``."""
         if topk:
             # the beam fork point (ISSUE 20): the FIRST generated
             # position's token order by logit, stable-sorted so ties
             # break deterministically; order[0] == argmax, so beam 0 is
             # the greedy continuation
-            order = np.argsort(-np.asarray(row, np.float64),
-                               kind="stable")
-            req.first_topk = [int(t) for t in order[:req.want_topk]]
+            req.first_topk = _top_order(row, req.want_topk)
         if req.mask is not None:
             return self._masked_choice(req, row, position)
-        if req.temperature <= 0.0:
-            return greedy, False
-        return sample_token(row, req.temperature, req.top_k, req.seed,
-                            position), False
+        if self._draws_on_host(req):
+            return self._choose(row, req, position), False
+        return chosen, False
 
     def _complete(self, s: _Slot):
         self.cache.allocator.free(s.req.seq_id)

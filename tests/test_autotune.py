@@ -340,8 +340,10 @@ def test_decode_auto_slots_zero_post_warm_compiles():
             assert eng.slot_ladder == [1, 2, 3]
             compiles = metrics.counter("serving.decode.compiles")
             c_warm = compiles.value()
-            assert c_warm == len(eng.slot_ladder) * \
-                len(eng.table_width_ladder) * len(eng.chunk_ladder)
+            # the step ladder, and the host route's row fetch: one
+            # program a slot count (ISSUE 29)
+            assert c_warm == len(eng.slot_ladder) * (
+                len(eng.table_width_ladder) * len(eng.chunk_ladder) + 1)
             rng = np.random.RandomState(3)
             reqs = [eng.submit(rng.randint(0, 32,
                                            size=1 + int(rng.randint(4))),

@@ -324,21 +324,29 @@ def test_decode_greedy_is_deterministic():
         eng.stop()
 
 
-def test_sampling_deterministic_given_seed_and_batch_independent():
+@pytest.mark.parametrize("top_k", [8, 0])
+def test_sampling_deterministic_given_seed_and_batch_independent(top_k):
     """temperature/top-k sampling (ISSUE 8 satellite, the ROADMAP
     beyond-greedy residual): the rng derives only from (request seed,
     token position), so a request's sampled output is identical across
     engines, slot ladders, and co-riding traffic — continuous batching
-    cannot perturb it."""
+    cannot perturb it. On both routes (ISSUE 29): ``top_k`` 8 is drawn
+    on the host by ``sample_token``, the full vocabulary by
+    ``choose_tokens`` inside the step's program."""
     from paddle_tpu.serving.decode import sample_token
 
     eng = _engine()
     try:
         a = eng.generate([3, 1, 4], max_new_tokens=5, temperature=0.9,
-                         top_k=8, seed=1234)
+                         top_k=top_k, seed=1234)
         b = eng.generate([3, 1, 4], max_new_tokens=5, temperature=0.9,
-                         top_k=8, seed=1234)
+                         top_k=top_k, seed=1234)
         assert a["tokens"] == b["tokens"]
+        route = "host_choices" if top_k else "device_choices"
+        assert metrics.counter("serving.decode." + route).value() == 10
+        other = eng.generate([3, 1, 4], max_new_tokens=5,
+                             temperature=0.9, top_k=top_k, seed=1235)
+        assert other["tokens"] != a["tokens"]       # the seed decides
         # a different engine shape AND concurrent traffic: same tokens
         eng2 = _engine(name="toy_s2", slots=[1, 2, 4], num_pages=16)
         try:
@@ -346,7 +354,7 @@ def test_sampling_deterministic_given_seed_and_batch_independent():
                                  temperature=0.5, seed=i)
                      for i in range(3)]
             c = eng2.generate([3, 1, 4], max_new_tokens=5,
-                              temperature=0.9, top_k=8, seed=1234)
+                              temperature=0.9, top_k=top_k, seed=1234)
             for r in noise:
                 assert r.ev.wait(120) and r.error is None
             assert c["tokens"] == a["tokens"]
@@ -392,6 +400,259 @@ def test_sampling_rpc_roundtrip(decode_server):
     assert out1["tokens"] == out2["tokens"] and len(out1["tokens"]) == 4
     with pytest.raises(ValueError, match="temperature"):
         cli.generate("gen", [1], max_new_tokens=2, temperature=-1.0)
+
+
+# --- the choice made by the step's program (ISSUE 29) --------------------
+
+def test_device_draw_matches_softmax_by_chi_square():
+    """choose_tokens at temperature > 0 is an exact draw from
+    softmax(logits / T): 4000 (seed, position) pairs on one 5-lane row,
+    held to the chi-square bound of 4 degrees of freedom (18.47 is its
+    99.9th percentile; the seeds are fixed, so this never flakes), at
+    two temperatures in one batch."""
+    from paddle_tpu.serving.decode import choose_tokens
+
+    row = np.array([0.1, 2.0, -1.0, 1.5, 0.0], np.float32)
+    n = 4000
+    seeds = (np.arange(n, dtype=np.uint64) * 2654435761 + 17) % (1 << 32)
+    positions = np.arange(n, dtype=np.int32) % 97 + 3
+    for temp in (0.7, 2.5):
+        ids = np.asarray(choose_tokens(
+            np.tile(row, (n, 1)), np.full(n, temp, np.float32),
+            seeds.astype(np.uint32), positions))
+        assert ids.dtype == np.int32 and ids.shape == (n,)
+        p = np.exp(row.astype(np.float64) / temp)
+        p /= p.sum()
+        seen = np.bincount(ids, minlength=5)
+        chi2 = float(((seen - n * p) ** 2 / (n * p)).sum())
+        assert chi2 < 18.47, (temp, seen.tolist(), (n * p).tolist())
+
+
+def test_device_choice_is_pure_in_seed_and_position_and_greedy_at_zero():
+    """A row's id depends on (its logits, temperature, seed, position)
+    and on nothing else of the batch: the same row drawn alone, in
+    another batch size and at another row index is the same id; rows at
+    temperature 0 are np.argmax, the first index on ties, whatever
+    their seed."""
+    from paddle_tpu.serving.decode import choose_tokens
+
+    rng = np.random.RandomState(3)
+    logits = rng.randn(6, 64).astype(np.float32)
+    logits[1, [5, 9, 40]] = logits[1].max() + 1.0       # a three-way tie
+    logits[4, :] = 0.25                                 # all lanes tie
+    temp = np.array([0.0, 0.0, 1.0, 0.6, 0.0, 1.0], np.float32)
+    seed = np.array([7, 0xFFFFFFFF, 11, 0xFFFFFFFF, 3, 11], np.uint32)
+    pos = np.array([1, 2, 30, 2 ** 31 - 1, 5, 31], np.int32)
+    ids = np.asarray(choose_tokens(logits, temp, seed, pos))
+    for i in (0, 1, 4):
+        assert ids[i] == np.argmax(logits[i])
+    assert ids[1] == 5 and ids[4] == 0
+    # rows 2 and 5 share a seed and differ in position: over the 64
+    # lanes of two unrelated rows that is two draws, not one
+    order = [5, 3, 2]
+    again = np.asarray(choose_tokens(logits[order], temp[order],
+                                     seed[order], pos[order]))
+    assert again.tolist() == ids[order].tolist()
+    for i in (2, 3, 5):
+        alone = np.asarray(choose_tokens(
+            logits[i:i + 1], temp[i:i + 1], seed[i:i + 1], pos[i:i + 1]))
+        assert alone[0] == ids[i]
+    # seed and position both move the draw somewhere in 40 tries
+    base = [int(np.asarray(choose_tokens(
+        logits[2:3], temp[2:3], np.array([s], np.uint32),
+        np.array([p], np.int32)))[0]) for s, p in
+        [(11, q) for q in range(40)] + [(r, 30) for r in range(40)]]
+    assert len(set(base[:40])) > 1 and len(set(base[40:])) > 1
+
+
+def test_step_hands_back_ids_and_a_row_only_on_request():
+    """The step's program hands back [slots] int32 ids beside the
+    logits, which stay on the device; _fetch_row brings one row, bitwise
+    the batch's; the call takes its five shapes by position and the
+    sampling arrays by keyword."""
+    import jax
+
+    from paddle_tpu.serving.decode import choose_tokens
+
+    eng = _engine()
+    try:
+        tokens = np.array([[3], [9]], np.int32)
+        positions = np.zeros((2, 1), np.int32)
+        ones = np.ones(2, np.int32)
+        tables = np.full((2, 1), GARBAGE_PAGE, np.int32)
+        sampling = dict(temperature=np.array([0.0, 0.8], np.float32),
+                        seed=np.array([0, 77], np.uint32))
+        base = metrics.counter("serving.decode.compiles").value()
+        ids, logits = eng._run_step_arrays(tokens, positions, ones,
+                                           tables, ones, **sampling)
+        assert isinstance(ids, jax.Array) and isinstance(logits, jax.Array)
+        assert ids.shape == (2,) and ids.dtype == np.int32
+        assert logits.shape == (2, 32) and logits.dtype == np.float32
+        whole = np.asarray(logits)
+        assert not np.array_equal(whole[0], whole[1])
+        for i in range(2):
+            row = eng._fetch_row(logits, i)
+            assert row.shape == (32,) and np.array_equal(row, whole[i])
+        assert np.asarray(ids)[0] == np.argmax(whole[0])
+        # the draw's position is the call's own lens
+        assert np.asarray(ids).tolist() == np.asarray(choose_tokens(
+            logits, *sampling.values(), ones)).tolist()
+        # five positional arguments alone are an all-greedy call of the
+        # same compiled shape (warm()'s, and the benchmark's log of
+        # shapes reads them by position)
+        ids0, logits0 = eng._run_step_arrays(tokens, positions, ones,
+                                             tables, ones)
+        assert np.array_equal(np.asarray(logits0), whole)
+        assert np.asarray(ids0).tolist() == whole.argmax(-1).tolist()
+        assert metrics.counter("serving.decode.compiles").value() == base
+    finally:
+        eng.stop()
+
+
+def test_host_route_requests_answer_as_before(monkeypatch):
+    """top_k > 0, a constraint mask and the first position's first_topk
+    order still go through the host's numpy choice, now on ONE fetched
+    row: sample_token sees a [vocab] row a token and its answer is the
+    token; first_topk is the stable order of the row the greedy token
+    leads; a request with neither never reaches the host sampler."""
+    from paddle_tpu.serving import decode
+    from paddle_tpu.serving.workloads import TokenMaskSpec
+
+    seen = []
+    real = decode.sample_token
+
+    def recorded(row, *a, **k):
+        tok = real(row, *a, **k)
+        seen.append((np.array(row), a, tok))
+        return tok
+
+    monkeypatch.setattr(decode, "sample_token", recorded)
+    eng = _engine()
+    try:
+        out = eng.generate([3, 1, 4], max_new_tokens=4, temperature=0.9,
+                           top_k=8, seed=5)
+        assert [t for _r, _a, t in seen] == out["tokens"]
+        assert all(r.shape == (32,) for r, _a, _t in seen)
+        assert [a for _r, a, _t in seen] == [
+            (0.9, 8, 5, 3 + i) for i in range(4)]
+        del seen[:]
+        eng.generate([3, 1, 4], max_new_tokens=4, temperature=0.9,
+                     seed=5)                    # full vocabulary
+        plain = eng.generate([3, 1, 4], max_new_tokens=4)
+        assert seen == []
+        beam = eng.generate([3, 1, 4], max_new_tokens=4, topk_first=5)
+        assert beam["tokens"] == plain["tokens"]
+        assert len(beam["first_topk"]) == 5
+        assert beam["first_topk"][0] == plain["tokens"][0]
+        masked = eng.generate(
+            [3, 1, 4], max_new_tokens=4, temperature=0.9, seed=5,
+            mask=TokenMaskSpec.regex("( 5 | 6 | 7 ) *").compile())
+        assert all(t in (5, 6, 7) for t in masked["tokens"])
+        assert len(seen) == 4           # the masked draw is the host's
+        counts = metrics.snapshot("serving.decode.")
+        assert counts["serving.decode.host_choices"] == 4 + 1 + 4
+        assert counts["serving.decode.device_choices"] == 4 + 4 + 3
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("k", [0, 1, 7, 63, 64, 99])
+def test_first_topk_order_is_the_whole_rows_stable_sort(k):
+    """The first position's token order comes from a partition and a
+    sort of the lanes that can be in it: lane for lane what the stable
+    sort of the whole row gave, through ties that straddle the cut,
+    -inf lanes (a mask's) and a row that is one tie."""
+    from paddle_tpu.serving.decode import _top_order
+
+    rng = np.random.RandomState(k)
+    rows = [rng.randn(64).astype(np.float32),
+            rng.randint(-2, 3, size=64).astype(np.float32),
+            np.full(64, 0.5, np.float32)]
+    rows.append(np.where(rng.rand(64) < 0.5, -np.inf, rows[1])
+                .astype(np.float32))
+    for row in rows:
+        want = np.argsort(-row.astype(np.float64), kind="stable")[:k]
+        assert _top_order(row, k) == want.tolist()
+
+
+def test_a_step_nobody_reads_a_token_of_is_not_waited_for():
+    """A step whose chunks all end inside their prompts hands back ids
+    nobody reads: the scheduler fetches nothing and goes on (at most two
+    such steps queued), and the answer is what an engine that waits for
+    every step gives."""
+    prompt = list(range(1, 14))                 # 13 tokens, chunks of 4
+    waits = []
+    eng = _engine(max_seq_len=24, num_pages=16)
+    every = _engine(max_seq_len=24, num_pages=16, name="every")
+    try:
+        real = eng._await_ids
+
+        def recorded(ids, chooses):
+            waits.append(chooses)
+            out = real(ids, chooses)
+            assert (out is None) == (not chooses)
+            assert (eng._unread is None) == chooses
+            return out
+
+        eng._await_ids = recorded
+        real_every = every._await_ids
+        every._await_ids = lambda ids, chooses: real_every(ids, True)
+        kw = dict(max_new_tokens=5, temperature=0.8, seed=21)
+        got = eng.generate(prompt, **kw)
+        # 4 + 4 + 4 inside the prompt, then its last token and 4 more
+        assert waits == [False] * 3 + [True] * 5
+        assert got["tokens"] == every.generate(prompt, **kw)["tokens"]
+        assert got["tokens"] == eng.generate(prompt, **kw)["tokens"]
+    finally:
+        eng.stop()
+        every.stop()
+
+
+def test_choice_counters_on_a_mixed_batch_and_no_compile_after_warm():
+    """device_choices / host_choices count one a chosen token by the
+    route its request takes, in one shared batch; device_choice_pct
+    observes once a step that chose a token; sample_ms reads 0.0 on the
+    steps without a host row; and a churn that mixes both routes
+    compiles nothing after warm(), the row fetch included."""
+    eng = _engine(slots=[1, 2, 4], num_pages=64, max_seq_len=16)
+    try:
+        warm = metrics.counter("serving.decode.compiles").value()
+        # the step ladder, and one row-fetch program a slot count
+        assert warm == len(eng.stats()["compiled_shapes"]) + 3
+        metrics.reset_metrics("serving.decode.")
+        reqs = _drive(eng, [
+            ([1, 2, 3], dict(max_new_tokens=6)),
+            ([4, 5], dict(max_new_tokens=5, temperature=1.0, seed=8)),
+            ([6], dict(max_new_tokens=4, temperature=1.0, top_k=4,
+                       seed=8)),
+            ([7, 8, 9], dict(max_new_tokens=3, topk_first=3))],
+            together=True)
+        assert all(r.error is None for r in reqs)
+        snap = metrics.snapshot("serving.decode.")
+        assert snap["serving.decode.device_choices"] == 6 + 5 + (3 - 1)
+        assert snap["serving.decode.host_choices"] == 4 + 1
+        assert snap["serving.decode.tokens"] == 18
+        pct = snap["serving.decode.device_choice_pct"]
+        # the longest answer takes six choosing steps; a prefill-only
+        # step observes nothing
+        assert pct["count"] == 6 <= snap["serving.decode.steps"]
+        assert 0.0 < pct["min"] < pct["max"] == 100.0
+        sample = snap["serving.decode.sample_ms"]
+        assert sample["count"] == snap["serving.decode.steps"]
+        assert sample["min"] == 0.0 < sample["max"]
+        # ragged churn over both routes: nothing compiles
+        rng = np.random.RandomState(2)
+        how = [{}, dict(temperature=0.7, seed=1),
+               dict(temperature=0.7, top_k=3, seed=1),
+               dict(topk_first=2)]
+        churn = [eng.submit(rng.randint(0, 32, size=1 + int(rng.randint(5))),
+                            max_new_tokens=1 + int(rng.randint(6)),
+                            **how[i % 4]) for i in range(12)]
+        for r in churn:
+            assert r.ev.wait(120) and r.error is None
+        assert metrics.counter("serving.decode.compiles").value() == 0
+    finally:
+        eng.stop()
 
 
 def test_continuous_beats_drain_by_exact_step_count():
@@ -618,7 +879,7 @@ def test_round_spans_in_order_on_the_profilers_clock(profiler_session,
     overlapping its sibling; device_call's args add up to the work the
     submitted prompts need."""
     eng = _engine(max_seq_len=16)
-    requests = [([1, 2, 3, 4, 5, 6], dict(max_new_tokens=3,
+    requests = [([1, 2, 3, 4, 5, 6], dict(max_new_tokens=3, top_k=8,
                                           temperature=1.0, seed=3)),
                 ([7, 8], dict(max_new_tokens=4, topk_first=4)),
                 ([5] * 9, dict(max_new_tokens=2))]
@@ -655,8 +916,9 @@ def test_round_spans_in_order_on_the_profilers_clock(profiler_session,
             assert set(kids) <= {"sample"}
         else:
             assert kids == []
-    # the sampled request's tokens and the first_topk sort were chosen
-    # inside sample spans; greedy slots open none
+    # the top_k request's tokens and the first_topk sort took the host
+    # route, inside sample spans; a slot whose token the step's program
+    # chose (greedy here) opens none
     n_sample = sum(short(e) == "sample" for e in events)
     assert n_sample == 3 + 1
     want = [_fed(len(p), kw["max_new_tokens"], alone=not together)

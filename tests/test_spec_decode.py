@@ -148,26 +148,38 @@ def test_disagreeing_draft_still_bitwise_and_counters_balance():
         1 for o in outs if o["spec_proposed"])
 
 
-def test_seeded_sampling_identical_spec_on_vs_off():
+@pytest.mark.parametrize("top_k", [6, 0])
+def test_seeded_sampling_identical_spec_on_vs_off(top_k):
     """Seeded sampling draws from an rng keyed ONLY by (seed,
     position); the verify walk re-derives the same draw per position,
     so rejection/acceptance cannot perturb the realization — same-seed
-    equality with speculation on vs off, the ISSUE 14 tier-1 pin."""
+    equality with speculation on vs off, the ISSUE 14 tier-1 pin. Both
+    routes (ISSUE 29): ``top_k`` > 0 is drawn on the host from fetched
+    rows, the full vocabulary by ``choose_tokens`` inside the plain
+    step, the draft and the verify programs — one definition each,
+    shared by all three."""
     off = _engine(name="sds_off")
     on = _engine(name="sds_on", draft_spec=_draft_small(), spec_k=3)
+    route = "host_choices" if top_k else "device_choices"
     try:
         for seed in (11, 303):
+            base = _ctr("serving.decode." + route)
             a = off.generate([7, 2, 19], max_new_tokens=10,
-                             temperature=0.9, top_k=6, seed=seed)
+                             temperature=0.9, top_k=top_k, seed=seed)
             b = on.generate([7, 2, 19], max_new_tokens=10,
-                            temperature=0.9, top_k=6, seed=seed)
+                            temperature=0.9, top_k=top_k, seed=seed)
             assert a["tokens"] == b["tokens"], f"seed {seed} diverged"
+            # every token of both engines took the request's one route
+            assert _ctr("serving.decode." + route) - base == 20
+        greedy = off.generate([7, 2, 19], max_new_tokens=10)
+        assert a["tokens"] != greedy["tokens"]      # it did sample
     finally:
         off.stop()
         on.stop()
 
 
-def test_spec_tokens_batch_composition_independent():
+@pytest.mark.parametrize("top_k", [5, 0])
+def test_spec_tokens_batch_composition_independent(top_k):
     """Speculative rounds batched with OTHER live slots commit the same
     tokens as running alone — slot assignment and co-resident
     sequences never leak into the acceptance walk."""
@@ -175,15 +187,15 @@ def test_spec_tokens_batch_composition_independent():
                  draft_spec=_draft_small(), spec_k=2)
     try:
         r1 = on.submit([4, 9, 1], max_new_tokens=8, temperature=0.7,
-                       top_k=5, seed=21)
+                       top_k=top_k, seed=21)
         r2 = on.submit([8, 8, 3], max_new_tokens=8, temperature=0.7,
-                       top_k=5, seed=22)
+                       top_k=top_k, seed=22)
         assert r1.ev.wait(120) and r2.ev.wait(120)
         assert r1.error is None and r2.error is None
         solo1 = on.generate([4, 9, 1], max_new_tokens=8,
-                            temperature=0.7, top_k=5, seed=21)
+                            temperature=0.7, top_k=top_k, seed=21)
         solo2 = on.generate([8, 8, 3], max_new_tokens=8,
-                            temperature=0.7, top_k=5, seed=22)
+                            temperature=0.7, top_k=top_k, seed=22)
     finally:
         on.stop()
     assert r1.result["tokens"] == solo1["tokens"]
@@ -222,11 +234,19 @@ def test_spec_churn_zero_post_warm_compiles():
                  draft_spec=_draft_small(), spec_k=3)
     try:
         warm = _ctr("serving.decode.compiles")
-        assert warm == len(on.stats()["compiled_shapes"])
+        # the step shapes, and the host route's row fetch: one program a
+        # slot count for the newest-lane logits (the target's and the
+        # draft's alike) and one for the verify's lanes
+        assert warm == (len(on.stats()["compiled_shapes"])
+                        + 2 * len(on.slot_ladder))
         rng = np.random.RandomState(5)
+        # greedy, drawn by the programs, drawn on the host: all warmed
+        how = [{}, dict(temperature=0.8, seed=9),
+               dict(temperature=0.8, top_k=4, seed=9)]
         reqs = [on.submit(rng.randint(0, 32, size=1 + int(rng.randint(6))),
-                          max_new_tokens=1 + int(rng.randint(8)))
-                for _ in range(6)]
+                          max_new_tokens=1 + int(rng.randint(8)),
+                          **how[i % 3])
+                for i in range(6)]
         for r in reqs:
             assert r.ev.wait(120) and r.error is None
         assert _ctr("serving.decode.compiles") == warm, \
