@@ -268,7 +268,7 @@ def run_reprefill(spec, workload):
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.serving.decode import (_ln, _pos_encoding,
+    from paddle_tpu.models.decoders import (_ln, _pos_encoding,
                                            build_decoder_params)
 
     params = build_decoder_params(spec)

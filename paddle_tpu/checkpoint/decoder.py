@@ -24,29 +24,12 @@ __all__ = ["save_decoder_checkpoint", "load_decoder_checkpoint",
 
 def expected_decoder_tensors(spec) -> Dict[str, Tuple[int, ...]]:
     """Flat ``{name: shape}`` the decoder param-tree contract implies
-    for ``spec`` — computed analytically (no parameter draws), so
+    for ``spec`` — asked of the model (``models/decoders.py``), which
+    works it out from the spec alone (no parameter draws), so
     validation is cheap even for models whose seed-build would not be.
-    The names mirror ``build_decoder_params``'s tree under the
-    ``format._flatten`` scheme (tuples index as ``/0``, ``/1``)."""
-    dm, dh = spec.d_model, spec.head_dim
-    out: Dict[str, Tuple[int, ...]] = {
-        "tok_emb": (spec.vocab, dm),
-        "lnf/0": (dm,),
-        "lnf/1": (dm,),
-    }
-    for l in range(spec.n_layers):
-        p = f"layer{l}"
-        out[f"{p}/ln1/0"] = (dm,)
-        out[f"{p}/ln1/1"] = (dm,)
-        out[f"{p}/wq"] = (dm, spec.n_heads * dh)
-        out[f"{p}/wk"] = (dm, spec.n_kv_heads * dh)
-        out[f"{p}/wv"] = (dm, spec.n_kv_heads * dh)
-        out[f"{p}/wo"] = (spec.n_heads * dh, dm)
-        out[f"{p}/ln2/0"] = (dm,)
-        out[f"{p}/ln2/1"] = (dm,)
-        out[f"{p}/w1"] = (dm, 4 * dm)
-        out[f"{p}/w2"] = (4 * dm, dm)
-    return out
+    The names mirror the model's tree under the ``format._flatten``
+    scheme (tuples index as ``/0``, ``/1``)."""
+    return spec.tensors()
 
 
 def save_decoder_checkpoint(dirname: str, spec,
@@ -78,10 +61,10 @@ def save_decoder_checkpoint(dirname: str, spec,
     feature)."""
     import numpy as _np
 
-    from ..serving.decode import build_decoder_params
-
     if params is None:
-        params = build_decoder_params(spec)
+        import jax
+
+        params = jax.device_put(spec.seeded_arrays())
     meta: Dict[str, Any] = {"kind": "decoder", "spec": spec.to_dict()}
     if step is not None:
         meta["step"] = int(step)
@@ -135,7 +118,7 @@ def load_decoder_checkpoint(dirname: str, verify: bool = True):
     tensor set is validated against the spec FIRST (names and shapes),
     so a wrong-model or hand-edited checkpoint fails with the offending
     tensor named."""
-    from ..serving.decode import DecoderSpec
+    from ..models.decoders import spec_from_dict
 
     tree, manifest = load_checkpoint_tree(dirname, verify=verify)
     meta = manifest.get("meta") or {}
@@ -144,7 +127,7 @@ def load_decoder_checkpoint(dirname: str, verify: bool = True):
             f"'{dirname}' is a {meta.get('kind') or 'generic'} "
             "checkpoint, not a decoder checkpoint (no DecoderSpec in "
             "its meta)")
-    spec = DecoderSpec.from_dict(dict(meta["spec"]))
+    spec = spec_from_dict(dict(meta["spec"]))
 
     # validate the FLAT view against the analytic contract before any
     # device transfer
@@ -152,6 +135,9 @@ def load_decoder_checkpoint(dirname: str, verify: bool = True):
 
     flat, _skel = _flatten(tree)
     want = expected_decoder_tensors(spec)
+    import jax.numpy as jnp
+
+    want_dtype = np.dtype(jnp.dtype(spec.param_dtype))
     missing = sorted(set(want) - set(flat))
     extra = sorted(set(flat) - set(want))
     if missing or extra:
@@ -166,14 +152,14 @@ def load_decoder_checkpoint(dirname: str, verify: bool = True):
                 f"tensor '{name}' in '{dirname}' has shape {got}, "
                 f"spec requires {shape}")
         dt = np.dtype(flat[name].dtype)
-        if dt != np.float32:
-            # refuse, don't downcast: jnp.asarray would silently
-            # squeeze a float64 (or quantized) tree into float32 and
-            # the served tokens would differ from the saved model's —
-            # the bitwise-roundtrip promise dies without a named error
+        if dt != want_dtype:
+            # refuse, don't cast: jnp.asarray would silently squeeze a
+            # float64 (or quantized) tree into the served dtype and the
+            # served tokens would differ from the saved model's — the
+            # bitwise-roundtrip promise dies without a named error
             raise CheckpointError(
-                f"tensor '{name}' in '{dirname}' is {dt}, the decoder "
-                f"contract serves float32 — convert at save time, "
-                "never implicitly at deploy")
+                f"tensor '{name}' in '{dirname}' is {dt}, the "
+                f"{spec.family} decoder contract serves {want_dtype} — "
+                "convert at save time, never implicitly at deploy")
 
     return spec, tree

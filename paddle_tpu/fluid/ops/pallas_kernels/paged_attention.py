@@ -129,9 +129,26 @@ def _canon_chunked(q, kv_lens, q_lens):
     return q, q_lens
 
 
+def _key_limit(kv_len, q_len, lane, block_length: int):
+    """The last key position query lane ``lane`` sees. The lane sits at
+    absolute position ``pos = kv_len - q_len + lane``. Causal
+    (``block_length`` 1): its own, ``pos``. Block diffusion (ISSUE 30,
+    ``block_length`` B > 1, chunks of whole blocks starting at a multiple
+    of B): causal between blocks and both ways inside one, so the last
+    key of its block that exists, ``min(kv_len, (pos // B + 1) * B) -
+    1``. The causal form is spelt as it always was: B = 1 traces the
+    program it always did."""
+    pos = kv_len - q_len + lane
+    if block_length == 1:
+        return pos
+    return jnp.minimum(kv_len,
+                       (pos // block_length + 1) * block_length) - 1
+
+
 def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
                               *, q_lens=None,
-                              scale: Optional[float] = None):
+                              scale: Optional[float] = None,
+                              block_length: int = 1):
     """Pure-jax oracle: gather the pages, mask causally past each
     query's visibility limit, dense softmax. Same signature/semantics
     as the kernel. Returns the same rank as ``q``. Its two dots run at
@@ -155,11 +172,13 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
     hi = jax.lax.Precision.HIGHEST
     s = jnp.einsum("bchd,bthd->bcht", qf, k.astype(jnp.float32),
                    precision=hi)
-    # chunk-causal visibility: query j (absolute position
-    # kv_len - q_len + j) sees keys at positions <= its own; dead
-    # lanes (j >= q_len) see nothing -> exact-zero rows
+    # visibility: query j (absolute position kv_len - q_len + j) sees
+    # keys at positions <= its own (its block's end under block
+    # diffusion, _key_limit); dead lanes (j >= q_len) see nothing ->
+    # exact-zero rows
     lane = jnp.arange(c)[None, :]                       # [1, C]
-    limit = kv_lens[:, None] - q_lens[:, None] + lane   # [B, C]
+    limit = _key_limit(kv_lens[:, None], q_lens[:, None], lane,
+                       block_length)                    # [B, C]
     valid = lane < q_lens[:, None]                      # [B, C]
     t = jnp.arange(w * ps)[None, None, :]               # [1, 1, T]
     keep = (t <= limit[:, :, None]) & valid[:, :, None]  # [B, C, T]
@@ -176,7 +195,7 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
 
 def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, k_ref,
                   v_ref, o_ref, m_sc, l_sc, acc_sc, *, scale, page_size,
-                  rep, chunk):
+                  rep, chunk, block_length):
     """One (sequence b, page w) grid step: fold this page's keys into
     the running online softmax for every query lane of the chunk. W
     iterates innermost (TPU grids run sequentially), so the scratch
@@ -202,12 +221,13 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, k_ref,
         v = jnp.repeat(v, rep, axis=1)
     # this page covers absolute key positions [w*ps, w*ps + ps);
     # query lane j sits at absolute position kv_len - q_len + j and
-    # sees keys at positions <= its own (chunk-causal); dead lanes
+    # sees keys at positions <= its own (chunk-causal; up to its
+    # block's end under block diffusion, _key_limit); dead lanes
     # (j >= q_len) see nothing
     offs = w * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)                 # [1, ps]
     lane = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)  # [C, 1]
-    limit = kv_len - q_len + lane                     # [C, 1]
+    limit = _key_limit(kv_len, q_len, lane, block_length)  # [C, 1]
     keep = (offs <= limit) & (lane < q_len)           # [C, ps]
     keep = keep[:, None, :]                           # [C, 1, ps]
     # s[c, h, p] = q[c, h, :] . k[p, h, :]  (head-batched matvec: the
@@ -240,7 +260,8 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, k_ref,
 def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
                             *, q_lens=None,
                             scale: Optional[float] = None,
-                            interpret: bool = False):
+                            interpret: bool = False,
+                            block_length: int = 1):
     b, c, hq, d, ps, hkv, w = _check_shapes(q, k_pages, v_pages,
                                             page_tables, kv_lens, q_lens)
     squeeze = q.ndim == 3
@@ -273,7 +294,8 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
         ],
     )
     kernel = functools.partial(_paged_kernel, scale=scale, page_size=ps,
-                               rep=rep, chunk=c)
+                               rep=rep, chunk=c,
+                               block_length=int(block_length))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -308,13 +330,16 @@ def paged_route(slots: int, impl: Optional[str] = None) -> str:
 def paged_attention(q, k_pages, v_pages, page_tables, kv_lens,
                     *, q_lens=None, scale: Optional[float] = None,
                     interpret: Optional[bool] = None,
-                    impl: Optional[str] = None):
+                    impl: Optional[str] = None,
+                    block_length: int = 1):
     """Route between the Pallas kernel (compiled on TPU; interpret mode
     off-TPU when forced via ``use_pallas_kernels=True`` for tests) and
     the pure-jax reference, as ``paged_route`` names it; every trace
     counts its route. ``q`` may be ``[B, Hq, D]`` (one token per slot)
     or ``[B, C, Hq, D]`` with ``q_lens`` (a prefill chunk per slot,
-    causal within the chunk)."""
+    causal within the chunk). ``block_length`` (static) is the mask's
+    block: 1 is causal; B > 1 lets a lane see its whole block of B
+    (``_key_limit``), for chunks of whole blocks."""
     from ...flags import pallas_interpret
 
     if paged_route(q.shape[0], impl) == "paged_kernel":
@@ -323,7 +348,8 @@ def paged_attention(q, k_pages, v_pages, page_tables, kv_lens,
             q, k_pages, v_pages, page_tables, kv_lens, q_lens=q_lens,
             scale=scale,
             interpret=pallas_interpret() if interpret is None
-            else interpret)
+            else interpret, block_length=block_length)
     _m_route_ref.inc()
     return paged_attention_reference(q, k_pages, v_pages, page_tables,
-                                     kv_lens, q_lens=q_lens, scale=scale)
+                                     kv_lens, q_lens=q_lens, scale=scale,
+                                     block_length=block_length)

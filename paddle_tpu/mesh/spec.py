@@ -344,6 +344,10 @@ def decoder_rules(tp: str = "tp") -> ShardingRules:
             (r"/w1$", P(None, tp)),
             (r"/w2$", P(tp, None)),
             (r"^tok_emb$", P(tp, None)),
+            # an untied output head [d, vocab] (models/sdar_moe.py)
+            # shards its vocabulary columns; experts and routers
+            # replicate until the rules gain an expert axis (ROADMAP M1)
+            (r"^head$", P(None, tp)),
         ],
         batch_axis=None,
     )

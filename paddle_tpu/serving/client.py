@@ -157,7 +157,8 @@ class ServingClient:
                  deadline_ms: Optional[float] = None,
                  temperature: float = 0.0, top_k: int = 0,
                  seed: int = 0, stream: bool = False,
-                 stream_wait_ms: float = 20000.0
+                 stream_wait_ms: float = 20000.0,
+                 denoise_steps: Optional[int] = None
                  ) -> Union[Dict[str, Any], TokenStream]:
         """Autoregressive decode on a loaded decoder. Buffered
         (default) returns ``{"model", "version", "tokens",
@@ -171,20 +172,25 @@ class ServingClient:
         re-decoding. ``temperature``/``top_k``/``seed`` select the
         per-request sampling policy (0.0 = greedy argmax; sampled
         output is deterministic given the seed — which is also what
-        makes a fleet-level stream resume exact)."""
+        makes a fleet-level stream resume exact). ``denoise_steps``
+        is a block-diffusion model's field (ISSUE 30): the denoise
+        passes a block takes; such a model's tokens arrive a block at a
+        time. It travels only when given, so a causal decoder's frames
+        are what they were."""
         prompt = [int(t) for t in prompt]
+        extra = () if denoise_steps is None else (int(denoise_steps),)
         try:
             if stream:
                 header = self._rpc.call(
                     "generate_stream_start", model, prompt,
                     int(max_new_tokens), deadline_ms, float(temperature),
-                    int(top_k), int(seed))
+                    int(top_k), int(seed), *extra)
                 return TokenStream(self, model, header,
                                    wait_ms=stream_wait_ms)
             return self._rpc.call(
                 "generate", model, prompt,
                 int(max_new_tokens), deadline_ms, float(temperature),
-                int(top_k), int(seed))
+                int(top_k), int(seed), *extra)
         except RuntimeError as e:
             _raise_typed(e)
 

@@ -95,7 +95,7 @@ from .kv_cache import GARBAGE_PAGE, HostSpillStore, PagedKvCache
 __all__ = ["DecoderSpec", "DecodeEngine", "build_decoder_params",
            "seeded_decoder_arrays", "decoder_step",
            "decoder_step_chunked", "width_ladder", "sample_token",
-           "choose_tokens", "validate_draft_spec"]
+           "choose_tokens", "choose_block", "validate_draft_spec"]
 
 _log = get_logger("serving")
 
@@ -178,276 +178,35 @@ _m_masked_tokens = _metrics.counter("serving.decode.masked_tokens")
 _m_embed_requests = _metrics.counter("serving.decode.embed.requests")
 _m_embed_steps = _metrics.counter("serving.decode.embed.steps")
 _m_embed_tokens = _metrics.counter("serving.decode.embed.tokens")
+# generation by diffusion over blocks (ISSUE 30): a block model's
+# decoding slot runs PASSES of block_length lanes, denoise passes that
+# unmask some lanes and one commit pass whose K/V stand; a commit answers
+# up to block_length tokens at once (tokens_dropped: those of a block past
+# max_new or an eos). tokens_per_pass observes, once a step that ran a
+# block pass, the tokens the step committed over the slots that ran one
+_m_block_passes = _metrics.counter("serving.decode.block.passes")
+_m_block_committed = _metrics.counter(
+    "serving.decode.block.tokens_committed")
+_m_block_dropped = _metrics.counter("serving.decode.block.tokens_dropped")
+_m_block_tokens_per_pass = _metrics.histogram(
+    "serving.decode.block.tokens_per_pass")
+# sparse experts (ISSUE 30): token-to-expert assignments the steps made
+# (live lanes x experts a token x layers), and, once a step that ran a
+# block pass, the largest per-expert count over the mean in the step's
+# worst layer (1.0 = even load)
+_m_moe_assignments = _metrics.counter("serving.decode.moe.assignments")
+_m_moe_load = _metrics.histogram("serving.decode.moe.load_max_over_mean")
 
 
 # --- the pluggable decoder model ----------------------------------------
-
-class DecoderSpec:
-    """Architecture + identity of a decoder the engine can serve.
-    ``d_model == n_heads * head_dim`` (enforced); ``n_heads`` must be a
-    multiple of ``n_kv_heads`` (GQA). Params are DETERMINISTIC in
-    ``seed`` so two replicas loading the same spec serve bitwise the
-    same model — and tests can reference-check outputs."""
-
-    __slots__ = ("vocab", "d_model", "n_layers", "n_heads", "n_kv_heads",
-                 "head_dim", "seed", "eos_id")
-
-    def __init__(self, vocab: int = 64, d_model: int = 32,
-                 n_layers: int = 2, n_heads: int = 4,
-                 n_kv_heads: Optional[int] = None, seed: int = 0,
-                 eos_id: Optional[int] = None):
-        self.vocab = int(vocab)
-        self.d_model = int(d_model)
-        self.n_layers = int(n_layers)
-        self.n_heads = int(n_heads)
-        self.n_kv_heads = int(n_kv_heads if n_kv_heads is not None
-                              else n_heads)
-        if self.d_model % 2:
-            raise ValueError(f"d_model {d_model} must be even "
-                             f"(sinusoidal encoding pairs sin/cos halves)")
-        if self.d_model % self.n_heads:
-            raise ValueError(f"d_model {d_model} not divisible by "
-                             f"n_heads {n_heads}")
-        if self.n_heads % self.n_kv_heads:
-            raise ValueError(f"n_heads {n_heads} not a multiple of "
-                             f"n_kv_heads {self.n_kv_heads}")
-        self.head_dim = self.d_model // self.n_heads
-        self.seed = int(seed)
-        self.eos_id = None if eos_id is None else int(eos_id)
-
-    def to_dict(self) -> Dict[str, Any]:
-        return {k: getattr(self, k) for k in
-                ("vocab", "d_model", "n_layers", "n_heads", "n_kv_heads",
-                 "seed", "eos_id")}
-
-    @classmethod
-    def from_dict(cls, d: Dict[str, Any]) -> "DecoderSpec":
-        allowed = ("vocab", "d_model", "n_layers", "n_heads",
-                   "n_kv_heads", "seed", "eos_id")
-        # reject, don't drop: a misspelled field silently deploying a
-        # default-architecture decoder is a wrong-model hot-swap
-        # (head_dim is derived — accepted only if consistent)
-        unknown = sorted(set(d) - set(allowed) - {"head_dim"})
-        if unknown:
-            raise ValueError(
-                f"unknown DecoderSpec field(s) {unknown}; "
-                f"valid: {sorted(allowed)}")
-        spec = cls(**{k: v for k, v in d.items() if k in allowed})
-        if "head_dim" in d and int(d["head_dim"]) != spec.head_dim:
-            raise ValueError(
-                f"head_dim {d['head_dim']} contradicts d_model "
-                f"{spec.d_model} / n_heads {spec.n_heads} = "
-                f"{spec.head_dim} — head_dim is derived, not free")
-        return spec
-
-
-def validate_draft_spec(target: DecoderSpec, draft: DecoderSpec):
-    """Cross-validate a speculative DRAFT decoder against its target
-    (ISSUE 14 satellite): a mismatched draft must fail at LOAD, typed
-    and naming the field, not mid-verify with garbage acceptance. The
-    draft proposes token ids the target scores, so the vocabularies
-    must be identical; page geometry (page_size / num_pages) is shared
-    BY CONSTRUCTION — the draft's pool mirrors the target's allocator
-    and page tables, so it cannot diverge. Everything architectural
-    (layers, heads, d_model) is free: that asymmetry is the whole
-    speedup."""
-    if draft.vocab != target.vocab:
-        raise ValueError(
-            f"draft/target DecoderSpec mismatch on field 'vocab': "
-            f"draft {draft.vocab} != target {target.vocab} — the draft "
-            f"proposes token ids the target must score")
-    if draft.eos_id != target.eos_id:
-        raise ValueError(
-            f"draft/target DecoderSpec mismatch on field 'eos_id': "
-            f"draft {draft.eos_id} != target {target.eos_id} — "
-            f"termination is decided on committed (target-verified) "
-            f"tokens, so the specs must agree on it")
-
-
-def seeded_decoder_arrays(spec: DecoderSpec) -> Dict[str, Any]:
-    """The deterministic parameter tree as HOST numpy arrays (seeded
-    draws, scaled-normal init). Whoever serves it places it: the
-    engine puts each leaf straight onto its shard of a mesh, so no
-    tensor is first materialized whole on one chip."""
-    rng = np.random.RandomState(spec.seed)
-    dm, dh = spec.d_model, spec.head_dim
-
-    def mat(fan_in, *shape):
-        return (rng.randn(*shape) / math.sqrt(fan_in)).astype(np.float32)
-
-    def ln():
-        return (np.ones((dm,), np.float32), np.zeros((dm,), np.float32))
-
-    params: Dict[str, Any] = {"tok_emb": mat(dm, spec.vocab, dm),
-                              "lnf": ln()}
-    for l in range(spec.n_layers):
-        params[f"layer{l}"] = {
-            "ln1": ln(),
-            "wq": mat(dm, dm, spec.n_heads * dh),
-            "wk": mat(dm, dm, spec.n_kv_heads * dh),
-            "wv": mat(dm, dm, spec.n_kv_heads * dh),
-            "wo": mat(dm, spec.n_heads * dh, dm),
-            "ln2": ln(),
-            "w1": mat(dm, dm, 4 * dm),
-            "w2": mat(4 * dm, 4 * dm, dm),
-        }
-    return params
-
-
-def build_decoder_params(spec: DecoderSpec) -> Dict[str, Any]:
-    """``seeded_decoder_arrays`` on the default device — the test/bench
-    stand-in for loading a checkpoint."""
-    import jax
-
-    return jax.device_put(seeded_decoder_arrays(spec))
-
-
-def _ln(x, gb):
-    import jax.numpy as jnp
-
-    g, b = gb
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-    return (x - mu) / jnp.sqrt(var + 1e-6) * g + b
-
-
-def _pos_encoding(positions, d_model):
-    """Sinusoidal [B, d_model] — unbounded positions, no learned table
-    to cap sequence length."""
-    import jax.numpy as jnp
-
-    half = d_model // 2
-    freq = jnp.exp(-math.log(10000.0) * jnp.arange(half) / half)
-    ang = positions[:, None].astype(jnp.float32) * freq[None, :]
-    return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
-
-
-def decoder_step_chunked(params, spec: DecoderSpec, tokens, positions,
-                         q_lens, k_pool, v_pool, page_tables, kv_lens,
-                         all_lanes: bool = False,
-                         return_hidden: bool = False,
-                         attention_impl: Optional[str] = None):
-    """ONE mixed decode/prefill step for a fixed-slot batch
-    (ISSUE 10). Each slot carries up to C tokens of ITS sequence — a
-    prefill chunk, a single decode token at C lane 0, or nothing —
-    attending causally within the chunk. Functional: writes every
-    valid lane's K/V into the paged pools (dead lanes and dead slots
-    write the garbage page), attends through the page tables, returns
-    ``(k_pool, v_pool, logits [B, vocab])``.
-
-    tokens/positions: [B, C] int32, lane ``j`` of slot ``i`` valid iff
-    ``j < q_lens[i]`` (invalid lanes: 0/0 — masked to the garbage
-    page, never trusted). kv_lens: [B] int32 — valid keys INCLUDING
-    this step's q_len tokens. Chunking is pure packing: the math per
-    token is identical to feeding the same tokens one step at a time
-    (the chunked-vs-unchunked greedy-equality test pins it).
-
-    Logits come back ONLY for each slot's newest lane (``q_len - 1``)
-    — the one position a token is ever chosen at (a chunk that
-    doesn't finish its prompt uses no logits at all). Unembedding is
-    the widest matmul of the step: unembedding all C lanes would waste
-    ~(C-1)/C of it plus a C-times-larger device->host transfer on
-    every prefill step.
-
-    ``all_lanes=True`` is the SPECULATIVE-VERIFY form (ISSUE 14):
-    logits come back for EVERY lane (``[B, C, vocab]``) — lane ``j`` is
-    the target's distribution for position ``positions[:, j] + 1``, so
-    one call scores a draft's ``k`` proposals plus the bonus position.
-    The full-lane unembed is exactly the price of verification (C =
-    spec_k + 1 lanes, not the prefill chunk width); acceptance happens
-    host-side in the engine.
-
-    ``return_hidden=True`` (requires ``all_lanes``) additionally
-    returns the final-norm hidden states ``[B, C, d_model]`` — the
-    EMBEDDING/SCORING form (ISSUE 20): one chunked call yields both
-    every lane's pooled-representation input and its next-token
-    distribution (per-token logprobs), so prompt-only scoring requests
-    ride the exact prefill path generation uses.
-
-    ``attention_impl`` is handed to ``paged_attention`` as ``impl``:
-    None lets the flags route, ``"reference"`` names the pure-jax path
-    (the engine does under a mesh).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..fluid.ops.pallas_kernels.paged_attention import paged_attention
-
-    b, c = tokens.shape
-    ps = k_pool.shape[2]
-    dm, dh = spec.d_model, spec.head_dim
-    # the jax.named_scope blocks name the step's device work by role
-    # (ISSUE 27): every operation's op_name in the compiled program, and
-    # so in a device trace, carries decoder.embed / .kv_write / .attn /
-    # .mlp / .head. Trace-time metadata only
-    with jax.named_scope("decoder.embed"):
-        lane = jnp.arange(c)[None, :]                      # [1, C]
-        valid = lane < q_lens[:, None]                     # [B, C]
-        x = params["tok_emb"][tokens] * math.sqrt(dm) + \
-            _pos_encoding(positions.reshape(-1), dm).reshape(b, c, dm)
-        page_idx = positions // ps
-        # each lane's physical page: its slot's table row at the
-        # token's page index. Invalid lanes (j >= q_len, padded dead
-        # slots) are FORCED to the garbage page — a live slot's row 0
-        # must never be clobbered by a dead lane's position-0 write
-        page = jnp.where(
-            valid, jnp.take_along_axis(page_tables, page_idx, axis=1),
-            GARBAGE_PAGE)                                  # [B, C]
-        off = jnp.where(valid, positions % ps, 0)
-    for l in range(spec.n_layers):
-        lp = params[f"layer{l}"]
-        with jax.named_scope("decoder.attn"):
-            h = _ln(x, lp["ln1"])
-            q = (h @ lp["wq"]).reshape(b, c, spec.n_heads, dh)
-            k = (h @ lp["wk"]).reshape(b, c, spec.n_kv_heads, dh)
-            v = (h @ lp["wv"]).reshape(b, c, spec.n_kv_heads, dh)
-        # write the whole chunk's K/V, THEN attend: within the chunk,
-        # query j sees keys i <= j of the same chunk — write-before-
-        # attend makes the chunk exactly equal to sequential steps
-        with jax.named_scope("decoder.kv_write"):
-            k_pool = k_pool.at[l, page, off].set(k.astype(k_pool.dtype))
-            v_pool = v_pool.at[l, page, off].set(v.astype(v_pool.dtype))
-        with jax.named_scope("decoder.attn"):
-            attn = paged_attention(q, k_pool[l], v_pool[l], page_tables,
-                                   kv_lens, q_lens=q_lens,
-                                   impl=attention_impl)
-            x = x + attn.reshape(b, c, spec.n_heads * dh) @ lp["wo"]
-        with jax.named_scope("decoder.mlp"):
-            h2 = _ln(x, lp["ln2"])
-            x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
-    with jax.named_scope("decoder.head"):
-        if all_lanes:
-            # verify form: every lane's logits ([B, C, vocab]) — the
-            # acceptance walk needs the target's distribution at each
-            # proposed position, not just the newest
-            h = _ln(x, params["lnf"])
-            logits = h @ params["tok_emb"].T
-            if return_hidden:
-                return k_pool, v_pool, logits, h
-            return k_pool, v_pool, logits
-        # unembed only each slot's newest lane (dead slots gather lane
-        # 0 — garbage the scheduler never samples)
-        last = jnp.maximum(q_lens - 1, 0)[:, None, None]   # [B, 1, 1]
-        x_last = jnp.take_along_axis(
-            x, jnp.broadcast_to(last, (b, 1, dm)), axis=1)[:, 0]
-        logits = _ln(x_last, params["lnf"]) @ params["tok_emb"].T
-        return k_pool, v_pool, logits
-
-
-def decoder_step(params, spec: DecoderSpec, tokens, positions,
-                 k_pool, v_pool, page_tables, kv_lens):
-    """The PR 6 single-token step — now the C=1 case of
-    ``decoder_step_chunked`` (one implementation, so the two forms
-    cannot drift). tokens/positions: [B] int32 (dead slots: 0/0 with
-    an all-garbage table row); kv_lens: [B] int32 — valid keys
-    INCLUDING this step's token (0 = dead slot -> exact-zero attention
-    output). Returns ``(k_pool, v_pool, logits [B, vocab])``."""
-    import jax.numpy as jnp
-
-    q_lens = (kv_lens > 0).astype(jnp.int32)
-    return decoder_step_chunked(
-        params, spec, tokens[:, None], positions[:, None], q_lens,
-        k_pool, v_pool, page_tables, kv_lens)
+# The engine asks a MODEL (paddle_tpu/models/decoders.py, D1): its pool
+# layout, its parameter tree, its step and the block length it generates
+# by. The dense decoder this engine was built on is one such model and
+# keeps its names here.
+from ..models.decoders import (DecoderSpec, build_decoder_params,  # noqa: E402,F401
+                               decoder_step, decoder_step_chunked,
+                               seeded_decoder_arrays, spec_from_dict,
+                               validate_draft_spec)
 
 
 # --- sampling -----------------------------------------------------------
@@ -536,13 +295,49 @@ def choose_tokens(logits, temperature, seed, position):
         return jnp.argmax(scores, axis=-1).astype(jnp.int32)
 
 
+def choose_block(logits, temperature, seed, positions, masked, n_unmask):
+    """The block form of ``choose_tokens`` (ISSUE 30), for a model that
+    generates by diffusion over blocks: ``logits [S, B, V]`` float32 of a
+    pass's B lanes (lane i predicts the token AT i), ``temperature [S]``,
+    ``seed [S]`` uint32, ``positions [S, B]`` int32 (each lane's absolute
+    index), ``masked [S, B]`` bool (the lanes still to be filled: slot
+    STATE, never a comparison with the mask id) and ``n_unmask [S]`` int32
+    -> ``(x0 [S, B] int32, confidence [S, B] float32, unmask [S, B]
+    bool)``. ``x0`` is ``choose_tokens``' choice at every lane (argmax at
+    temperature 0, else the draw keyed by (seed, position)), its
+    confidence ``softmax(logits)[x0]``, and ``unmask`` marks the
+    ``n_unmask`` most confident of the masked lanes, ties to the lower
+    lane (confidence-ordered static unmasking). All per slot: batch
+    composition cannot move a request's tokens."""
+    import jax
+    import jax.numpy as jnp
+
+    s, b, v = logits.shape
+    x0 = choose_tokens(logits.reshape(s * b, v), jnp.repeat(temperature, b),
+                       jnp.repeat(seed, b),
+                       positions.reshape(s * b)).reshape(s, b)
+    with jax.named_scope("decoder.unmask"):
+        chosen = jnp.take_along_axis(logits, x0[..., None], axis=-1)[..., 0]
+        conf = jnp.exp(chosen - jax.nn.logsumexp(logits, axis=-1))
+        score = jnp.where(masked, conf, -1.0)
+        lane = jnp.arange(b)
+        ahead = (score[:, None, :] > score[:, :, None]) | (
+            (score[:, None, :] == score[:, :, None])
+            & (lane[None, None, :] < lane[None, :, None]))
+        rank = jnp.sum(ahead, axis=-1)                   # [S, B]
+        unmask = masked & (rank < n_unmask[:, None])
+    return x0, conf, unmask
+
+
 def _call_work(slots: int, chunk: int, width: int, q_lens,
-               kv_lens) -> Dict[str, int]:
+               kv_lens, block: int = 1) -> Dict[str, int]:
     """``serving.decode.device_call``'s args: the compiled buckets of
     one step call and the three sums its attention work follows from,
     whatever implements the call — query tokens, keys in view, and
-    causal query-key pairs (a slot's ``q`` newest tokens see ``kv-q+1``
-    up to ``kv`` keys). A layer's attention needs
+    query-key pairs under the model's mask: a slot's ``q`` newest
+    tokens, whole blocks of ``block``, see from ``kv-q+block`` up to
+    ``kv`` keys (``block`` 1 is the causal mask: ``kv-q+1`` up to
+    ``kv``). A layer's attention needs
     ``4 * heads * head_dim * attn_pairs`` operations and reads
     ``2 * kv_heads * head_dim * kv_tokens`` K/V elements, beside
     ``2 * heads * head_dim * q_tokens`` of q and out. Dead slots are
@@ -550,7 +345,7 @@ def _call_work(slots: int, chunk: int, width: int, q_lens,
     q, kv = q_lens.astype(np.int64), kv_lens.astype(np.int64)
     return {"slots": slots, "chunk": chunk, "width": width,
             "q_tokens": int(q.sum()), "kv_tokens": int(kv.sum()),
-            "attn_pairs": int(((2 * kv - q + 1) * q // 2).sum())}
+            "attn_pairs": int(((2 * kv - q + block) * q // 2).sum())}
 
 
 # --- ladders ------------------------------------------------------------
@@ -577,12 +372,14 @@ class _DecodeRequest:
                  "seed", "produced", "cached_tokens", "cow", "resume_pos",
                  "published", "carry_steps", "carry_fts", "needs_alloc",
                  "resume_dpos", "spec_proposed", "spec_accepted",
-                 "mask", "mask_state", "want_topk", "first_topk")
+                 "mask", "mask_state", "want_topk", "first_topk",
+                 "denoise_steps", "passes")
 
     def __init__(self, prompt: np.ndarray, max_new: int,
                  deadline: Optional[float], seq_id: int,
                  temperature: float = 0.0, top_k: int = 0, seed: int = 0,
-                 mask: Optional[Any] = None, want_topk: int = 0):
+                 mask: Optional[Any] = None, want_topk: int = 0,
+                 denoise_steps: int = 1):
         self.prompt = prompt
         self.max_new = int(max_new)
         self.deadline = deadline
@@ -634,6 +431,14 @@ class _DecodeRequest:
         self.mask_state = mask.start if mask is not None else 0
         self.want_topk = int(want_topk)
         self.first_topk: Optional[List[int]] = None
+        # block diffusion (ISSUE 30): denoise passes a block (each
+        # unmasks block_length / denoise_steps lanes); a request that
+        # asked for its first_topk also keeps every pass's input block,
+        # choices, confidences and unmasked lanes (result["passes"]):
+        # what a comparison with a plain reference needs
+        self.denoise_steps = int(denoise_steps)
+        self.passes: Optional[List[Dict[str, Any]]] = (
+            [] if want_topk else None)
 
     def fail(self, err: BaseException):
         self.error = err
@@ -642,7 +447,7 @@ class _DecodeRequest:
 
 class _Slot:
     __slots__ = ("req", "pos", "pages_held", "steps", "first_token_steps",
-                 "pending_restore", "dpos")
+                 "pending_restore", "dpos", "block", "masked")
 
     def __init__(self, req: _DecodeRequest, pages_held: int):
         self.req = req
@@ -661,6 +466,12 @@ class _Slot:
         # itself), so the next propose round catches up with a <= 2-
         # lane chunk before proposing
         self.dpos = 0
+        # block diffusion (ISSUE 30): the block this slot is filling, at
+        # positions [pos, pos + B) — its tokens as far as known, and
+        # WHICH LANES ARE MASKED (state; a prompt that holds the mask id
+        # is harmless). None = no block open (prefill, or a causal model)
+        self.block: Optional[List[int]] = None
+        self.masked: Optional[List[bool]] = None
 
     def token_at(self, idx: int) -> int:
         """The sequence's token at absolute position ``idx``: a prompt
@@ -752,6 +563,26 @@ class DecodeEngine:
         self.name = str(name)
         self.version = int(version)
         self.spec = spec
+        # the block length the MODEL generates by (ISSUE 30): 1 for a
+        # causal model, the engine it always was; B > 1 for generation by
+        # diffusion over blocks — a decoding slot then runs passes of B
+        # lanes (_step_blocks) and a prefill chunk is whole blocks. What
+        # speculation, the embed lane and the prefix cache assume (one
+        # token a pass; K/V that are a function of the tokens before
+        # them) does not hold for such a model: each refuses it by name
+        self._block = int(spec.block_length)
+        if self._block > 1:
+            for field, given in (("draft_spec", draft_spec is not None),
+                                 ("spec_k", bool(spec_k)),
+                                 ("embeddings", bool(embeddings)),
+                                 ("prefix_cache", bool(prefix_cache))):
+                if given:
+                    raise ValueError(
+                        f"decoder '{name}' generates by diffusion over "
+                        f"blocks of {self._block} (family "
+                        f"{spec.family!r}): '{field}' is for causal "
+                        f"models (block_length 1)")
+            prefix_cache = False
         # mesh-sharded serving (ISSUE 15): one replica SPANS chips.
         # `mesh` is a MeshSpec / axes dict / "tp=2" string (None reads
         # FLAGS['serving_mesh_axes']; '' = single-chip, bit-identical
@@ -780,7 +611,7 @@ class DecodeEngine:
         # shares _step_mu with the compiled step + shape set: the lock
         # serializes every read-step-rebind against retirement's drop
         self._params = self._place_params(
-            seeded_decoder_arrays(spec)
+            spec.seeded_arrays()
             if params is None else params)  # guarded-by: _step_mu
         # the Pallas paged kernel has no SPMD form (a shard_map form is
         # ROADMAP S5), so an engine whose pools are sharded over a mesh
@@ -835,7 +666,7 @@ class DecodeEngine:
         self._headroom_pages = max(0, int(FLAGS["kv_decode_headroom"]))
         self.cache = PagedKvCache(
             spec.n_layers, spec.n_kv_heads, spec.head_dim,
-            page_size=ps, num_pages=npages,
+            page_size=ps, num_pages=npages, dtype=spec.pool_dtype,
             label=f"{self.name}.v{self.version}",
             prefix_cache=self._prefix_on,
             mesh=self._mesh, shard_spec=self._pool_spec())
@@ -857,11 +688,16 @@ class DecodeEngine:
                     if prefill_chunk is None else prefill_chunk)
         self._prefill_chunk = max(1, min(chunk, max(1,
                                                     self.max_seq_len - 1)))
+        # a block model prefills whole blocks: its chunk is the next
+        # multiple of the block length
+        self._prefill_chunk = -(-self._prefill_chunk
+                                // self._block) * self._block
         # the third padded dimension of the compiled step: pure-decode
         # steps ride the C=1 shapes (exactly the PR 6 step — chunking
-        # costs nothing when no prompt is in flight), steps carrying a
-        # prefill grant ride the C=chunk shapes
-        self._chunk_ladder = sorted({1, self._prefill_chunk})
+        # costs nothing when no prompt is in flight; a block model's
+        # passes ride C=block_length), steps carrying a prefill grant
+        # ride the C=chunk shapes
+        self._chunk_ladder = sorted({self._block, self._prefill_chunk})
         # speculative decoding (ISSUE 14): a small DRAFT decoder
         # proposes spec_k tokens per decoding slot per round; the
         # target verifies all k+1 positions in ONE chunked call. The
@@ -872,7 +708,7 @@ class DecodeEngine:
         # autotune cache through effective_flag ('spec_k'), else the
         # FLAGS cold default (0 = off, bit-identical old behavior).
         if isinstance(draft_spec, dict):
-            draft_spec = DecoderSpec.from_dict(draft_spec)
+            draft_spec = spec_from_dict(draft_spec)
         k_spec = int(effective_flag("spec_k")
                      if spec_k is None else spec_k)
         if k_spec < 0:
@@ -904,12 +740,13 @@ class DecodeEngine:
             self._draft_chunk_ladder = sorted(
                 {1, 2, self._prefill_chunk})
             self._draft_params = self._place_params(
-                seeded_decoder_arrays(draft_spec)
+                draft_spec.seeded_arrays()
                 if draft_params is None
                 else draft_params)  # guarded-by: _step_mu
             self._draft_cache = PagedKvCache(
                 draft_spec.n_layers, draft_spec.n_kv_heads,
                 draft_spec.head_dim, page_size=ps, num_pages=npages,
+                dtype=draft_spec.pool_dtype,
                 allocator=self.cache.allocator,
                 mesh=self._mesh,
                 shard_spec=self._pool_spec())  # guarded-by: _step_mu
@@ -959,13 +796,32 @@ class DecodeEngine:
         # only where a request needs the host (_fetch_row)
         def _step(params, tokens, positions, q_lens, k_pool, v_pool,
                   tables, lens, temperature, seed):
-            k, v, logits = decoder_step_chunked(
-                params, spec_ref, tokens, positions, q_lens, k_pool,
-                v_pool, tables, lens, attention_impl=impl)
+            k, v, logits, _aux = spec_ref.step(
+                params, tokens, positions, q_lens, k_pool,
+                v_pool, tables, lens, attention_impl=impl,
+                garbage_page=GARBAGE_PAGE)
             # lens (the keys including this chunk) is the new token's
             # absolute index in its sequence: the position of the draw
             return k, v, choose_tokens(logits, temperature, seed,
                                        lens), logits
+
+        def _block_step(params, tokens, positions, q_lens, k_pool,
+                        v_pool, tables, lens, temperature, seed, masked,
+                        n_unmask):
+            # a block model's one program (ISSUE 30): prefill chunks,
+            # denoise and commit passes slot by slot; the choice, its
+            # confidence and the lanes to unmask are made here, and what
+            # goes to the host is [slots, B] of each and the model's
+            # per-expert counts
+            k, v, logits, aux = spec_ref.step(
+                params, tokens, positions, q_lens, k_pool,
+                v_pool, tables, lens, attention_impl=impl,
+                garbage_page=GARBAGE_PAGE)
+            x0, conf, unmask = choose_block(
+                logits, temperature, seed,
+                positions[:, :logits.shape[1]], masked, n_unmask)
+            return k, v, {"ids": x0, "confidence": conf,
+                          "unmask": unmask, **aux}, logits
 
         # donate the pools on TPU so XLA updates the KV pages in place
         # (HBM footprint stays the preallocated pool); CPU ignores
@@ -990,7 +846,7 @@ class DecodeEngine:
                                   replicated)
         self._step_out_shardings = step_out_shardings
         self._step_fn = jax.jit(
-            _step,
+            _block_step if self._block > 1 else _step,
             donate_argnums=(4, 5) if donate else (),
             **({"out_shardings": step_out_shardings}
                if step_out_shardings is not None
@@ -1002,10 +858,10 @@ class DecodeEngine:
                         v_pool, tables, lens, temperature, seed):
                 import jax.numpy as jnp
 
-                k, v, logits = decoder_step_chunked(
-                    params, spec_ref, tokens, positions, q_lens, k_pool,
+                k, v, logits, _aux = spec_ref.step(
+                    params, tokens, positions, q_lens, k_pool,
                     v_pool, tables, lens, all_lanes=True,
-                    attention_impl=impl)
+                    attention_impl=impl, garbage_page=GARBAGE_PAGE)
                 b, c, vocab = logits.shape
                 # a lane is a row of its own: the slot's temperature
                 # and seed, and the position after the lane's own
@@ -1017,9 +873,10 @@ class DecodeEngine:
 
             def _draft(params, tokens, positions, q_lens, k_pool,
                        v_pool, tables, lens, temperature, seed):
-                k, v, logits = decoder_step_chunked(
-                    params, draft_ref, tokens, positions, q_lens,
-                    k_pool, v_pool, tables, lens, attention_impl=impl)
+                k, v, logits, _aux = draft_ref.step(
+                    params, tokens, positions, q_lens,
+                    k_pool, v_pool, tables, lens, attention_impl=impl,
+                    garbage_page=GARBAGE_PAGE)
                 return k, v, choose_tokens(logits, temperature, seed,
                                            lens), logits
 
@@ -1039,12 +896,11 @@ class DecodeEngine:
         if self._embed_on:
             def _embed(params, tokens, positions, q_lens, k_pool,
                        v_pool, tables, lens):
-                return decoder_step_chunked(params, spec_ref, tokens,
-                                            positions, q_lens, k_pool,
-                                            v_pool, tables, lens,
-                                            all_lanes=True,
-                                            return_hidden=True,
-                                            attention_impl=impl)
+                k, v, logits, aux = spec_ref.step(
+                    params, tokens, positions, q_lens, k_pool, v_pool,
+                    tables, lens, all_lanes=True, return_hidden=True,
+                    attention_impl=impl, garbage_page=GARBAGE_PAGE)
+                return k, v, logits, aux["hidden"]
 
             # (pools, logits, hidden): hidden states replicate like
             # logits (pooling and logprob scoring are host-side), so the
@@ -1223,7 +1079,8 @@ class DecodeEngine:
                 # the host route's row fetch: one program a logits
                 # shape, [s, vocab] whatever the width and the chunk
                 # (the draft's logits share it: its vocab is the
-                # target's) and the verify's [s, lanes, vocab]
+                # target's) and the verify's [s, lanes, vocab]; a block
+                # model's [s, B, vocab] for a first pass's first_topk
                 self._fetch_row(logits, 0)
                 if self._spec_k:
                     self._fetch_row(lanes, 0)
@@ -1232,7 +1089,8 @@ class DecodeEngine:
                deadline_ms: Optional[float] = None,
                temperature: float = 0.0, top_k: int = 0,
                seed: int = 0, mask: Optional[Any] = None,
-               topk_first: int = 0) -> _DecodeRequest:
+               topk_first: int = 0,
+               denoise_steps: Optional[int] = None) -> _DecodeRequest:
         """Validate + reserve KV pages + enqueue. All refusals are
         synchronous and typed: ``ServerOverloaded`` (queue full OR page
         pool exhausted), ``RequestTooLarge`` (can't ever fit),
@@ -1250,17 +1108,46 @@ class DecodeEngine:
         unconstrained. The sequence finishes early when the automaton
         has no further transition. ``topk_first`` asks for the first
         generated position's top-k token order in the result
-        (``first_topk``) — the beam fork point."""
+        (``first_topk``) — the beam fork point.
+
+        ``denoise_steps`` (ISSUE 30) is a block model's field, as
+        ``temperature`` is any model's: the denoise passes a block of B
+        tokens takes, each unmasking ``B / denoise_steps`` lanes (default
+        B: one lane a pass; it must divide B). Such a model answers up to
+        B tokens at a time, and refuses ``top_k`` and ``mask``, which
+        choose on the host from one causal logits row."""
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
+        bl = self._block
+        if bl == 1:
+            if denoise_steps is not None:
+                raise ValueError(
+                    "'denoise_steps' is a block model's field; decoder "
+                    f"'{self.name}' is causal (block_length 1)")
+            denoise_steps = 1
+        else:
+            denoise_steps = bl if denoise_steps is None \
+                else int(denoise_steps)
+            if denoise_steps < 1 or bl % denoise_steps:
+                raise ValueError(
+                    f"denoise_steps must divide the block length {bl}, "
+                    f"got {denoise_steps}")
+            for field, given in (("top_k", int(top_k) > 0),
+                                 ("mask", mask is not None)):
+                if given:
+                    raise ValueError(
+                        f"'{field}' chooses on the host from one causal "
+                        f"logits row; decoder '{self.name}' generates by "
+                        f"diffusion over blocks of {bl}")
         if int(prompt.min()) < 0 or int(prompt.max()) >= self.spec.vocab:
             raise ValueError(
                 f"prompt token ids must be in [0, {self.spec.vocab})")
         max_new = int(max_new_tokens)
         if max_new < 1:
             raise ValueError("max_new_tokens must be >= 1")
-        total = int(prompt.size) + max_new
+        # a block model writes whole blocks: the last one to its end
+        total = -(-(int(prompt.size) + max_new) // bl) * bl
         if total > self.max_seq_len:
             raise RequestTooLarge(
                 f"prompt ({prompt.size}) + max_new_tokens ({max_new}) = "
@@ -1338,7 +1225,8 @@ class DecodeEngine:
             req = _DecodeRequest(prompt, max_new, deadline, seq_id,
                                  temperature=temperature, top_k=top_k,
                                  seed=seed, mask=automaton,
-                                 want_topk=topk_first)
+                                 want_topk=topk_first,
+                                 denoise_steps=denoise_steps)
             req.cached_tokens = res["cached_tokens"]
             req.cow = res["cow"]
             self._queue.append(req)
@@ -1360,7 +1248,8 @@ class DecodeEngine:
                  timeout: float = 300.0, temperature: float = 0.0,
                  top_k: int = 0, seed: int = 0,
                  mask: Optional[Any] = None,
-                 topk_first: int = 0) -> Dict[str, Any]:
+                 topk_first: int = 0,
+                 denoise_steps: Optional[int] = None) -> Dict[str, Any]:
         """Blocking convenience: submit + wait. Returns
         ``{"tokens": [...], "prompt_len": n, "version": v,
         "steps_to_first_token": k}``.
@@ -1369,7 +1258,8 @@ class DecodeEngine:
         ``mask``/``topk_first`` to the workload layer (ISSUE 20)."""
         req = self.submit(prompt, max_new_tokens, deadline_ms=deadline_ms,
                           temperature=temperature, top_k=top_k, seed=seed,
-                          mask=mask, topk_first=topk_first)
+                          mask=mask, topk_first=topk_first,
+                          denoise_steps=denoise_steps)
         if not req.ev.wait(timeout):
             # withdraw before raising: an abandoned sequence must not
             # keep its page reservation or burn further decode steps.
@@ -1721,7 +1611,8 @@ class DecodeEngine:
                 self._queue.pop(0)
                 continue
             if req.needs_alloc:
-                total = len(req.prompt) + req.max_new
+                total = -(-(len(req.prompt) + req.max_new)
+                          // self._block) * self._block
                 try:
                     if req.resume_pos is not None:
                         # restore-before-step: cover what was spilled
@@ -1900,8 +1791,19 @@ class DecodeEngine:
             seed[i] = s.req.seed & 0xFFFFFFFF
         return temperature, seed
 
+    def _mask_args(self, rows: int, masked=None, n_unmask=None):
+        """A block model's two further arguments of the step program
+        (``masked [rows, B]`` bool, ``n_unmask [rows]`` int32; none masked
+        where the caller gives none); nothing for a causal model."""
+        if self._block == 1:
+            return ()
+        return (np.zeros((rows, self._block), bool)
+                if masked is None else masked,
+                np.zeros(rows, np.int32) if n_unmask is None else n_unmask)
+
     def _run_step_arrays(self, tokens, positions, q_lens, tables, lens,
-                         *, temperature=None, seed=None):
+                         *, temperature=None, seed=None, masked=None,
+                         n_unmask=None):
         """Shared by warm() and live steps: count a DISTINCT-shape
         compile, run the jitted step, rebind the pools. With a draft
         attached the shape keys carry a model tag ('target'/'verify'/
@@ -1913,7 +1815,12 @@ class DecodeEngine:
         and ``seed`` uint32, at position ``lens`` (the new token's
         absolute index). The sampling arrays are keyword-only: the five
         positional arguments are the call's shapes, which the
-        benchmark's traced runs log by position."""
+        benchmark's traced runs log by position. A block model's program
+        (ISSUE 30) also takes ``masked [B, block]`` bool and ``n_unmask
+        [B]`` int32 (none masked where the caller gives none) and its
+        ``ids`` is a dict of device arrays: ``ids``, ``confidence``,
+        ``unmask``, each ``[B, block]``, and what the model's pass
+        reports (``expert_counts [layers, E]``)."""
         with self._step_mu:
             key = (len(tokens), tables.shape[1], tokens.shape[1])
             if self._spec_k or self._embed_on:
@@ -1925,10 +1832,11 @@ class DecodeEngine:
                 self._compiled_shapes.add(key)
                 _m_compiles.inc()
             _m_target_steps.inc()
+            extra = self._mask_args(len(tokens), masked, n_unmask)
             k, v, ids, logits = self._step_fn(
                 self._params, tokens, positions, q_lens, self.cache.k,
                 self.cache.v, tables, lens, *self._sampling(
-                    temperature, seed, len(tokens)))
+                    temperature, seed, len(tokens)), *extra)
             self.cache.rebind(k, v)
             return ids, logits
 
@@ -2225,7 +2133,20 @@ class DecodeEngine:
         lanes are never budgeted against prefill."""
         budget = self._prefill_chunk
         grants = []
+        bl = self._block
         for s in live:
+            if bl > 1:
+                # a block model prefills the prompt's whole blocks, whole
+                # blocks at a time (at least one a step), and then runs
+                # passes of one block; the P mod B tokens left over open
+                # the first generated block
+                remaining = len(s.req.prompt) // bl * bl - s.pos
+                g = bl
+                if remaining > 0:
+                    g = max(bl, min(remaining, budget) // bl * bl)
+                    budget = max(0, budget - g)
+                grants.append(g)
+                continue
             remaining_prompt = len(s.req.prompt) - s.pos
             if remaining_prompt > 0:
                 g = max(1, min(remaining_prompt, budget))
@@ -2439,6 +2360,8 @@ class DecodeEngine:
             live, grants = self._prepare(live)
         if not live:
             return
+        if self._block > 1:
+            return self._step_blocks(live, grants)
         # split the round: decoding slots with a draft attached ride
         # the propose/verify path; prefill chunks (and everything when
         # speculation is off) ride the PR 9 chunked step unchanged
@@ -2525,17 +2448,8 @@ class DecodeEngine:
             if spec_rows:
                 spec_out = self._spec_substep(
                     [live[i] for i in spec_rows], w_bucket)
-        t_step_end = time.perf_counter()
-        _m_step_ms.observe((t_step_end - t0) * 1e3)
-        _m_steps.inc()
-        _m_occupancy.observe(
-            len(live) / float(_bucket_for(self._slot_ladder,
-                                          len(live))))
-        # prices the token-budget policy next to occupancy: how much of
-        # each step's budget real prefill work consumed
-        _m_prefill_per_step.observe(prefill_toks)
-        if prefill_toks:
-            _m_prefill_tokens.inc(prefill_toks)
+        step_s = time.perf_counter() - t0
+        self._observe_step(step_s, len(live), prefill_toks)
         now = time.monotonic()
         done: List[_Slot] = []
         # the whole answer phase holds _cond: stop(drain=False) fails
@@ -2658,34 +2572,7 @@ class DecodeEngine:
                                 or (tok is not None
                                     and self.spec.eos_id is not None
                                     and tok == self.spec.eos_id))
-                notes[s.req.seq_id] = s.pos
-                if finished:
-                    # finished beats a lapsed deadline: the result is
-                    # fully paid for — deliver it rather than discard
-                    done.append(s)
-                    self._complete(s)
-                elif s.req.deadline is not None and now > s.req.deadline:
-                    _m_deadline_miss.inc()
-                    done.append(s)
-                    self._fail_locked(s.req, DeadlineExceeded(
-                        f"request to decoder '{self.name}' lapsed "
-                        f"mid-decode after {len(s.req.produced)} tokens"))
-            # one allocator-lock round-trip for the whole step; seqs
-            # freed by _complete/_fail above are skipped inside
-            self.cache.allocator.note_tokens_many(notes)
-            if done:
-                self._slots = [s for s in self._slots if s not in done]
-                self._g_live.set(len(self._slots))
-            if done or produced_any:
-                # wake completion waiters AND streaming readers parked
-                # in stream_tokens — a token exists the moment this
-                # notify lands, ceil(prompt/chunk) steps after
-                # admission, not when the whole sequence finishes
-                self._cond.notify_all()
-            # the round's last bookkeeping, still inside the span (and
-            # the condition): once it is released the woken clients
-            # run, and what the scheduler then waits belongs to the
-            # next round's admit
+                self._retire_locked(s, finished, now, done, notes)
             if n_proposed:
                 _m_spec_proposed.inc(n_proposed)
                 _m_spec_accepted.inc(n_accepted)
@@ -2697,11 +2584,239 @@ class DecodeEngine:
             if n_device + n_host:
                 _m_device_choice_pct.observe(
                     100.0 * n_device / (n_device + n_host))
-            t_end = time.perf_counter()
-            _m_sample_ms.observe(sample_s * 1e3)
-            _m_sched_ms.observe((t_end - self._t_round
-                                 - (t_step_end - t0) - sample_s) * 1e3)
-            self._t_round = t_end
+            self._close_round_locked(done, produced_any, notes, step_s,
+                                     sample_s)
+
+    def _observe_step(self, seconds: float, n_live: int,
+                      prefill_toks: int):
+        """What a round records when its device call is back, whatever
+        kind of step ran it (``serving.decode.step_ms``'s stretch)."""
+        _m_step_ms.observe(seconds * 1e3)
+        _m_steps.inc()
+        _m_occupancy.observe(
+            n_live / float(_bucket_for(self._slot_ladder, n_live)))
+        # prices the token-budget policy next to occupancy: how much of
+        # each step's budget real prefill work consumed
+        _m_prefill_per_step.observe(prefill_toks)
+        if prefill_toks:
+            _m_prefill_tokens.inc(prefill_toks)
+
+    def _retire_locked(self, s: _Slot, finished: bool, now: float,
+                       done: List[_Slot], notes: Dict[int, int]):
+        """The end of one slot's answer: note how far it got, and
+        complete it or fail it on a lapsed deadline."""
+        notes[s.req.seq_id] = s.pos
+        if finished:
+            # finished beats a lapsed deadline: the result is fully
+            # paid for — deliver it rather than discard
+            done.append(s)
+            self._complete(s)
+        elif s.req.deadline is not None and now > s.req.deadline:
+            _m_deadline_miss.inc()
+            done.append(s)
+            self._fail_locked(s.req, DeadlineExceeded(
+                f"request to decoder '{self.name}' lapsed "
+                f"mid-decode after {len(s.req.produced)} tokens"))
+
+    def _close_round_locked(self, done: List[_Slot], produced: bool,
+                            notes: Dict[int, int], step_s: float,
+                            sample_s: float):
+        """The answer phase's last lines, still inside its span and the
+        condition: once that is released the woken clients run, and
+        what the scheduler then waits belongs to the next round's
+        admit."""
+        # one allocator-lock round-trip for the whole step; seqs freed
+        # by _complete/_fail are skipped inside
+        self.cache.allocator.note_tokens_many(notes)
+        if done:
+            self._slots = [s for s in self._slots if s not in done]
+            self._g_live.set(len(self._slots))
+        if done or produced:
+            # wake completion waiters AND streaming readers parked in
+            # stream_tokens — a token exists the moment this notify
+            # lands, ceil(prompt/chunk) steps after admission, not when
+            # the whole sequence finishes
+            self._cond.notify_all()
+        t_end = time.perf_counter()
+        _m_sample_ms.observe(sample_s * 1e3)
+        _m_sched_ms.observe((t_end - self._t_round - step_s - sample_s)
+                            * 1e3)
+        self._t_round = t_end
+
+    def _open_block(self, s: _Slot):
+        """Open the block at ``[s.pos, s.pos + B)``: the tokens that are
+        known there (the ``P mod B`` prompt tokens left over by the
+        prefill of whole blocks, in the first generated block) stay
+        unmasked, every other lane is masked."""
+        known = len(s.req.prompt) + len(s.req.produced)
+        lanes = range(s.pos, s.pos + self._block)
+        s.masked = [p >= known for p in lanes]
+        s.block = [self.spec.mask_token_id if m else s.token_at(p)
+                   for p, m in zip(lanes, s.masked)]
+
+    def _step_blocks(self, live: List[_Slot], grants: List[int]):
+        """One round of a model that generates by diffusion over blocks
+        (ISSUE 30), after ``_prepare``. Slot by slot the ONE jitted call
+        carries a prefill chunk of whole blocks, a DENOISE pass (the
+        block with the mask id at its masked lanes; the program's x0 at
+        the ``B / denoise_steps`` most confident masked lanes is kept) or,
+        once no lane is masked, the COMMIT pass (the B real tokens,
+        whose K/V later blocks read), B lanes each. Every pass writes its
+        lanes' K/V (write-before-attend; the commit pass's write is the
+        one that stands). On commit the slot answers up to B tokens at
+        once — fewer at ``max_new`` or at ``eos_id``, the rest of the
+        block is dropped — and ``pos`` advances by B."""
+        bl = self._block
+        w_bucket = _bucket_for(self._width_ladder,
+                               max(s.pages_held for s in live))
+        s_bucket = _bucket_for(self._slot_ladder, len(live))
+        c_bucket = _bucket_for(self._chunk_ladder, max(grants))
+        kinds: List[str] = []
+        t0 = time.perf_counter()
+        with _tracing.adopt(live[0].req.trace_ctx), \
+                _tracing.span("serving.decode.step", model=self.name,
+                              version=self.version, width=w_bucket,
+                              live=len(live)):
+            with _tracing.span("serving.decode.build"):
+                tokens = np.zeros((s_bucket, c_bucket), np.int32)
+                positions = np.zeros((s_bucket, c_bucket), np.int32)
+                q_lens = np.zeros(s_bucket, np.int32)
+                lens = np.zeros(s_bucket, np.int32)
+                masked = np.zeros((s_bucket, bl), bool)
+                n_unmask = np.zeros(s_bucket, np.int32)
+                temperature, seed = self._slot_sampling(live, s_bucket)
+                for i, (s, g) in enumerate(zip(live, grants)):
+                    if s.pos < len(s.req.prompt) // bl * bl:
+                        kinds.append("prefill")
+                        tokens[i, :g] = s.req.prompt[s.pos:s.pos + g]
+                    else:
+                        if s.block is None:
+                            self._open_block(s)
+                        tokens[i, :bl] = s.block
+                        masked[i] = s.masked
+                        kinds.append("denoise" if any(s.masked)
+                                     else "commit")
+                        if kinds[-1] == "denoise":
+                            n_unmask[i] = bl // s.req.denoise_steps
+                    positions[i, :g] = np.arange(s.pos, s.pos + g)
+                    q_lens[i] = g
+                    lens[i] = s.pos + g
+                    self._check_reservation(s, int(lens[i]))
+                tables = self.cache.table_array(
+                    [s.req.seq_id for s in live], w_bucket, rows=s_bucket)
+            n_kind = {k: kinds.count(k)
+                      for k in ("prefill", "denoise", "commit")}
+            passes = n_kind["denoise"] + n_kind["commit"]
+            assignments = (int(q_lens.sum())
+                           * self.spec.moe_assignments_per_token)
+            counts = None
+            with _tracing.span("serving.decode.device_call") as sp:
+                if sp.live:
+                    for key, value in _call_work(
+                            s_bucket, c_bucket, w_bucket, q_lens,
+                            lens, block=bl).items():
+                        sp.set_arg(key, value)
+                    for kind, n in n_kind.items():
+                        sp.set_arg(kind + "_slots", n)
+                    sp.set_arg("moe_assignments", assignments)
+                out, logits = self._run_step_arrays(
+                    tokens, positions, q_lens, tables, lens,
+                    temperature=temperature, seed=seed, masked=masked,
+                    n_unmask=n_unmask)
+                if passes:
+                    for a in out.values():
+                        a.copy_to_host_async()
+                # a step of prefill chunks alone is not waited for
+                ids = self._await_ids(out["ids"], passes > 0)
+                if passes:
+                    conf = np.asarray(out["confidence"])
+                    unmask = np.asarray(out["unmask"])
+                    if "expert_counts" in out:
+                        counts = np.asarray(out["expert_counts"])
+                        sp.set_arg("moe_experts_touched",
+                                   int((counts > 0).sum()))
+        step_s = time.perf_counter() - t0
+        self._observe_step(step_s, len(live),
+                           sum(g for g, k in zip(grants, kinds)
+                               if k == "prefill"))
+        if assignments:
+            _m_moe_assignments.inc(assignments)
+        now = time.monotonic()
+        done: List[_Slot] = []
+        notes: Dict[int, int] = {}
+        n_committed = n_dropped = 0
+        sample_s = 0.0
+        with _tracing.span("serving.decode.answer"), self._cond:
+            self._n_steps += 1
+            for i, s in enumerate(live):
+                if s.req.ev.is_set():
+                    done.append(s)      # canceled or failed: see _step
+                    continue
+                s.steps += 1
+                finished = False
+                if kinds[i] == "prefill":
+                    s.pos += grants[i]
+                elif kinds[i] == "denoise":
+                    req = s.req
+                    if req.want_topk and req.first_topk is None:
+                        # the first pass's first masked lane: its order
+                        # of the best tokens, from one fetched row
+                        t_sample = time.perf_counter()
+                        with _tracing.span("serving.decode.sample"):
+                            lane = s.masked.index(True)
+                            req.first_topk = _top_order(
+                                self._fetch_row(logits, i)[lane],
+                                req.want_topk)
+                        sample_s += time.perf_counter() - t_sample
+                    if req.passes is not None:
+                        req.passes.append({
+                            "pos": int(s.pos),
+                            "input": [int(t) for t in tokens[i, :bl]],
+                            "masked": list(s.masked),
+                            "ids": [int(t) for t in ids[i]],
+                            "confidence": [float(c) for c in conf[i]],
+                            "unmasked": [bool(u) for u in unmask[i]]})
+                    for j in np.flatnonzero(unmask[i]):
+                        s.block[j] = int(ids[i, j])
+                        s.masked[j] = False
+                else:
+                    # commit: the block's tokens that are not the
+                    # prompt's, in order, as far as max_new and eos let
+                    known = len(s.req.prompt) + len(s.req.produced)
+                    fresh = s.block[max(0, known - s.pos):]
+                    taken = 0
+                    for tok in fresh:
+                        s.req.produced.append(tok)
+                        taken += 1
+                        if (len(s.req.produced) >= s.req.max_new
+                                or tok == self.spec.eos_id):
+                            finished = True
+                            break
+                    n_committed += taken
+                    n_dropped += len(fresh) - taken
+                    s.pos += bl
+                    s.block = s.masked = None
+                    if s.first_token_steps is None:
+                        s.first_token_steps = s.steps
+                        _m_first_token_steps.observe(s.steps)
+                self._retire_locked(s, finished, now, done, notes)
+            if passes:
+                _m_block_passes.inc(passes)
+                _m_block_tokens_per_pass.observe(n_committed / passes)
+            if n_committed:
+                _m_tokens.inc(n_committed)
+                _m_block_committed.inc(n_committed)
+                _m_device_choices.inc(n_committed)
+                _m_device_choice_pct.observe(100.0)
+            if n_dropped:
+                _m_block_dropped.inc(n_dropped)
+            if counts is not None and counts.sum():
+                _m_moe_load.observe(float(
+                    (counts.max(axis=1) / np.maximum(
+                        counts.mean(axis=1), 1e-9)).max()))
+            # ONE wake-up delivers a block's tokens, in order
+            self._close_round_locked(done, n_committed > 0, notes, step_s,
+                                     sample_s)
 
     def _sample(self, req: _DecodeRequest, row, chosen: int,
                 position: int, topk: bool) -> Tuple[int, bool]:
@@ -2754,6 +2869,8 @@ class DecodeEngine:
             # absent unless asked for, so every pre-existing result
             # shape is untouched
             s.req.result["first_topk"] = list(s.req.first_topk or [])
+            if self._block > 1:
+                s.req.result["passes"] = list(s.req.passes or [])
         if s.req.spec_proposed:
             _m_spec_accept_rate.observe(
                 s.req.spec_accepted / s.req.spec_proposed)

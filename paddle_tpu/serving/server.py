@@ -266,7 +266,8 @@ class ServingServer:
                   max_new_tokens: int = 16,
                   deadline_ms: Optional[float] = None,
                   temperature: float = 0.0, top_k: int = 0,
-                  seed: int = 0) -> Dict[str, Any]:
+                  seed: int = 0,
+                  denoise_steps: Optional[int] = None) -> Dict[str, Any]:
         """Autoregressive decode on a loaded DecodeEngine. Same swap-
         resubmit contract as _infer: racing a hot-swap re-enqueues on
         the replacement decoder instead of failing the request.
@@ -283,7 +284,8 @@ class ServingServer:
                     **engine.generate(
                         prompt, max_new_tokens=max_new_tokens,
                         deadline_ms=deadline_ms, temperature=temperature,
-                        top_k=top_k, seed=seed)})
+                        top_k=top_k, seed=seed,
+                        denoise_steps=denoise_steps)})
 
     def _workload(self, model: str, workload: Dict[str, Any]
                   ) -> Dict[str, Any]:
@@ -342,7 +344,9 @@ class ServingServer:
                                max_new_tokens: int = 16,
                                deadline_ms: Optional[float] = None,
                                temperature: float = 0.0, top_k: int = 0,
-                               seed: int = 0) -> Dict[str, Any]:
+                               seed: int = 0,
+                               denoise_steps: Optional[int] = None
+                               ) -> Dict[str, Any]:
         """Admit a decode sequence and hand back a stream id; tokens
         are pulled incrementally with generate_stream_next. Rides the
         dedup cache (NOT idempotent-declared): a retransmitted start
@@ -354,7 +358,7 @@ class ServingServer:
                 req = engine.submit(
                     prompt, max_new_tokens=max_new_tokens,
                     deadline_ms=deadline_ms, temperature=temperature,
-                    top_k=top_k, seed=seed)
+                    top_k=top_k, seed=seed, denoise_steps=denoise_steps)
                 sid = uuid.uuid4().hex
                 # bound checked at INSERT (one locked section, no
                 # check-then-act window for concurrent starts to

@@ -48,6 +48,11 @@ def beam_search(engine, prompt: Sequence[int], k: int = 2,
     max_new = int(max_new_tokens)
     if max_new < 1:
         raise ValueError(f"max_new_tokens must be >= 1, got {max_new}")
+    if engine.spec.block_length != 1:
+        raise ServingError(
+            f"decoder '{engine.name}' generates by diffusion over blocks "
+            f"('block_length' {engine.spec.block_length}): beam forks "
+            "one causal position's token order")
     if not engine.prefix_cache_enabled:
         raise ServingError(
             f"decoder '{engine.name}' has no prefix cache — beam "

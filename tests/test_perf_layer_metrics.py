@@ -1,6 +1,9 @@
-"""The benchmark's per-layer metric that reads where tokens are chosen
-(ISSUE 29): `sched_device_choice_pct`, data only under `perf/`, read from
-the program's histogram `serving.decode.device_choice_pct`.
+"""The benchmark's per-layer metrics that later PRs added as files of
+their own under `perf/layer_metrics/`: `sched_device_choice_pct` (ISSUE 29:
+where tokens are chosen, data only, read from the program's histogram
+`serving.decode.device_choice_pct`) and the five of the `sdar30b_blockgen`
+cell (ISSUE 30): `block_tokens_per_pass`, `moe_load_max_over_mean`,
+`moe_experts_roofline`, `moe_route_share_pct`, `paged_attn_block_roofline`.
 
 The benchmark's own tests live in `perf/tests` and are not collected by
 the tier-1 command; this case is, so that a tree whose BENCHMARK.json no
@@ -27,11 +30,12 @@ def bench():
 
 def test_device_choice_metric_loads_and_reads_the_histograms_avg(bench):
     bench.check_files()
-    entry = bench.doc["per_layer"][-1]          # appended, not inserted
+    entry, = [m for m in bench.doc["per_layer"] if m["name"] == NAME]
     assert entry == {
         "name": NAME, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "scheduler",
-        "moves": "serve_tokens_per_s", "workloads": [CELL]}
+        "moves": "serve_tokens_per_s",
+        "workloads": [CELL, "sdar30b_blockgen"]}
     assert entry["moves"] in bench.end_to_end(CELL)
     (found, desc), = [(m, d) for m, d in bench.per_layer(CELL)
                       if m["name"] == NAME]
@@ -65,3 +69,242 @@ def test_the_program_registers_what_the_metric_reads():
     assert isinstance(snap[HISTOGRAM], dict)
     for name in ("device_choices", "host_choices"):
         assert snap["serving.decode." + name] == 0
+
+
+# --- the sdar30b_blockgen cell's metrics (ISSUE 30) ------------------------
+
+NEW_CELL = "sdar30b_blockgen"
+NEW = {"block_tokens_per_pass": ("tokens", "higher", "program_counter",
+                                 "scheduler", "serve_tokens_per_s"),
+       "moe_load_max_over_mean": ("ratio", "lower", "program_counter",
+                                  "model step", "token_gap_p95_ms"),
+       "moe_experts_roofline": ("%", "higher", "device_trace", "kernels",
+                                "serve_tokens_per_s"),
+       "moe_route_share_pct": ("%", "lower", "device_trace", "model step",
+                               "token_gap_p95_ms"),
+       "paged_attn_block_roofline": ("%", "higher", "device_trace", "kernels",
+                                     "serve_tokens_per_s")}
+
+
+def test_the_new_cell_and_configuration_are_appended_and_their_files_exist(
+        bench):
+    bench.check_files()
+    assert [w["name"] for w in bench.doc["workloads"]][:2] == [
+        "xglm17b_chat", "resnet50_train"]
+    assert bench.doc["workloads"][2] == {
+        "name": NEW_CELL, "config": "sdar-30b-a3b-chat",
+        "traffic": "blockgen", "chips": 1,
+        "why": bench.cell(NEW_CELL)["why"]}
+    assert all(w["chips"] == 1 for w in bench.doc["workloads"])
+    entry = bench.doc["configs"][2]
+    assert (entry["name"], entry["reduced"]) == ("sdar-30b-a3b-chat",
+                                                 ["num_hidden_layers"])
+    cfg = bench.config("sdar-30b-a3b-chat")
+    # every published width unchanged; depth the one cut
+    assert (cfg["hidden_size"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["head_dim"],
+            cfg["moe_intermediate_size"], cfg["num_experts"],
+            cfg["num_experts_per_tok"], cfg["vocab_size"], cfg["rope_theta"],
+            cfg["rms_norm_eps"]) == (2048, 32, 4, 128, 768, 128, 8, 151936,
+                                     1000000, 1e-6)
+    assert (cfg["num_hidden_layers"],
+            cfg["num_hidden_layers_published"]) == (6, 48)
+    assert cfg["assumed"]["block_length"] == 4
+    assert 0 <= cfg["assumed"]["mask_token_id"] < cfg["vocab_size"]
+    # ttft_p95_ms is not resolved at the issue's traffic (PERF.md section
+    # 6): the cell is not held to it, nor to the metric that moves it
+    assert set(bench.end_to_end(NEW_CELL)) == {
+        "serve_tokens_per_s", "token_gap_p95_ms", "setup_s"}
+    names = [m["name"] for m, _d in bench.per_layer(NEW_CELL)]
+    assert "paged_attn_roofline" not in names       # PERF.md section 7
+    assert "sched_queue_wait_ms" not in names
+    assert (bench.cell(NEW_CELL)["traffic"]["think_ms"],
+            bench.cell(NEW_CELL)["traffic"]["think_stagger_ms"]) == (10, 1)
+    assert set(NEW) <= set(names) and "serve_mfu_pct" in names
+    # nothing of the new cell is asked of the cells that were there
+    for cell in ("xglm17b_chat", "resnet50_train"):
+        assert not set(NEW) & {m["name"] for m, _d in bench.per_layer(cell)}
+
+
+@pytest.mark.parametrize("name", sorted(NEW))
+def test_a_new_metric_loads_and_reads_what_its_file_says(bench, name):
+    unit, better, source, layer, moves = NEW[name]
+    entry, = [m for m in bench.doc["per_layer"] if m["name"] == name]
+    assert entry == {"name": name, "unit": unit, "better": better,
+                     "source": source, "layer": layer, "moves": moves,
+                     "workloads": [NEW_CELL]}
+    assert bench.doc["per_layer"].index(entry) >= 13    # appended
+    (_m, desc), = [(m, d) for m, d in bench.per_layer(NEW_CELL)
+                   if m["name"] == name]
+    assert (desc["name"], desc["unit"], desc["layer"], desc["moves"]) == (
+        name, unit, layer, moves)
+    cfg = bench.config("sdar-30b-a3b-chat")
+    peaks = {"bf16_flops_per_s": 1.97e14, "hbm_bytes_per_s": 8.19e11}
+    # a program without the histogram, the scopes or the spans' args (the
+    # parent): nothing to read, nothing raised
+    assert bench.read_layer_metric(
+        entry, desc, {"histograms": {}, "config": cfg, "peaks": peaks,
+                      "trace": None}) is None
+    if desc["reader"] == "histogram":
+        assert desc["histogram"] in (
+            "serving.decode.block.tokens_per_pass",
+            "serving.decode.moe.load_max_over_mean")
+        facts = {"histograms": {desc["histogram"]: {
+            "count": 10, "avg": 1.25, "p50": 1.5, "sum": 12.5}}}
+        assert bench.read_layer_metric(entry, desc, facts) == (
+            1.25 if desc["stat"] == "avg" else 1.5)
+        return
+    # one traced step of 64 lanes: 64 x 8 x 6 assignments on 700 of the
+    # 768 expert matrices, 12 ms in the grouped products, 3 ms routing
+    # and 14 ms in the paged kernel: 16 passes of 4 lanes at 400 keys each
+    found = {"experts_s": 0.012, "route_s": 0.003, "attn_s": 0.014,
+             "device_s": 0.030,
+             "calls": [{"moe_assignments": 3072, "moe_experts_touched": 700,
+                        "q_tokens": 64, "kv_tokens": 6400,
+                        "attn_pairs": 25600},
+                       {"moe_assignments": 1024,
+                        "moe_experts_touched": None, "q_tokens": None,
+                        "kv_tokens": None, "attn_pairs": None}]}
+    value = bench.read_layer_metric(entry, desc, {
+        "moe_trace": found, "config": cfg, "peaks": peaks})
+    if name == "moe_route_share_pct":
+        assert value == pytest.approx(10.0)
+    elif name == "paged_attn_block_roofline":
+        # bytes bound: the bfloat16 K and V of 6400 keys on 4 heads of 128
+        # and q and out of 64 lanes on 32, six layers, over the 14 ms
+        nbytes = (2 * 4 * 128 * 6400 + 2 * 32 * 128 * 64) * 2
+        assert value == pytest.approx(100 * 6 * nbytes / 8.19e11 / 0.014)
+        assert 0 < value < 100
+    else:
+        # bytes bound: 700 x 3 x 2048 x 768 x 2 B of weights + tokens in
+        # and out, at 819 GB/s, over the 12 ms the products took
+        nbytes = (700 * 3 * 2048 * 768 + 3072 * 2 * 2048) * 2
+        assert value == pytest.approx(100 * nbytes / 8.19e11 / 0.012)
+        assert 0 < value < 100
+
+
+def test_operations_and_bytes_of_the_expert_model():
+    from perf.lib import flops_moe
+
+    cfg = Benchmark(ROOT).config("sdar-30b-a3b-chat")
+    assert flops_moe.param_count(cfg) == cfg["sizes"]["parameters"]
+    assert flops_moe.kv_bytes_per_token(cfg) == 12288
+    m = flops_moe.dims(cfg)
+    # active parameters only: 8 experts a token, the head only if asked
+    lane = flops_moe.lane_flops(cfg, 0, False)
+    assert lane == 6 * 2 * (2048 * 4096 * 2 + 2048 * 512 * 2 + 2048 * 128
+                            + 8 * 3 * 2048 * 768)
+    assert flops_moe.lane_flops(cfg, 0, True) - lane == 2 * 2048 * 151936
+    assert flops_moe.lane_flops(cfg, 10, False) - lane == (
+        6 * 4 * 10 * m["heads"] * m["head_dim"])
+    assert flops_moe.block_flops(cfg, 8, 4, 2) == 3 * 4 * (
+        flops_moe.lane_flops(cfg, 12, True))
+    assert flops_moe.prefill_flops(cfg, 0, 8, 4) == 4 * (
+        flops_moe.lane_flops(cfg, 4, False)
+        + flops_moe.lane_flops(cfg, 8, False))
+    ops, nbytes = flops_moe.experts_call_cost(cfg, 512, 126)
+    assert ops == 512 * 2 * 3 * 2048 * 768
+    assert nbytes == (126 * 3 * 2048 * 768 + 512 * 2 * 2048) * 2
+    # one layer's attention: the pool's and the activations' stated widths
+    ops, nbytes = flops_moe.attention_call_cost(cfg, 64, 6400, 25600)
+    assert ops == 4 * 32 * 128 * 25600
+    assert nbytes == (2 * 4 * 128 * 6400 + 2 * 32 * 128 * 64) * 2
+
+
+@pytest.mark.parametrize("block,q,kv", [(1, 5, 9), (4, 4, 12), (4, 16, 48)])
+def test_a_device_calls_pairs_follow_the_models_mask(block, q, kv):
+    """`attn_pairs` of `serving.decode.device_call` counted lane by lane: a
+    lane at position i sees the keys under its block's end."""
+    import numpy as np
+
+    from paddle_tpu.serving.decode import _call_work
+
+    work = _call_work(1, q, 8, np.array([q, 0]), np.array([kv, 0]),
+                      block=block)
+    lanes = range(kv - q, kv)
+    assert work["attn_pairs"] == sum(
+        min(kv, (i // block + 1) * block) for i in lanes)
+    assert (work["q_tokens"], work["kv_tokens"]) == (q, kv)
+
+
+def test_the_trace_reduction_tells_the_paged_kernel_from_the_grouped_products(
+        bench, tmp_path, monkeypatch):
+    """Both are Mosaic calls with the target tpu_custom_call: the paged
+    kernel is the instruction the program named."""
+    from perf.lib import trace as tracelib
+    from perf.lib import xplane
+    from perf.lib.loader import load_module
+
+    runner = load_module(os.path.join(ROOT, "perf", "runners",
+                                      "serve_model.py"), "serve_model")
+    call = ('custom-call(%x), custom_call_target="tpu_custom_call", '
+            'metadata={op_name="jit(_block_step)/decoder.')
+
+    def event(name, us, **stats):
+        return {"name": name, "start_ns": 0, "dur_ns": us * 1000,
+                "stats": stats}
+
+    planes = [
+        {"name": tracelib.DEVICE_PLANE_PREFIX + "0", "lines": [
+            {"name": tracelib.OP_LINE, "events": [
+                event("%paged_attention.7 = bf16[16,4,32,128]{3,2,1,0} "
+                      + call + 'attn/paged_attention"}', 29,
+                      tf_op="jit(_block_step)/decoder.attn/paged_attention"),
+                event("%ragged-dot-none.3 = bf16[2048,768]{1,0} " + call
+                      + 'moe.experts/ragged_dot"}', 11),
+                event("%fusion.9 = f32[64,128]{1,0} fusion(%a), kind=kLoop",
+                      2, tf_op="jit(_block_step)/decoder.moe.route/top_k"),
+                event("%fusion.4 = bf16[1024,16,4,128]{3,2,1,0} fusion(%p), "
+                      "kind=kLoop", 5,
+                      tf_op="jit(_block_step)/decoder.attn/squeeze")]},
+            {"name": "Steps", "events": [event("step", 99)]}]},
+        {"name": "/host:CPU", "lines": [{"name": "python", "events": [
+            event("serving.decode.device_call", 60, slots=16, q_tokens=64,
+                  kv_tokens=6400, attn_pairs=25600, moe_assignments=3072),
+            event("serving.decode.answer", 1)]}]}]
+    monkeypatch.setattr(xplane, "newest", lambda d: d)
+    monkeypatch.setattr(xplane, "read", lambda path: planes)
+    found = runner.moe_trace(str(tmp_path), bench)
+    assert found["attn_s"] == pytest.approx(29e-6)
+    assert found["experts_s"] == pytest.approx(11e-6)
+    assert found["route_s"] == pytest.approx(2e-6)
+    assert found["device_s"] == pytest.approx(47e-6)
+    assert found["custom_calls_s"] == {
+        "%paged_attention": pytest.approx(29e-6),
+        "%ragged-dot-none": pytest.approx(11e-6)}
+    assert found["calls"] == [dict.fromkeys(runner.CALL_ARGS) | {
+        "slots": 16, "q_tokens": 64, "kv_tokens": 6400, "attn_pairs": 25600,
+        "moe_assignments": 3072}]
+
+
+def test_the_program_registers_what_the_new_metrics_read():
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.serving import decode  # noqa: F401  (registers them)
+
+    snap = metrics.snapshot("serving.decode.")
+    for name in ("block.tokens_per_pass", "moe.load_max_over_mean"):
+        assert isinstance(snap["serving.decode." + name], dict)
+    for name in ("block.passes", "block.tokens_committed",
+                 "block.tokens_dropped", "moe.assignments"):
+        assert "serving.decode." + name in snap
+
+
+def test_the_xplane_reader_keeps_an_annotations_args(tmp_path):
+    """perf/lib/xplane.py on a trace made here: the spans' keyword
+    arguments come back as the event's stats."""
+    import jax
+    import jax.numpy as jnp
+
+    from perf.lib import xplane
+
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("serving.decode.device_call", slots=16,
+                                      moe_assignments=3072, kind="pass"):
+        jax.jit(lambda a: a @ a)(jnp.ones((8, 8))).block_until_ready()
+    jax.profiler.stop_trace()
+    events = [e for p in xplane.read(xplane.newest(str(tmp_path)))
+              for l in p["lines"] for e in l["events"]
+              if e["name"] == "serving.decode.device_call"]
+    assert len(events) == 1 and events[0]["dur_ns"] > 0
+    assert events[0]["stats"] == {"slots": 16, "moe_assignments": 3072,
+                                  "kind": "pass"}
