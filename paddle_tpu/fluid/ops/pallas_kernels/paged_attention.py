@@ -43,10 +43,16 @@ tests/test_decode_serving.py):
   - ``_paged_attention_pallas`` — a Pallas TPU kernel on grid
     ``(B, W)`` with the page table (and both length vectors) as
     SCALAR-PREFETCH operands: the BlockSpec index_map reads
-    ``tables[b, w]`` so the pipeline DMAs exactly the pages each
-    sequence owns, page by page, with an online softmax across pages
-    (flash-attention style running max/sum) — the [B, C, W*page_size]
-    score tensor never materializes.
+    ``tables[b, w]`` so the pipeline DMAs the pages each sequence owns,
+    page by page, with an online softmax across pages (flash-attention
+    style running max/sum) — the [B, C, W*page_size] score tensor never
+    materializes. The grid is the compiled ``(B, W)`` bucket, the WORK
+    is what the two length vectors say (ISSUE 31): a column past a
+    slot's last live page names that page again (no new DMA: neither a
+    garbage column nor a page held past ``kv_len`` is fetched) and runs
+    two scalar compares; a live page is folded into lanes
+    ``0 .. q_len - 1`` only. What lies past either length cannot reach
+    the output, NaN included.
 
 The single-token form is exactly the chunked form at C=1 with
 ``q_len = (kv_len > 0)`` — both implementations canonicalize to the
@@ -195,12 +201,23 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
 
 def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, k_ref,
                   v_ref, o_ref, m_sc, l_sc, acc_sc, *, scale, page_size,
-                  rep, chunk, block_length):
-    """One (sequence b, page w) grid step: fold this page's keys into
-    the running online softmax for every query lane of the chunk. W
+                  rep, block_length):
+    """One (sequence b, table column w) grid step: fold this page's keys
+    into the running online softmax of the slot's LIVE query lanes. W
     iterates innermost (TPU grids run sequentially), so the scratch
     accumulators carry across a sequence's pages and reset at its
-    first."""
+    first. The work follows the two length vectors, not the compiled
+    ``(C, W)`` buckets (ISSUE 31): a column past the slot's last live
+    page runs the two scalar compares and nothing else (its K/V block is
+    the one already fetched, see ``_live_columns``), and the fold walks
+    lanes ``0 .. q_len - 1`` only — a decoding slot in a ``C = 16`` step
+    pays for one lane. A lane or a slot nothing was folded into keeps
+    the zero accumulator and emits exact zeros.
+
+    The page stays ``[ps, H, D]`` as it lies in the pool: a lane's
+    scores are a reduce over D of ``q[None] * k`` (``[ps, H, 1]``), and
+    the softmax statistics and ``p . v`` reduce over the page's rows,
+    the LEADING axis — plain adds of whole registers, no transpose."""
     w = pl.program_id(1)
     nw = pl.num_programs(1)
 
@@ -213,48 +230,58 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, k_ref,
     b = pl.program_id(0)
     kv_len = kv_lens_ref[b]
     q_len = q_lens_ref[b]
-    q = q_ref[0].astype(jnp.float32) * scale          # [C, Hq, D]
-    k = k_ref[0].astype(jnp.float32)                  # [ps, Hkv, D]
-    v = v_ref[0].astype(jnp.float32)
-    if rep > 1:
-        k = jnp.repeat(k, rep, axis=1)                # [ps, Hq, D]
-        v = jnp.repeat(v, rep, axis=1)
-    # this page covers absolute key positions [w*ps, w*ps + ps);
-    # query lane j sits at absolute position kv_len - q_len + j and
-    # sees keys at positions <= its own (chunk-causal; up to its
-    # block's end under block diffusion, _key_limit); dead lanes
-    # (j >= q_len) see nothing
-    offs = w * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)                 # [1, ps]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)  # [C, 1]
-    limit = _key_limit(kv_len, q_len, lane, block_length)  # [C, 1]
-    keep = (offs <= limit) & (lane < q_len)           # [C, ps]
-    keep = keep[:, None, :]                           # [C, 1, ps]
-    # s[c, h, p] = q[c, h, :] . k[p, h, :]  (head-batched matvec: the
-    # decode step is bandwidth-bound — VPU elementwise+reduce is fine)
-    s = jnp.sum(q[:, :, None, :] * k.transpose(1, 0, 2)[None],
-                axis=-1)                              # [C, Hq, ps]
-    s = jnp.where(keep, s, NEG_INF)
-    m_old = m_sc[...].reshape(chunk, q.shape[1], 1)   # [C, Hq, 1]
-    m_new = jnp.maximum(m_old, jnp.max(s, axis=2, keepdims=True))
-    alpha = jnp.exp(m_old - m_new)
-    p = jnp.exp(s - m_new) * keep                     # [C, Hq, ps]
-    l_old = l_sc[...].reshape(chunk, q.shape[1], 1)
-    l_new = l_old * alpha + jnp.sum(p, axis=2, keepdims=True)
-    # pv[c, h, d] = sum_p p[c, h, p] * v[p, h, d]
-    pv = jnp.sum(p[:, :, :, None] * v.transpose(1, 0, 2)[None],
-                 axis=2)                              # [C, Hq, D]
-    m_sc[...] = m_new.reshape(m_sc.shape)
-    l_sc[...] = l_new.reshape(l_sc.shape)
-    acc_flat = acc_sc[...].reshape(chunk, q.shape[1], q.shape[2])
-    acc_sc[...] = (acc_flat * alpha + pv).reshape(acc_sc.shape)
+
+    @pl.when(w * page_size < kv_len)
+    def _fold():
+        k = k_ref[0].astype(jnp.float32)              # [ps, Hkv, D]
+        v = v_ref[0].astype(jnp.float32)
+        if rep > 1:
+            k = jnp.repeat(k, rep, axis=1)            # [ps, Hq, D]
+            v = jnp.repeat(v, rep, axis=1)
+        # this page covers absolute key positions [w*ps, w*ps + ps)
+        offs = w * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (page_size, 1, 1), 0)          # [ps, 1, 1]
+
+        def _lane(j, carry):
+            # query lane j sits at absolute position kv_len - q_len + j
+            # and sees keys at positions <= its own (chunk-causal; up
+            # to its block's end under block diffusion, _key_limit)
+            keep = offs <= _key_limit(kv_len, q_len, j, block_length)
+            q = q_ref[0, j].astype(jnp.float32) * scale   # [Hq, D]
+            # s[p, h] = q[h, :] . k[p, h, :]  (float32 on the VPU:
+            # elementwise + reduce)
+            s = jnp.sum(q[None] * k, axis=-1, keepdims=True)  # [ps, Hq, 1]
+            s = jnp.where(keep, s, NEG_INF)
+            m_old = m_sc[j]                           # [Hq, 1]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=0))
+            alpha = jnp.exp(m_old - m_new)
+            p = jnp.exp(s - m_new[None]) * keep       # [ps, Hq, 1]
+            m_sc[j] = m_new
+            l_sc[j] = l_sc[j] * alpha + jnp.sum(p, axis=0)
+            # pv[h, d] = sum_p p[p, h] * v[p, h, d]
+            acc_sc[j] = acc_sc[j] * alpha + jnp.sum(p * v, axis=0)
+            return carry
+
+        jax.lax.fori_loop(0, q_len, _lane, 0)
 
     @pl.when(w == nw - 1)
     def _emit():
-        l = jnp.maximum(l_sc[...].reshape(chunk, q.shape[1], 1),
-                        jnp.finfo(jnp.float32).tiny)
-        acc = acc_sc[...].reshape(chunk, q.shape[1], q.shape[2])
-        o_ref[0] = (acc / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_sc[...], jnp.finfo(jnp.float32).tiny)
+        o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
+
+
+def _live_columns(tables, kv_lens, page_size: int):
+    """The page table with every column past a slot's last live page
+    naming that last page again. The grid walks all W columns of every
+    slot; consecutive grid steps on one block make the pipeline issue no
+    new DMA, so neither the table's garbage columns nor a page the slot
+    holds past ``kv_len`` is ever fetched (a dead slot fetches its
+    column 0 once and folds nothing). Done here, once a call, and not in
+    the index map, which runs twice every grid step."""
+    last = jnp.maximum(pl.cdiv(kv_lens, page_size) - 1, 0)       # [B]
+    col = jnp.arange(tables.shape[1], dtype=jnp.int32)[None]     # [1, W]
+    return jnp.take_along_axis(tables, jnp.minimum(col, last[:, None]),
+                               axis=1)
 
 
 def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
@@ -268,9 +295,9 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
     q, q_lens = _canon_chunked(q, kv_lens, q_lens)
     scale = float(scale) if scale else d ** -0.5
     rep = hq // hkv
-    tables = page_tables.astype(jnp.int32)
     kv_l = kv_lens.astype(jnp.int32)
     q_l = q_lens.astype(jnp.int32)
+    tables = _live_columns(page_tables.astype(jnp.int32), kv_l, ps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,   # page_tables, kv_lens, q_lens in SMEM
         grid=(b, w),
@@ -278,8 +305,7 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
             pl.BlockSpec((1, c, hq, d), lambda bb, ww, t, n, m: (bb, 0, 0,
                                                                  0)),
             # THE paged read: the index map picks each sequence's w-th
-            # page out of the pool, so the pipeline DMAs only owned
-            # pages (garbage-padded entries fetch page 0, fully masked)
+            # live page out of the pool (_live_columns)
             pl.BlockSpec((1, ps, hkv, d),
                          lambda bb, ww, t, n, m: (t[bb, ww], 0, 0, 0)),
             pl.BlockSpec((1, ps, hkv, d),
@@ -287,15 +313,16 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
         ],
         out_specs=pl.BlockSpec((1, c, hq, d),
                                lambda bb, ww, t, n, m: (bb, 0, 0, 0)),
+        # the lane is the leading index, so the fold takes one lane's
+        # [Hq, .] slab by a dynamic first-axis index
         scratch_shapes=[
-            pltpu.VMEM((c * hq, 1), jnp.float32),   # running max
-            pltpu.VMEM((c * hq, 1), jnp.float32),   # running sum
-            pltpu.VMEM((c * hq, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((c, hq, 1), jnp.float32),    # running max
+            pltpu.VMEM((c, hq, 1), jnp.float32),    # running sum
+            pltpu.VMEM((c, hq, d), jnp.float32),    # output accumulator
         ],
     )
     kernel = functools.partial(_paged_kernel, scale=scale, page_size=ps,
-                               rep=rep, chunk=c,
-                               block_length=int(block_length))
+                               rep=rep, block_length=int(block_length))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

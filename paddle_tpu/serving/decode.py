@@ -136,6 +136,13 @@ _m_total = _metrics.histogram("serving.decode.total_ms")
 # live slots / slot bucket per step: the continuous-batching win is
 # this histogram staying fat while drain-per-batch's decays
 _m_occupancy = _metrics.histogram("serving.decode.occupancy")
+# how much of the attention grid a step call walks holds a live page
+# (ISSUE 31), one observation a step call that runs the attention:
+# 100 * sum over slots of ceil(kv_len / page_size) over slot bucket x
+# width bucket. The traffic and the two ladders set it, not the kernel:
+# the paged kernel walks every (slot, column) and skips the empty ones,
+# so the rest is what a dynamic grid would still save
+_m_attn_grid_live = _metrics.histogram("serving.decode.attn_grid_live_pct")
 # chunked prefill (ISSUE 10): prompt tokens consumed via prefill
 # grants, per-step grant totals (prices the token-budget policy next
 # to the occupancy/fragmentation gauges), and how many scheduler steps
@@ -329,10 +336,16 @@ def choose_block(logits, temperature, seed, positions, masked, n_unmask):
     return x0, conf, unmask
 
 
+def _live_pages(kv_lens, page_size: int) -> int:
+    """Pages that hold a key a step call's slots see."""
+    return int((-(-kv_lens.astype(np.int64) // page_size)).sum())
+
+
 def _call_work(slots: int, chunk: int, width: int, q_lens,
-               kv_lens, block: int = 1) -> Dict[str, int]:
+               kv_lens, block: int = 1, *,
+               page_size: int) -> Dict[str, int]:
     """``serving.decode.device_call``'s args: the compiled buckets of
-    one step call and the three sums its attention work follows from,
+    one step call and the sums its attention work follows from,
     whatever implements the call — query tokens, keys in view, and
     query-key pairs under the model's mask: a slot's ``q`` newest
     tokens, whole blocks of ``block``, see from ``kv-q+block`` up to
@@ -341,11 +354,16 @@ def _call_work(slots: int, chunk: int, width: int, q_lens,
     ``4 * heads * head_dim * attn_pairs`` operations and reads
     ``2 * kv_heads * head_dim * kv_tokens`` K/V elements, beside
     ``2 * heads * head_dim * q_tokens`` of q and out. Dead slots are
-    0/0 and add nothing."""
+    0/0 and add nothing. ``kv_pages`` (pages that hold a key in view)
+    and ``q_lanes`` (``slots * chunk``) set what is walked beside what
+    is live: ``slots * width`` table columns and ``q_lanes`` query
+    lanes against ``kv_pages`` and ``q_tokens``."""
     q, kv = q_lens.astype(np.int64), kv_lens.astype(np.int64)
     return {"slots": slots, "chunk": chunk, "width": width,
             "q_tokens": int(q.sum()), "kv_tokens": int(kv.sum()),
-            "attn_pairs": int(((2 * kv - q + block) * q // 2).sum())}
+            "attn_pairs": int(((2 * kv - q + block) * q // 2).sum()),
+            "kv_pages": _live_pages(kv_lens, page_size),
+            "q_lanes": slots * chunk}
 
 
 # --- ladders ------------------------------------------------------------
@@ -2423,11 +2441,8 @@ class DecodeEngine:
                 # dispatch of the jitted step to the chosen ids on the
                 # host; the logits stay on the device
                 with _tracing.span("serving.decode.device_call") as sp:
-                    if sp.live:
-                        for key, value in _call_work(
-                                s_bucket, c_bucket, w_bucket, q_lens,
-                                lens).items():
-                            sp.set_arg(key, value)
+                    self._note_call(sp, s_bucket, c_bucket, w_bucket,
+                                    q_lens, lens)
                     # the draw's position is lens, each slot's new
                     # token's absolute index in its sequence: the (seed,
                     # position) pair that makes sampling independent of
@@ -2587,6 +2602,21 @@ class DecodeEngine:
             self._close_round_locked(done, produced_any, notes, step_s,
                                      sample_s)
 
+    def _note_call(self, sp, slots: int, chunk: int, width: int, q_lens,
+                   kv_lens, block: int = 1):
+        """What a step call that runs the attention records before it
+        is dispatched: the share of its ``slots x width`` grid that
+        holds a live page, always, and ``_call_work``'s args on the
+        ``device_call`` span where one is live."""
+        ps = self.cache.page_size
+        _m_attn_grid_live.observe(
+            100.0 * _live_pages(kv_lens, ps) / (slots * width))
+        if sp.live:
+            for key, value in _call_work(slots, chunk, width, q_lens,
+                                         kv_lens, block,
+                                         page_size=ps).items():
+                sp.set_arg(key, value)
+
     def _observe_step(self, seconds: float, n_live: int,
                       prefill_toks: int):
         """What a round records when its device call is back, whatever
@@ -2711,11 +2741,9 @@ class DecodeEngine:
                            * self.spec.moe_assignments_per_token)
             counts = None
             with _tracing.span("serving.decode.device_call") as sp:
+                self._note_call(sp, s_bucket, c_bucket, w_bucket, q_lens,
+                                lens, block=bl)
                 if sp.live:
-                    for key, value in _call_work(
-                            s_bucket, c_bucket, w_bucket, q_lens,
-                            lens, block=bl).items():
-                        sp.set_arg(key, value)
                     for kind, n in n_kind.items():
                         sp.set_arg(kind + "_slots", n)
                     sp.set_arg("moe_assignments", assignments)

@@ -936,6 +936,40 @@ def test_round_spans_in_order_on_the_profilers_clock(profiler_session,
     assert tracing.trace_events() == []         # the ring stayed off
 
 
+def test_grid_live_histogram_and_span_args_read_what_the_build_sent(
+        profiler_session):
+    """serving.decode.attn_grid_live_pct observes once a step call, span
+    or no span, and ``kv_pages`` / ``q_lanes`` of device_call say what
+    the call's grid walks beside what is live (ISSUE 31). One request
+    alone, page 4, chunk 4: a prompt of 6 goes in as 4 + 2, then two
+    decode steps — keys in view 4, 6, 7, 8 on 1, 2, 2, 2 pages."""
+    eng = _engine(max_seq_len=16)
+    profiler_session.start()
+    _drive(eng, [([1, 2, 3, 4, 5, 6], dict(max_new_tokens=3))])
+    events = profiler_session.stop(prefix="serving.decode.device_call")
+    calls = [e["args"] for e in events]
+    assert [c["kv_tokens"] for c in calls] == [4, 6, 7, 8]
+    assert [c["kv_pages"] for c in calls] == [1, 2, 2, 2]
+    assert [c["q_tokens"] for c in calls] == [4, 2, 1, 1]
+    assert [c["q_lanes"] for c in calls] == [4, 4, 1, 1]
+    assert all(c["q_lanes"] == c["slots"] * c["chunk"] for c in calls)
+    # the request holds 3 pages (6 + 3 tokens): width bucket 4, one slot
+    assert {(c["slots"], c["width"]) for c in calls} == {(1, 4)}
+    # no session open, the histogram alone: two prompts of 5 and 3 in
+    # one round share the step's budget of 4 prompt tokens, a token each
+    # at least — keys in view (4, 1) then (5, 3) on slots 2 x width 2
+    _drive(eng, [([11, 12, 13, 14, 15], dict(max_new_tokens=1)),
+                 ([7, 8, 9], dict(max_new_tokens=1))], together=True)
+    eng.stop()
+    snap = metrics.snapshot("serving.decode.")
+    live = snap["serving.decode.attn_grid_live_pct"]
+    assert live["count"] == snap["serving.decode.steps"] == 4 + 2
+    want = [100.0 * pages / grid for pages, grid in
+            [(1, 4), (2, 4), (2, 4), (2, 4), (1 + 1, 4), (2 + 1, 4)]]
+    assert live["sum"] == pytest.approx(sum(want))
+    assert (live["min"], live["max"]) == (25.0, 75.0)
+
+
 @pytest.mark.parametrize("all_lanes", [False, True])
 def test_decoder_step_names_its_device_work(all_lanes):
     """decoder_step_chunked's lowered text holds every decoder.* scope,

@@ -440,6 +440,70 @@ def test_conv2d_bn_relu_op_uses_pallas_when_forced():
         set_flags({"use_pallas_kernels": "auto"})
 
 
+# --- the paged kernel's work follows kv_lens and q_lens (ISSUE 31) --------
+# The kernel neither fetches nor folds a table column past a slot's last
+# live page, and multiplies no query lane past q_len: whatever lies there
+# cannot reach the output. A kernel that multiplied it and masked the
+# product (0 x NaN) would fail every case.
+
+_PAGED_KV = (0, 1, 13, 16, 32)      # dead, one key, mid-page, boundary, full
+_PAGED_Q = (0, 1, 4, 16)            # dead, a decoding lane, a block, all of C
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32pool", "bf16pool"])
+@pytest.mark.parametrize("heads", [(16, 16), (32, 4)],
+                         ids=["h16_16", "h32_4"])
+@pytest.mark.parametrize("block_length", [1, 4])
+def test_paged_kernel_touches_nothing_past_kv_len_or_q_len(
+        block_length, heads, pool_dtype):
+    """One slot for each (kv_len, q_len) of 5 x 4 at C 16, page 8 and a
+    table of 4 columns. Every table column past a slot's last live page
+    names a page of NaN (the garbage page among them) and every q lane
+    past q_len is NaN: the output is finite, equals the reference on
+    clean data, and is exactly zero on dead lanes and dead slots."""
+    from paddle_tpu.fluid.ops.pallas_kernels.paged_attention import (
+        _paged_attention_pallas, paged_attention_reference)
+
+    hq, hkv = heads
+    c, d, ps, w = 16, 8, 8, 4
+    kv_lens = np.repeat(_PAGED_KV, len(_PAGED_Q)).astype(np.int32)
+    q_lens = np.minimum(np.tile(_PAGED_Q, len(_PAGED_KV)),
+                        kv_lens).astype(np.int32)
+    b = len(kv_lens)
+    rng = np.random.RandomState(31)
+    # page 0 is the garbage page, page 1 a page some slot reserved and
+    # nobody wrote; live pages are each slot's own
+    tables = np.ones((b, w), np.int32)
+    tables[:, w // 2:] = 0
+    for i, n in enumerate(kv_lens):
+        live = -(-int(n) // ps)
+        tables[i, :live] = 2 + i * w + np.arange(live)
+    pages = 2 + b * w
+    k = rng.randn(pages, ps, hkv, d).astype(np.float32)
+    v = rng.randn(pages, ps, hkv, d).astype(np.float32)
+    q = rng.randn(b, c, hq, d).astype(np.float32)
+    dead_lane = np.arange(c)[None, :] >= q_lens[:, None]        # [B, C]
+
+    def call(fn, q, k, v, **kw):
+        return np.asarray(fn(
+            jnp.asarray(q), jnp.asarray(k, pool_dtype),
+            jnp.asarray(v, pool_dtype), jnp.asarray(tables),
+            jnp.asarray(kv_lens), q_lens=jnp.asarray(q_lens),
+            block_length=block_length, **kw))
+
+    want = call(paged_attention_reference, q, k, v)
+    k[:2] = v[:2] = np.nan
+    q[dead_lane] = np.nan
+    got = call(_paged_attention_pallas, q, k, v, interpret=True)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    np.testing.assert_array_equal(got[dead_lane], 0.0)
+    assert dead_lane[q_lens == 0].all() and (kv_lens == 0).sum() == 4
+    # every live lane of every live slot saw a key
+    assert np.abs(got[~dead_lane]).max(axis=(-1, -2)).min() > 0
+
+
 # --- compiled for a TPU v5e without one ----------------------------------
 # The cases above run the kernels INTERPRETED; Mosaic, the compiler the
 # chip uses, has never seen them. libtpu can describe a v5e topology to a
@@ -471,20 +535,27 @@ def _on(dev_or_sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
 
-@pytest.mark.parametrize("chunk", [1, 16])
-def test_paged_kernel_compiles_for_v5e(v5e, chunk):
-    """The served attention geometry: 16 query / 16 kv heads of 128,
-    page 16 — single-token decode (C=1) and a prefill chunk (C=16)."""
+@pytest.mark.parametrize("chunk,hq,hkv,dtype,block_length", [
+    (1, 16, 16, jnp.float32, 1), (16, 16, 16, jnp.float32, 1),
+    (4, 32, 4, jnp.bfloat16, 4), (16, 32, 4, jnp.bfloat16, 4)],
+    ids=["chat_c1", "chat_c16", "block_c4", "block_c16"])
+def test_paged_kernel_compiles_for_v5e(v5e, chunk, hq, hkv, dtype,
+                                       block_length):
+    """The served attention geometries, heads of 128 and page 16. The
+    dense family's: 16 query / 16 kv heads, float32 q and pools, causal
+    — single-token decode (C=1) and a prefill chunk (C=16). The block
+    model's: 32 query / 4 kv heads, bfloat16 q and pools, blocks of 4 —
+    a block pass (C=4) and a prefill chunk (C=16)."""
     from paddle_tpu.fluid.ops.pallas_kernels.paged_attention import (
         _paged_attention_pallas)
 
-    b, w, h, d, ps, pages = 8, 64, 16, 128, 16, 128
+    b, w, d, ps, pages = 16, 64, 128, 16, 128
     d0 = v5e[0]
     jax.jit(lambda q, k, v, t, n, m: _paged_attention_pallas(
-        q, k, v, t, n, q_lens=m)).lower(
-        _on(d0, (b, chunk, h, d), jnp.float32),
-        _on(d0, (pages, ps, h, d), jnp.float32),
-        _on(d0, (pages, ps, h, d), jnp.float32),
+        q, k, v, t, n, q_lens=m, block_length=block_length)).lower(
+        _on(d0, (b, chunk, hq, d), dtype),
+        _on(d0, (pages, ps, hkv, d), dtype),
+        _on(d0, (pages, ps, hkv, d), dtype),
         _on(d0, (b, w), jnp.int32), _on(d0, (b,), jnp.int32),
         _on(d0, (b,), jnp.int32)).compile()
 

@@ -3,7 +3,9 @@ their own under `perf/layer_metrics/`: `sched_device_choice_pct` (ISSUE 29:
 where tokens are chosen, data only, read from the program's histogram
 `serving.decode.device_choice_pct`) and the five of the `sdar30b_blockgen`
 cell (ISSUE 30): `block_tokens_per_pass`, `moe_load_max_over_mean`,
-`moe_experts_roofline`, `moe_route_share_pct`, `paged_attn_block_roofline`.
+`moe_experts_roofline`, `moe_route_share_pct`, `paged_attn_block_roofline`;
+and `paged_attn_grid_live_pct` (ISSUE 31: data only, the program's histogram
+`serving.decode.attn_grid_live_pct`).
 
 The benchmark's own tests live in `perf/tests` and are not collected by
 the tier-1 command; this case is, so that a tree whose BENCHMARK.json no
@@ -21,6 +23,12 @@ from perf.lib.loader import Benchmark  # noqa: E402
 
 NAME, CELL = "sched_device_choice_pct", "xglm17b_chat"
 HISTOGRAM = "serving.decode.device_choice_pct"
+# the data-only metrics read from a histogram's mean in both serving cells:
+# name -> (histogram, layer); ISSUE 31's is the benchmark's newest entry
+HISTOGRAM_AVG = {
+    NAME: (HISTOGRAM, "scheduler"),
+    "paged_attn_grid_live_pct": ("serving.decode.attn_grid_live_pct",
+                                 "kernels")}
 
 
 @pytest.fixture(scope="module")
@@ -28,35 +36,40 @@ def bench():
     return Benchmark(ROOT)
 
 
-def test_device_choice_metric_loads_and_reads_the_histograms_avg(bench):
+@pytest.mark.parametrize("name", sorted(HISTOGRAM_AVG))
+def test_a_histogram_metric_loads_and_reads_the_histograms_avg(bench, name):
+    histogram, layer = HISTOGRAM_AVG[name]
     bench.check_files()
-    entry, = [m for m in bench.doc["per_layer"] if m["name"] == NAME]
+    entry, = [m for m in bench.doc["per_layer"] if m["name"] == name]
     assert entry == {
-        "name": NAME, "unit": "%", "better": "higher",
-        "source": "program_counter", "layer": "scheduler",
+        "name": name, "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": layer,
         "moves": "serve_tokens_per_s",
         "workloads": [CELL, "sdar30b_blockgen"]}
-    assert entry["moves"] in bench.end_to_end(CELL)
-    (found, desc), = [(m, d) for m, d in bench.per_layer(CELL)
-                      if m["name"] == NAME]
-    assert found is entry
-    assert (desc["reader"], desc["histogram"], desc["stat"]) == (
-        "histogram", HISTOGRAM, "avg")
-    assert (desc["name"], desc["unit"], desc["layer"], desc["moves"]) == (
-        NAME, "%", "scheduler", "serve_tokens_per_s")
+    assert bench.doc["per_layer"][-1]["name"] == "paged_attn_grid_live_pct"
     # data only: no reader of its own, and the training cell is not asked
-    assert not os.path.exists(bench.path("layer_metrics", NAME + ".py"))
-    assert NAME not in [m["name"] for m, _d in
+    assert not os.path.exists(bench.path("layer_metrics", name + ".py"))
+    assert name not in [m["name"] for m, _d in
                         bench.per_layer("resnet50_train")]
-    facts = {"histograms": {HISTOGRAM: {
+    for cell in entry["workloads"]:
+        assert entry["moves"] in bench.end_to_end(cell)
+        (found, desc), = [(m, d) for m, d in bench.per_layer(cell)
+                          if m["name"] == name]
+        assert found is entry
+        assert (desc["reader"], desc["histogram"], desc["stat"]) == (
+            "histogram", histogram, "avg")
+        assert (desc["name"], desc["unit"], desc["layer"], desc["source"],
+                desc["moves"]) == (name, "%", layer, "program_counter",
+                                   "serve_tokens_per_s")
+    facts = {"histograms": {histogram: {
         "count": 424, "sum": 42188.0, "avg": 99.5, "min": 93.75,
         "max": 100.0, "p50": 100.0}}}
     assert bench.read_layer_metric(entry, desc, facts) == 99.5
     # the parent's case: a program without the histogram, or one whose
-    # window chose no token, gives nothing to read and does not raise
+    # window observed nothing, gives nothing to read and does not raise
     assert bench.read_layer_metric(entry, desc, {"histograms": {}}) is None
     assert bench.read_layer_metric(
-        entry, desc, {"histograms": {HISTOGRAM: {"count": 0}}}) is None
+        entry, desc, {"histograms": {histogram: {"count": 0}}}) is None
 
 
 def test_the_program_registers_what_the_metric_reads():
@@ -66,7 +79,8 @@ def test_the_program_registers_what_the_metric_reads():
     from paddle_tpu.serving import decode  # noqa: F401  (registers them)
 
     snap = metrics.snapshot("serving.decode.")
-    assert isinstance(snap[HISTOGRAM], dict)
+    for histogram, _layer in HISTOGRAM_AVG.values():
+        assert isinstance(snap[histogram], dict)
     for name in ("device_choices", "host_choices"):
         assert snap["serving.decode." + name] == 0
 
@@ -219,12 +233,15 @@ def test_a_device_calls_pairs_follow_the_models_mask(block, q, kv):
 
     from paddle_tpu.serving.decode import _call_work
 
-    work = _call_work(1, q, 8, np.array([q, 0]), np.array([kv, 0]),
-                      block=block)
+    work = _call_work(2, q, 8, np.array([q, 0]), np.array([kv, 0]),
+                      block=block, page_size=8)
     lanes = range(kv - q, kv)
     assert work["attn_pairs"] == sum(
         min(kv, (i // block + 1) * block) for i in lanes)
     assert (work["q_tokens"], work["kv_tokens"]) == (q, kv)
+    # what is walked beside what is live: 2 x 8 table columns of which
+    # the live slot's pages hold a key, and 2 x q lanes of which q do
+    assert (work["kv_pages"], work["q_lanes"]) == (-(-kv // 8), 2 * q)
 
 
 def test_the_trace_reduction_tells_the_paged_kernel_from_the_grouped_products(
