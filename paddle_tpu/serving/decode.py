@@ -68,6 +68,38 @@ with paged attention + tied-embedding logits, deterministic params
 from a seed) is the test/bench/selftest vehicle — real checkpoints
 implement the same step signature.
 
+ONE WAY TO BUILD, RUN AND ANSWER A CALL (ISSUE 32). What one call to
+the device looks like is decided in one place each:
+
+  - the PROGRAM TABLE (``DecodeEngine._programs``): ``__init__`` jits
+    each body through one helper (donation and output shardings applied
+    once) into a ``_Program`` record under its tag — ``target`` (the
+    plain ``_step``, or a block model's ``_block_step``), and where the
+    engine has them ``verify``, ``draft`` and ``embed``. The record says
+    whose params and pool a call reads, which counter it bumps and
+    which further arrays it takes;
+  - the RUN FUNCTION (``_run``): under ``_step_mu`` it counts a
+    distinct compiled shape, bumps the program's counter, calls, and
+    rebinds that program's pool. ``_run_step_arrays`` is the target
+    program's entry point into it, reached by attribute at every call
+    (the benchmark wraps it on the instance and plants faults on the
+    class);
+  - the ARRAY BUILDER (``_build_arrays``): every call is "slot i feeds
+    these tokens from this position" — a prefill chunk, a decode token,
+    a block pass, an embed chunk, the draft's catch-up and singles, the
+    verify chunk — and one function pads the feeds to the compiled
+    buckets and refuses a feed past its slot's reservation;
+  - the ROUND (``_step``): prepare -> PLAN (per slot: which pass, what
+    it feeds, whether anybody reads its choice) -> build -> device call
+    -> ANSWER (per slot, by the pass it ran) -> retire -> notify. What a
+    causal slot and a block slot differ in is the plan and the answer
+    (``_plan_causal`` / ``_plan_blocks``; ``_answer_plain`` /
+    ``_answer_spec`` / ``_answer_block``), chosen by the model's block
+    length and the slot's state; buckets, spans, dispatch, waiting,
+    timing, retirement and the wake-up are written once. The embed lane
+    keeps its own slot list and scoring and shares the builder, the run
+    function and the retire/notify tail.
+
 Lifecycle mirrors the one-shot engine so the SAME ModelRegistry
 hot-swaps decoders: ``stop(drain=True)`` finishes every admitted
 sequence then drops params/pools/compiled steps (executables release
@@ -76,10 +108,12 @@ re-raising so the registry's rollback leaks nothing.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import math
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -498,6 +532,16 @@ class _Slot:
         return (int(p[idx]) if idx < len(p)
                 else self.req.produced[idx - len(p)])
 
+    def tokens_at(self, start: int, n: int):
+        """The sequence's ``n`` tokens from absolute position ``start``
+        on: a slice of the prompt where they all lie in it."""
+        if start + n <= len(self.req.prompt):
+            return self.req.prompt[start:start + n]
+        return [self.token_at(i) for i in range(start, start + n)]
+
+    def progress(self) -> str:
+        return f"mid-decode after {len(self.req.produced)} tokens"
+
 
 class _EmbedRequest:
     """A prompt-only embedding/scoring request (ISSUE 20): admitted by
@@ -545,6 +589,73 @@ class _EmbedSlot:
         self.pages_held = pages_held
         self.steps = 0
 
+    def progress(self) -> str:
+        return f"mid-prefill at {self.pos} tokens"
+
+
+class _Program(NamedTuple):
+    """One entry of the engine's program table: a jitted body
+    ``fn(params, tokens, positions, q_lens, k_pool, v_pool, tables,
+    lens, ...)`` that hands back the two pools and then what it answers,
+    and what ``DecodeEngine._run`` must know to call it: whether it
+    reads the DRAFT's params and pool or the target's, the counter a
+    call bumps, and whether it takes the sampling arrays
+    (``temperature``, ``seed``) and a block model's mask arrays
+    (``masked``, ``n_unmask``) after ``lens``."""
+
+    fn: Any
+    draft: bool = False
+    steps: Any = _m_target_steps
+    sampled: bool = True
+    masked: bool = False
+
+
+class _Round:
+    """One decoding round between its plan and its answers (the
+    scheduler thread's own): which slots ride the target program's call
+    and what each feeds, what the call handed back, and what the slots'
+    answers tally for the round's counters."""
+
+    def __init__(self):
+        # the plan: the call's slots in row order, each row's (start
+        # position, tokens) feed, a block model's pass a row, and the
+        # decoding slots a draft proposes for (they ride no row)
+        self.rows: List[_Slot] = []
+        self.row_of: Dict[int, int] = {}    # id(slot) -> its row
+        self.feeds: List[Tuple[int, Any]] = []
+        self.kinds: List[str] = []
+        self.spec: List[_Slot] = []
+        # whether a slot of the call reads its choice (a call whose
+        # chunks all end inside their prompts is not waited for)
+        self.reads = False
+        self.prefill_toks = 0
+        # a block program's further arrays, a row a slot of the bucket,
+        # and the device_call span's further args
+        self.call_kw: Dict[str, np.ndarray] = {}
+        self.call_args: Dict[str, int] = {}
+        # the call: what it answered, on the host once somebody reads it
+        # (``ids`` and whatever else the program hands back), its logits
+        # on the device, and the substep's {id(slot): (committed, k_eff,
+        # accepted)}
+        self.out: Optional[Dict[str, np.ndarray]] = None
+        self.logits = None
+        self.spec_out: Dict[int, Tuple[List[int], int, int]] = {}
+        # the answers' tallies: tokens chosen on the "device" / the
+        # "host", draft tokens "proposed" / "accepted", a block model's
+        # "passes" and expert "assignments" (both the plan's) and its
+        # "dropped" tokens; and the host sampler's seconds
+        self.n: collections.Counter = collections.Counter()
+        self.sample_s = 0.0
+
+    @contextlib.contextmanager
+    def sampling(self):
+        """The host route's choice of one slot's token: its span, and
+        its time in the round's ``sample_ms``."""
+        t0 = time.perf_counter()
+        with _tracing.span("serving.decode.sample"):
+            yield
+        self.sample_s += time.perf_counter() - t0
+
 
 # --- the engine ---------------------------------------------------------
 
@@ -584,7 +695,7 @@ class DecodeEngine:
         # the block length the MODEL generates by (ISSUE 30): 1 for a
         # causal model, the engine it always was; B > 1 for generation by
         # diffusion over blocks — a decoding slot then runs passes of B
-        # lanes (_step_blocks) and a prefill chunk is whole blocks. What
+        # lanes (_plan_blocks) and a prefill chunk is whole blocks. What
         # speculation, the embed lane and the prefix cache assume (one
         # token a pass; K/V that are a function of the tokens before
         # them) does not hold for such a model: each refuses it by name
@@ -711,7 +822,7 @@ class DecodeEngine:
         self._prefill_chunk = -(-self._prefill_chunk
                                 // self._block) * self._block
         # the third padded dimension of the compiled step: pure-decode
-        # steps ride the C=1 shapes (exactly the PR 6 step — chunking
+        # steps ride the C=1 shapes (one token a slot — chunking
         # costs nothing when no prompt is in flight; a block model's
         # passes ride C=block_length), steps carrying a prefill grant
         # ride the C=chunk shapes
@@ -847,28 +958,34 @@ class DecodeEngine:
         donate = (bool(FLAGS["donate_state"])
                   and jax.default_backend() == "tpu")
         self._donate = donate
-        step_out_shardings = None
+        jit_kw: Dict[str, Any] = {"donate_argnums": (4, 5) if donate else ()}
         if self._mesh is not None:
             # pin the step outputs: pools keep the kv-head sharding they
             # came in with, the ids and the logits come back replicated
             # (the scheduler fetches the ids, and a logits row where a
-            # request is answered host-side). Without the pin GSPMD may
-            # choose a different output layout per shape and the next
-            # step's input sharding drift would mint a post-warm compile.
+            # request is answered host-side; the embed program's hidden
+            # states replicate like logits, pooling and scoring being
+            # host-side, so the same pin of two pools and two replicated
+            # outputs fits it). Without the pin GSPMD may choose a
+            # different output layout per shape and the next step's
+            # input sharding drift would mint a post-warm compile.
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as _P
 
             pool_sh = NamedSharding(self._mesh, self._pool_spec())
             replicated = NamedSharding(self._mesh, _P())
-            step_out_shardings = (pool_sh, pool_sh, replicated,
-                                  replicated)
-        self._step_out_shardings = step_out_shardings
-        self._step_fn = jax.jit(
+            jit_kw["out_shardings"] = (pool_sh, pool_sh, replicated,
+                                       replicated)
+
+        def program(body, **what) -> _Program:
+            # how a body becomes a program, once: the jitted function
+            # keeps the body's name, which every device operation's
+            # op_name and the persistent compile cache's keys carry
+            return _Program(jax.jit(body, **jit_kw), **what)
+
+        programs = {"target": program(
             _block_step if self._block > 1 else _step,
-            donate_argnums=(4, 5) if donate else (),
-            **({"out_shardings": step_out_shardings}
-               if step_out_shardings is not None
-               else {}))  # guarded-by: _step_mu
+            masked=self._block > 1)}
         if self._spec_k:
             draft_ref = self._draft_spec
 
@@ -898,19 +1015,18 @@ class DecodeEngine:
                 return k, v, choose_tokens(logits, temperature, seed,
                                            lens), logits
 
-            _sharded_kw = ({"out_shardings": step_out_shardings}
-                           if step_out_shardings is not None else {})
-            self._verify_fn = jax.jit(
-                _verify,
-                donate_argnums=(4, 5) if donate
-                else (), **_sharded_kw)  # guarded-by: _step_mu
-            self._draft_fn = jax.jit(
-                _draft,
-                donate_argnums=(4, 5) if donate
-                else (), **_sharded_kw)  # guarded-by: _step_mu
-        else:
-            self._verify_fn = None  # guarded-by: _step_mu
-            self._draft_fn = None  # guarded-by: _step_mu
+            # the speculative-verify target call: same pools, all-lane
+            # logits [B, C, vocab] (C = spec_k + 1) and the choice at
+            # every lane, ids [B, C] (lane j's is the token at
+            # positions[:, j] + 1) — one target step scores every
+            # proposal plus the bonus position
+            programs["verify"] = program(_verify)
+            # one DRAFT step (propose singles, catch-up chunks, prefill
+            # shadowing) against the mirrored draft pool — same page
+            # tables as the target, newest-lane (ids, logits) like the
+            # plain step's
+            programs["draft"] = program(_draft, draft=True,
+                                        steps=_m_draft_steps)
         if self._embed_on:
             def _embed(params, tokens, positions, q_lens, k_pool,
                        v_pool, tables, lens):
@@ -920,17 +1036,15 @@ class DecodeEngine:
                     attention_impl=impl, garbage_page=GARBAGE_PAGE)
                 return k, v, logits, aux["hidden"]
 
-            # (pools, logits, hidden): hidden states replicate like
-            # logits (pooling and logprob scoring are host-side), so the
-            # step's pin of two pools and two replicated outputs fits
-            self._embed_fn = jax.jit(
-                _embed,
-                donate_argnums=(4, 5) if donate else (),
-                **({"out_shardings": step_out_shardings}
-                   if step_out_shardings is not None
-                   else {}))  # guarded-by: _step_mu
-        else:
-            self._embed_fn = None  # guarded-by: _step_mu
+            # one EMBED step (ISSUE 20): the all-lane + hidden form
+            # against the shared target pool — every prompt lane's
+            # logits [B, C, vocab] (per-token scoring) and final-norm
+            # hidden states [B, C, d_model] (pooling) in one call
+            programs["embed"] = program(_embed, steps=_m_embed_steps,
+                                        sampled=False)
+        # the program table: tag -> _Program. A second entry is what
+        # makes the compiled-shape keys carry their tag (_run)
+        self._programs = programs  # guarded-by: _step_mu
         # one logits row (or one slot's lanes) to the host, for the
         # requests whose token is chosen there: ONE program a logits
         # shape with the row index dynamic, so that which slot asks
@@ -1075,25 +1189,21 @@ class DecodeEngine:
             for s in self._slot_ladder:
                 for w in self._width_ladder:
                     def dead(c):
-                        return (np.zeros((s, c), np.int32),
-                                np.zeros((s, c), np.int32),
-                                np.zeros(s, np.int32),
-                                np.full((s, w), GARBAGE_PAGE, np.int32),
-                                np.zeros(s, np.int32))
+                        return self._build_arrays((), (), s, c, w)
 
                     for c in self._chunk_ladder:
                         _ids, logits = self._run_step_arrays(*dead(c))
                     if self._spec_k:
-                        _ids, lanes = self._run_verify_arrays(
-                            *dead(self._verify_lanes))
+                        _ids, lanes = self._run(
+                            "verify", *dead(self._verify_lanes))
                         for c in self._draft_chunk_ladder:
-                            self._run_draft_arrays(*dead(c))
+                            self._run("draft", *dead(c))
                     if self._embed_on:
                         # the embed lane's all-lane+hidden family warms
                         # over the same triples — a mixed churn of
                         # generate + embeddings compiles nothing
                         for c in self._chunk_ladder:
-                            self._run_embed_arrays(*dead(c))
+                            self._run("embed", *dead(c))
                 # the host route's row fetch: one program a logits
                 # shape, [s, vocab] whatever the width and the chunk
                 # (the draft's logits share it: its vocab is the
@@ -1482,19 +1592,16 @@ class DecodeEngine:
         if self._thread.is_alive():  # pragma: no cover - wedged scheduler
             _log.error("decode scheduler for %s v%d did not exit in %.0fs",
                        self.name, self.version, timeout)
-        # params/step/pools drop under _step_mu — THEIR guard (guards-lint
-        # finding: they used to drop under _cond while _run_step_arrays
-        # reads them under _step_mu; safe only by join-ordering, which a
+        # params/programs/pools drop under _step_mu — THEIR guard (guards-
+        # lint finding: they used to drop under _cond while _run reads
+        # them under _step_mu; safe only by join-ordering, which a
         # static model can't see and a future warm()-after-stop wouldn't
         # honor)
         with self._step_mu:
             self._params = None
-            self._step_fn = None
+            self._programs = {}
             self._row_fn = None
-            self._embed_fn = None
             self._draft_params = None
-            self._verify_fn = None
-            self._draft_fn = None
             if self._draft_cache is not None:
                 # shared allocator: retire() is idempotent, the draft
                 # pool's HBM frees with its own k/v drop
@@ -1516,8 +1623,8 @@ class DecodeEngine:
     def stats(self) -> Dict[str, Any]:
         # _compiled_shapes is _step_mu state: snapshot it under ITS lock
         # (guards-lint finding — sorted() here used to iterate the set
-        # under _cond while the scheduler's _run_step_arrays add()ed to
-        # it under _step_mu: a mid-iteration mutation raises
+        # under _cond while the scheduler's _run add()ed to it under
+        # _step_mu: a mid-iteration mutation raises
         # "Set changed size during iteration" on a stats scrape)
         with self._step_mu:
             shapes = sorted(self._compiled_shapes)
@@ -1588,29 +1695,17 @@ class DecodeEngine:
         req.fail(err)
 
     def _drop_expired_locked(self, now: float):
-        keep = []
-        for r in self._queue:
-            if r.deadline is not None and now > r.deadline:
+        for queue in (self._queue, self._embed_queue):
+            late = [r for r in queue
+                    if r.deadline is not None and now > r.deadline]
+            for r in late:
                 _m_deadline_miss.inc()
                 self._fail_locked(r, DeadlineExceeded(
                     f"request to decoder '{self.name}' missed its "
                     "deadline while queued"))
-            else:
-                keep.append(r)
-        if len(keep) != len(self._queue):
-            self._queue[:] = keep
-            self._g_depth.set(len(keep))
-        ekeep = []
-        for r in self._embed_queue:
-            if r.deadline is not None and now > r.deadline:
-                _m_deadline_miss.inc()
-                self._fail_locked(r, DeadlineExceeded(
-                    f"request to decoder '{self.name}' missed its "
-                    "deadline while queued"))
-            else:
-                ekeep.append(r)
-        if len(ekeep) != len(self._embed_queue):
-            self._embed_queue[:] = ekeep
+            if late:
+                queue[:] = [r for r in queue if r not in late]
+                self._g_depth.set(len(self._queue))
 
     def _admit_locked(self):
         """Move queued requests into free slots. Continuous mode admits
@@ -1788,16 +1883,6 @@ class DecodeEngine:
                         return
 
     @staticmethod
-    def _sampling(temperature, seed, rows: int):
-        """The sampling arrays of one call as the programs take them;
-        all-greedy where the caller gives none (warm(), and a call whose
-        choice nobody reads). They are data: whoever samples, the
-        compiled shape is the same."""
-        if temperature is None:
-            return DecodeEngine._slot_sampling((), rows)
-        return temperature, seed
-
-    @staticmethod
     def _slot_sampling(slots: Sequence[_Slot], rows: int):
         """``(temperature [rows] float32, seed [rows] uint32)`` of a
         call's slots, what ``choose_tokens`` draws each one's token by;
@@ -1809,95 +1894,105 @@ class DecodeEngine:
             seed[i] = s.req.seed & 0xFFFFFFFF
         return temperature, seed
 
-    def _mask_args(self, rows: int, masked=None, n_unmask=None):
-        """A block model's two further arguments of the step program
-        (``masked [rows, B]`` bool, ``n_unmask [rows]`` int32; none masked
-        where the caller gives none); nothing for a causal model."""
-        if self._block == 1:
-            return ()
-        return (np.zeros((rows, self._block), bool)
-                if masked is None else masked,
-                np.zeros(rows, np.int32) if n_unmask is None else n_unmask)
+    def _build_arrays(self, slots: Sequence[Any], feeds: Sequence[Any],
+                      s_bucket: int, c_bucket: int, w_bucket: int,
+                      tables=None):
+        """THE arrays of one call, in the order every program takes
+        them: ``(tokens [S, C], positions [S, C], q_lens [S], tables
+        [S, W], lens [S])`` int32 at the three compiled buckets. Row i
+        is ``slots[i]`` feeding ``feeds[i] = (start, tokens)``: those
+        tokens at positions ``start ..``, ``q_lens`` how many, ``lens``
+        the keys INCLUDING them (within the chunk, query j attends only
+        keys up to its own position) — a prefill chunk, a decode token,
+        a block pass, an embed chunk, the draft's catch-up and singles,
+        the verify chunk alike. A ``None`` feed and the rows past
+        ``slots`` are dead: all zero, written to nowhere; table columns
+        past a sequence's pages (and a dead row's every column) name the
+        garbage page. A feed that would write past its slot's
+        reservation is refused. ``tables`` hands in those of an earlier
+        call of the round over the same slots and buckets."""
+        tokens = np.zeros((s_bucket, c_bucket), np.int32)
+        starts = np.zeros(s_bucket, np.int32)
+        q_lens = np.zeros(s_bucket, np.int32)
+        for i, (s, feed) in enumerate(zip(slots, feeds)):
+            if feed is None:
+                continue
+            start, fed = feed
+            n = len(fed)
+            tokens[i, :n] = fed
+            starts[i] = start
+            q_lens[i] = n
+            self._check_reservation(s, start + n)
+        # lane j of a row is position start + j, as far as the row feeds
+        lanes = np.arange(c_bucket, dtype=np.int32)
+        positions = np.where(lanes < q_lens[:, None],
+                             starts[:, None] + lanes, np.int32(0))
+        lens = starts + q_lens
+        if tables is None:
+            tables = self.cache.table_array(
+                [s.req.seq_id for s in slots], w_bucket, rows=s_bucket)
+        return tokens, positions, q_lens, tables, lens
+
+    def _run(self, tag: str, tokens, positions, q_lens, tables, lens, *,
+             temperature=None, seed=None, masked=None, n_unmask=None):
+        """THE device call, shared by warm() and every live step: count
+        a DISTINCT-shape compile, bump the program's step counter, run
+        the jitted program ``tag`` of the table on ITS params and pool,
+        rebind that pool, hand back the rest. Once the table holds a
+        second program the shape keys carry the tag ('target' /
+        'verify' / 'draft' / 'embed'), so the compiled families stay
+        distinct in the same churn-pinned set; an engine with one
+        program keeps bare (slots, width, chunk) triples — the two never
+        mix in one set (stats() sorts it). The sampling arrays are data,
+        not shape: all-greedy where the caller gives none (warm(), and a
+        call whose choice nobody reads), as a block program's ``masked``
+        has no lane masked."""
+        with self._step_mu:
+            prog = self._programs[tag]
+            key = (len(tokens), tables.shape[1], tokens.shape[1])
+            if len(self._programs) > 1:
+                key = (tag,) + key
+            if key not in self._compiled_shapes:
+                self._compiled_shapes.add(key)
+                _m_compiles.inc()
+            prog.steps.inc()
+            params, cache = ((self._draft_params, self._draft_cache)
+                             if prog.draft else (self._params, self.cache))
+            rows, args = len(tokens), ()
+            if prog.sampled:
+                args = ((np.zeros(rows, np.float32),
+                         np.zeros(rows, np.uint32))
+                        if temperature is None else (temperature, seed))
+            if prog.masked:
+                args += (np.zeros((rows, self._block), bool)
+                         if masked is None else masked,
+                         np.zeros(rows, np.int32)
+                         if n_unmask is None else n_unmask)
+            k, v, *out = prog.fn(params, tokens, positions, q_lens,
+                                 cache.k, cache.v, tables, lens, *args)
+            cache.rebind(k, v)
+            return tuple(out)
 
     def _run_step_arrays(self, tokens, positions, q_lens, tables, lens,
                          *, temperature=None, seed=None, masked=None,
                          n_unmask=None):
-        """Shared by warm() and live steps: count a DISTINCT-shape
-        compile, run the jitted step, rebind the pools. With a draft
-        attached the shape keys carry a model tag ('target'/'verify'/
-        'draft') so the three compiled families stay distinct in the
-        same churn-pinned set; without one they stay the bare PR 6/9
-        triples. Returns ``(ids [B] int32, logits [B, vocab])``, both on
-        the device: the program's own choice for each slot's newest
-        lane (``choose_tokens``) from ``[B]`` ``temperature`` float32
-        and ``seed`` uint32, at position ``lens`` (the new token's
-        absolute index). The sampling arrays are keyword-only: the five
+        """The TARGET program's entry point into ``_run``. Returns
+        ``(ids [B] int32, logits [B, vocab])``, both on the device: the
+        program's own choice for each slot's newest lane
+        (``choose_tokens``) from ``[B]`` ``temperature`` float32 and
+        ``seed`` uint32, at position ``lens`` (the new token's absolute
+        index). The sampling arrays are keyword-only: the five
         positional arguments are the call's shapes, which the
-        benchmark's traced runs log by position. A block model's program
+        benchmark's traced runs log by position; it wraps this name on
+        the instance and plants faults on the class, so the scheduler
+        reaches it by attribute at every call. A block model's program
         (ISSUE 30) also takes ``masked [B, block]`` bool and ``n_unmask
-        [B]`` int32 (none masked where the caller gives none) and its
-        ``ids`` is a dict of device arrays: ``ids``, ``confidence``,
-        ``unmask``, each ``[B, block]``, and what the model's pass
-        reports (``expert_counts [layers, E]``)."""
-        with self._step_mu:
-            key = (len(tokens), tables.shape[1], tokens.shape[1])
-            if self._spec_k or self._embed_on:
-                # tagged whenever a second compiled family exists —
-                # bare triples and tagged tuples must never mix in one
-                # set (stats() sorts it)
-                key = ("target",) + key
-            if key not in self._compiled_shapes:
-                self._compiled_shapes.add(key)
-                _m_compiles.inc()
-            _m_target_steps.inc()
-            extra = self._mask_args(len(tokens), masked, n_unmask)
-            k, v, ids, logits = self._step_fn(
-                self._params, tokens, positions, q_lens, self.cache.k,
-                self.cache.v, tables, lens, *self._sampling(
-                    temperature, seed, len(tokens)), *extra)
-            self.cache.rebind(k, v)
-            return ids, logits
-
-    def _run_verify_arrays(self, tokens, positions, q_lens, tables,
-                           lens, *, temperature=None, seed=None):
-        """The speculative-verify target call: same pools, all-lane
-        logits ``[B, C, vocab]`` (C = spec_k + 1) and the choice at
-        every lane, ``ids [B, C]`` (lane j's is the token at
-        ``positions[:, j] + 1``). One target step scores every
-        proposal plus the bonus position."""
-        with self._step_mu:
-            key = ("verify", len(tokens), tables.shape[1],
-                   tokens.shape[1])
-            if key not in self._compiled_shapes:
-                self._compiled_shapes.add(key)
-                _m_compiles.inc()
-            _m_target_steps.inc()
-            k, v, ids, logits = self._verify_fn(
-                self._params, tokens, positions, q_lens, self.cache.k,
-                self.cache.v, tables, lens, *self._sampling(
-                    temperature, seed, len(tokens)))
-            self.cache.rebind(k, v)
-            return ids, logits
-
-    def _run_draft_arrays(self, tokens, positions, q_lens, tables,
-                          lens, *, temperature=None, seed=None):
-        """One DRAFT step (propose singles, catch-up chunks, prefill
-        shadowing) against the mirrored draft pool — same page tables
-        as the target, newest-lane ``(ids, logits)`` like the plain
-        step's."""
-        with self._step_mu:
-            key = ("draft", len(tokens), tables.shape[1],
-                   tokens.shape[1])
-            if key not in self._compiled_shapes:
-                self._compiled_shapes.add(key)
-                _m_compiles.inc()
-            _m_draft_steps.inc()
-            k, v, ids, logits = self._draft_fn(
-                self._draft_params, tokens, positions, q_lens,
-                self._draft_cache.k, self._draft_cache.v, tables, lens,
-                *self._sampling(temperature, seed, len(tokens)))
-            self._draft_cache.rebind(k, v)
-            return ids, logits
+        [B]`` int32 and its ``ids`` is a dict of device arrays: ``ids``,
+        ``confidence``, ``unmask``, each ``[B, block]``, and what the
+        model's pass reports (``expert_counts [layers, E]``)."""
+        return self._run("target", tokens, positions, q_lens, tables,
+                         lens, temperature=temperature, seed=seed,
+                         masked=masked, n_unmask=n_unmask)
 
     def _await_ids(self, ids, chooses: bool):  # lint: allow-unguarded(_unread)
         """The plain step's chosen ids ``[B]`` on the host, where a slot
@@ -1928,25 +2023,6 @@ class DecodeEngine:
                 self._row_shapes.add(logits.shape)
                 _m_compiles.inc()
             return np.asarray(self._row_fn(logits, np.int32(row)))
-
-    def _run_embed_arrays(self, tokens, positions, q_lens, tables,
-                          lens):
-        """One EMBED step (ISSUE 20): the all-lane + hidden form
-        against the shared target pool — every prompt lane's logits
-        ``[B, C, vocab]`` (per-token scoring) and final-norm hidden
-        states ``[B, C, d_model]`` (pooling) in one call."""
-        with self._step_mu:
-            key = ("embed", len(tokens), tables.shape[1],
-                   tokens.shape[1])
-            if key not in self._compiled_shapes:
-                self._compiled_shapes.add(key)
-                _m_compiles.inc()
-            _m_embed_steps.inc()
-            k, v, logits, hidden = self._embed_fn(
-                self._params, tokens, positions, q_lens, self.cache.k,
-                self.cache.v, tables, lens)
-            self.cache.rebind(k, v)
-            return logits, hidden
 
     def _prepare(self, live: List[_Slot]
                  ) -> Tuple[List[_Slot], List[int]]:
@@ -2259,16 +2335,23 @@ class DecodeEngine:
         _faults.fire("serving.decode.spec")
         s_bucket = _bucket_for(self._slot_ladder, len(slots))
         keff = [self._k_eff(s) for s in slots]
-        for s, ke in zip(slots, keff):
-            # the verify chunk writes positions pos .. pos+ke
-            self._check_reservation(s, s.pos + ke + 1)
-        tables = self.cache.table_array(
-            [s.req.seq_id for s in slots], w_bucket, rows=s_bucket)
         proposals: List[List[int]] = [[] for _ in slots]
         # the slots' sampling arrays, the same through the round; the
         # positions of the choices are each call's own ``lens``
         host = [self._draws_on_host(s.req) for s in slots]
         temperature, seed = self._slot_sampling(slots, s_bucket)
+        tables = None
+
+        def call(tag, c_bucket, feeds):
+            # each call's build checks ITS writes against the
+            # reservation (the verify chunk's reach through pos + k_eff);
+            # the page tables are the round's first call's
+            nonlocal tables
+            arrays = self._build_arrays(slots, feeds, s_bucket, c_bucket,
+                                        w_bucket, tables)
+            tables = arrays[3]
+            return self._run(tag, *arrays, temperature=temperature,
+                             seed=seed)
 
         def propose(ids, logits, j):
             """Proposal d_j of every slot that still wants one, from a
@@ -2286,66 +2369,33 @@ class DecodeEngine:
                            k=self._spec_k):
             # catch-up + first proposal: feed each slot the committed
             # tokens its draft pool lacks (positions dpos..pos — the
-            # last is the pending token), newest-lane logits -> d_1
-            gaps = [s.pos - s.dpos for s in slots]
+            # last is the pending token, so lens == pos + 1, d_1's own),
+            # newest-lane logits -> d_1. A bonus-only slot (k_eff 0)
+            # needs no proposal: a dead row
             c1 = _bucket_for(self._draft_chunk_ladder,
-                             max(g + 1 for g in gaps))
-            tokens = np.zeros((s_bucket, c1), np.int32)
-            positions = np.zeros((s_bucket, c1), np.int32)
-            q_lens = np.zeros(s_bucket, np.int32)
-            lens = np.zeros(s_bucket, np.int32)
-            for i, s in enumerate(slots):
-                if keff[i] < 1:
-                    continue  # bonus-only slot: no proposals needed
-                g = gaps[i] + 1
-                for j in range(g):
-                    tokens[i, j] = s.token_at(s.dpos + j)
-                    positions[i, j] = s.dpos + j
-                q_lens[i] = g
-                lens[i] = s.dpos + g        # == s.pos + 1, d_1's own
-            if int(q_lens.max(initial=0)) > 0:
-                propose(*self._run_draft_arrays(
-                    tokens, positions, q_lens, tables, lens,
-                    temperature=temperature, seed=seed), 1)
-                # singles: feed d_{j-1}, propose d_j
+                             max(s.pos - s.dpos for s in slots) + 1)
+            if any(ke >= 1 for ke in keff):
+                propose(*call("draft", c1, [
+                    (s.dpos, s.tokens_at(s.dpos, s.pos - s.dpos + 1))
+                    if ke >= 1 else None
+                    for s, ke in zip(slots, keff)]), 1)
+                # singles: feed d_{j-1} at pos + j - 1, propose d_j
                 for j in range(2, self._spec_k + 1):
                     if not any(ke >= j for ke in keff):
                         break
-                    tokens = np.zeros((s_bucket, 1), np.int32)
-                    positions = np.zeros((s_bucket, 1), np.int32)
-                    q_lens = np.zeros(s_bucket, np.int32)
-                    lens = np.zeros(s_bucket, np.int32)
-                    for i, s in enumerate(slots):
-                        if keff[i] >= j:
-                            tokens[i, 0] = proposals[i][j - 2]
-                            positions[i, 0] = s.pos + j - 1
-                            q_lens[i] = 1
-                            lens[i] = s.pos + j     # d_j's own
-                    propose(*self._run_draft_arrays(
-                        tokens, positions, q_lens, tables, lens,
-                        temperature=temperature, seed=seed), j)
+                    propose(*call("draft", 1, [
+                        (s.pos + j - 1, proposals[i][j - 2:j - 1])
+                        if keff[i] >= j else None
+                        for i, s in enumerate(slots)]), j)
         # verify: ONE target call over [pending, d_1..d_k] at the
         # FIXED spec_k+1 chunk entry; lane j's logits are the target's
         # distribution for position pos+1+j
         with _tracing.span("serving.decode.spec.verify",
                            model=self.name, version=self.version,
                            slots=s_bucket, lanes=self._verify_lanes):
-            C = self._verify_lanes
-            tokens = np.zeros((s_bucket, C), np.int32)
-            positions = np.zeros((s_bucket, C), np.int32)
-            q_lens = np.zeros(s_bucket, np.int32)
-            lens = np.zeros(s_bucket, np.int32)
-            for i, s in enumerate(slots):
-                tokens[i, 0] = s.token_at(s.pos)
-                positions[i, 0] = s.pos
-                for j, d in enumerate(proposals[i]):
-                    tokens[i, 1 + j] = d
-                    positions[i, 1 + j] = s.pos + 1 + j
-                q_lens[i] = 1 + keff[i]
-                lens[i] = s.pos + 1 + keff[i]
-            ids, lg = self._run_verify_arrays(
-                tokens, positions, q_lens, tables, lens,
-                temperature=temperature, seed=seed)
+            ids, lg = call("verify", self._verify_lanes, [
+                (s.pos, [s.token_at(s.pos)] + proposals[i])
+                for i, s in enumerate(slots)])
             # ids [B, C], lg [B, C, V]
             ids = np.asarray(ids)
         out: Dict[int, Tuple[List[int], int, int]] = {}
@@ -2364,7 +2414,84 @@ class DecodeEngine:
             out[id(s)] = (committed, keff[i], accepted)
         return out
 
+    def _plan_causal(self, live: List[_Slot], grants: List[int]) -> _Round:
+        """A causal model's round: decoding slots with a draft attached
+        ride the propose/verify substep; prefill chunks (and everything
+        when speculation is off) ride the target program's chunked step,
+        each feeding its ``grant`` next tokens."""
+        rnd = _Round()
+        for s, g in zip(live, grants):
+            if self._spec_k and not s.req.ev.is_set() \
+                    and s.pos >= len(s.req.prompt) and s.req.mask is None:
+                rnd.spec.append(s)
+                continue
+            rnd.row_of[id(s)] = len(rnd.rows)
+            rnd.rows.append(s)
+            rnd.feeds.append((s.pos, s.tokens_at(s.pos, g)))
+            if s.pos < len(s.req.prompt):
+                rnd.prefill_toks += g
+            rnd.reads |= s.pos + g >= len(s.req.prompt)
+        return rnd
+
+    def _open_block(self, s: _Slot):
+        """Open the block at ``[s.pos, s.pos + B)``: the tokens that are
+        known there (the ``P mod B`` prompt tokens left over by the
+        prefill of whole blocks, in the first generated block) stay
+        unmasked, every other lane is masked."""
+        known = len(s.req.prompt) + len(s.req.produced)
+        lanes = range(s.pos, s.pos + self._block)
+        s.masked = [p >= known for p in lanes]
+        s.block = [self.spec.mask_token_id if m else s.token_at(p)
+                   for p, m in zip(lanes, s.masked)]
+
+    def _plan_blocks(self, live: List[_Slot], grants: List[int]) -> _Round:
+        """The round of a model that generates by diffusion over blocks
+        (ISSUE 30). Slot by slot the ONE jitted call carries a prefill
+        chunk of whole blocks, a DENOISE pass (the block with the mask
+        id at its masked lanes; the program's x0 at the ``B /
+        denoise_steps`` most confident masked lanes is kept) or, once no
+        lane is masked, the COMMIT pass (the B real tokens, whose K/V
+        later blocks read), B lanes each. Every pass writes its lanes'
+        K/V (write-before-attend; the commit pass's write is the one
+        that stands)."""
+        bl = self._block
+        rnd = _Round()
+        rnd.rows = live
+        rnd.row_of = {id(s): i for i, s in enumerate(live)}
+        rows = _bucket_for(self._slot_ladder, len(live))
+        masked = np.zeros((rows, bl), bool)
+        n_unmask = np.zeros(rows, np.int32)
+        for i, (s, g) in enumerate(zip(live, grants)):
+            if s.pos < len(s.req.prompt) // bl * bl:
+                kind, fed = "prefill", s.req.prompt[s.pos:s.pos + g]
+                rnd.prefill_toks += g
+            else:
+                if s.block is None:
+                    self._open_block(s)
+                kind, fed = "denoise" if any(s.masked) else "commit", s.block
+                masked[i] = s.masked
+                if kind == "denoise":
+                    n_unmask[i] = bl // s.req.denoise_steps
+            rnd.kinds.append(kind)
+            rnd.feeds.append((s.pos, fed))
+        n_kind = {k + "_slots": rnd.kinds.count(k)
+                  for k in ("prefill", "denoise", "commit")}
+        rnd.n["passes"] = n_kind["denoise_slots"] + n_kind["commit_slots"]
+        rnd.n["assignments"] = (sum(grants)
+                                * self.spec.moe_assignments_per_token)
+        # a step of prefill chunks alone is not waited for
+        rnd.reads = rnd.n["passes"] > 0
+        rnd.call_kw = {"masked": masked, "n_unmask": n_unmask}
+        rnd.call_args = dict(n_kind, moe_assignments=rnd.n["assignments"])
+        return rnd
+
     def _step(self, live: List[_Slot]):
+        """ONE decoding round, whatever the model: prepare -> plan (per
+        slot: which pass, what it feeds, whether anybody reads its
+        choice) -> build -> device call (-> the propose/verify substep
+        where a draft is attached) -> answer (per slot, by the pass it
+        ran) -> retire -> notify. A causal slot and a block slot differ
+        in the plan and the answer; the rest is written here, once."""
         # named chaos seam for the SCHEDULER cadence: a
         # `delay@serving.decode.step:*=0.004` plan simulates a slow
         # decoder (long-context model, contended chip) so streaming/
@@ -2378,243 +2505,288 @@ class DecodeEngine:
             live, grants = self._prepare(live)
         if not live:
             return
-        if self._block > 1:
-            return self._step_blocks(live, grants)
-        # split the round: decoding slots with a draft attached ride
-        # the propose/verify path; prefill chunks (and everything when
-        # speculation is off) ride the PR 9 chunked step unchanged
-        spec_rows = [i for i, s in enumerate(live)
-                     if self._spec_k and not s.req.ev.is_set()
-                     and s.pos >= len(s.req.prompt)
-                     and s.req.mask is None]
-        spec_set = set(spec_rows)
-        plain_rows = [i for i in range(len(live)) if i not in spec_set]
-        w_need = max(s.pages_held for s in live)
-        w_bucket = _bucket_for(self._width_ladder, w_need)
-        prefill_toks = sum(grants[i] for i in plain_rows
-                           if live[i].pos < len(live[i].req.prompt))
+        rnd = (self._plan_blocks if self._block > 1
+               else self._plan_causal)(live, grants)
+        w_bucket = _bucket_for(self._width_ladder,
+                               max(s.pages_held for s in live))
         t0 = time.perf_counter()
-        logits = ids = None
-        plain_row_of: Dict[int, int] = {}
-        spec_out: Dict[int, Tuple[List[int], int, int]] = {}
         # one decode step joins the OLDEST live request's trace (a span
         # has one parent); per-slot request spans live in the server
         with _tracing.adopt(live[0].req.trace_ctx), \
                 _tracing.span("serving.decode.step", model=self.name,
                               version=self.version, width=w_bucket,
-                              prefill_tokens=prefill_toks,
-                              spec_slots=len(spec_rows),
-                              live=len(live)):
-            if plain_rows:
-                ps_slots = [live[i] for i in plain_rows]
-                ps_grants = [grants[i] for i in plain_rows]
-                s_bucket = _bucket_for(self._slot_ladder, len(ps_slots))
+                              prefill_tokens=rnd.prefill_toks,
+                              spec_slots=len(rnd.spec), live=len(live)):
+            if rnd.rows:
+                s_bucket = _bucket_for(self._slot_ladder, len(rnd.rows))
                 # pure-decode steps (and 1-token prefill tails) ride
-                # the C=1 shapes — exactly the PR 6 step; only steps
-                # carrying a real chunk pay the chunk-wide compute
-                c_bucket = _bucket_for(self._chunk_ladder,
-                                       max(max(ps_grants), 1))
+                # the C=1 shapes, a block model's passes C=block_length;
+                # only steps carrying a real chunk pay the chunk-wide
+                # compute
+                c_bucket = _bucket_for(
+                    self._chunk_ladder,
+                    max(len(fed) for _start, fed in rnd.feeds))
                 with _tracing.span("serving.decode.build"):
-                    tokens = np.zeros((s_bucket, c_bucket), np.int32)
-                    positions = np.zeros((s_bucket, c_bucket), np.int32)
-                    q_lens = np.zeros(s_bucket, np.int32)
-                    lens = np.zeros(s_bucket, np.int32)
+                    arrays = self._build_arrays(rnd.rows, rnd.feeds,
+                                                s_bucket, c_bucket, w_bucket)
                     # a chunk that ends inside its prompt chooses a
                     # token too: garbage nobody reads
-                    temperature, seed = self._slot_sampling(ps_slots,
+                    temperature, seed = self._slot_sampling(rnd.rows,
                                                             s_bucket)
-                    chooses = False
-                    for i, (s, g) in enumerate(zip(ps_slots, ps_grants)):
-                        plain_row_of[id(s)] = i
-                        chooses |= s.pos + g >= len(s.req.prompt)
-                        for j in range(g):
-                            tokens[i, j] = s.token_at(s.pos + j)
-                            positions[i, j] = s.pos + j
-                        q_lens[i] = g
-                        # keys INCLUDING this chunk; within it, query j
-                        # attends only keys up to its own position
-                        lens[i] = s.pos + g
-                        self._check_reservation(s, int(lens[i]))
-                    tables = self.cache.table_array(
-                        [s.req.seq_id for s in ps_slots], w_bucket,
-                        rows=s_bucket)
                 # dispatch of the jitted step to the chosen ids on the
                 # host; the logits stay on the device
                 with _tracing.span("serving.decode.device_call") as sp:
                     self._note_call(sp, s_bucket, c_bucket, w_bucket,
-                                    q_lens, lens)
+                                    arrays[2], arrays[4], rnd.call_args)
                     # the draw's position is lens, each slot's new
                     # token's absolute index in its sequence: the (seed,
                     # position) pair that makes sampling independent of
                     # batch composition AND chunking
-                    ids, logits = self._run_step_arrays(
-                        tokens, positions, q_lens, tables, lens,
-                        temperature=temperature, seed=seed)
-                    if chooses:
-                        ids.copy_to_host_async()
+                    out, rnd.logits = self._run_step_arrays(
+                        *arrays, temperature=temperature, seed=seed,
+                        **rnd.call_kw)
+                    if self._block == 1:
+                        out = {"ids": out}
+                    if rnd.reads:
+                        for name in out:
+                            out[name].copy_to_host_async()
                     if self._spec_k:
                         # the draft shadows every prefill chunk so its
                         # mirrored pool tracks the committed sequence
                         # (its choice is discarded; its watermark
                         # advances in the answer phase with pos)
-                        self._run_draft_arrays(tokens, positions, q_lens,
-                                               tables, lens)
-                    ids = self._await_ids(ids, chooses)
-            if spec_rows:
-                spec_out = self._spec_substep(
-                    [live[i] for i in spec_rows], w_bucket)
+                        self._run("draft", *arrays)
+                    if self._await_ids(out["ids"], rnd.reads) is not None:
+                        rnd.out = {k: np.asarray(a) for k, a in out.items()}
+                        if "expert_counts" in rnd.out:
+                            sp.set_arg("moe_experts_touched", int(
+                                (rnd.out["expert_counts"] > 0).sum()))
+                    # the device's copies of what was fetched go HERE,
+                    # inside the call's stretch, not when this frame
+                    # does: letting them go after the answer phase cost
+                    # 0.6 ms between a round's wake-up and the next
+                    # round's admit (PERF.md section 6, PR 32)
+                    del out
+            if rnd.spec:
+                rnd.spec_out = self._spec_substep(rnd.spec, w_bucket)
         step_s = time.perf_counter() - t0
-        self._observe_step(step_s, len(live), prefill_toks)
+        self._observe_step(step_s, len(live), rnd.prefill_toks)
+        n = rnd.n
+        if n["assignments"]:
+            _m_moe_assignments.inc(n["assignments"])
         now = time.monotonic()
         done: List[_Slot] = []
+        notes: Dict[int, int] = {}
         # the whole answer phase holds _cond: stop(drain=False) fails
         # requests under _cond, so check-ev-then-answer must be atomic
-        # with it or the two sides can each answer the same request
-        notes: Dict[int, int] = {}
-        produced_any = False
-        n_proposed = n_accepted = 0
-        n_device = n_host = 0
-        sample_s = 0.0
-        # the span opens before the condition is taken: the wait for it
+        # with it or the two sides can each answer the same request.
+        # The span opens before the condition is taken: the wait for it
         # (clients reading their streams hold it) is the phase's time
         with _tracing.span("serving.decode.answer"), self._cond:
             self._n_steps += 1
-            for i, s in enumerate(live):
+            for s, g in zip(live, grants):
                 if s.req.ev.is_set():
                     # already answered — stop(drain=False) raced this
-                    # step and failed the request; don't double-answer
-                    # or count a completion/token for it
+                    # step and failed the request (or it was canceled);
+                    # don't double-answer or count a completion/token
                     done.append(s)
                     continue
                 s.steps += 1
-                finished = False
-                if id(s) in spec_out:
-                    committed, ke, acc = spec_out[id(s)]
-                    pos_old = s.pos
-                    s.req.spec_proposed += ke
-                    s.req.spec_accepted += acc
-                    n_proposed += ke
-                    n_accepted += acc
-                    on_host = self._draws_on_host(s.req)
-                    for tok in committed:
-                        s.pos += 1
-                        s.req.produced.append(tok)
-                        produced_any = True
-                        _m_tokens.inc()
-                        n_host += on_host
-                        n_device += not on_host
-                        if s.first_token_steps is None:
-                            s.first_token_steps = s.steps
-                            _m_first_token_steps.observe(s.steps)
-                        if (len(s.req.produced) >= s.req.max_new
-                                or (self.spec.eos_id is not None
-                                    and tok == self.spec.eos_id)):
-                            # tokens past an accepted eos are
-                            # discarded: the committed walk ends here
-                            finished = True
-                            break
-                    if ke > 0 and not finished:
-                        # draft validity watermark: the draft wrote
-                        # through pos_old+ke-1 and tokens are committed
-                        # through pos_old+acc — a fully-accepted round
-                        # leaves it one token behind (it never fed its
-                        # own last proposal), anything else re-syncs
-                        s.dpos = pos_old + min(ke - 1, acc) + 1
-                    if not finished and self._reservation == "demand":
-                        # ROLLBACK (ISSUE 14): any page grown for this
-                        # verify chunk that now holds ONLY rejected
-                        # positions goes straight back to the pool;
-                        # coverage for the pending token's next write
-                        # (pos itself) is kept so acceptance never
-                        # thrashes grow/shrink. note_tokens_many below
-                        # records the rolled-back pos — the "un-note".
-                        need = self.cache.allocator.pages_for_tokens(
-                            s.pos + 1)
-                        if s.pages_held > need:
-                            s.pages_held -= self.cache.allocator.shrink(
-                                s.req.seq_id, s.pages_held - need)
+                if self._block > 1:
+                    finished = self._answer_block(s, g, rnd)
+                elif id(s) in rnd.spec_out:
+                    finished = self._answer_spec(s, rnd)
                 else:
-                    g = grants[i]    # >= 1: every live slot progresses
-                    s.pos += g
-                    if self._prefix_on and not s.req.published and \
-                            s.pos >= len(s.req.prompt):
-                        # prompt K/V fully on-device as of THIS step:
-                        # publish the prompt pages into the prefix
-                        # index (metadata only; from here they are
-                        # immutable — this sequence only ever writes
-                        # PAST them, and they outlive its free() as
-                        # the shared cache)
-                        self.cache.allocator.publish(s.req.seq_id,
-                                                     s.req.prompt)
-                        s.req.published = True
-                    if self._spec_k:
-                        # the draft shadowed this prefill chunk lane
-                        # for lane — its watermark advances in lockstep
-                        s.dpos = s.pos
-                    tok = None
-                    mask_done = False
-                    if s.pos >= len(s.req.prompt):
-                        # row is the slot's newest lane (the step
-                        # unembeds only lane q_len-1): prompt token P-1
-                        # when the chunk just finished prefill, else
-                        # the decode token; ids[row] is the program's
-                        # own choice there, greedy or drawn by (seed,
-                        # s.pos)
-                        row = plain_row_of[id(s)]
-                        tok = int(ids[row])
-                        topk = (s.req.want_topk
-                                and s.req.first_topk is None)
-                        if topk or self._draws_on_host(s.req):
-                            # the host route: this slot's row, fetched
-                            # alone, then today's numpy choice
-                            t_sample = time.perf_counter()
-                            with _tracing.span("serving.decode.sample"):
-                                tok, mask_done = self._sample(
-                                    s.req, self._fetch_row(logits, row),
-                                    tok, s.pos, topk)
-                            sample_s += time.perf_counter() - t_sample
-                            n_host += 1
-                        else:
-                            n_device += 1
-                        s.req.produced.append(tok)
-                        produced_any = True
-                        _m_tokens.inc()
-                        if s.first_token_steps is None:
-                            s.first_token_steps = s.steps
-                            _m_first_token_steps.observe(s.steps)
-                    finished = (len(s.req.produced) >= s.req.max_new
-                                or mask_done
-                                or (tok is not None
-                                    and self.spec.eos_id is not None
-                                    and tok == self.spec.eos_id))
+                    finished = self._answer_plain(s, g, rnd)
                 self._retire_locked(s, finished, now, done, notes)
-            if n_proposed:
-                _m_spec_proposed.inc(n_proposed)
-                _m_spec_accepted.inc(n_accepted)
-                _m_spec_rejected.inc(n_proposed - n_accepted)
-            if n_device:
-                _m_device_choices.inc(n_device)
-            if n_host:
-                _m_host_choices.inc(n_host)
-            if n_device + n_host:
-                _m_device_choice_pct.observe(
-                    100.0 * n_device / (n_device + n_host))
-            self._close_round_locked(done, produced_any, notes, step_s,
-                                     sample_s)
+            chosen = n["device"] + n["host"]
+            if n["proposed"]:
+                _m_spec_proposed.inc(n["proposed"])
+                _m_spec_accepted.inc(n["accepted"])
+                _m_spec_rejected.inc(n["proposed"] - n["accepted"])
+            if n["passes"]:
+                _m_block_passes.inc(n["passes"])
+                _m_block_tokens_per_pass.observe(n["device"] / n["passes"])
+                if n["device"]:
+                    _m_block_committed.inc(n["device"])
+            if n["dropped"]:
+                _m_block_dropped.inc(n["dropped"])
+            if n["device"]:
+                _m_device_choices.inc(n["device"])
+            if n["host"]:
+                _m_host_choices.inc(n["host"])
+            if chosen:
+                _m_tokens.inc(chosen)
+                _m_device_choice_pct.observe(100.0 * n["device"] / chosen)
+            counts = (rnd.out or {}).get("expert_counts")
+            if counts is not None and counts.sum():
+                _m_moe_load.observe(float(
+                    (counts.max(axis=1) / np.maximum(
+                        counts.mean(axis=1), 1e-9)).max()))
+            # ONE wake-up delivers a round's tokens, in order
+            self._close_round_locked(done, chosen > 0, notes)
+            # the round's host clock, still inside the span and the
+            # condition: once that is released the woken clients run,
+            # and what the scheduler then waits belongs to the next
+            # round's admit
+            t_end = time.perf_counter()
+            _m_sample_ms.observe(rnd.sample_s * 1e3)
+            _m_sched_ms.observe(
+                (t_end - self._t_round - step_s - rnd.sample_s) * 1e3)
+            self._t_round = t_end
+
+    def _emit_locked(self, s: _Slot, tok: int, rnd: _Round,
+                     route: str) -> bool:
+        """One generated token of a slot's answer, chosen on ``route``
+        ("device" / "host"): append it, tally it, note the slot's first.
+        True = the sequence ends with it (``max_new`` or ``eos_id``)."""
+        s.req.produced.append(tok)
+        rnd.n[route] += 1
+        if s.first_token_steps is None:
+            s.first_token_steps = s.steps
+            _m_first_token_steps.observe(s.steps)
+        return (len(s.req.produced) >= s.req.max_new
+                or tok == self.spec.eos_id)
+
+    def _answer_plain(self, s: _Slot, g: int, rnd: _Round) -> bool:
+        """The answer of a causal slot that rode the target program's
+        call: its chunk of ``g`` (>= 1: every live slot progresses) is
+        written, and once the prompt is through its newest lane's token
+        is the program's own choice or the host route's."""
+        s.pos += g
+        if self._prefix_on and not s.req.published and \
+                s.pos >= len(s.req.prompt):
+            # prompt K/V fully on-device as of THIS step: publish the
+            # prompt pages into the prefix index (metadata only; from
+            # here they are immutable — this sequence only ever writes
+            # PAST them, and they outlive its free() as the shared
+            # cache)
+            self.cache.allocator.publish(s.req.seq_id, s.req.prompt)
+            s.req.published = True
+        if self._spec_k:
+            # the draft shadowed this prefill chunk lane for lane — its
+            # watermark advances in lockstep
+            s.dpos = s.pos
+        if s.pos < len(s.req.prompt):
+            return False
+        # row is the slot's newest lane (the step unembeds only lane
+        # q_len-1): prompt token P-1 when the chunk just finished
+        # prefill, else the decode token; ids[row] is the program's own
+        # choice there, greedy or drawn by (seed, s.pos)
+        row = rnd.row_of[id(s)]
+        tok, mask_done, route = int(rnd.out["ids"][row]), False, "device"
+        topk = s.req.want_topk and s.req.first_topk is None
+        if topk or self._draws_on_host(s.req):
+            # the host route: this slot's row, fetched alone, then
+            # today's numpy choice
+            with rnd.sampling():
+                tok, mask_done = self._sample(
+                    s.req, self._fetch_row(rnd.logits, row), tok, s.pos,
+                    topk)
+            route = "host"
+        return self._emit_locked(s, tok, rnd, route) or mask_done
+
+    def _answer_spec(self, s: _Slot, rnd: _Round) -> bool:
+        """The answer of a decoding slot the draft proposed for: the
+        committed walk of the verify (``_spec_substep``), the draft's
+        watermark, and the rollback of what was grown for rejected
+        positions."""
+        committed, ke, acc = rnd.spec_out[id(s)]
+        pos_old = s.pos
+        s.req.spec_proposed += ke
+        s.req.spec_accepted += acc
+        rnd.n["proposed"] += ke
+        rnd.n["accepted"] += acc
+        route = "host" if self._draws_on_host(s.req) else "device"
+        finished = False
+        for tok in committed:
+            s.pos += 1
+            if self._emit_locked(s, tok, rnd, route):
+                # tokens past an accepted eos are discarded: the
+                # committed walk ends here
+                finished = True
+                break
+        if ke > 0 and not finished:
+            # draft validity watermark: the draft wrote through
+            # pos_old+ke-1 and tokens are committed through pos_old+acc
+            # — a fully-accepted round leaves it one token behind (it
+            # never fed its own last proposal), anything else re-syncs
+            s.dpos = pos_old + min(ke - 1, acc) + 1
+        if not finished and self._reservation == "demand":
+            # ROLLBACK (ISSUE 14): any page grown for this verify chunk
+            # that now holds ONLY rejected positions goes straight back
+            # to the pool; coverage for the pending token's next write
+            # (pos itself) is kept so acceptance never thrashes
+            # grow/shrink. note_tokens_many at the round's close records
+            # the rolled-back pos — the "un-note".
+            need = self.cache.allocator.pages_for_tokens(s.pos + 1)
+            if s.pages_held > need:
+                s.pages_held -= self.cache.allocator.shrink(
+                    s.req.seq_id, s.pages_held - need)
+        return finished
+
+    def _answer_block(self, s: _Slot, g: int, rnd: _Round) -> bool:
+        """A block model's slot after its pass: a prefill chunk is
+        written; a denoise pass unmasks the lanes the program marked,
+        at its choices; the commit pass answers up to B tokens at once —
+        fewer at ``max_new`` or at ``eos_id``, the rest of the block is
+        dropped — and ``pos`` advances by B."""
+        i = rnd.row_of[id(s)]
+        if rnd.kinds[i] == "prefill":
+            s.pos += g
+            return False
+        if rnd.kinds[i] == "denoise":
+            req = s.req
+            ids, unmask = rnd.out["ids"][i], rnd.out["unmask"][i]
+            if req.want_topk and req.first_topk is None:
+                # the first pass's first masked lane: its order of the
+                # best tokens, from one fetched row
+                with rnd.sampling():
+                    lane = s.masked.index(True)
+                    req.first_topk = _top_order(
+                        self._fetch_row(rnd.logits, i)[lane], req.want_topk)
+            if req.passes is not None:
+                req.passes.append({
+                    "pos": int(s.pos),
+                    "input": [int(t) for t in s.block],
+                    "masked": list(s.masked),
+                    "ids": [int(t) for t in ids],
+                    "confidence": [float(c)
+                                   for c in rnd.out["confidence"][i]],
+                    "unmasked": [bool(u) for u in unmask]})
+            for j in np.flatnonzero(unmask):
+                s.block[j] = int(ids[j])
+                s.masked[j] = False
+            return False
+        # commit: the block's tokens that are not the prompt's, in
+        # order, as far as max_new and eos let
+        known = len(s.req.prompt) + len(s.req.produced)
+        fresh = s.block[max(0, known - s.pos):]
+        taken, finished = 0, False
+        for tok in fresh:
+            taken += 1
+            if self._emit_locked(s, tok, rnd, "device"):
+                finished = True
+                break
+        rnd.n["dropped"] += len(fresh) - taken
+        s.pos += self._block
+        s.block = s.masked = None
+        return finished
 
     def _note_call(self, sp, slots: int, chunk: int, width: int, q_lens,
-                   kv_lens, block: int = 1):
+                   kv_lens, more: Dict[str, int]):
         """What a step call that runs the attention records before it
         is dispatched: the share of its ``slots x width`` grid that
-        holds a live page, always, and ``_call_work``'s args on the
-        ``device_call`` span where one is live."""
+        holds a live page, always, and on the ``device_call`` span where
+        one is live ``_call_work``'s args and the round's own
+        (``more``)."""
         ps = self.cache.page_size
         _m_attn_grid_live.observe(
             100.0 * _live_pages(kv_lens, ps) / (slots * width))
         if sp.live:
-            for key, value in _call_work(slots, chunk, width, q_lens,
-                                         kv_lens, block,
-                                         page_size=ps).items():
+            for key, value in {**_call_work(slots, chunk, width, q_lens,
+                                            kv_lens, self._block,
+                                            page_size=ps), **more}.items():
                 sp.set_arg(key, value)
 
     def _observe_step(self, seconds: float, n_live: int,
@@ -2631,10 +2803,10 @@ class DecodeEngine:
         if prefill_toks:
             _m_prefill_tokens.inc(prefill_toks)
 
-    def _retire_locked(self, s: _Slot, finished: bool, now: float,
-                       done: List[_Slot], notes: Dict[int, int]):
-        """The end of one slot's answer: note how far it got, and
-        complete it or fail it on a lapsed deadline."""
+    def _retire_locked(self, s, finished: bool, now: float, done: List[Any],
+                       notes: Dict[int, int]):
+        """The end of one slot's answer, in either lane: note how far it
+        got, and complete it or fail it on a lapsed deadline."""
         notes[s.req.seq_id] = s.pos
         if finished:
             # finished beats a lapsed deadline: the result is fully
@@ -2646,205 +2818,28 @@ class DecodeEngine:
             done.append(s)
             self._fail_locked(s.req, DeadlineExceeded(
                 f"request to decoder '{self.name}' lapsed "
-                f"mid-decode after {len(s.req.produced)} tokens"))
+                + s.progress()))
 
-    def _close_round_locked(self, done: List[_Slot], produced: bool,
-                            notes: Dict[int, int], step_s: float,
-                            sample_s: float):
-        """The answer phase's last lines, still inside its span and the
-        condition: once that is released the woken clients run, and
-        what the scheduler then waits belongs to the next round's
-        admit."""
+    def _close_round_locked(self, done: List[Any], produced: bool,
+                            notes: Dict[int, int]):
+        """The last lines of a round's answers, in either lane, still
+        under the condition: the allocator's notes, the done slots out
+        of their lane, and the wake-up."""
         # one allocator-lock round-trip for the whole step; seqs freed
         # by _complete/_fail are skipped inside
         self.cache.allocator.note_tokens_many(notes)
         if done:
             self._slots = [s for s in self._slots if s not in done]
+            self._embed_slots = [s for s in self._embed_slots
+                                 if s not in done]
             self._g_live.set(len(self._slots))
+            self._g_embed.set(len(self._embed_slots))
         if done or produced:
             # wake completion waiters AND streaming readers parked in
             # stream_tokens — a token exists the moment this notify
             # lands, ceil(prompt/chunk) steps after admission, not when
             # the whole sequence finishes
             self._cond.notify_all()
-        t_end = time.perf_counter()
-        _m_sample_ms.observe(sample_s * 1e3)
-        _m_sched_ms.observe((t_end - self._t_round - step_s - sample_s)
-                            * 1e3)
-        self._t_round = t_end
-
-    def _open_block(self, s: _Slot):
-        """Open the block at ``[s.pos, s.pos + B)``: the tokens that are
-        known there (the ``P mod B`` prompt tokens left over by the
-        prefill of whole blocks, in the first generated block) stay
-        unmasked, every other lane is masked."""
-        known = len(s.req.prompt) + len(s.req.produced)
-        lanes = range(s.pos, s.pos + self._block)
-        s.masked = [p >= known for p in lanes]
-        s.block = [self.spec.mask_token_id if m else s.token_at(p)
-                   for p, m in zip(lanes, s.masked)]
-
-    def _step_blocks(self, live: List[_Slot], grants: List[int]):
-        """One round of a model that generates by diffusion over blocks
-        (ISSUE 30), after ``_prepare``. Slot by slot the ONE jitted call
-        carries a prefill chunk of whole blocks, a DENOISE pass (the
-        block with the mask id at its masked lanes; the program's x0 at
-        the ``B / denoise_steps`` most confident masked lanes is kept) or,
-        once no lane is masked, the COMMIT pass (the B real tokens,
-        whose K/V later blocks read), B lanes each. Every pass writes its
-        lanes' K/V (write-before-attend; the commit pass's write is the
-        one that stands). On commit the slot answers up to B tokens at
-        once — fewer at ``max_new`` or at ``eos_id``, the rest of the
-        block is dropped — and ``pos`` advances by B."""
-        bl = self._block
-        w_bucket = _bucket_for(self._width_ladder,
-                               max(s.pages_held for s in live))
-        s_bucket = _bucket_for(self._slot_ladder, len(live))
-        c_bucket = _bucket_for(self._chunk_ladder, max(grants))
-        kinds: List[str] = []
-        t0 = time.perf_counter()
-        with _tracing.adopt(live[0].req.trace_ctx), \
-                _tracing.span("serving.decode.step", model=self.name,
-                              version=self.version, width=w_bucket,
-                              live=len(live)):
-            with _tracing.span("serving.decode.build"):
-                tokens = np.zeros((s_bucket, c_bucket), np.int32)
-                positions = np.zeros((s_bucket, c_bucket), np.int32)
-                q_lens = np.zeros(s_bucket, np.int32)
-                lens = np.zeros(s_bucket, np.int32)
-                masked = np.zeros((s_bucket, bl), bool)
-                n_unmask = np.zeros(s_bucket, np.int32)
-                temperature, seed = self._slot_sampling(live, s_bucket)
-                for i, (s, g) in enumerate(zip(live, grants)):
-                    if s.pos < len(s.req.prompt) // bl * bl:
-                        kinds.append("prefill")
-                        tokens[i, :g] = s.req.prompt[s.pos:s.pos + g]
-                    else:
-                        if s.block is None:
-                            self._open_block(s)
-                        tokens[i, :bl] = s.block
-                        masked[i] = s.masked
-                        kinds.append("denoise" if any(s.masked)
-                                     else "commit")
-                        if kinds[-1] == "denoise":
-                            n_unmask[i] = bl // s.req.denoise_steps
-                    positions[i, :g] = np.arange(s.pos, s.pos + g)
-                    q_lens[i] = g
-                    lens[i] = s.pos + g
-                    self._check_reservation(s, int(lens[i]))
-                tables = self.cache.table_array(
-                    [s.req.seq_id for s in live], w_bucket, rows=s_bucket)
-            n_kind = {k: kinds.count(k)
-                      for k in ("prefill", "denoise", "commit")}
-            passes = n_kind["denoise"] + n_kind["commit"]
-            assignments = (int(q_lens.sum())
-                           * self.spec.moe_assignments_per_token)
-            counts = None
-            with _tracing.span("serving.decode.device_call") as sp:
-                self._note_call(sp, s_bucket, c_bucket, w_bucket, q_lens,
-                                lens, block=bl)
-                if sp.live:
-                    for kind, n in n_kind.items():
-                        sp.set_arg(kind + "_slots", n)
-                    sp.set_arg("moe_assignments", assignments)
-                out, logits = self._run_step_arrays(
-                    tokens, positions, q_lens, tables, lens,
-                    temperature=temperature, seed=seed, masked=masked,
-                    n_unmask=n_unmask)
-                if passes:
-                    for a in out.values():
-                        a.copy_to_host_async()
-                # a step of prefill chunks alone is not waited for
-                ids = self._await_ids(out["ids"], passes > 0)
-                if passes:
-                    conf = np.asarray(out["confidence"])
-                    unmask = np.asarray(out["unmask"])
-                    if "expert_counts" in out:
-                        counts = np.asarray(out["expert_counts"])
-                        sp.set_arg("moe_experts_touched",
-                                   int((counts > 0).sum()))
-        step_s = time.perf_counter() - t0
-        self._observe_step(step_s, len(live),
-                           sum(g for g, k in zip(grants, kinds)
-                               if k == "prefill"))
-        if assignments:
-            _m_moe_assignments.inc(assignments)
-        now = time.monotonic()
-        done: List[_Slot] = []
-        notes: Dict[int, int] = {}
-        n_committed = n_dropped = 0
-        sample_s = 0.0
-        with _tracing.span("serving.decode.answer"), self._cond:
-            self._n_steps += 1
-            for i, s in enumerate(live):
-                if s.req.ev.is_set():
-                    done.append(s)      # canceled or failed: see _step
-                    continue
-                s.steps += 1
-                finished = False
-                if kinds[i] == "prefill":
-                    s.pos += grants[i]
-                elif kinds[i] == "denoise":
-                    req = s.req
-                    if req.want_topk and req.first_topk is None:
-                        # the first pass's first masked lane: its order
-                        # of the best tokens, from one fetched row
-                        t_sample = time.perf_counter()
-                        with _tracing.span("serving.decode.sample"):
-                            lane = s.masked.index(True)
-                            req.first_topk = _top_order(
-                                self._fetch_row(logits, i)[lane],
-                                req.want_topk)
-                        sample_s += time.perf_counter() - t_sample
-                    if req.passes is not None:
-                        req.passes.append({
-                            "pos": int(s.pos),
-                            "input": [int(t) for t in tokens[i, :bl]],
-                            "masked": list(s.masked),
-                            "ids": [int(t) for t in ids[i]],
-                            "confidence": [float(c) for c in conf[i]],
-                            "unmasked": [bool(u) for u in unmask[i]]})
-                    for j in np.flatnonzero(unmask[i]):
-                        s.block[j] = int(ids[i, j])
-                        s.masked[j] = False
-                else:
-                    # commit: the block's tokens that are not the
-                    # prompt's, in order, as far as max_new and eos let
-                    known = len(s.req.prompt) + len(s.req.produced)
-                    fresh = s.block[max(0, known - s.pos):]
-                    taken = 0
-                    for tok in fresh:
-                        s.req.produced.append(tok)
-                        taken += 1
-                        if (len(s.req.produced) >= s.req.max_new
-                                or tok == self.spec.eos_id):
-                            finished = True
-                            break
-                    n_committed += taken
-                    n_dropped += len(fresh) - taken
-                    s.pos += bl
-                    s.block = s.masked = None
-                    if s.first_token_steps is None:
-                        s.first_token_steps = s.steps
-                        _m_first_token_steps.observe(s.steps)
-                self._retire_locked(s, finished, now, done, notes)
-            if passes:
-                _m_block_passes.inc(passes)
-                _m_block_tokens_per_pass.observe(n_committed / passes)
-            if n_committed:
-                _m_tokens.inc(n_committed)
-                _m_block_committed.inc(n_committed)
-                _m_device_choices.inc(n_committed)
-                _m_device_choice_pct.observe(100.0)
-            if n_dropped:
-                _m_block_dropped.inc(n_dropped)
-            if counts is not None and counts.sum():
-                _m_moe_load.observe(float(
-                    (counts.max(axis=1) / np.maximum(
-                        counts.mean(axis=1), 1e-9)).max()))
-            # ONE wake-up delivers a block's tokens, in order
-            self._close_round_locked(done, n_committed > 0, notes, step_s,
-                                     sample_s)
 
     def _sample(self, req: _DecodeRequest, row, chosen: int,
                 position: int, topk: bool) -> Tuple[int, bool]:
@@ -2866,10 +2861,22 @@ class DecodeEngine:
             return self._choose(row, req, position), False
         return chosen, False
 
-    def _complete(self, s: _Slot):
+    def _complete(self, s):
+        """Deliver a finished slot's result, in either lane."""
         self.cache.allocator.free(s.req.seq_id)
         _m_completions.inc()
         _m_total.observe((time.monotonic() - s.req.t_enq) * 1e3)
+        if isinstance(s, _EmbedSlot):
+            p = len(s.req.prompt)
+            s.req.result = {
+                "embedding": [float(x) for x in s.req.hidden_sum / p],
+                "logprobs": list(s.req.logprobs),
+                "prompt_len": p,
+                "version": self.version,
+                "steps": int(s.steps),
+            }
+            s.req.ev.set()
+            return
         s.req.result = {
             "tokens": list(s.req.produced),
             "prompt_len": int(len(s.req.prompt)),
@@ -2907,11 +2914,12 @@ class DecodeEngine:
     # -- the embed lane ---------------------------------------------------
     def _embed_step(self, live: List[_EmbedSlot]):
         """One chunked-prefill step for the embedding/scoring lane
-        (ISSUE 20): the same Sarathi-style token budget, page tables,
-        and compiled ladders as generation — but the all-lane + hidden
-        step form, and nothing is ever sampled: every lane feeds the
-        pooled-hidden accumulator and the per-token logprobs. Decode
-        slots are untouched by construction (separate slot list)."""
+        (ISSUE 20): the same Sarathi-style token budget, array builder,
+        run function and compiled ladders as generation — but the
+        all-lane + hidden program, and nothing is ever sampled: every
+        lane feeds the pooled-hidden accumulator and the per-token
+        logprobs. Decode slots are untouched by construction (separate
+        slot list)."""
         # named chaos seam for the embed cadence (mirrors
         # serving.decode.step); the workload layer's per-kind site
         # (serving.workload.embed) lives at the dispatch boundary
@@ -2923,36 +2931,21 @@ class DecodeEngine:
             g = max(1, min(remaining, budget))
             budget = max(0, budget - g)
             grants.append(g)
-        s_bucket = _bucket_for(self._slot_ladder, len(live))
-        c_bucket = _bucket_for(self._chunk_ladder, max(grants))
-        w_need = max(s.pages_held for s in live)
-        w_bucket = _bucket_for(self._width_ladder, w_need)
-        tokens = np.zeros((s_bucket, c_bucket), np.int32)
-        positions = np.zeros((s_bucket, c_bucket), np.int32)
-        q_lens = np.zeros(s_bucket, np.int32)
-        lens = np.zeros(s_bucket, np.int32)
-        with self._cond:
-            for i, (s, g) in enumerate(zip(live, grants)):
-                if s.req.ev.is_set():
-                    continue  # canceled: pages freed, all-garbage row
-                for j in range(g):
-                    tokens[i, j] = int(s.req.prompt[s.pos + j])
-                    positions[i, j] = s.pos + j
-                q_lens[i] = g
-                lens[i] = s.pos + g
-                if int(lens[i]) > s.pages_held * self.cache.page_size:
-                    raise ServingError(
-                        f"embed chunk grant escaped seq "
-                        f"{s.req.seq_id}'s page reservation")
-        tables = self.cache.table_array(
-            [s.req.seq_id for s in live], w_bucket, rows=s_bucket)
+        w_bucket = _bucket_for(self._width_ladder,
+                               max(s.pages_held for s in live))
+        # canceled: pages freed, a dead row
+        arrays = self._build_arrays(
+            live, [None if s.req.ev.is_set()
+                   else (s.pos, s.req.prompt[s.pos:s.pos + g])
+                   for s, g in zip(live, grants)],
+            _bucket_for(self._slot_ladder, len(live)),
+            _bucket_for(self._chunk_ladder, max(grants)), w_bucket)
         t0 = time.perf_counter()
         with _tracing.adopt(live[0].req.trace_ctx), \
                 _tracing.span("serving.decode.embed", model=self.name,
                               version=self.version, width=w_bucket,
                               live=len(live)):
-            logits, hidden = self._run_embed_arrays(
-                tokens, positions, q_lens, tables, lens)
+            logits, hidden = self._run("embed", *arrays)
         logits_np = np.asarray(logits)  # [B, C, vocab]
         hidden_np = np.asarray(hidden)  # [B, C, d_model]
         _m_step_ms.observe((time.perf_counter() - t0) * 1e3)
@@ -2961,12 +2954,11 @@ class DecodeEngine:
         notes: Dict[int, int] = {}
         with self._cond:
             self._n_steps += 1
-            for i, s in enumerate(live):
+            for i, (s, g) in enumerate(zip(live, grants)):
                 if s.req.ev.is_set():
                     done.append(s)
                     continue
                 s.steps += 1
-                g = grants[i]
                 prompt = s.req.prompt
                 s.req.hidden_sum += np.asarray(
                     hidden_np[i, :g], np.float64).sum(axis=0)
@@ -2984,33 +2976,6 @@ class DecodeEngine:
                             float(lg[j, int(prompt[nxt])] - lse[j]))
                 s.pos += g
                 _m_embed_tokens.inc(g)
-                notes[s.req.seq_id] = s.pos
-                if s.pos >= len(prompt):
-                    done.append(s)
-                    self._complete_embed(s)
-                elif s.req.deadline is not None and now > s.req.deadline:
-                    _m_deadline_miss.inc()
-                    done.append(s)
-                    self._fail_locked(s.req, DeadlineExceeded(
-                        f"embed request to decoder '{self.name}' "
-                        f"lapsed mid-prefill at {s.pos} tokens"))
-            self.cache.allocator.note_tokens_many(notes)
-            if done:
-                self._embed_slots = [s for s in self._embed_slots
-                                     if s not in done]
-                self._g_embed.set(len(self._embed_slots))
-                self._cond.notify_all()
-
-    def _complete_embed(self, s: _EmbedSlot):
-        self.cache.allocator.free(s.req.seq_id)
-        _m_completions.inc()
-        _m_total.observe((time.monotonic() - s.req.t_enq) * 1e3)
-        p = len(s.req.prompt)
-        s.req.result = {
-            "embedding": [float(x) for x in s.req.hidden_sum / p],
-            "logprobs": list(s.req.logprobs),
-            "prompt_len": p,
-            "version": self.version,
-            "steps": int(s.steps),
-        }
-        s.req.ev.set()
+                self._retire_locked(s, s.pos >= len(prompt), now, done,
+                                    notes)
+            self._close_round_locked(done, False, notes)

@@ -1104,8 +1104,9 @@ def test_step_failure_with_donated_pools_retires_engine():
         def _boom(*a, **k):
             raise RuntimeError("injected step failure")
         eng._donate = True      # CPU tests never donate; force the path
-        with eng._step_mu:      # _step_fn is _step_mu-guarded state
-            eng._step_fn = _boom
+        with eng._step_mu:      # the program table is _step_mu-guarded
+            eng._programs["target"] = \
+                eng._programs["target"]._replace(fn=_boom)
         req = eng.submit([1, 2], max_new_tokens=4)
         assert req.ev.wait(60)
         assert isinstance(req.error, ServingError)
