@@ -14,6 +14,15 @@ where explicit VMEM blocking beats XLA's default schedule:
 Each has a jnp reference backward (custom_vjp), and `interpret=True` runs
 on CPU for tests. Enable via FLAGS['use_pallas_kernels'] (auto-picked by
 emitters when the backend is TPU).
+
+Two more serve the decode engine, forward only, each beside the pure-jax
+implementation it is routed against by the same flag:
+
+  - paged_attention: decode attention read through per-sequence page
+    tables; its work follows kv_lens and q_lens
+  - moe_gmm: the grouped expert products of a sparse-expert layer, walking
+    only the (row tile, expert) pairs that hold a live row and streaming
+    each touched expert's weights once
 """
 from .conv_bn_relu import fold_bn, fused_conv_bn_relu  # noqa: F401
 from .flash_attention import flash_attention  # noqa: F401

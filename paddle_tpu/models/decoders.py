@@ -14,6 +14,9 @@ sharding rules ask, instead of each knowing one decoder's tensor names:
                       and of the parameter tree
   ``moe_assignments_per_token``   token-to-expert assignments a live
                       token makes in one pass (0 for a dense model)
+  ``expert_width``    an expert's inner width, of a model that has
+                      experts: with ``d_model`` what routes its grouped
+                      products (``pallas_kernels/moe_gmm.moe_route``)
   ``to_dict()`` / ``from_dict()``   the checkpoint meta / wire form
   ``tensors()``       ``{flat name: shape}`` of the parameter tree
   ``seeded_arrays()`` the deterministic tree as host arrays, in the

@@ -16,9 +16,10 @@ between layers and the K/V pools in bfloat16; every product accumulates
 in float32; RMSNorm statistics, rotary angles, the router's logits and
 softmax, and the final logits in float32. K and V go to the pool AFTER
 QK-norm and rotary. The experts are DROPLESS: the ``T * k`` assignments
-are sorted by expert, one grouped product (``jax.lax.ragged_dot``) runs
-over the ragged groups, and the results are scatter-added back with the
-renormalised weights. There is no capacity, no dropped token and no
+are sorted by expert, grouped products run over the ragged groups
+(``pallas_kernels/moe_gmm.py``: the Pallas kernel on a TPU,
+``jax.lax.ragged_dot`` elsewhere), and the results are scatter-added back
+with the renormalised weights. There is no capacity, no dropped token and no
 ``[T, E, C]`` one-hot (``parallel/moe.py`` stays what the Fluid training
 path uses).
 """
@@ -283,15 +284,20 @@ def _dot(a, w):
     return jnp.dot(a, w, preferred_element_type=jnp.float32)
 
 
-def moe_layer(h, lp, valid, spec: SdarMoeSpec):
+def moe_layer(h, lp, valid, spec: SdarMoeSpec,
+              impl: Optional[str] = None):
     """The dropless expert layer over ``h`` [T, d] (the normed hidden
     states, in the weights' dtype): ``(out [T, d] float32, counts [E]
     int32)``. ``valid`` [T] marks the live lanes; a dead lane is routed
     nowhere, weighs nothing and is not counted. ``counts`` sums to
     ``valid.sum() * experts_per_token`` and each live token's weights sum
-    to 1 (``norm_topk_prob``)."""
+    to 1 (``norm_topk_prob``). ``impl`` is the step's ``attention_impl``:
+    ``"reference"`` names XLA's grouped product as it names the attention's
+    reference (``moe_gmm.moe_route``)."""
     import jax
     import jax.numpy as jnp
+
+    from ..fluid.ops.pallas_kernels.moe_gmm import grouped_dot
 
     t = h.shape[0]
     e, k = spec.n_experts, spec.experts_per_token
@@ -309,17 +315,14 @@ def moe_layer(h, lp, valid, spec: SdarMoeSpec):
         routed = idx[order] < e
         xs = h[token]
     with jax.named_scope("decoder.moe.experts"):
-        # ONE grouped product a projection over the ragged groups: rows
-        # [sum(counts[:j]), sum(counts[:j+1])) meet expert j's matrix
-        def grouped(x, mats):
-            return jax.lax.ragged_dot(
-                x, mats, counts, preferred_element_type=jnp.float32)
-
-        act = (jax.nn.silu(grouped(xs, lp["gate"]))
-               * grouped(xs, lp["up"])).astype(h.dtype)
-        y = grouped(act, lp["down"])                         # [T*k, d]
+        # grouped products over the ragged groups: rows [sum(counts[:j]),
+        # sum(counts[:j+1])) meet expert j's matrices. The gated product
+        # silu(gate) * up is rounded to the weights' dtype before down
+        act = grouped_dot(xs, lp["up"], counts, gate=lp["gate"], impl=impl)
+        y = grouped_dot(act, lp["down"], counts, impl=impl)  # [T*k, d] f32
     with jax.named_scope("decoder.moe.combine"):
-        # rows behind the last group belong to no expert: exact zeros
+        # rows behind the last group belong to no expert and hold whatever
+        # the product left there: exact zeros from here on
         y = jnp.where(routed[:, None], y * w[order][:, None], 0.0)
         out = jnp.zeros((t, h.shape[1]), jnp.float32).at[token].add(y)
     return out, counts
@@ -340,7 +343,11 @@ def sdar_moe_step(params, spec: SdarMoeSpec, tokens, positions, q_lens,
     slot's FIRST B lanes only (a block pass's; a prefill chunk's are
     garbage nobody reads, as under a causal model), lane ``i`` predicting
     the token AT ``i`` (no shift). Dead lanes write ``garbage_page``, the
-    page the pools' owner keeps for them (the engine passes its cache's)."""
+    page the pools' owner keeps for them (the engine passes its cache's).
+    ``attention_impl`` routes BOTH Mosaic kernels of the step:
+    ``"reference"`` (the engine under a mesh) names the attention's
+    reference and the experts' ``ragged_dot``; ``None`` lets the flags,
+    the backend and the widths decide."""
     import jax
     import jax.numpy as jnp
 
@@ -386,7 +393,7 @@ def sdar_moe_step(params, spec: SdarMoeSpec, tokens, positions, q_lens,
                  ).astype(act)
         h2 = _rms(x, lp["ln2"], spec.rms_eps).astype(act)
         out, n = moe_layer(h2.reshape(b * c, -1), lp, valid.reshape(-1),
-                           spec)
+                           spec, impl=attention_impl)
         counts.append(n)
         x = (x.astype(jnp.float32) + out.reshape(b, c, -1)).astype(act)
     with jax.named_scope("decoder.head"):

@@ -748,6 +748,15 @@ class DecodeEngine:
         # the flags route. stats()/load_report show the result.
         self._attention_impl = ("reference" if self._mesh is not None
                                 else None)
+        # a model with experts takes the same word for its grouped
+        # products: the Pallas moe_gmm single-chip on a TPU, XLA's
+        # ragged_dot under a mesh, off a TPU and at widths the kernel
+        # does not tile
+        from ..fluid.ops.pallas_kernels.moe_gmm import moe_route
+
+        self._experts_route = (
+            moe_route(spec.d_model, spec.expert_width, self._attention_impl)
+            if spec.moe_assignments_per_token else None)
         # slots="auto" resolves through the tuner exactly like the
         # one-shot engine's buckets="auto": a derived ladder from the
         # observed slot-demand histogram (or the cached one), else the
@@ -1646,6 +1655,7 @@ class DecodeEngine:
                 "mesh": (dict(self._mesh_spec.axes)
                          if self._mesh_spec is not None else None),
                 "attention_route": self._attention_routes,
+                "experts_route": self._experts_route,
                 "draft": (self._draft_spec.to_dict()
                           if self._draft_spec is not None else None),
                 "prefix_cache": self._prefix_on,

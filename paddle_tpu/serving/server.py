@@ -644,6 +644,8 @@ class ServingServer:
                 # steps take — a mesh-spanning engine names the
                 # reference, and that must be visible, not inferred
                 entry["attention_route"] = st["attention_route"]
+                if st.get("experts_route"):
+                    entry["experts_route"] = st["experts_route"]
                 # prefix-cache warmth (ISSUE 13): the MRU depth-1
                 # chain digests let a FleetRouter recognize a replica
                 # whose cache already covers a request's prefix —

@@ -5,6 +5,7 @@ experts top-2 of width 32, vocab 128, B 4), seeded weights, against the ONE
 plain reference the benchmark also uses, ``perf/references/
 sdar-30b-a3b-chat.py``, loaded by path.
 """
+import contextlib
 import importlib.util
 import os
 
@@ -14,7 +15,9 @@ import numpy as np
 import pytest
 
 from paddle_tpu.checkpoint.decoder import expected_decoder_tensors
+from paddle_tpu.fluid.flags import FLAGS, set_flags
 from paddle_tpu.fluid.ops.pallas_kernels import paged_attention as pa
+from paddle_tpu.fluid.ops.pallas_kernels.moe_gmm import moe_route
 from paddle_tpu.models.decoders import (DecoderSpec, spec_from_dict,
                                         validate_draft_spec)
 from paddle_tpu.models.sdar_moe import (TINY_CONFIG, SdarMoeSpec, moe_layer,
@@ -24,7 +27,11 @@ from paddle_tpu.serving.decode import DecodeEngine
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REFERENCE = os.path.join(ROOT, "perf", "references", "sdar-30b-a3b-chat.py")
 MASK_ID = 127
-CFG = dict(TINY_CONFIG, assumed={"block_length": 4, "mask_token_id": MASK_ID})
+ASSUMED = {"block_length": 4, "mask_token_id": MASK_ID}
+CFG = dict(TINY_CONFIG, assumed=ASSUMED)
+# the tiny preset at widths the experts' kernel tiles (multiples of 128)
+WIDE_CONFIG = dict(TINY_CONFIG, hidden_size=128, head_dim=32,
+                   moe_intermediate_size=128)
 
 
 def load_reference():
@@ -39,10 +46,31 @@ def ref():
     return load_reference()
 
 
-def tiny_spec(dtype="float32", seed=3, **kw):
-    return SdarMoeSpec.from_config(TINY_CONFIG, block_length=4,
+def tiny_spec(dtype="float32", seed=3, config=TINY_CONFIG, **kw):
+    return SdarMoeSpec.from_config(config, block_length=4,
                                    mask_token_id=MASK_ID, dtype=dtype,
                                    seed=seed, **kw)
+
+
+@contextlib.contextmanager
+def pallas_forced():
+    """``use_pallas_kernels`` forced on: off a TPU the kernels then run in
+    interpret mode wherever their routes accept the shapes."""
+    was = FLAGS["use_pallas_kernels"]
+    set_flags({"use_pallas_kernels": True})
+    try:
+        yield
+    finally:
+        set_flags({"use_pallas_kernels": was})
+
+
+def experts_case(impl):
+    """``(config, context)`` of one implementation of the grouped products:
+    ``ragged_dot`` is the tiny preset as the CPU routes it, ``gmm_interpret``
+    the Pallas kernel in interpret mode at widths of 128."""
+    if impl == "ragged_dot":
+        return TINY_CONFIG, contextlib.nullcontext()
+    return WIDE_CONFIG, pallas_forced()
 
 
 def test_reference_imports_nothing_of_the_program():
@@ -194,18 +222,21 @@ def _layer_params(spec, seed=0, skew_to=None):
     return lp
 
 
+@pytest.mark.parametrize("impl", ["ragged_dot", "gmm_interpret"])
 @pytest.mark.parametrize("skew", [None, 5])
 def test_routing_is_dropless_and_equals_the_dense_all_experts_formula(
-        ref, skew):
-    spec = tiny_spec()
+        ref, skew, impl):
+    config, forced = experts_case(impl)
+    spec = tiny_spec(config=config)
     lp = _layer_params(spec, skew_to=skew)
     rng = np.random.RandomState(7)
     t = 48
     h = jnp.asarray(np.abs(rng.randn(t, spec.d_model)) if skew is not None
                     else rng.randn(t, spec.d_model), jnp.float32)
     valid = jnp.asarray(np.arange(t) % 6 != 5)       # some dead lanes
-    out, counts = jax.jit(lambda h, lp, v: moe_layer(h, lp, v, spec))(
-        h, lp, valid)
+    with forced:
+        out, counts = jax.jit(lambda h, lp, v: moe_layer(h, lp, v, spec))(
+            h, lp, valid)
     counts = np.asarray(counts)
     n_valid = int(np.asarray(valid).sum())
     # no capacity, no dropped token: every live token's k assignments land
@@ -227,7 +258,8 @@ def test_routing_is_dropless_and_equals_the_dense_all_experts_formula(
 
 # --- the step through the cache against the reference's full pass ----------
 
-def _run_through_cache(spec, params, prompt, block, chunk, dtype):
+def _run_through_cache(spec, params, prompt, block, chunk, dtype,
+                       attention_impl="reference"):
     """Prefill the prompt's whole blocks in chunks of ``chunk``, then one
     pass over ``block`` at the next position: the pass's logits [B, V]."""
     ps, pages = 4, 24
@@ -236,8 +268,8 @@ def _run_through_cache(spec, params, prompt, block, chunk, dtype):
     k, v = pool, pool
     tables = jnp.asarray(1 + np.arange(16)[None, :], jnp.int32)
     step = jax.jit(lambda p, t, pos, ql, k, v, kl: sdar_moe_step(
-        p, spec, t, pos, ql, k, v, tables, kl, attention_impl="reference"),
-        static_argnames=())
+        p, spec, t, pos, ql, k, v, tables, kl,
+        attention_impl=attention_impl), static_argnames=())
     whole = len(prompt) // 4 * 4
     done = 0
     logits = None
@@ -256,29 +288,82 @@ def _run_through_cache(spec, params, prompt, block, chunk, dtype):
     return np.asarray(logits[0]), np.asarray(aux["expert_counts"])
 
 
+@pytest.mark.parametrize("impl", ["ragged_dot", "gmm_interpret"])
 @pytest.mark.parametrize("p_mod_4", [0, 1, 3])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_prefill_chunks_then_a_block_pass_give_the_references_logits(
-        ref, p_mod_4, dtype):
+        ref, p_mod_4, dtype, impl):
     """Tolerances: in float32 the two passes differ by summation order
     only (2e-4 on logits of spread ~1); in the stated bfloat16 the
     activations between layers, the K/V pool and the products' operands
     round to 8 bits (relative 2^-9 each), which over 2 layers reads up to
     a few hundredths on a logit: 0.12, a tenth of the logits' spread, and
-    the best token's probability within 12 %."""
-    spec = tiny_spec(dtype=dtype)
+    the best token's probability within 12 %. ``gmm_interpret`` runs the
+    step as a single-chip engine on a TPU traces it (``attention_impl``
+    None): both Pallas kernels, in interpret mode."""
+    config, forced = experts_case(impl)
+    spec = tiny_spec(dtype=dtype, config=config)
     params = jax.device_put(spec.seeded_arrays())
     rng = np.random.RandomState(p_mod_4)
     prompt = [int(t) for t in rng.randint(0, 120, size=12 + p_mod_4)]
     left = prompt[12:]
     block = left + [MASK_ID] * (4 - len(left))
-    got, counts = _run_through_cache(spec, params, prompt, block, 8,
-                                     jnp.dtype(dtype))
-    want = np.asarray(ref.logits_at(params, CFG, prompt[:12] + block,
-                                    range(12, 16)))
+    with forced:
+        got, counts = _run_through_cache(
+            spec, params, prompt, block, 8, jnp.dtype(dtype),
+            attention_impl="reference" if impl == "ragged_dot" else None)
+    want = np.asarray(ref.logits_at(params, dict(config, assumed=ASSUMED),
+                                    prompt[:12] + block, range(12, 16)))
     atol = 2e-4 if dtype == "float32" else 0.12
     np.testing.assert_allclose(got, want, atol=atol)
     assert counts.shape == (2, 8) and (counts.sum(-1) == 4 * 2).all()
+
+
+def test_experts_route_by_backend_widths_and_the_callers_word():
+    """The kernel where ``use_pallas_kernels`` is on and both widths are
+    multiples of 128; ``ragged_dot`` off a TPU, at the tiny preset's widths
+    and wherever the caller names the reference (the engine under a mesh).
+    Each trace of a grouped product counts its route."""
+    from paddle_tpu.observability import metrics
+
+    kernel = metrics.counter("moe.route.gmm_kernel")
+    ragged = metrics.counter("moe.route.ragged_dot")
+    tiny, wide = tiny_spec(), tiny_spec(config=WIDE_CONFIG)
+    assert moe_route(128, 128) == "ragged_dot"        # the CPU, flag auto
+    assert FLAGS["use_pallas_kernels"] == "auto"
+    with pytest.raises(ValueError, match="None or 'reference'"):
+        moe_route(128, 128, "kernel")
+
+    def trace(spec, impl):
+        lp = _layer_params(spec)
+        h = jnp.ones((8, spec.d_model), jnp.float32)
+        before = kernel.value(), ragged.value()
+        jax.jit(lambda h: moe_layer(h, lp, jnp.ones((8,), bool), spec,
+                                    impl=impl)).lower(h)
+        return kernel.value() - before[0], ragged.value() - before[1]
+
+    assert trace(wide, None) == (0, 2)                # gate-and-up, down
+    with pallas_forced():
+        assert moe_route(wide.d_model, wide.expert_width) == "gmm_kernel"
+        assert moe_route(wide.d_model, wide.expert_width,
+                         "reference") == "ragged_dot"
+        assert moe_route(tiny.d_model, tiny.expert_width) == "ragged_dot"
+        assert trace(wide, None) == (2, 0)
+        assert trace(wide, "reference") == (0, 2)
+        assert trace(tiny, None) == (0, 2)
+        eng = DecodeEngine(wide, name="wide", slots=[1], page_size=4,
+                           num_pages=16, max_seq_len=16, warm=False)
+        try:
+            assert eng.stats()["experts_route"] == "gmm_kernel"
+        finally:
+            eng.stop()
+    eng = DecodeEngine(tiny, name="tiny", slots=[1], page_size=4,
+                       num_pages=16, max_seq_len=16, warm=False)
+    try:
+        assert eng.stats()["experts_route"] == "ragged_dot"
+        assert eng.stats()["attention_route"] == ["paged_reference"]
+    finally:
+        eng.stop()
 
 
 def test_draft_validation_names_the_block_length():

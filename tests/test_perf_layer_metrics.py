@@ -299,11 +299,14 @@ def test_the_program_registers_what_the_new_metrics_read():
     from paddle_tpu.serving import decode  # noqa: F401  (registers them)
 
     snap = metrics.snapshot("serving.decode.")
-    for name in ("block.tokens_per_pass", "moe.load_max_over_mean"):
-        assert isinstance(snap["serving.decode." + name], dict)
-    for name in ("block.passes", "block.tokens_committed",
-                 "block.tokens_dropped", "moe.assignments"):
-        assert "serving.decode." + name in snap
+    for name in ("serving.decode.block.tokens_per_pass",
+                 "serving.decode.moe.load_max_over_mean"):
+        assert isinstance(snap[name], dict)
+    for name in ("serving.decode.block.passes",
+                 "serving.decode.block.tokens_committed",
+                 "serving.decode.block.tokens_dropped",
+                 "serving.decode.moe.assignments"):
+        assert name in snap
 
 
 def test_the_xplane_reader_keeps_an_annotations_args(tmp_path):
