@@ -54,6 +54,20 @@ tests/test_decode_serving.py):
     ``0 .. q_len - 1`` only. What lies past either length cannot reach
     the output, NaN included.
 
+A WINDOW (ISSUE 34, ``window=`` static; ``None`` = none, the programs
+that pass none are the programs they were): a query lane at position
+``p`` sees keys in ``(p - window, p]``. The table of such a call STARTS
+AT THE WINDOW'S FIRST PAGE: ``table_starts [B]`` int32 gives the logical
+page index of each slot's column 0 (a cache that gives window layers'
+pages back as the sequence grows holds, and hands in, only the pages from
+there on: ``serving/kv_cache.py``), so column ``w`` holds the keys at
+positions ``(table_starts[b] + w) * page_size ...``, and the table is as
+wide as the window and a chunk, not as the sequence. The work follows the
+window as it follows the two lengths: a column every key of which lies
+behind the window of the slot's OLDEST live lane (position ``kv_len -
+q_len``) names the first live page again and is neither fetched nor
+folded.
+
 The single-token form is exactly the chunked form at C=1 with
 ``q_len = (kv_len > 0)`` — both implementations canonicalize to the
 chunked layout internally, so the two forms cannot drift.
@@ -151,12 +165,25 @@ def _key_limit(kv_len, q_len, lane, block_length: int):
                        (pos // block_length + 1) * block_length) - 1
 
 
+def _table_starts(table_starts, b: int):
+    """``table_starts`` as [B] int32 (``None``: every table starts at its
+    sequence's page 0)."""
+    if table_starts is None:
+        return jnp.zeros((b,), jnp.int32)
+    if table_starts.shape != (b,):
+        raise ValueError(f"table_starts {table_starts.shape} != ({b},)")
+    return table_starts.astype(jnp.int32)
+
+
 def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
                               *, q_lens=None,
                               scale: Optional[float] = None,
-                              block_length: int = 1):
+                              block_length: int = 1,
+                              window: Optional[int] = None,
+                              table_starts=None):
     """Pure-jax oracle: gather the pages, mask causally past each
-    query's visibility limit, dense softmax. Same signature/semantics
+    query's visibility limit (and behind its ``window``, with column 0 at
+    logical page ``table_starts``), dense softmax. Same signature/semantics
     as the kernel. Returns the same rank as ``q``. Its two dots run at
     HIGHEST precision: the kernel multiplies in float32 on the VPU, and
     a float32 oracle that let the TPU's default single bf16 pass stand
@@ -187,7 +214,14 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
                        block_length)                    # [B, C]
     valid = lane < q_lens[:, None]                      # [B, C]
     t = jnp.arange(w * ps)[None, None, :]               # [1, 1, T]
+    if window is not None:
+        # column 0 is logical page table_starts: key t sits at position
+        # table_starts * ps + t, and lane j sees (pos - window, pos]
+        t = t + _table_starts(table_starts, b)[:, None, None] * ps
     keep = (t <= limit[:, :, None]) & valid[:, :, None]  # [B, C, T]
+    if window is not None:
+        pos = kv_lens[:, None] - q_lens[:, None] + lane   # [B, C]
+        keep &= t > (pos - int(window))[:, :, None]
     keep = keep[:, :, None, :]                          # [B, C, 1, T]
     s = jnp.where(keep, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
@@ -199,9 +233,8 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
     return o[:, 0] if squeeze else o
 
 
-def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, k_ref,
-                  v_ref, o_ref, m_sc, l_sc, acc_sc, *, scale, page_size,
-                  rep, block_length):
+def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, *refs, scale,
+                  page_size, rep, block_length, window=None):
     """One (sequence b, table column w) grid step: fold this page's keys
     into the running online softmax of the slot's LIVE query lanes. W
     iterates innermost (TPU grids run sequentially), so the scratch
@@ -217,7 +250,16 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, k_ref,
     The page stays ``[ps, H, D]`` as it lies in the pool: a lane's
     scores are a reduce over D of ``q[None] * k`` (``[ps, H, 1]``), and
     the softmax statistics and ``p . v`` reduce over the page's rows,
-    the LEADING axis — plain adds of whole registers, no transpose."""
+    the LEADING axis — plain adds of whole registers, no transpose.
+
+    Under a ``window`` a fourth prefetched vector, the logical page of
+    each slot's column 0, leads ``refs``: column ``w`` is page
+    ``starts[b] + w``, a column wholly behind the oldest live lane's
+    window is skipped like one past ``kv_len``, and a lane keeps the keys
+    in ``(pos - window, pos]``."""
+    if window is not None:
+        starts_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc = refs
     w = pl.program_id(1)
     nw = pl.num_programs(1)
 
@@ -231,7 +273,15 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, k_ref,
     kv_len = kv_lens_ref[b]
     q_len = q_lens_ref[b]
 
-    @pl.when(w * page_size < kv_len)
+    if window is None:
+        first_page, live = w, w * page_size < kv_len
+    else:
+        first_page = starts_ref[b] + w
+        live = (first_page * page_size < kv_len) & (
+            (first_page + 1) * page_size
+            > _window_floor(kv_len, q_len, window))
+
+    @pl.when(live)
     def _fold():
         k = k_ref[0].astype(jnp.float32)              # [ps, Hkv, D]
         v = v_ref[0].astype(jnp.float32)
@@ -239,7 +289,7 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, k_ref,
             k = jnp.repeat(k, rep, axis=1)            # [ps, Hq, D]
             v = jnp.repeat(v, rep, axis=1)
         # this page covers absolute key positions [w*ps, w*ps + ps)
-        offs = w * page_size + jax.lax.broadcasted_iota(
+        offs = first_page * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (page_size, 1, 1), 0)          # [ps, 1, 1]
 
         def _lane(j, carry):
@@ -247,6 +297,8 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, k_ref,
             # and sees keys at positions <= its own (chunk-causal; up
             # to its block's end under block diffusion, _key_limit)
             keep = offs <= _key_limit(kv_len, q_len, j, block_length)
+            if window is not None:
+                keep &= offs > kv_len - q_len + j - window
             q = q_ref[0, j].astype(jnp.float32) * scale   # [Hq, D]
             # s[p, h] = q[h, :] . k[p, h, :]  (float32 on the VPU:
             # elementwise + reduce)
@@ -270,9 +322,19 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, k_ref,
         o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
 
 
-def _live_columns(tables, kv_lens, page_size: int):
+def _window_floor(kv_len, q_len, window: int):
+    """The first key position a call's slot can see under ``window``: the
+    oldest live lane sits at ``kv_len - q_len`` and sees ``window`` keys,
+    its own among them."""
+    return jnp.maximum(kv_len - q_len - window + 1, 0)
+
+
+def _live_columns(tables, kv_lens, page_size: int, first=None, floor=None):
     """The page table with every column past a slot's last live page
-    naming that last page again. The grid walks all W columns of every
+    naming that last page again (and, under a window, every column before
+    the first page the slot's oldest lane sees naming that one: ``first``
+    [B] is column 0's logical page, ``floor`` [B] the first key position
+    in view). The grid walks all W columns of every
     slot; consecutive grid steps on one block make the pipeline issue no
     new DMA, so neither the table's garbage columns nor a page the slot
     holds past ``kv_len`` is ever fetched (a dead slot fetches its
@@ -280,15 +342,22 @@ def _live_columns(tables, kv_lens, page_size: int):
     the index map, which runs twice every grid step."""
     last = jnp.maximum(pl.cdiv(kv_lens, page_size) - 1, 0)       # [B]
     col = jnp.arange(tables.shape[1], dtype=jnp.int32)[None]     # [1, W]
-    return jnp.take_along_axis(tables, jnp.minimum(col, last[:, None]),
-                               axis=1)
+    if first is None:
+        return jnp.take_along_axis(
+            tables, jnp.minimum(col, last[:, None]), axis=1)
+    lo = jnp.clip(floor // page_size - first, 0, tables.shape[1] - 1)
+    hi = jnp.maximum(last - first, lo)
+    return jnp.take_along_axis(
+        tables, jnp.clip(col, lo[:, None], hi[:, None]), axis=1)
 
 
 def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
                             *, q_lens=None,
                             scale: Optional[float] = None,
                             interpret: bool = False,
-                            block_length: int = 1):
+                            block_length: int = 1,
+                            window: Optional[int] = None,
+                            table_starts=None):
     b, c, hq, d, ps, hkv, w = _check_shapes(q, k_pages, v_pages,
                                             page_tables, kv_lens, q_lens)
     squeeze = q.ndim == 3
@@ -297,22 +366,31 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
     rep = hq // hkv
     kv_l = kv_lens.astype(jnp.int32)
     q_l = q_lens.astype(jnp.int32)
-    tables = _live_columns(page_tables.astype(jnp.int32), kv_l, ps)
+    if window is None:
+        prefetch = (_live_columns(page_tables.astype(jnp.int32), kv_l, ps),
+                    kv_l, q_l)
+    else:
+        starts = _table_starts(table_starts, b)
+        prefetch = (_live_columns(page_tables.astype(jnp.int32), kv_l, ps,
+                                  starts, _window_floor(kv_l, q_l,
+                                                        int(window))),
+                    kv_l, q_l, starts)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,   # page_tables, kv_lens, q_lens in SMEM
+        # page_tables, kv_lens, q_lens (and a window's table_starts) in
+        # SMEM
+        num_scalar_prefetch=len(prefetch),
         grid=(b, w),
         in_specs=[
-            pl.BlockSpec((1, c, hq, d), lambda bb, ww, t, n, m: (bb, 0, 0,
-                                                                 0)),
+            pl.BlockSpec((1, c, hq, d), lambda bb, ww, *_: (bb, 0, 0, 0)),
             # THE paged read: the index map picks each sequence's w-th
             # live page out of the pool (_live_columns)
             pl.BlockSpec((1, ps, hkv, d),
-                         lambda bb, ww, t, n, m: (t[bb, ww], 0, 0, 0)),
+                         lambda bb, ww, t, *_: (t[bb, ww], 0, 0, 0)),
             pl.BlockSpec((1, ps, hkv, d),
-                         lambda bb, ww, t, n, m: (t[bb, ww], 0, 0, 0)),
+                         lambda bb, ww, t, *_: (t[bb, ww], 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, c, hq, d),
-                               lambda bb, ww, t, n, m: (bb, 0, 0, 0)),
+                               lambda bb, ww, *_: (bb, 0, 0, 0)),
         # the lane is the leading index, so the fold takes one lane's
         # [Hq, .] slab by a dynamic first-axis index
         scratch_shapes=[
@@ -321,8 +399,10 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
             pltpu.VMEM((c, hq, d), jnp.float32),    # output accumulator
         ],
     )
-    kernel = functools.partial(_paged_kernel, scale=scale, page_size=ps,
-                               rep=rep, block_length=int(block_length))
+    kernel = functools.partial(
+        _paged_kernel, scale=scale, page_size=ps, rep=rep,
+        block_length=int(block_length),
+        window=None if window is None else int(window))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -330,7 +410,7 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
         interpret=interpret,
         # the kernel's name in the compiled program and a device trace
         name="paged_attention",
-    )(tables, kv_l, q_l, q, k_pages, v_pages)
+    )(*prefetch, q, k_pages, v_pages)
     return out[:, 0] if squeeze else out
 
 
@@ -358,7 +438,9 @@ def paged_attention(q, k_pages, v_pages, page_tables, kv_lens,
                     *, q_lens=None, scale: Optional[float] = None,
                     interpret: Optional[bool] = None,
                     impl: Optional[str] = None,
-                    block_length: int = 1):
+                    block_length: int = 1,
+                    window: Optional[int] = None,
+                    table_starts=None):
     """Route between the Pallas kernel (compiled on TPU; interpret mode
     off-TPU when forced via ``use_pallas_kernels=True`` for tests) and
     the pure-jax reference, as ``paged_route`` names it; every trace
@@ -366,8 +448,22 @@ def paged_attention(q, k_pages, v_pages, page_tables, kv_lens,
     or ``[B, C, Hq, D]`` with ``q_lens`` (a prefill chunk per slot,
     causal within the chunk). ``block_length`` (static) is the mask's
     block: 1 is causal; B > 1 lets a lane see its whole block of B
-    (``_key_limit``), for chunks of whole blocks."""
+    (``_key_limit``), for chunks of whole blocks. ``window`` (static;
+    causal masks only) keeps a lane's newest ``window`` keys, its own
+    among them, and ``table_starts [B]`` then says at which logical page
+    each slot's table begins (module docstring)."""
     from ...flags import pallas_interpret
+
+    if window is None:
+        if table_starts is not None:
+            raise ValueError("table_starts is a windowed call's argument")
+        more = {}
+    else:
+        if int(window) < 1 or block_length != 1:
+            raise ValueError(
+                f"window must be >= 1 under the causal mask, got window "
+                f"{window} with block_length {block_length}")
+        more = {"window": int(window), "table_starts": table_starts}
 
     if paged_route(q.shape[0], impl) == "paged_kernel":
         _m_route_kernel.inc()
@@ -375,8 +471,8 @@ def paged_attention(q, k_pages, v_pages, page_tables, kv_lens,
             q, k_pages, v_pages, page_tables, kv_lens, q_lens=q_lens,
             scale=scale,
             interpret=pallas_interpret() if interpret is None
-            else interpret, block_length=block_length)
+            else interpret, block_length=block_length, **more)
     _m_route_ref.inc()
     return paged_attention_reference(q, k_pages, v_pages, page_tables,
                                      kv_lens, q_lens=q_lens, scale=scale,
-                                     block_length=block_length)
+                                     block_length=block_length, **more)

@@ -12,6 +12,13 @@ sharding rules ask, instead of each knowing one decoder's tensor names:
   ``mask_token_id``   the id a block model feeds at a masked lane
   ``pool_dtype``, ``param_dtype``   the dtypes of the paged K/V pools
                       and of the parameter tree
+  ``layer_kinds``     what each layer keeps of a sequence, in layer
+                      order: ``"full"`` (every key) or ``"window"`` (the
+                      newest ``window`` keys); the engine holds a cache a
+                      kind (``serving/kv_cache.py``). ``all_full(n)`` is
+                      the answer of a model with no window
+  ``window``          keys a window layer's query sees, its own among
+                      them (``None`` where every layer is full)
   ``moe_assignments_per_token``   token-to-expert assignments a live
                       token makes in one pass (0 for a dense model)
   ``expert_width``    an expert's inner width, of a model that has
@@ -25,21 +32,51 @@ sharding rules ask, instead of each knowing one decoder's tensor names:
          kv_lens, **kw)``   ``decoder_step_chunked``'s signature; gives
                       ``(k_pool, v_pool, logits, aux)`` with ``aux`` a
                       dict of what else the pass reports (hidden states,
-                      per-expert counts)
+                      per-expert counts). A model with window layers
+                      takes and gives each pool as the pair ``(full
+                      kind's [full layers, ...], window kind's [window
+                      layers, ...])`` and ``page_tables`` as a
+                      ``KindTables`` of the full kind's table, the window
+                      kind's and the logical page the latter's rows
+                      start at
 
 The dense decoder the engine was built on (``DecoderSpec``) is one such
-model, unchanged in arithmetic; ``sdar_moe.SdarMoeSpec`` is another.
+model, unchanged in arithmetic; ``sdar_moe.SdarMoeSpec`` is another, and
+``afmoe.AfmoeSpec`` (window and full layers, gated attention, sigmoid-
+routed experts beside a shared one) a third.
 """
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
 __all__ = ["DecoderSpec", "build_decoder_params", "seeded_decoder_arrays",
            "decoder_step", "decoder_step_chunked", "validate_draft_spec",
-           "spec_from_dict"]
+           "spec_from_dict", "KindTables", "all_full"]
+
+
+class KindTables(NamedTuple):
+    """The page tables of one call to a model with window layers:
+    ``full [S, W]`` (the full kind's, as every model's), ``window [S,
+    Ww]`` (the window kind's: a row holds the pages from the window's
+    first on) and ``starts [S]`` (the logical page of each window row's
+    column 0). ``shape`` is the full table's, the call's compiled
+    ``(slots, width)`` bucket."""
+
+    full: Any
+    window: Any
+    starts: Any
+
+    @property
+    def shape(self):
+        return self.full.shape
+
+
+def all_full(n_layers: int) -> Tuple[str, ...]:
+    """``layer_kinds`` of a model whose layers all keep every key."""
+    return ("full",) * int(n_layers)
 
 
 class DecoderSpec:
@@ -57,6 +94,11 @@ class DecoderSpec:
     pool_dtype = "float32"
     param_dtype = "float32"
     moe_assignments_per_token = 0   # a dense model routes nothing
+    window = None                   # every layer keeps every key
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        return all_full(self.n_layers)
 
     def __init__(self, vocab: int = 64, d_model: int = 32,
                  n_layers: int = 2, n_heads: int = 4,
@@ -378,9 +420,14 @@ def spec_from_dict(d: Dict[str, Any]):
     if family == DecoderSpec.family:
         return DecoderSpec.from_dict(
             {k: v for k, v in d.items() if k != "family"})
-    from .sdar_moe import SdarMoeSpec
+    # a family's module is imported only when a dict names it
+    if family == "sdar_moe":
+        from .sdar_moe import SdarMoeSpec
 
-    if family == SdarMoeSpec.family:
         return SdarMoeSpec.from_dict(d)
+    if family == "afmoe":
+        from .afmoe import AfmoeSpec
+
+        return AfmoeSpec.from_dict(d)
     raise ValueError(f"unknown decoder family {family!r}; known: "
-                     f"{[DecoderSpec.family, SdarMoeSpec.family]}")
+                     f"{[DecoderSpec.family, 'sdar_moe', 'afmoe']}")

@@ -31,7 +31,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["SdarMoeSpec", "sdar_moe_step", "moe_layer", "TINY_CONFIG"]
+__all__ = ["SdarMoeSpec", "sdar_moe_step", "moe_layer", "softmax_scores",
+           "TINY_CONFIG"]
 
 # the preset the CPU tests run: every mechanism at toy widths
 TINY_CONFIG = {
@@ -146,6 +147,14 @@ class SdarMoeSpec:
     @property
     def moe_assignments_per_token(self) -> int:
         return self.experts_per_token * self.n_layers
+
+    window = None               # every layer keeps every key
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        from .decoders import all_full
+
+        return all_full(self.n_layers)
 
     @property
     def param_dtype(self) -> str:
@@ -284,16 +293,34 @@ def _dot(a, w):
     return jnp.dot(a, w, preferred_element_type=jnp.float32)
 
 
-def moe_layer(h, lp, valid, spec: SdarMoeSpec,
-              impl: Optional[str] = None):
-    """The dropless expert layer over ``h`` [T, d] (the normed hidden
-    states, in the weights' dtype): ``(out [T, d] float32, counts [E]
-    int32)``. ``valid`` [T] marks the live lanes; a dead lane is routed
-    nowhere, weighs nothing and is not counted. ``counts`` sums to
-    ``valid.sum() * experts_per_token`` and each live token's weights sum
-    to 1 (``norm_topk_prob``). ``impl`` is the step's ``attention_impl``:
-    ``"reference"`` names XLA's grouped product as it names the attention's
-    reference (``moe_gmm.moe_route``)."""
+def softmax_scores(h, lp, spec: SdarMoeSpec):
+    """This family's scoring of ``moe_layer``: softmax over the router's
+    float32 logits, the ``experts_per_token`` largest, renormalised to sum
+    to 1 under ``norm_topk_prob``. ``(w [T, k] float32, idx [T, k])``."""
+    import jax
+    import jax.numpy as jnp
+
+    probs = jax.nn.softmax(_dot(h, lp["router"]), axis=-1)   # f32
+    w, idx = jax.lax.top_k(probs, spec.experts_per_token)    # [T, k]
+    if spec.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, idx
+
+
+def moe_layer(h, lp, valid, spec, impl: Optional[str] = None, *,
+              score=softmax_scores):
+    """THE dropless expert layer of every served family, over ``h`` [T,
+    d] (the normed hidden states, in the weights' dtype): ``(out [T, d]
+    float32, counts [E] int32)``. The MODEL supplies the scoring
+    (``score(h, lp, spec) -> (w [T, k] float32, idx [T, k] int32)``: which
+    ``experts_per_token`` of ``n_experts`` a token meets and how each
+    weighs; ``softmax_scores`` here, ``afmoe.sigmoid_scores``); the sort,
+    the grouped products and the scatter-add are one. ``valid`` [T] marks
+    the live lanes; a dead lane is routed nowhere, weighs nothing and is
+    not counted. ``counts`` sums to ``valid.sum() * experts_per_token``.
+    ``impl`` is the step's ``attention_impl``: ``"reference"`` names XLA's
+    grouped product as it names the attention's reference
+    (``moe_gmm.moe_route``)."""
     import jax
     import jax.numpy as jnp
 
@@ -302,10 +329,7 @@ def moe_layer(h, lp, valid, spec: SdarMoeSpec,
     t = h.shape[0]
     e, k = spec.n_experts, spec.experts_per_token
     with jax.named_scope("decoder.moe.route"):
-        probs = jax.nn.softmax(_dot(h, lp["router"]), axis=-1)   # f32
-        w, idx = jax.lax.top_k(probs, k)                     # [T, k]
-        if spec.norm_topk_prob:
-            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        w, idx = score(h, lp, spec)
         # dead lanes sort behind every expert's group (sentinel E)
         idx = jnp.where(valid[:, None], idx, e).reshape(t * k)
         w = jnp.where(valid[:, None], w, 0.0).reshape(t * k)

@@ -100,6 +100,23 @@ the device looks like is decided in one place each:
     keeps its own slot list and scoring and shares the builder, the run
     function and the retire/notify tail.
 
+LAYER KINDS (ISSUE 34). The engine asks the model what each layer keeps
+of a sequence (``layer_kinds``: ``"full"`` or ``"window"``, and the
+``window``). A model whose layers are all full — the dense decoder, the
+block model — takes exactly the path, the shapes and the page arithmetic
+above. One with window layers (``models/afmoe.py``) is served from a PAIR
+of caches, ``cache`` (the full kind's) and ``window_cache``, each over its
+own allocator: reservation, admission, demand growth, preemption and spill
+count pages by kind (``_alloc_kinds``, ``_prepare``, ``_preempt``); each
+round the window kind gives back the pages that fell behind the window of
+the step's oldest lane and grows to its last write, so a sequence never
+holds more than ``ceil((window + chunk - 1) / page_size) + 1`` of its
+pages; and the one builder hands the one step both tables, the window
+kind's as wide as that and starting at the window's first page
+(``KindTables``). What assumes that a sequence's pages stay its own to the
+end (the prefix cache, a draft's mirrored pool, the embed lane) and a mesh
+refuse such a model by the field's name.
+
 Lifecycle mirrors the one-shot engine so the SAME ModelRegistry
 hot-swaps decoders: ``stop(drain=True)`` finishes every admitted
 sequence then drops params/pools/compiled steps (executables release
@@ -237,6 +254,13 @@ _m_block_tokens_per_pass = _metrics.histogram(
 # worst layer (1.0 = even load)
 _m_moe_assignments = _metrics.counter("serving.decode.moe.assignments")
 _m_moe_load = _metrics.histogram("serving.decode.moe.load_max_over_mean")
+# window layers (ISSUE 34). held_pct observes, once a round, the pages the
+# window kind holds for the round's sequences over the pages the full kind
+# holds for them; attn_window_skip_pct, once a step call, the share of the
+# query-key pairs a causal mask would give the step's window layers that
+# lie behind the window (0 until a sequence passes it)
+_m_window_held = _metrics.histogram("serving.kv.window.held_pct")
+_m_window_skip = _metrics.histogram("serving.decode.attn_window_skip_pct")
 
 
 # --- the pluggable decoder model ----------------------------------------
@@ -244,7 +268,8 @@ _m_moe_load = _metrics.histogram("serving.decode.moe.load_max_over_mean")
 # layout, its parameter tree, its step and the block length it generates
 # by. The dense decoder this engine was built on is one such model and
 # keeps its names here.
-from ..models.decoders import (DecoderSpec, build_decoder_params,  # noqa: E402,F401
+from ..models.decoders import (DecoderSpec, KindTables,  # noqa: E402,F401
+                               build_decoder_params,
                                decoder_step, decoder_step_chunked,
                                seeded_decoder_arrays, spec_from_dict,
                                validate_draft_spec)
@@ -376,8 +401,8 @@ def _live_pages(kv_lens, page_size: int) -> int:
 
 
 def _call_work(slots: int, chunk: int, width: int, q_lens,
-               kv_lens, block: int = 1, *,
-               page_size: int) -> Dict[str, int]:
+               kv_lens, block: int = 1, *, page_size: int,
+               window: Optional[int] = None) -> Dict[str, int]:
     """``serving.decode.device_call``'s args: the compiled buckets of
     one step call and the sums its attention work follows from,
     whatever implements the call — query tokens, keys in view, and
@@ -391,13 +416,34 @@ def _call_work(slots: int, chunk: int, width: int, q_lens,
     0/0 and add nothing. ``kv_pages`` (pages that hold a key in view)
     and ``q_lanes`` (``slots * chunk``) set what is walked beside what
     is live: ``slots * width`` table columns and ``q_lanes`` query
-    lanes against ``kv_pages`` and ``q_tokens``."""
+    lanes against ``kv_pages`` and ``q_tokens``.
+
+    A model with WINDOW layers (ISSUE 34, ``window`` keys a lane) has the
+    sums by kind as well, a layer of each: ``kv_tokens_full`` /
+    ``attn_pairs_full`` are the causal sums above, ``kv_tokens_window``
+    the keys its window layers have in view (a slot's oldest lane sees
+    ``window``, the chunk's others the lanes before them too) and
+    ``attn_pairs_window`` the pairs (a lane at position p sees ``min(p +
+    1, window)``)."""
     q, kv = q_lens.astype(np.int64), kv_lens.astype(np.int64)
-    return {"slots": slots, "chunk": chunk, "width": width,
-            "q_tokens": int(q.sum()), "kv_tokens": int(kv.sum()),
-            "attn_pairs": int(((2 * kv - q + block) * q // 2).sum()),
-            "kv_pages": _live_pages(kv_lens, page_size),
-            "q_lanes": slots * chunk}
+    out = {"slots": slots, "chunk": chunk, "width": width,
+           "q_tokens": int(q.sum()), "kv_tokens": int(kv.sum()),
+           "attn_pairs": int(((2 * kv - q + block) * q // 2).sum()),
+           "kv_pages": _live_pages(kv_lens, page_size),
+           "q_lanes": slots * chunk}
+    if window is not None:
+        # the first lane sees ``first`` keys; ``low`` lanes see fewer
+        # than a window's (an arithmetic run), the rest a window's
+        first = kv - q + 1
+        low = np.clip(window - first + 1, 0, q)
+        out.update(
+            kv_tokens_full=out["kv_tokens"],
+            attn_pairs_full=out["attn_pairs"],
+            kv_tokens_window=int(np.where(
+                q > 0, np.minimum(kv, window + q - 1), 0).sum()),
+            attn_pairs_window=int((low * (2 * first + low - 1) // 2
+                                   + window * (q - low)).sum()))
+    return out
 
 
 # --- ladders ------------------------------------------------------------
@@ -425,7 +471,7 @@ class _DecodeRequest:
                  "published", "carry_steps", "carry_fts", "needs_alloc",
                  "resume_dpos", "spec_proposed", "spec_accepted",
                  "mask", "mask_state", "want_topk", "first_topk",
-                 "denoise_steps", "passes")
+                 "denoise_steps", "passes", "resume_wfirst")
 
     def __init__(self, prompt: np.ndarray, max_new: int,
                  deadline: Optional[float], seq_id: int,
@@ -470,6 +516,9 @@ class _DecodeRequest:
         # and the request's propose/accept tallies (accept_rate in the
         # result dict)
         self.resume_dpos: Optional[int] = None
+        # window layers (ISSUE 34): the logical page a preempted
+        # sequence's window kind began at, where its restore begins
+        self.resume_wfirst = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
         # constrained decode (ISSUE 20): a compiled MaskAutomaton and
@@ -499,7 +548,8 @@ class _DecodeRequest:
 
 class _Slot:
     __slots__ = ("req", "pos", "pages_held", "steps", "first_token_steps",
-                 "pending_restore", "dpos", "block", "masked")
+                 "pending_restore", "dpos", "block", "masked",
+                 "wpages_held", "wfirst")
 
     def __init__(self, req: _DecodeRequest, pages_held: int):
         self.req = req
@@ -524,6 +574,11 @@ class _Slot:
         # is harmless). None = no block open (prefill, or a causal model)
         self.block: Optional[List[int]] = None
         self.masked: Optional[List[bool]] = None
+        # window layers (ISSUE 34): the pages the WINDOW kind holds for
+        # this sequence and the logical page the first of them is; 0/0
+        # under a model whose layers are all full
+        self.wpages_held = 0
+        self.wfirst = 0
 
     def token_at(self, idx: int) -> int:
         """The sequence's token at absolute position ``idx``: a prompt
@@ -686,6 +741,7 @@ class DecodeEngine:
                  mesh: Optional[Any] = None,
                  mesh_rules: Optional[Any] = None,
                  embeddings: bool = False,
+                 num_window_pages: Optional[int] = None,
                  warm: bool = True):
         from ..fluid.flags import FLAGS, effective_flag
 
@@ -712,6 +768,35 @@ class DecodeEngine:
                         f"{spec.family!r}): '{field}' is for causal "
                         f"models (block_length 1)")
             prefix_cache = False
+        # layer kinds (ISSUE 34): a model with WINDOW layers is served
+        # from a pair of caches, and its window kind gives a sequence's
+        # pages back from the front as it grows. What assumes that a
+        # sequence's pages, once written, stay its own to the end — a
+        # shared prefix (sharers would need the window pages kept), a
+        # draft's mirrored pool and its rollback, the embed lane's own
+        # slots — and the mesh's sharded pools are not carried through
+        # for such a model: each is refused by name where a caller asks
+        # for it, and off where nobody does
+        self._window = spec.window if "window" in spec.layer_kinds else None
+        if self._window is not None:
+            for field, given in (("draft_spec", draft_spec is not None),
+                                 ("spec_k", bool(spec_k)),
+                                 ("embeddings", bool(embeddings)),
+                                 ("prefix_cache", bool(prefix_cache)),
+                                 ("mesh", bool(mesh))):
+                if given:
+                    raise ValueError(
+                        f"decoder '{name}' has window layers (family "
+                        f"{spec.family!r}, window {self._window}): "
+                        f"'{field}' is not carried through for a cache "
+                        f"that gives window pages back as a sequence "
+                        f"grows")
+            prefix_cache, mesh = False, ""
+        elif num_window_pages is not None:
+            raise ValueError(
+                f"'num_window_pages' sizes the window kind's pool; "
+                f"decoder '{name}' (family {spec.family!r}) has no window "
+                f"layer")
         # mesh-sharded serving (ISSUE 15): one replica SPANS chips.
         # `mesh` is a MeshSpec / axes dict / "tp=2" string (None reads
         # FLAGS['serving_mesh_axes']; '' = single-chip, bit-identical
@@ -802,8 +887,10 @@ class DecodeEngine:
                 f"got {reservation!r}")
         self._reservation = reservation
         self._headroom_pages = max(0, int(FLAGS["kv_decode_headroom"]))
+        # the FULL kind's cache, which every model has: the layers that
+        # keep every key (all of them, but for a model with window layers)
         self.cache = PagedKvCache(
-            spec.n_layers, spec.n_kv_heads, spec.head_dim,
+            spec.layer_kinds.count("full"), spec.n_kv_heads, spec.head_dim,
             page_size=ps, num_pages=npages, dtype=spec.pool_dtype,
             label=f"{self.name}.v{self.version}",
             prefix_cache=self._prefix_on,
@@ -836,6 +923,33 @@ class DecodeEngine:
         # passes ride C=block_length), steps carrying a prefill grant
         # ride the C=chunk shapes
         self._chunk_ladder = sorted({self._block, self._prefill_chunk})
+        # the WINDOW kind's cache (ISSUE 34), over an allocator of its
+        # own: a pair, as a draft's pool beside its target's, and not one
+        # pool with a kind axis, because the two kinds hold different
+        # NUMBERS of pages for one sequence and free them at different
+        # times. A step's oldest lane sees ``window`` keys and writes up
+        # to a chunk, so a sequence never needs more than
+        # ``_wwidth`` pages of it, which is also the width of the table
+        # its kernel call reads
+        self._wcache = None
+        self._wwidth = 0
+        if self._window is not None:
+            self._wwidth = min(w_max, -(-(self._window + self._prefill_chunk
+                                          - 1) // ps) + 1)
+            wpages = int(npages if num_window_pages is None
+                         else num_window_pages)
+            if wpages - 1 < self._wwidth:
+                raise ValueError(
+                    f"num_window_pages {wpages} cannot hold one sequence's "
+                    f"window: {self._wwidth} pages of {ps} for window "
+                    f"{self._window} and a chunk of {self._prefill_chunk}")
+            self._wcache = PagedKvCache(
+                spec.layer_kinds.count("window"), spec.n_kv_heads,
+                spec.head_dim, page_size=ps, num_pages=wpages,
+                dtype=spec.pool_dtype,
+                label=f"{self.name}.v{self.version}.window")
+        self._hbm_bytes = self.cache.hbm_bytes + (
+            self._wcache.hbm_bytes if self._wcache is not None else 0)
         # speculative decoding (ISSUE 14): a small DRAFT decoder
         # proposes spec_k tokens per decoding slot per round; the
         # target verifies all k+1 positions in ONE chunked call. The
@@ -934,14 +1048,16 @@ class DecodeEngine:
         # only where a request needs the host (_fetch_row)
         def _step(params, tokens, positions, q_lens, k_pool, v_pool,
                   tables, lens, temperature, seed):
-            k, v, logits, _aux = spec_ref.step(
+            k, v, logits, aux = spec_ref.step(
                 params, tokens, positions, q_lens, k_pool,
                 v_pool, tables, lens, attention_impl=impl,
                 garbage_page=GARBAGE_PAGE)
             # lens (the keys including this chunk) is the new token's
             # absolute index in its sequence: the position of the draw
-            return k, v, choose_tokens(logits, temperature, seed,
-                                       lens), logits
+            ids = choose_tokens(logits, temperature, seed, lens)
+            # what else a model's pass reports (per-expert counts) rides
+            # beside the ids, as a block program's does
+            return k, v, ({"ids": ids, **aux} if aux else ids), logits
 
         def _block_step(params, tokens, positions, q_lens, k_pool,
                         v_pool, tables, lens, temperature, seed, masked,
@@ -1099,6 +1215,17 @@ class DecodeEngine:
     @property
     def chunk_ladder(self) -> List[int]:
         return list(self._chunk_ladder)
+
+    @property
+    def hbm_bytes(self) -> int:
+        """The preallocated KV budget of every kind's pools (fixed at
+        construction; a retired engine still says what it held)."""
+        return self._hbm_bytes
+
+    @property
+    def window_cache(self) -> Optional[PagedKvCache]:
+        """The window kind's cache (None: every layer is full)."""
+        return self._wcache
 
     @property
     def spec_k(self) -> int:
@@ -1582,7 +1709,7 @@ class DecodeEngine:
                             f"decoder '{self.name}' v{self.version} "
                             "unloaded"))
                     else:
-                        self.cache.allocator.free(s.req.seq_id)
+                        self._free_pages(s.req.seq_id)
                 self._embed_slots = []
                 for s in self._slots:
                     # a slot _complete()d mid-step may still be in
@@ -1593,7 +1720,7 @@ class DecodeEngine:
                             f"decoder '{self.name}' v{self.version} "
                             "unloaded"))
                     else:
-                        self.cache.allocator.free(s.req.seq_id)
+                        self._free_pages(s.req.seq_id)
                 self._slots = []
                 self._g_depth.set(0)
             self._cond.notify_all()
@@ -1616,6 +1743,8 @@ class DecodeEngine:
                 # pool's HBM frees with its own k/v drop
                 self._draft_cache.release()
                 self._draft_cache = None
+            if self._wcache is not None:
+                self._wcache.release()
             self.cache.release()
         # any spills that survived the drain (preempted sequences the
         # retirement failed) die with the engine — files included
@@ -1662,6 +1791,13 @@ class DecodeEngine:
                 "prefix": self.cache.allocator.prefix_stats(),
                 "spilled_sequences": self._spill.count(),
                 "kv": self.cache.allocator.stats(),
+                # layer kinds (ISSUE 34): the window kind's pool beside
+                # the full kind's (None: every layer is full), and the
+                # whole KV budget of both
+                "kv_window": (self._wcache.allocator.stats()
+                              if self._wcache is not None else None),
+                "window": self._window,
+                "kv_hbm_bytes": self.hbm_bytes,
                 "queue_depth": len(self._queue),
                 "live": len(self._slots),
                 "embeddings": self._embed_on,
@@ -1689,11 +1825,35 @@ class DecodeEngine:
         if self._prefix_on:
             return self.cache.allocator.alloc_prefix(seq_id, prompt,
                                                      reserve)
-        self.cache.allocator.alloc(seq_id, reserve)
+        self._alloc_kinds(seq_id, reserve)
         return {"cached_tokens": 0, "cow": None}
 
+    def _alloc_kinds(self, seq_id: int, tokens: int, first_page: int = 0):
+        """Reserve ``tokens`` in the full kind and, under a model with
+        window layers, what the window kind needs of them from logical
+        page ``first_page`` on: no more than the pages one sequence ever
+        holds of it (``_wwidth``); what lies beyond is grown, and what
+        falls behind given back, round by round (``_prepare``). A refusal
+        by either leaves neither holding a page."""
+        self.cache.allocator.alloc(seq_id, tokens)
+        if self._wcache is not None:
+            try:
+                self._wcache.allocator.alloc(
+                    seq_id, min(tokens, (first_page + self._wwidth)
+                                * self.cache.page_size), first_page)
+            except ServerOverloaded:
+                self.cache.allocator.free(seq_id)
+                raise
+
+    def _free_pages(self, seq_id: int):
+        """Return a sequence's pages of every kind (idempotent, as the
+        allocator's ``free``)."""
+        self.cache.allocator.free(seq_id)
+        if self._wcache is not None:
+            self._wcache.allocator.free(seq_id)
+
     def _fail_locked(self, req: _DecodeRequest, err: BaseException):
-        self.cache.allocator.free(req.seq_id)
+        self._free_pages(req.seq_id)
         if req.cow is not None:
             # the COW source pin must not outlive the request (a pinned
             # entry is un-evictable)
@@ -1745,7 +1905,8 @@ class DecodeEngine:
                         reserve = min(total, max(req.resume_pos, 1)
                                       + self._headroom_pages
                                       * self.cache.page_size)
-                        self.cache.allocator.alloc(req.seq_id, reserve)
+                        self._alloc_kinds(req.seq_id, reserve,
+                                          req.resume_wfirst)
                     else:
                         res = self._reserve_locked(req.seq_id,
                                                    req.prompt, total)
@@ -1757,6 +1918,10 @@ class DecodeEngine:
             self._queue.pop(0)
             slot = _Slot(req,
                          self.cache.allocator.held_pages(req.seq_id))
+            if self._wcache is not None:
+                slot.wpages_held = self._wcache.allocator.held_pages(
+                    req.seq_id)
+                slot.wfirst = self._wcache.allocator.head(req.seq_id)
             if req.resume_pos is not None:
                 slot.pos = req.resume_pos
                 # the draft pool restores from the same spill; its
@@ -1767,6 +1932,7 @@ class DecodeEngine:
                 slot.pending_restore = True
                 req.resume_pos = None
                 req.resume_dpos = None
+                req.resume_wfirst = 0
             else:
                 # cached prompt pages are already written (and mapped):
                 # prefill starts at the first uncached token — in BOTH
@@ -1909,7 +2075,9 @@ class DecodeEngine:
                       tables=None):
         """THE arrays of one call, in the order every program takes
         them: ``(tokens [S, C], positions [S, C], q_lens [S], tables
-        [S, W], lens [S])`` int32 at the three compiled buckets. Row i
+        [S, W], lens [S])`` int32 at the three compiled buckets (``tables``
+        a ``KindTables`` of both kinds' under a model with window layers:
+        its ``shape`` is the full table's). Row i
         is ``slots[i]`` feeding ``feeds[i] = (start, tokens)``: those
         tokens at positions ``start ..``, ``q_lens`` how many, ``lens``
         the keys INCLUDING them (within the chunk, query j attends only
@@ -1939,8 +2107,19 @@ class DecodeEngine:
                              starts[:, None] + lanes, np.int32(0))
         lens = starts + q_lens
         if tables is None:
-            tables = self.cache.table_array(
-                [s.req.seq_id for s in slots], w_bucket, rows=s_bucket)
+            seq_ids = [s.req.seq_id for s in slots]
+            tables = self.cache.table_array(seq_ids, w_bucket,
+                                            rows=s_bucket)
+            if self._wcache is not None:
+                # the window kind's table beside it: a row holds the
+                # pages from the window's first on (``starts`` says which
+                # logical page that is) and is as wide as a window and a
+                # chunk, or as the full table where that is narrower
+                tables = KindTables(
+                    tables, self._wcache.table_array(
+                        seq_ids, min(w_bucket, self._wwidth),
+                        rows=s_bucket),
+                    self._wcache.allocator.table_starts(seq_ids, s_bucket))
         return tokens, positions, q_lens, tables, lens
 
     def _run(self, tag: str, tokens, positions, q_lens, tables, lens, *,
@@ -1968,6 +2147,10 @@ class DecodeEngine:
             prog.steps.inc()
             params, cache = ((self._draft_params, self._draft_cache)
                              if prog.draft else (self._params, self.cache))
+            # a model with window layers takes and gives each pool as the
+            # pair of its kinds (it has no draft)
+            kinds = ((cache,) if self._wcache is None
+                     else (cache, self._wcache))
             rows, args = len(tokens), ()
             if prog.sampled:
                 args = ((np.zeros(rows, np.float32),
@@ -1978,9 +2161,14 @@ class DecodeEngine:
                          if masked is None else masked,
                          np.zeros(rows, np.int32)
                          if n_unmask is None else n_unmask)
-            k, v, *out = prog.fn(params, tokens, positions, q_lens,
-                                 cache.k, cache.v, tables, lens, *args)
-            cache.rebind(k, v)
+            k, v = ((cache.k, cache.v) if len(kinds) == 1 else
+                    (tuple(c.k for c in kinds), tuple(c.v for c in kinds)))
+            k, v, *out = prog.fn(params, tokens, positions, q_lens, k, v,
+                                 tables, lens, *args)
+            if len(kinds) == 1:
+                k, v = (k,), (v,)
+            for c, ck, cv in zip(kinds, k, v):
+                c.rebind(ck, cv)
             return tuple(out)
 
     def _run_step_arrays(self, tokens, positions, q_lens, tables, lens,
@@ -2040,10 +2228,14 @@ class DecodeEngine:
         COW copies and preemption restores (device writes, batched,
         under ``_step_mu`` — the same serialization every pool touch
         gets), then grow demand-mode reservations to cover this step's
-        grants, preempting/demoting when the pool runs dry. Returns the
-        (possibly shrunk) live list and its grants."""
+        grants, preempting/demoting when the pool runs dry. Under a model
+        with window layers the window kind's pages are counted beside the
+        full kind's: what fell behind the window goes back first, then
+        either kind's growth is asked for, and a refusal by either is
+        answered the same way. Returns the (possibly shrunk) live list
+        and its grants."""
         cows: List[Tuple[int, int]] = []
-        restores = []
+        restores, wrestores = [], []
         spills: Dict[int, Any] = {}
         for s in live:
             if s.pending_restore:
@@ -2065,6 +2257,11 @@ class DecodeEngine:
                 if spill is not None:
                     pages = self.cache.allocator.pages_of(s.req.seq_id)
                     restores.append((pages[:spill[0].shape[1]], spill))
+                    if self._wcache is not None:
+                        wpages = self._wcache.allocator.pages_of(
+                            s.req.seq_id)
+                        wrestores.append((wpages[:spill[2].shape[1]],
+                                          spill[2], spill[3]))
                     _m_restores.inc()
                 if s.req.cow is not None:
                     cows.append((s.req.cow["src"], s.req.cow["dst"]))
@@ -2087,6 +2284,9 @@ class DecodeEngine:
                     if self._draft_cache is not None and len(spill) == 4:
                         self._draft_cache.scatter_pages(
                             pages, spill[2], spill[3])
+                for wpages, wk, wv in wrestores:
+                    self._wcache.scatter_pages(wpages, wk, wv)
+        pages_for = self.cache.allocator.pages_for_tokens
         while True:
             grants = self._grants(live)
             grower = None
@@ -2094,16 +2294,40 @@ class DecodeEngine:
                 if s.req.ev.is_set():
                     continue  # canceled: pages gone, rides one last
                     # step through the garbage table, answered nowhere
-                need = self.cache.allocator.pages_for_tokens(s.pos + g)
+                need = pages_for(s.pos + g)
                 if need > s.pages_held:
-                    grower = (s, need - s.pages_held)
+                    grower = (s, need - s.pages_held, False)
                     break
+                if self._wcache is not None:
+                    # the window kind, in THIS round: the pages every key
+                    # of which lies behind the window of the step's oldest
+                    # lane (position pos) go back first, then the far end
+                    # grows to the step's last write
+                    behind = (max(0, s.pos - self._window + 1)
+                              // self.cache.page_size)
+                    if behind > s.wfirst:
+                        gone = self._wcache.allocator.release_head(
+                            s.req.seq_id, behind)
+                        s.wfirst += gone
+                        s.wpages_held -= gone
+                    if need - s.wfirst > s.wpages_held:
+                        grower = (s, need - s.wfirst - s.wpages_held, True)
+                        break
             if grower is None:
+                if self._wcache is not None:
+                    held = sum(s.pages_held for s in live)
+                    if held:
+                        _m_window_held.observe(100.0 * sum(
+                            s.wpages_held for s in live) / held)
                 return live, grants
-            s, n = grower
+            s, n, windowed = grower
             try:
-                self.cache.allocator.grow(s.req.seq_id, n)
-                s.pages_held += n
+                if windowed:
+                    self._wcache.allocator.grow(s.req.seq_id, n)
+                    s.wpages_held += n
+                else:
+                    self.cache.allocator.grow(s.req.seq_id, n)
+                    s.pages_held += n
                 continue
             except ServerOverloaded:
                 pass
@@ -2137,7 +2361,7 @@ class DecodeEngine:
             for req in reversed(self._queue):
                 if req.ev.is_set() or req.needs_alloc:
                     continue
-                self.cache.allocator.free(req.seq_id)
+                self._free_pages(req.seq_id)
                 if req.cow is not None:
                     self.cache.allocator.release_cow(req.cow["key"])
                     req.cow = None
@@ -2181,10 +2405,16 @@ class DecodeEngine:
                     if self._draft_cache is not None:
                         arrays = arrays + self._draft_cache.gather_pages(
                             pages[:n_keep])
+                    if self._wcache is not None:
+                        # the window kind's pages that hold a committed
+                        # key: from its first held page to pos's
+                        arrays = arrays + self._wcache.gather_pages(
+                            self._wcache.allocator.pages_of(req.seq_id)[
+                                :n_keep - victim.wfirst])
                 # put (disk-backed spills savez) stays outside the
                 # step mutex, same as the pop side in _prepare
                 self._spill.put(req.seq_id, *arrays)
-            self.cache.allocator.free(req.seq_id)
+            self._free_pages(req.seq_id)
             _m_preemptions.inc()
             with self._cond:
                 self._slots = [x for x in self._slots if x is not victim]
@@ -2195,6 +2425,7 @@ class DecodeEngine:
                 else:
                     req.resume_pos = victim.pos
                     req.resume_dpos = victim.dpos
+                    req.resume_wfirst = victim.wfirst if victim.pos else 0
                     req.carry_steps = victim.steps
                     req.carry_fts = victim.first_token_steps
                     req.needs_alloc = True
@@ -2321,12 +2552,20 @@ class DecodeEngine:
         strips asserts. Canceled slots are exempt — their pages are
         gone and their table row is all-garbage, so their writes land
         on the garbage page by construction."""
-        if not s.req.ev.is_set() and \
-                end_tokens > s.pages_held * self.cache.page_size:
+        if s.req.ev.is_set():
+            return
+        ps = self.cache.page_size
+        if end_tokens > s.pages_held * ps:
             raise ServingError(
                 f"chunk grant escaped seq {s.req.seq_id}'s page "
                 f"reservation ({end_tokens} tokens > "
-                f"{s.pages_held} pages x {self.cache.page_size})")
+                f"{s.pages_held} pages x {ps})")
+        if self._wcache is not None and \
+                end_tokens > (s.wfirst + s.wpages_held) * ps:
+            raise ServingError(
+                f"chunk grant escaped seq {s.req.seq_id}'s window page "
+                f"reservation ({end_tokens} tokens > pages {s.wfirst} + "
+                f"{s.wpages_held} x {ps})")
 
     def _spec_substep(self, slots: List[_Slot], w_bucket: int
                       ) -> Dict[int, Tuple[List[int], int, int]]:
@@ -2441,6 +2680,11 @@ class DecodeEngine:
             if s.pos < len(s.req.prompt):
                 rnd.prefill_toks += g
             rnd.reads |= s.pos + g >= len(s.req.prompt)
+        per_token = self.spec.moe_assignments_per_token
+        if per_token:
+            rnd.n["assignments"] = per_token * sum(
+                len(fed) for _start, fed in rnd.feeds)
+            rnd.call_args = {"moe_assignments": rnd.n["assignments"]}
         return rnd
 
     def _open_block(self, s: _Slot):
@@ -2555,7 +2799,7 @@ class DecodeEngine:
                     out, rnd.logits = self._run_step_arrays(
                         *arrays, temperature=temperature, seed=seed,
                         **rnd.call_kw)
-                    if self._block == 1:
+                    if not isinstance(out, dict):
                         out = {"ids": out}
                     if rnd.reads:
                         for name in out:
@@ -2793,10 +3037,16 @@ class DecodeEngine:
         ps = self.cache.page_size
         _m_attn_grid_live.observe(
             100.0 * _live_pages(kv_lens, ps) / (slots * width))
+        if sp.live or self._window is not None:
+            work = _call_work(slots, chunk, width, q_lens, kv_lens,
+                              self._block, page_size=ps,
+                              window=self._window)
+            if work.get("attn_pairs_full"):
+                _m_window_skip.observe(
+                    100.0 - 100.0 * work["attn_pairs_window"]
+                    / work["attn_pairs_full"])
         if sp.live:
-            for key, value in {**_call_work(slots, chunk, width, q_lens,
-                                            kv_lens, self._block,
-                                            page_size=ps), **more}.items():
+            for key, value in {**work, **more}.items():
                 sp.set_arg(key, value)
 
     def _observe_step(self, seconds: float, n_live: int,
@@ -2838,6 +3088,14 @@ class DecodeEngine:
         # one allocator-lock round-trip for the whole step; seqs freed
         # by _complete/_fail are skipped inside
         self.cache.allocator.note_tokens_many(notes)
+        if self._wcache is not None:
+            # the window kind holds a sequence's tokens from its first
+            # held page on (the round's slots are still in their lane)
+            ps = self.cache.page_size
+            first = {s.req.seq_id: s.wfirst for s in self._slots}
+            self._wcache.allocator.note_tokens_many(
+                {sid: max(0, n - first.get(sid, 0) * ps)
+                 for sid, n in notes.items()})
         if done:
             self._slots = [s for s in self._slots if s not in done]
             self._embed_slots = [s for s in self._embed_slots
@@ -2873,7 +3131,7 @@ class DecodeEngine:
 
     def _complete(self, s):
         """Deliver a finished slot's result, in either lane."""
-        self.cache.allocator.free(s.req.seq_id)
+        self._free_pages(s.req.seq_id)
         _m_completions.inc()
         _m_total.observe((time.monotonic() - s.req.t_enq) * 1e3)
         if isinstance(s, _EmbedSlot):

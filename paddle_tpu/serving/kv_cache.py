@@ -43,6 +43,22 @@ pages stay in the index (refcount 0 = reclaimable, evicted LRU
 leaf-first when the free list runs short) — ``pages_free`` counts them
 as free because one eviction pass away is economically free.
 
+LAYER KINDS (ISSUE 34): a model whose layers are not all alike in what
+they keep (Trinity-Mini: three WINDOW layers that see the newest 2048
+keys for every FULL layer that sees them all) is served from a PAIR of
+these caches, one a kind, each over its own allocator and its own page
+ids — as a speculative draft's pool mirrors its target's, but with
+allocators apart, because the two kinds hold different NUMBERS of pages
+for one sequence. The full kind is the cache every model has. The window
+kind's allocator gives pages back from the FRONT of a sequence as it
+grows (``release_head``): it remembers the logical page its first held
+page is (``head``), a restored sequence is reserved from there
+(``alloc(first_page=)``), and the table it hands the kernel starts at
+that page (``table_starts``) and is as wide as a window and a chunk. One
+pool of one shape with one table a sequence would hold every layer's
+pages for the whole sequence: two and a half times what five such layers
+need at 8k tokens.
+
 RESERVATION (ISSUE 13): ``alloc`` still takes a worst-case token
 count; demand-mode engines reserve only ``prompt + headroom`` and
 ``grow()`` one page at a time mid-decode — on exhaustion the ENGINE
@@ -91,6 +107,9 @@ _m_spill_bytes = _metrics.counter("serving.kv.spill_bytes")
 # verify chunk but ended up holding ONLY rejected tokens, returned to
 # the free list by PageAllocator.shrink (the exact-pool invariant)
 _m_shrunk_pages = _metrics.counter("serving.kv.shrunk_pages")
+# window layers (ISSUE 34): pages a sequence's window kind gave back
+# from its front because every key in them fell behind the window
+_m_window_released = _metrics.counter("serving.kv.window.pages_released")
 # one inc per TRACE of a fused page-move helper — i.e. one per distinct
 # (pool shape, index count) the jitted gather/scatter/copy ops compile
 # (the ROADMAP spill-economics residual: the helpers used to be eager
@@ -508,6 +527,9 @@ class PageAllocator:
         self._owner: Dict[int, List[int]] = {}  # guarded-by: _mu
         self._tokens: Dict[int, int] = {}  # guarded-by: _mu
         self._total_tokens = 0  # guarded-by: _mu
+        # the logical page a sequence's first held page is, where that
+        # is not 0: a window kind's sequences after release_head
+        self._head: Dict[int, int] = {}  # guarded-by: _mu
         self.prefix = (PrefixIndex(self._mu, self.page_size)
                        if prefix_cache else None)
         # gauges are keyed per allocator when a label (engine name.vN)
@@ -643,17 +665,23 @@ class PageAllocator:
                 f"kv_num_pages, or shed to another replica")
         return [self._free.pop() for _ in range(need)]
 
-    def alloc(self, seq_id: int, n_tokens: int) -> List[int]:
+    def alloc(self, seq_id: int, n_tokens: int,
+              first_page: int = 0) -> List[int]:
         """Reserve pages for a sequence of up to ``n_tokens``. Raises
         ``ServerOverloaded`` (the pool IS the admission bound) without
-        side effects when short."""
-        need = self.pages_for_tokens(n_tokens)
+        side effects when short. ``first_page`` > 0 reserves from that
+        logical page on (a window kind's sequence coming back from a
+        spill: what lay before it was given back long ago)."""
+        first_page = int(first_page)
+        need = max(1, self.pages_for_tokens(n_tokens) - first_page)
         with self._mu:
             if seq_id in self._owner:
                 raise ValueError(f"sequence {seq_id} already has pages")
             pages = self._take_locked(need, f"{n_tokens} tokens")
             self._owner[seq_id] = pages
             self._tokens[seq_id] = 0
+            if first_page:
+                self._head[seq_id] = first_page
             _m_allocs.inc(need)
             self._publish_locked()
             return list(pages)
@@ -787,6 +815,34 @@ class PageAllocator:
                 self._publish_locked()
             return freed
 
+    def release_head(self, seq_id: int, upto_page: int) -> int:
+        """Return to the free list every page of a live sequence BEFORE
+        logical page ``upto_page`` — the pages a window layer can no
+        longer see (ISSUE 34). The sequence keeps at least one page; its
+        ``head`` moves up by what was freed. Returns how many."""
+        with self._mu:
+            pages = self._owner.get(seq_id)
+            if pages is None:
+                raise ValueError(f"sequence {seq_id} holds no pages")
+            head = self._head.get(seq_id, 0)
+            take = max(0, min(int(upto_page) - head, len(pages) - 1))
+            if take:
+                # oldest first onto the stack: the next grow takes the
+                # newest-freed, as free() leaves them
+                self._free.extend(pages[:take])
+                del pages[:take]
+                self._head[seq_id] = head + take
+                _m_window_released.inc(take)
+                _m_frees.inc(take)
+                self._publish_locked()
+            return take
+
+    def head(self, seq_id: int) -> int:
+        """The logical page index of the sequence's first held page (0
+        unless ``release_head`` moved it or ``alloc`` began past it)."""
+        with self._mu:
+            return self._head.get(seq_id, 0)
+
     def publish(self, seq_id: int, prompt: Sequence[int]) -> int:
         """Publish a sequence's completed prompt pages into the prefix
         index (no-op without prefix caching). Metadata only — the K/V
@@ -841,6 +897,7 @@ class PageAllocator:
         with self._mu:
             pages = self._owner.pop(seq_id, None)
             self._total_tokens -= self._tokens.pop(seq_id, 0)
+            self._head.pop(seq_id, None)
             if not pages:
                 return 0
             freed = 0
@@ -873,6 +930,17 @@ class PageAllocator:
             row = np.full((width,), GARBAGE_PAGE, dtype=np.int32)
             self._fill_row_locked(seq_id, row)
             return row
+
+    def table_starts(self, seq_ids: Sequence[int], rows: int) -> np.ndarray:
+        """``[rows]`` int32: the logical page each sequence's table row
+        begins at (its ``head``; 0 for dead rows and unknown sequences)
+        — what a windowed ``paged_attention`` call takes beside the
+        tables."""
+        out = np.zeros((int(rows),), np.int32)
+        with self._mu:
+            for i, sid in enumerate(seq_ids):
+                out[i] = self._head.get(sid, 0)
+        return out
 
     def table_rows(self, seq_ids: Sequence[int], width: int,
                    rows: int) -> np.ndarray:

@@ -560,14 +560,41 @@ def test_paged_kernel_compiles_for_v5e(v5e, chunk, hq, hkv, dtype,
         _on(d0, (b,), jnp.int32)).compile()
 
 
-@pytest.mark.parametrize("rows", [512, 2048], ids=["block_c4", "block_c16"])
-def test_moe_gmm_compiles_for_v5e(v5e, rows):
-    """The served expert geometry (128 experts of 2048 x 768, bfloat16,
-    top-8) at both step shapes of 16 slots: the gated gate-and-up product
-    written in bfloat16, then ``down`` in float32, whole 3 MB panels."""
+@pytest.mark.parametrize("window_cols,chunk", [(133, 1), (133, 64)],
+                         ids=["window_c1", "window_c64"])
+def test_windowed_paged_kernel_compiles_for_v5e(v5e, window_cols, chunk):
+    """The window layers' geometry of ``trinity_mini_longmix`` (ISSUE 34):
+    32 query / 4 kv heads of 128, bfloat16, window 2048 over a table of
+    133 columns that starts at ``table_starts``, a decode token and a
+    prefill chunk of 64 lanes."""
+    from paddle_tpu.fluid.ops.pallas_kernels.paged_attention import (
+        _paged_attention_pallas)
+
+    b, d, ps, pages = 16, 128, 16, 256
+    d0 = v5e[0]
+    text = jax.jit(lambda q, k, v, t, n, m, s: _paged_attention_pallas(
+        q, k, v, t, n, q_lens=m, window=2048, table_starts=s)).lower(
+        _on(d0, (b, chunk, 32, d), jnp.bfloat16),
+        _on(d0, (pages, ps, 4, d), jnp.bfloat16),
+        _on(d0, (pages, ps, 4, d), jnp.bfloat16),
+        _on(d0, (b, window_cols), jnp.int32), _on(d0, (b,), jnp.int32),
+        _on(d0, (b,), jnp.int32), _on(d0, (b,), jnp.int32)
+    ).compile().as_text()
+    assert "paged_attention" in text
+
+
+@pytest.mark.parametrize("rows,f", [(512, 768), (2048, 768), (128, 1024),
+                                    (8192, 1024)],
+                         ids=["block_c4", "block_c16", "afmoe_c1",
+                              "afmoe_c64"])
+def test_moe_gmm_compiles_for_v5e(v5e, rows, f):
+    """The served expert geometries (128 experts of 2048 x 768 and, ISSUE
+    34, of 2048 x 1024, bfloat16, top-8) at the step shapes of 16 slots:
+    the gated gate-and-up product written in bfloat16, then ``down`` in
+    float32, whole 3 and 4 MB panels."""
     from paddle_tpu.fluid.ops.pallas_kernels.moe_gmm import moe_gmm
 
-    e, d, f = 128, 2048, 768
+    e, d = 128, 2048
     d0 = v5e[0]
 
     def experts(xs, gate, up, down, counts):

@@ -9,7 +9,9 @@ and `paged_attn_grid_live_pct` (ISSUE 31: data only, the program's histogram
 
 The benchmark's own tests live in `perf/tests` and are not collected by
 the tier-1 command; this case is, so that a tree whose BENCHMARK.json no
-longer loads with the metric fails here.
+longer loads with the metric fails here. ISSUE 34's two cells
+(`xglm17b_docqa`, `trinity_mini_longmix`), its configuration, its four
+metrics and its operations file are the last section.
 """
 import os
 import sys
@@ -41,12 +43,19 @@ def test_a_histogram_metric_loads_and_reads_the_histograms_avg(bench, name):
     histogram, layer = HISTOGRAM_AVG[name]
     bench.check_files()
     entry, = [m for m in bench.doc["per_layer"] if m["name"] == name]
+    # ISSUE 34 appended its two cells to the list, and its four metrics
+    # behind the last of these
     assert entry == {
         "name": name, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": layer,
         "moves": "serve_tokens_per_s",
-        "workloads": [CELL, "sdar30b_blockgen"]}
-    assert bench.doc["per_layer"][-1]["name"] == "paged_attn_grid_live_pct"
+        "workloads": [CELL, "sdar30b_blockgen", "xglm17b_docqa",
+                      "trinity_mini_longmix"]}
+    assert [m["name"] for m in bench.doc["per_layer"]].index(
+        "paged_attn_grid_live_pct") == 18
+    assert [m["name"] for m in bench.doc["per_layer"]][19:] == [
+        "kv_prefix_hit_pct", "paged_attn_window_roofline",
+        "attn_window_skip_pct", "kv_window_held_pct"]
     # data only: no reader of its own, and the training cell is not asked
     assert not os.path.exists(bench.path("layer_metrics", name + ".py"))
     assert name not in [m["name"] for m, _d in
@@ -144,9 +153,14 @@ def test_the_new_cell_and_configuration_are_appended_and_their_files_exist(
 def test_a_new_metric_loads_and_reads_what_its_file_says(bench, name):
     unit, better, source, layer, moves = NEW[name]
     entry, = [m for m in bench.doc["per_layer"] if m["name"] == name]
+    # ISSUE 34's cell has experts too and is appended to their lists; the
+    # block cell's own two stay its own
+    cells = ([NEW_CELL] if name in ("block_tokens_per_pass",
+                                    "paged_attn_block_roofline")
+             else [NEW_CELL, "trinity_mini_longmix"])
     assert entry == {"name": name, "unit": unit, "better": better,
                      "source": source, "layer": layer, "moves": moves,
-                     "workloads": [NEW_CELL]}
+                     "workloads": cells}
     assert bench.doc["per_layer"].index(entry) >= 13    # appended
     (_m, desc), = [(m, d) for m, d in bench.per_layer(NEW_CELL)
                    if m["name"] == name]
@@ -328,3 +342,168 @@ def test_the_xplane_reader_keeps_an_annotations_args(tmp_path):
     assert len(events) == 1 and events[0]["dur_ns"] > 0
     assert events[0]["stats"] == {"slots": 16, "moe_assignments": 3072,
                                   "kind": "pass"}
+
+
+# --- ISSUE 34: xglm17b_docqa, trinity-mini / trinity_mini_longmix ----------
+
+LONGMIX, DOCQA = "trinity_mini_longmix", "xglm17b_docqa"
+PR34 = {"kv_prefix_hit_pct": ("higher", "program_counter", "KV manager",
+                              DOCQA),
+        "paged_attn_window_roofline": ("higher", "device_trace", "kernels",
+                                       LONGMIX),
+        "attn_window_skip_pct": ("higher", "program_counter", "kernels",
+                                 LONGMIX),
+        "kv_window_held_pct": ("lower", "program_counter", "KV manager",
+                               LONGMIX)}
+PEAKS = {"bf16_flops_per_s": 1.97e14, "hbm_bytes_per_s": 8.19e11}
+
+
+def test_the_two_cells_and_the_configuration_are_appended(bench):
+    bench.check_files()
+    assert [w["name"] for w in bench.doc["workloads"]] == [
+        "xglm17b_chat", "resnet50_train", "sdar30b_blockgen", DOCQA,
+        LONGMIX]
+    assert all(w["chips"] == 1 for w in bench.doc["workloads"])
+    entry = bench.doc["configs"][3]
+    assert (entry["name"], entry["reduced"]) == (
+        "trinity-mini", ["num_hidden_layers", "num_dense_layers",
+                         "layer_types"])
+    cfg = bench.config("trinity-mini")
+    # every published width unchanged; depth the one cut
+    assert (cfg["hidden_size"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["head_dim"],
+            cfg["intermediate_size"], cfg["moe_intermediate_size"],
+            cfg["num_experts"], cfg["num_experts_per_tok"],
+            cfg["num_shared_experts"], cfg["vocab_size"],
+            cfg["sliding_window"], cfg["route_scale"]) == (
+        2048, 32, 4, 128, 6144, 1024, 128, 8, 1, 200192, 2048, 2.826)
+    assert (cfg["num_hidden_layers"], cfg["num_hidden_layers_published"],
+            cfg["num_dense_layers"], cfg["num_dense_layers_published"]) == (
+        5, 32, 1, 2)
+    assert cfg["layer_types"] == ["sliding_attention"] * 4 + [
+        "full_attention"]
+    assert set(bench.end_to_end(LONGMIX)) == {
+        "serve_tokens_per_s", "token_gap_p95_ms", "setup_s"}
+    cell = bench.cell(LONGMIX)
+    assert cell["engine"] == {
+        "slots": [16], "page_size": 16, "num_pages": 9472,
+        "num_window_pages": 2304, "max_seq_len": 9216, "prefill_chunk": 64}
+    names = [m["name"] for m, _d in bench.per_layer(LONGMIX)]
+    assert {"serve_mfu_pct", "moe_experts_roofline", "moe_route_share_pct",
+            "moe_load_max_over_mean", "paged_attn_grid_live_pct",
+            "device_idle_pct.serve"} <= set(names)
+    assert "paged_attn_block_roofline" not in names
+    assert "paged_attn_roofline" not in names
+    docqa = bench.cell(DOCQA)
+    assert docqa["engine"] == {"slots": [2], "page_size": 16,
+                               "num_pages": 1024, "max_seq_len": 2048}
+    assert (docqa["traffic"]["clients"],
+            docqa["traffic"]["requests_per_session"]) == (2, 3)
+    assert "kv_prefix_hit_pct" in [m["name"] for m, _d in
+                                   bench.per_layer(DOCQA)]
+    # nothing of the new cells is asked of the cells that were there
+    for old in ("xglm17b_chat", "resnet50_train", "sdar30b_blockgen"):
+        assert not set(PR34) & {m["name"] for m, _d in bench.per_layer(old)}
+
+
+@pytest.mark.parametrize("name", sorted(PR34))
+def test_a_metric_of_issue_34_loads_and_reads_what_its_file_says(bench,
+                                                                 name):
+    better, source, layer, cell = PR34[name]
+    entry, = [m for m in bench.doc["per_layer"] if m["name"] == name]
+    assert {k: entry[k] for k in ("unit", "better", "source", "layer",
+                                  "workloads")} == {
+        "unit": "%", "better": better, "source": source, "layer": layer,
+        "workloads": [cell]}
+    assert bench.doc["per_layer"].index(entry) >= 19        # appended
+    assert entry["moves"] in bench.end_to_end(cell)
+    (_m, desc), = [(m, d) for m, d in bench.per_layer(cell)
+                   if m["name"] == name]
+    assert (desc["name"], desc["unit"], desc["layer"], desc["moves"]) == (
+        name, "%", layer, entry["moves"])
+    cfg = bench.config("trinity-mini")
+    # the parent's case: no histogram, counter, scope or span argument
+    assert bench.read_layer_metric(entry, desc, {
+        "histograms": {}, "counters": {}, "config": cfg, "peaks": PEAKS,
+        "trace": None, "moe_trace": {"attn_s": 0.01, "calls": [
+            {"q_tokens": 16, "kv_tokens": 100, "attn_pairs": 100}]}}) is None
+    if desc["reader"] == "histogram":
+        assert desc["histogram"] in ("serving.decode.attn_window_skip_pct",
+                                     "serving.kv.window.held_pct")
+        facts = {"histograms": {desc["histogram"]: {
+            "count": 10, "avg": 41.5, "p50": 40.0, "sum": 415.0}}}
+        assert bench.read_layer_metric(entry, desc, facts) == 41.5
+    elif name == "kv_prefix_hit_pct":
+        facts = {"counters": {"serving.prefix.cached_tokens": 30000},
+                 "prompt_tokens_submitted": 48000}
+        assert bench.read_layer_metric(entry, desc, facts) == 62.5
+    else:
+        # a made-up trace whose time IS the roofline's own: one decode
+        # step of 16 slots at 4000 keys each, bytes bound in both kinds
+        from perf.lib import flops_afmoe
+
+        call = {"q_tokens": 16, "kv_tokens_full": 64000,
+                "attn_pairs_full": 64000, "kv_tokens_window": 32768,
+                "attn_pairs_window": 32768}
+        full = (2 * 4 * 128 * 64000 + 2 * 32 * 128 * 16) * 2 / 8.19e11
+        window = (2 * 4 * 128 * 32768 + 2 * 32 * 128 * 16) * 2 / 8.19e11
+        least = flops_afmoe.attention_step_least_s(cfg, call, PEAKS)
+        assert least == pytest.approx(full + 4 * window)
+        read = lambda seconds: bench.read_layer_metric(entry, desc, {
+            "moe_trace": {"attn_s": seconds, "calls": [call, {}]},
+            "config": cfg, "peaks": PEAKS})
+        assert read(least) == pytest.approx(100.0)
+        assert 0 < read(3 * least) < 100
+        # one layer's sums times num_hidden_layers would read 5x the full
+        # kind's: over 100 at the roofline's own time
+        assert 5 * full > least
+
+
+def test_operations_and_bytes_of_the_window_model():
+    from paddle_tpu.models.afmoe import TINY_CONFIG, AfmoeSpec
+    from perf.lib import flops_afmoe as fa
+
+    cfg = Benchmark(ROOT).config("trinity-mini")
+    assert fa.param_count(cfg) == cfg["sizes"]["parameters"] == sum(
+        int(__import__("numpy").prod(s))
+        for s in AfmoeSpec.from_config(cfg).tensors().values())
+    assert fa.kv_bytes_per_token(cfg) == (2048, 4 * 2048)
+    # the tiny preset by hand: d 64, 4/2 heads of 16, dense 96, experts 32,
+    # 8 experts top-2 + 1 shared, 1 dense + 4 expert layers, window 8
+    tiny = dict(TINY_CONFIG, precision=cfg["precision"])
+    spec = AfmoeSpec.from_config(TINY_CONFIG)
+    assert fa.param_count(tiny) == sum(
+        int(__import__("numpy").prod(s)) for s in spec.tensors().values())
+    proj = 2 * (64 * 64 * 3 + 64 * 32 * 2)
+    lane = 5 * proj + 2 * 3 * 64 * 96 + 4 * (
+        2 * 64 * 8 + (2 + 1) * 2 * 3 * 64 * 32)
+    assert fa.lane_matmul_flops(tiny) == lane
+    # positions 6..11: the full layer sees 7..12 keys, a window layer
+    # 7, 8, 8, 8, 8, 8; one lane unembedded
+    keys_full, keys_window = sum(range(7, 13)), 7 + 5 * 8
+    assert fa.span_flops(tiny, 6, 12, 1) == (
+        6 * lane + 4 * 4 * 16 * (keys_full + 4 * keys_window)
+        + 2 * 64 * 128)
+    assert fa.span_flops(tiny, 0, 3, 0) == 3 * lane + 4 * 4 * 16 * 5 * 6
+    ops, nbytes = fa.attention_call_cost(cfg, 16, 32768, 32768)
+    assert ops == 4 * 32 * 128 * 32768
+    assert nbytes == (2 * 4 * 128 * 32768 + 2 * 32 * 128 * 16) * 2
+    # the accepted experts' reader takes its cost from this file's keys
+    from perf.lib import flops_moe
+
+    ops, nbytes = flops_moe.experts_call_cost(cfg, 512, 300)
+    assert ops == 512 * 2 * 3 * 2048 * 1024
+    assert nbytes == (300 * 3 * 2048 * 1024 + 512 * 2 * 2048) * 2
+
+
+def test_the_program_registers_what_issue_34s_metrics_read():
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.serving import decode  # noqa: F401  (registers them)
+
+    snap = metrics.snapshot("serving.")
+    for name in ("serving.kv.window.held_pct",
+                 "serving.decode.attn_window_skip_pct"):
+        assert isinstance(snap[name], dict)
+    for name in ("serving.kv.window.pages_released",
+                 "serving.prefix.cached_tokens"):
+        assert name in snap
