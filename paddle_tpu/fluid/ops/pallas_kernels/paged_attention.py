@@ -54,6 +54,28 @@ tests/test_decode_serving.py):
     ``0 .. q_len - 1`` only. What lies past either length cannot reach
     the output, NaN included.
 
+Two FOLDS of a live page into that one online softmax (ISSUE 35), and
+``folds_by_dot`` says which a slot takes, from what the call carries:
+
+  - the LANE LOOP walks the slot's lanes one at a time: a lane's scores
+    are a broadcast multiply and a reduce over D, ``p . v`` another over
+    the page's rows, all float32 on the VPU with K and V repeated to the
+    query heads. A slot with few lanes takes it (every decoding slot,
+    ``q_len`` 1: one lane costs the latency of the page's fetch), and it
+    is the only fold in a program whose ``C * rep`` is too small for a
+    product to pay (``DOT_MIN_ROWS``): that program is traced as it
+    always was.
+  - the DOT FOLD takes all of a slot's lanes against the page at once, a
+    kv head at a time: the head's ``C * rep`` query rows (no repeat of K
+    or V: the head group is the product's row axis) against the page's
+    keys in one product on the MXU, ``p . v`` in a second, float32
+    accumulation, the mask and the statistics as the lane loop's. No
+    operand is narrowed: bfloat16 times bfloat16 is exact in float32 in
+    one pass, and ``p`` (float32) goes as the three bfloat16 terms that
+    hold its 24 bits (``_mxu``). A slot of ``DOT_MIN_LANES`` lanes or
+    more takes it: a prefill chunk, whose cost a page then does not grow
+    with its lanes.
+
 A WINDOW (ISSUE 34, ``window=`` static; ``None`` = none, the programs
 that pass none are the programs they were): a query lane at position
 ``p`` sees keys in ``(p - window, p]``. The table of such a call STARTS
@@ -87,6 +109,7 @@ pools are sharded over a mesh names the reference itself
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -99,7 +122,7 @@ from ....observability import metrics as _metrics
 NEG_INF = -1e30
 
 __all__ = ["paged_attention", "paged_attention_reference",
-           "paged_route"]
+           "paged_route", "folds_by_dot"]
 
 # trace-time routing counters (this function body runs once per
 # compiled shape, n_layers times per decoder trace — not per step):
@@ -175,6 +198,63 @@ def _table_starts(table_starts, b: int):
     return table_starts.astype(jnp.int32)
 
 
+# --- which fold a slot's live pages take (ISSUE 35) -----------------------
+# Measured on the kernel alone on a v5e (PERF.md section 6, PR 35).
+# DOT_MIN_LANES: the live lanes at which a slot takes the dot fold: a page
+# costs it the same whatever its lanes, the lane loop 0.4 us more a lane,
+# and they cross between two lanes and three. DOT_MIN_ROWS: the query rows
+# ``C * rep`` a kv head's product needs before the dot fold is traced at
+# all, by the pools' dtype. bfloat16: a block of four lanes on a head
+# group of eight already wins three to one. float32 products run at
+# HIGHEST, six passes each: at the dense family's 16 rows they win at a
+# full chunk and lose at eight lanes, and ISSUE 35 holds that geometry to
+# the program it has; the number keeps every served float32 geometry
+# there and lets tier-1 run the float32 products.
+DOT_MIN_LANES = 3
+DOT_MIN_ROWS = {"bfloat16": 32, "float32": 512}
+
+
+def folds_by_dot(c: int, rep: int, dtype, q_len=None):
+    """Whether a slot's live pages are folded by matrix products (or lane
+    by lane), from what a call carries: its chunk width ``c``, head group
+    ``rep = Hq // Hkv`` and pools' ``dtype``, which decide when the
+    program is traced whether the dot fold exists in it at all
+    (``q_len=None`` asks that alone), and the slot's ``q_len`` (a traced
+    scalar in the kernel, a numpy vector in the engine's counter). The
+    kernel and ``serving.decode.attn_dot_fold_pct`` both ask here, so
+    that what is counted is what ran."""
+    traced = c >= DOT_MIN_LANES and c * rep >= DOT_MIN_ROWS.get(
+        jnp.dtype(dtype).name, math.inf)
+    if q_len is None:
+        return traced
+    return (q_len >= DOT_MIN_LANES) & traced
+
+
+def _mxu(a, b, contract):
+    """``a . b`` over ``contract`` (one axis of each) on the MXU, float32
+    accumulation, neither operand narrowed. Two bfloat16 operands
+    multiply exactly in float32 in one pass. A float32 ``b`` beside a
+    bfloat16 ``a`` goes as the three bfloat16 terms that hold its 24
+    bits, stacked along the contracted axis beside ``a`` three times:
+    one product, every partial product exact. Anything else goes as
+    float32 at HIGHEST precision."""
+    bf16, dims = jnp.bfloat16, (contract, ((), ()))
+    if a.dtype == bf16 and b.dtype == jnp.float32:
+        terms = []
+        for _ in range(3):
+            terms.append(b.astype(bf16))
+            b = b - terms[-1].astype(jnp.float32)
+        a = jnp.concatenate([a] * 3, axis=contract[0][0])
+        b = jnp.concatenate(terms, axis=contract[1][0])
+    if a.dtype == b.dtype == bf16:
+        return jax.lax.dot_general(a, b, dims,
+                                   preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(
+        a.astype(jnp.float32), b.astype(jnp.float32), dims,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
 def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
                               *, q_lens=None,
                               scale: Optional[float] = None,
@@ -185,10 +265,12 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
     query's visibility limit (and behind its ``window``, with column 0 at
     logical page ``table_starts``), dense softmax. Same signature/semantics
     as the kernel. Returns the same rank as ``q``. Its two dots run at
-    HIGHEST precision: the kernel multiplies in float32 on the VPU, and
-    a float32 oracle that let the TPU's default single bf16 pass stand
-    in for float32 could not be compared with it (nor serve the same
-    tokens where the engine names it under a mesh)."""
+    HIGHEST precision: the kernel's lane loop multiplies in float32 on
+    the VPU and its dot fold narrows no operand of its products on the
+    MXU (module docstring), and a float32 oracle that let the TPU's
+    default single bf16 pass stand in for float32 could not be compared
+    with either (nor serve the same tokens where the engine names it
+    under a mesh)."""
     b, c, hq, d, ps, hkv, w = _check_shapes(q, k_pages, v_pages,
                                             page_tables, kv_lens, q_lens)
     squeeze = q.ndim == 3
@@ -234,7 +316,7 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, kv_lens,
 
 
 def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, *refs, scale,
-                  page_size, rep, block_length, window=None):
+                  page_size, rep, block_length, window=None, dot=None):
     """One (sequence b, table column w) grid step: fold this page's keys
     into the running online softmax of the slot's LIVE query lanes. W
     iterates innermost (TPU grids run sequentially), so the scratch
@@ -247,10 +329,15 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, *refs, scale,
     pays for one lane. A lane or a slot nothing was folded into keeps
     the zero accumulator and emits exact zeros.
 
-    The page stays ``[ps, H, D]`` as it lies in the pool: a lane's
-    scores are a reduce over D of ``q[None] * k`` (``[ps, H, 1]``), and
-    the softmax statistics and ``p . v`` reduce over the page's rows,
-    the LEADING axis — plain adds of whole registers, no transpose.
+    Two folds (ISSUE 35), one online softmax: ``_fold_lanes`` walks the
+    slot's lanes one at a time on the VPU; ``_fold_dot`` takes all of a
+    slot's lanes against the page in two matrix products a kv head. A
+    program whose geometry ``folds_by_dot`` rejects (``dot`` is None)
+    traces the lane loop alone, as it always did; in one that it accepts
+    (``dot`` is the static triple ``(c, rep, dtype)``) each slot takes,
+    for all its pages, the fold the same predicate names for its
+    ``q_len``, and the two folds have their own operands, scratch and
+    output (``_paged_attention_pallas`` says how they are joined).
 
     Under a ``window`` a fourth prefetched vector, the logical page of
     each slot's column 0, leads ``refs``: column ``w`` is page
@@ -259,15 +346,22 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, *refs, scale,
     in ``(pos - window, pos]``."""
     if window is not None:
         starts_ref, *refs = refs
-    q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc = refs
+    if dot is None:
+        q_ref, k_ref, v_ref, o_ref, *lane_sc = refs
+        folds = [(lane_sc, o_ref)]
+    else:
+        (q_ref, qg_ref, k_ref, v_ref, o_ref, og_ref, *sc) = refs
+        lane_sc, dot_sc = sc[:3], sc[3:]
+        folds = [(lane_sc, o_ref), (dot_sc, og_ref)]
     w = pl.program_id(1)
     nw = pl.num_programs(1)
 
     @pl.when(w == 0)
     def _init():
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
+        for (m_sc, l_sc, acc_sc), _out in folds:
+            m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+            l_sc[...] = jnp.zeros_like(l_sc)
+            acc_sc[...] = jnp.zeros_like(acc_sc)
 
     b = pl.program_id(0)
     kv_len = kv_lens_ref[b]
@@ -281,8 +375,14 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, *refs, scale,
             (first_page + 1) * page_size
             > _window_floor(kv_len, q_len, window))
 
-    @pl.when(live)
-    def _fold():
+    def _fold_lanes():
+        """The page stays ``[ps, H, D]`` as it lies in the pool, K and V
+        repeated to the query heads: a lane's scores are a reduce over D
+        of ``q[None] * k`` (``[ps, H, 1]``), and the softmax statistics
+        and ``p . v`` reduce over the page's rows, the LEADING axis —
+        plain adds of whole registers, no transpose. All float32 on the
+        VPU."""
+        m_sc, l_sc, acc_sc = lane_sc
         k = k_ref[0].astype(jnp.float32)              # [ps, Hkv, D]
         v = v_ref[0].astype(jnp.float32)
         if rep > 1:
@@ -316,10 +416,55 @@ def _paged_kernel(tables_ref, kv_lens_ref, q_lens_ref, *refs, scale,
 
         jax.lax.fori_loop(0, q_len, _lane, 0)
 
+    def _fold_dot():
+        """All of the slot's lanes against the page, a kv head at a
+        time: the head's ``N = C * rep`` query rows (lane ``c``, query
+        head ``g * rep + r`` is row ``c * rep + r``; no repeat of K or
+        V) lie along the LANES of everything the fold keeps, so the
+        scores are ``[ps, N]``, the statistics ``[1, N]`` and the
+        accumulator ``[D, N]``, transposed once a call by the wrapper.
+        Two products on the MXU with float32 accumulation and no
+        operand narrowed (``_mxu``); mask and statistics as the lane
+        loop's, vectorised over lanes."""
+        m_sc, l_sc, acc_sc = dot_sc
+        n = acc_sc.shape[-1]
+        # [Hkv, ps, D]: a head's keys as one matrix
+        k = jnp.swapaxes(k_ref[0].astype(jnp.float32), 0, 1)
+        v = jnp.swapaxes(v_ref[0].astype(jnp.float32), 0, 1)
+        offs = first_page * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (page_size, 1), 0)             # [ps, 1]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) // rep
+        keep = (offs <= _key_limit(kv_len, q_len, lane, block_length)) & (
+            lane < q_len)                             # [ps, N]
+        if window is not None:
+            keep &= offs > kv_len - q_len + lane - window
+        for g in range(k.shape[0]):
+            # s[p, n] = k[p, :] . q[n, :]
+            s = _mxu(k[g].astype(k_ref.dtype), qg_ref[0, g],
+                     ((1,), (1,))) * scale            # [ps, N]
+            s = jnp.where(keep, s, NEG_INF)
+            m_old = m_sc[g]                           # [1, N]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m_old - m_new)
+            p = jnp.exp(s - m_new) * keep             # [ps, N]
+            m_sc[g] = m_new
+            l_sc[g] = l_sc[g] * alpha + jnp.sum(p, axis=0, keepdims=True)
+            # pv[d, n] = sum_p v[p, d] * p[p, n]
+            acc_sc[g] = acc_sc[g] * alpha + _mxu(
+                v[g].astype(v_ref.dtype), p, ((0,), (0,)))
+
+    if dot is None:
+        pl.when(live)(_fold_lanes)
+    else:
+        @pl.when(live)
+        def _fold():
+            jax.lax.cond(folds_by_dot(*dot, q_len), _fold_dot, _fold_lanes)
+
     @pl.when(w == nw - 1)
     def _emit():
-        l = jnp.maximum(l_sc[...], jnp.finfo(jnp.float32).tiny)
-        o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
+        for (_m, l_sc, acc_sc), out in folds:
+            l = jnp.maximum(l_sc[...], jnp.finfo(jnp.float32).tiny)
+            out[0] = (acc_sc[...] / l).astype(out.dtype)
 
 
 def _window_floor(kv_len, q_len, window: int):
@@ -358,60 +503,103 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_tables, kv_lens,
                             block_length: int = 1,
                             window: Optional[int] = None,
                             table_starts=None):
-    b, c, hq, d, ps, hkv, w = _check_shapes(q, k_pages, v_pages,
-                                            page_tables, kv_lens, q_lens)
+    b, c, hq, d, _ps, hkv, _w = _check_shapes(q, k_pages, v_pages,
+                                              page_tables, kv_lens, q_lens)
     squeeze = q.ndim == 3
     q, q_lens = _canon_chunked(q, kv_lens, q_lens)
-    scale = float(scale) if scale else d ** -0.5
+    # a program that holds the dot fold lowers three times as slowly: its
+    # layers share one trace and one lowering (as moe_gmm's do)
+    call = (_paged_call_shared if folds_by_dot(c, hq // hkv, k_pages.dtype)
+            else _paged_call)
+    out = call(q, k_pages, v_pages, page_tables.astype(jnp.int32),
+               kv_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
+               None if window is None else _table_starts(table_starts, b),
+               scale=float(scale) if scale else d ** -0.5,
+               interpret=interpret, block_length=int(block_length),
+               window=None if window is None else int(window))
+    return out[:, 0] if squeeze else out
+
+
+def _paged_call(q, k_pages, v_pages, tables, kv_l, q_l, starts, *, scale,
+                interpret, block_length, window):
+    """The kernel's call on canonical operands: ``q [B, C, Hq, D]``, int32
+    tables and lengths, ``starts`` a windowed call's ``table_starts``."""
+    b, c, hq, d = q.shape
+    _pages, ps, hkv, _d = k_pages.shape
+    w = tables.shape[1]
     rep = hq // hkv
-    kv_l = kv_lens.astype(jnp.int32)
-    q_l = q_lens.astype(jnp.int32)
     if window is None:
-        prefetch = (_live_columns(page_tables.astype(jnp.int32), kv_l, ps),
-                    kv_l, q_l)
+        prefetch = (_live_columns(tables, kv_l, ps), kv_l, q_l)
     else:
-        starts = _table_starts(table_starts, b)
-        prefetch = (_live_columns(page_tables.astype(jnp.int32), kv_l, ps,
-                                  starts, _window_floor(kv_l, q_l,
-                                                        int(window))),
+        prefetch = (_live_columns(tables, kv_l, ps, starts,
+                                  _window_floor(kv_l, q_l, window)),
                     kv_l, q_l, starts)
+    dot = (c, rep, k_pages.dtype)
+    if not folds_by_dot(*dot):
+        dot = None
+    # the lane loop's operands: q and the output as the caller has them,
+    # the lane the leading index, so the fold takes one lane's [Hq, .]
+    # slab by a dynamic first-axis index. In a program that has the dot
+    # fold the loop walks slots of under DOT_MIN_LANES lanes only, and its
+    # operands are cut to those
+    cl = c if dot is None else min(c, DOT_MIN_LANES - 1)
+    by_slot = lambda *block: pl.BlockSpec(
+        (1,) + block, lambda bb, ww, *_: (bb,) + (0,) * len(block))
+    # THE paged read: the index map picks each sequence's w-th live page
+    # out of the pool (_live_columns)
+    page = pl.BlockSpec((1, ps, hkv, d),
+                        lambda bb, ww, t, *_: (t[bb, ww], 0, 0, 0))
+    operands, in_specs = [q if cl == c else q[:, :cl]], [by_slot(cl, hq, d)]
+    out_specs = [by_slot(cl, hq, d)]
+    out_shape = [jax.ShapeDtypeStruct((b, cl, hq, d), q.dtype)]
+    scratch = [pltpu.VMEM((cl, hq, 1), jnp.float32),    # running max
+               pltpu.VMEM((cl, hq, 1), jnp.float32),    # running sum
+               pltpu.VMEM((cl, hq, d), jnp.float32)]    # accumulator
+    if dot is not None:
+        # the dot fold's: a kv head's C * rep query rows as one matrix
+        # (row c * rep + r), and its output with those rows along the
+        # lanes, [Hkv, D, N]: both turned here, once a call, by XLA
+        n = c * rep
+        operands.append(q.reshape(b, c, hkv, rep, d).transpose(
+            0, 2, 1, 3, 4).reshape(b, hkv, n, d))
+        in_specs.append(by_slot(hkv, n, d))
+        out_specs.append(by_slot(hkv, d, n))
+        out_shape.append(jax.ShapeDtypeStruct((b, hkv, d, n), q.dtype))
+        scratch += [pltpu.VMEM((hkv, 1, n), jnp.float32),
+                    pltpu.VMEM((hkv, 1, n), jnp.float32),
+                    pltpu.VMEM((hkv, d, n), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # page_tables, kv_lens, q_lens (and a window's table_starts) in
         # SMEM
         num_scalar_prefetch=len(prefetch),
         grid=(b, w),
-        in_specs=[
-            pl.BlockSpec((1, c, hq, d), lambda bb, ww, *_: (bb, 0, 0, 0)),
-            # THE paged read: the index map picks each sequence's w-th
-            # live page out of the pool (_live_columns)
-            pl.BlockSpec((1, ps, hkv, d),
-                         lambda bb, ww, t, *_: (t[bb, ww], 0, 0, 0)),
-            pl.BlockSpec((1, ps, hkv, d),
-                         lambda bb, ww, t, *_: (t[bb, ww], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, c, hq, d),
-                               lambda bb, ww, *_: (bb, 0, 0, 0)),
-        # the lane is the leading index, so the fold takes one lane's
-        # [Hq, .] slab by a dynamic first-axis index
-        scratch_shapes=[
-            pltpu.VMEM((c, hq, 1), jnp.float32),    # running max
-            pltpu.VMEM((c, hq, 1), jnp.float32),    # running sum
-            pltpu.VMEM((c, hq, d), jnp.float32),    # output accumulator
-        ],
+        in_specs=in_specs + [page, page],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
     )
     kernel = functools.partial(
         _paged_kernel, scale=scale, page_size=ps, rep=rep,
-        block_length=int(block_length),
-        window=None if window is None else int(window))
-    out = pl.pallas_call(
+        block_length=block_length, window=window, dot=dot)
+    out, *by_dot = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, hq, d), q.dtype),
+        out_shape=out_shape,
         interpret=interpret,
         # the kernel's name in the compiled program and a device trace
         name="paged_attention",
-    )(*prefetch, q, k_pages, v_pages)
-    return out[:, 0] if squeeze else out
+    )(*prefetch, *operands, k_pages, v_pages)
+    if by_dot:
+        # each slot's output is the one its fold wrote
+        out = jnp.where(
+            folds_by_dot(*dot, q_l)[:, None, None, None],
+            by_dot[0].reshape(b, hkv, d, c, rep).transpose(
+                0, 3, 1, 4, 2).reshape(b, c, hq, d),
+            jnp.pad(out, ((0, 0), (0, c - cl), (0, 0), (0, 0))))
+    return out
+
+
+_paged_call_shared = jax.jit(_paged_call, static_argnames=(
+    "scale", "interpret", "block_length", "window"))
 
 
 def paged_route(slots: int, impl: Optional[str] = None) -> str:

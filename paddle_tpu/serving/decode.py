@@ -194,6 +194,12 @@ _m_occupancy = _metrics.histogram("serving.decode.occupancy")
 # the paged kernel walks every (slot, column) and skips the empty ones,
 # so the rest is what a dynamic grid would still save
 _m_attn_grid_live = _metrics.histogram("serving.decode.attn_grid_live_pct")
+# which fold the paged kernel's live pages take (ISSUE 35), beside it: 100 x
+# the (lane, live page) folds of a step call that the kernel's dot fold
+# takes (a slot's lanes against a page in two matrix products) over all of
+# the call's, by the kernel's own predicate; 0 for a call whose attention
+# is not the kernel's
+_m_attn_dot_fold = _metrics.histogram("serving.decode.attn_dot_fold_pct")
 # chunked prefill (ISSUE 10): prompt tokens consumed via prefill
 # grants, per-step grant totals (prices the token-budget policy next
 # to the occupancy/fragmentation gauges), and how many scheduler steps
@@ -398,6 +404,30 @@ def choose_block(logits, temperature, seed, positions, masked, n_unmask):
 def _live_pages(kv_lens, page_size: int) -> int:
     """Pages that hold a key a step call's slots see."""
     return int((-(-kv_lens.astype(np.int64) // page_size)).sum())
+
+
+def _dot_fold_pct(chunk: int, rep: int, pool_dtype, q_lens, kv_lens, *,
+                  page_size: int, window: Optional[int] = None,
+                  layers: Tuple[int, int] = (1, 0)) -> float:
+    """100 x the share of a step call's (lane, live page) folds that the
+    paged kernel's dot fold takes, ``folds_by_dot`` deciding slot by slot
+    as it does in the kernel. A slot folds each of its ``q_len`` lanes
+    into the pages it has in view: every page up to ``kv_len`` in each of
+    ``layers[0]`` full layers, those from its oldest lane's window on in
+    each of ``layers[1]`` window layers."""
+    from ..fluid.ops.pallas_kernels.paged_attention import folds_by_dot
+
+    if not folds_by_dot(chunk, rep, pool_dtype):
+        return 0.0      # the call's program holds the lane loop alone
+    q, kv = q_lens.astype(np.int64), kv_lens.astype(np.int64)
+    pages = -(-kv // page_size)
+    folds = q * pages * layers[0]
+    if window is not None:
+        folds += q * (pages - np.maximum(kv - q - window + 1, 0)
+                      // page_size) * layers[1]
+    total = int(folds.sum())
+    by_dot = np.asarray(folds_by_dot(chunk, rep, pool_dtype, q))
+    return 100.0 * int(folds[by_dot].sum()) / total if total else 0.0
 
 
 def _call_work(slots: int, chunk: int, width: int, q_lens,
@@ -852,9 +882,18 @@ class DecodeEngine:
         self._max_slots = self._slot_ladder[-1]
         from ..fluid.ops.pallas_kernels.paged_attention import paged_route
 
-        self._attention_routes = sorted(
-            {paged_route(n, self._attention_impl)
-             for n in self._slot_ladder})
+        routes = {n: paged_route(n, self._attention_impl)
+                  for n in self._slot_ladder}
+        self._attention_routes = sorted(set(routes.values()))
+        # the slot buckets whose step call's attention is the kernel's
+        self._kernel_slots = {n for n, route in routes.items()
+                              if route == "paged_kernel"}
+        # what the kernel's predicate asks beside a call's chunk and
+        # q_lens (head group, pools' dtype), and the layers of each kind
+        self._fold_geometry = (spec.n_heads // spec.n_kv_heads,
+                               spec.pool_dtype)
+        self._attn_layers = (spec.layer_kinds.count("full"),
+                             spec.layer_kinds.count("window"))
         if self._mesh is not None:
             _log.info("decode %s.v%d spans mesh %s: attention takes %s "
                       "(the Pallas paged kernel has no SPMD form)",
@@ -3031,12 +3070,17 @@ class DecodeEngine:
                    kv_lens, more: Dict[str, int]):
         """What a step call that runs the attention records before it
         is dispatched: the share of its ``slots x width`` grid that
-        holds a live page, always, and on the ``device_call`` span where
+        holds a live page and the share of its folds the kernel's dot
+        fold takes, always, and on the ``device_call`` span where
         one is live ``_call_work``'s args and the round's own
         (``more``)."""
         ps = self.cache.page_size
         _m_attn_grid_live.observe(
             100.0 * _live_pages(kv_lens, ps) / (slots * width))
+        _m_attn_dot_fold.observe(_dot_fold_pct(
+            chunk, *self._fold_geometry, q_lens, kv_lens, page_size=ps,
+            window=self._window, layers=self._attn_layers)
+            if slots in self._kernel_slots else 0.0)
         if sp.live or self._window is not None:
             work = _call_work(slots, chunk, width, q_lens, kv_lens,
                               self._block, page_size=ps,

@@ -970,6 +970,74 @@ def test_grid_live_histogram_and_span_args_read_what_the_build_sent(
     assert (live["min"], live["max"]) == (25.0, 75.0)
 
 
+def test_dot_fold_share_counts_folds_by_the_kernels_predicate():
+    """`_dot_fold_pct` by hand: a chunk slot of 64 lanes at 100 keys, one
+    of 2 lanes (under the predicate's lane count) at 37, two decoding
+    slots and a dead one, pages of 16. Full layers fold a lane into every
+    page up to kv_len; a window layer into those from its oldest lane's
+    window on."""
+    from paddle_tpu.fluid.ops.pallas_kernels import paged_attention as pa
+    from paddle_tpu.serving.decode import _dot_fold_pct
+
+    q = np.array([64, 2, 1, 1, 0])
+    kv = np.array([100, 37, 300, 16, 0])
+    assert list(pa.folds_by_dot(64, 8, "bfloat16", q)) == [
+        True, False, False, False, False]
+    full = np.array([64 * 7, 2 * 3, 19, 1, 0])
+    got = _dot_fold_pct(64, 8, "bfloat16", q, kv, page_size=16)
+    assert got == pytest.approx(100.0 * full[0] / full.sum())
+    # window 64: the oldest lanes sit at 36, 35, 299, 15 and see from key
+    # 0, 0, 236, 0 on: pages 0.., 0.., 14.., 0..
+    window = np.array([64 * 7, 2 * 3, 19 - 14, 1, 0])
+    got = _dot_fold_pct(64, 8, "bfloat16", q, kv, page_size=16, window=64,
+                        layers=(1, 4))
+    assert got == pytest.approx(100.0 * (full[0] + 4 * window[0])
+                                / (full.sum() + 4 * window.sum()))
+    # a geometry whose program has no dot fold, and a call of dead slots
+    assert not pa.folds_by_dot(16, 1, "float32")
+    assert _dot_fold_pct(16, 1, "float32", q, kv, page_size=16) == 0.0
+    assert _dot_fold_pct(64, 8, "bfloat16", q * 0, kv * 0,
+                         page_size=16) == 0.0
+
+
+@pytest.mark.parametrize("route", ["paged_kernel", "paged_reference"])
+def test_dot_fold_histogram_observes_once_a_step_call(route):
+    """serving.decode.attn_dot_fold_pct (ISSUE 35) observes once a step
+    call what the kernel's predicate gives for the call's slots: a prompt
+    of 66 alone at a chunk of 64 goes in as 64 lanes (every fold the dot
+    fold's: 100), then 2 (under the predicate's lane count: 0), then
+    decodes (0). Where the attention is not the kernel's, nothing is the
+    dot fold's: 0 throughout."""
+    from paddle_tpu.fluid.flags import FLAGS, set_flags
+    from paddle_tpu.fluid.ops.pallas_kernels import paged_attention as pa
+
+    spec = DecoderSpec(vocab=32, d_model=16, n_layers=1, n_heads=8,
+                       n_kv_heads=1, seed=7)
+    assert pa.folds_by_dot(64, 8, spec.pool_dtype)
+    assert pa.folds_by_dot(64, 8, spec.pool_dtype, 64)
+    assert not pa.folds_by_dot(64, 8, spec.pool_dtype, 2)
+    was = FLAGS["use_pallas_kernels"]
+    set_flags({"use_pallas_kernels": route == "paged_kernel"})
+    try:
+        eng = DecodeEngine(spec, name="toy", slots=[1], page_size=16,
+                           num_pages=8, max_seq_len=80, max_queue=4,
+                           prefill_chunk=64, prefix_cache=False)
+        assert eng.stats()["attention_route"] == [route]
+        metrics.reset_metrics("serving.decode.")
+        _drive(eng, [(list(range(1, 32)) * 2 + [1, 2, 3, 4],
+                      dict(max_new_tokens=3))])
+        eng.stop()
+    finally:
+        set_flags({"use_pallas_kernels": was})
+    snap = metrics.snapshot("serving.decode.")
+    share = snap["serving.decode.attn_dot_fold_pct"]
+    assert share["count"] == snap["serving.decode.steps"] == 4
+    assert share["count"] == snap["serving.decode.attn_grid_live_pct"][
+        "count"]
+    first = 100.0 if route == "paged_kernel" else 0.0
+    assert (share["sum"], share["max"], share["min"]) == (first, first, 0.0)
+
+
 @pytest.mark.parametrize("all_lanes", [False, True])
 def test_decoder_step_names_its_device_work(all_lanes):
     """decoder_step_chunked's lowered text holds every decoder.* scope,

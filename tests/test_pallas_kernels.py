@@ -504,6 +504,175 @@ def test_paged_kernel_touches_nothing_past_kv_len_or_q_len(
     assert np.abs(got[~dead_lane]).max(axis=(-1, -2)).min() > 0
 
 
+# --- the chunk fold on the MXU (ISSUE 35) ---------------------------------
+# A slot that carries many lanes has them folded into a live page by two
+# matrix products a kv head; one with few walks them one by one. Which,
+# `folds_by_dot` says, from C, rep, the pools' dtype and q_len. Both folds
+# are one online softmax: against the reference and against each other at
+# the tolerances the lane loop's cases hold.
+
+def _dot_case(geometry, pool_dtype, q_dtype, mode):
+    """One call of eight slots in the kernel's layouts: q_lens on both
+    sides of the predicate's lane count (a full chunk, partial ones,
+    decoding slots, a dead one), kv_lens mid-page and on a boundary.
+    Returns what both implementations take, clean and poisoned."""
+    from paddle_tpu.fluid.ops.pallas_kernels import paged_attention as pa
+
+    c, hq, hkv = geometry
+    d, ps = 8, 8
+    n = pa.DOT_MIN_LANES
+    block = 4 if mode == "block" else 1
+    q_lens = np.array([c, n - 1, n, n + 1, 1, 1, 0, c // 2 + 3], np.int32)
+    kv_lens = np.array([c + 21, 40, n, 3 * n + 5, 1, 77, 0, c // 2 + 3],
+                       np.int32)
+    if block > 1:           # chunks of whole blocks, a pass of one block
+        q_lens = np.array([c, 4, 8, 12, 4, 4, 0, c // 2 + 4], np.int32)
+        kv_lens = np.array([c + 20, 40, 8, 36, 4, 76, 0, c // 2 + 4],
+                           np.int32)
+    assert (q_lens <= np.minimum(c, kv_lens)).all()
+    b = len(q_lens)
+    kw = {"block_length": block}
+    window = None
+    if mode == "window":
+        # a window shorter than the longest sequences: their tables start
+        # past the sequence's first pages
+        window = 2 * ps + 3
+        kw = {"window": window}
+    first = (np.maximum(kv_lens - q_lens - (window or 10 ** 6) + 1, 0)
+             // ps)
+    held = -(-kv_lens // ps) - first
+    w = int(held.max()) + 2
+    rng = np.random.RandomState(35)
+    # pages 0 and 1 are nobody's: every column past a slot's last page
+    tables = np.tile(np.array([1, 0], np.int32), (b, w))[:, :w]
+    for i in range(b):
+        tables[i, :held[i]] = 2 + i * w + np.arange(held[i])
+    pages = 2 + b * w
+    k = rng.randn(pages, ps, hkv, d).astype(np.float32)
+    v = rng.randn(pages, ps, hkv, d).astype(np.float32)
+    q = rng.randn(b, c, hq, d).astype(np.float32)
+    if window is not None:
+        kw["table_starts"] = jnp.asarray(first.astype(np.int32))
+    dead_lane = np.arange(c)[None, :] >= q_lens[:, None]
+
+    def call(fn, q, k, v, **more):
+        return np.asarray(fn(
+            jnp.asarray(q, q_dtype), jnp.asarray(k, pool_dtype),
+            jnp.asarray(v, pool_dtype), jnp.asarray(tables),
+            jnp.asarray(kv_lens), q_lens=jnp.asarray(q_lens), **kw,
+            **more).astype(jnp.float32))
+
+    kn, vn, qn = k.copy(), v.copy(), q.copy()
+    kn[:2] = vn[:2] = np.nan
+    qn[dead_lane] = np.nan
+    return call, (q, k, v), (qn, kn, vn), dead_lane, q_lens
+
+
+_DOT_GEOMETRIES = {"rep8": (16, 32, 4), "rep1": (32, 2, 2),
+                   "rep8_c64": (64, 32, 4)}
+
+
+@pytest.mark.parametrize("mode", ["causal", "window", "block"])
+@pytest.mark.parametrize("dtypes", [
+    ("rep8", jnp.bfloat16, jnp.float32), ("rep8", jnp.bfloat16, jnp.bfloat16),
+    ("rep1", jnp.bfloat16, jnp.float32), ("rep1", jnp.bfloat16, jnp.bfloat16),
+    ("rep8_c64", jnp.float32, jnp.float32)],
+    ids=["rep8_bf16pool_f32q", "rep8_bf16", "rep1_bf16pool_f32q",
+         "rep1_bf16", "rep8_f32"])
+def test_paged_kernel_dot_fold_is_the_lane_loops_softmax(dtypes, mode,
+                                                          monkeypatch):
+    """The dot fold against `paged_attention_reference` and against the
+    lane loop (the same call with the dot fold not traced) on a mixed
+    call: a full chunk, partial chunks on each side of the predicate's
+    lane count, decoding slots, a dead slot; causal, under a window whose
+    tables start past a window's length, and under the block mask. Dead
+    lanes and dead slots are exact zeros, and NaN planted in every column
+    past a slot's last page and every lane past q_len does not reach the
+    output."""
+    from paddle_tpu.fluid.ops.pallas_kernels import paged_attention as pa
+
+    name, pool_dtype, q_dtype = dtypes
+    c, hq, hkv = _DOT_GEOMETRIES[name]
+    call, clean, poisoned, dead_lane, q_lens = _dot_case(
+        (c, hq, hkv), pool_dtype, q_dtype, mode)
+    # the geometry qualifies, and the call holds slots on both sides
+    assert pa.folds_by_dot(c, hq // hkv, pool_dtype)
+    takes = np.asarray(pa.folds_by_dot(c, hq // hkv, pool_dtype, q_lens))
+    # (a block model's passes are whole blocks, all of them the dot fold's)
+    assert takes.any() and (mode == "block"
+                            or (~takes & (q_lens > 0)).any())
+    want = call(pa.paged_attention_reference, *clean)
+    got = call(pa._paged_attention_pallas, *poisoned, interpret=True)
+    monkeypatch.setattr(pa, "DOT_MIN_ROWS", {
+        key: 10 ** 9 for key in pa.DOT_MIN_ROWS})
+    assert not pa.folds_by_dot(c, hq // hkv, pool_dtype)
+    lanes = call(pa._paged_attention_pallas, *poisoned, interpret=True)
+    assert np.isfinite(got).all() and np.isfinite(lanes).all()
+    np.testing.assert_array_equal(got[dead_lane], 0.0)
+    assert np.abs(got[~dead_lane]).max(axis=(-1, -2)).min() > 0
+    if q_dtype == jnp.float32:
+        # the tolerances of the lane loop's cases above
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+        np.testing.assert_allclose(got, lanes, rtol=2e-5, atol=2e-6)
+    else:
+        # a bfloat16 output: one step of its 8 bits (a float32 sum in
+        # another order can land on the other side of a rounding)
+        np.testing.assert_allclose(got, want, rtol=2.0 ** -7, atol=1e-6)
+        np.testing.assert_allclose(got, lanes, rtol=2.0 ** -7, atol=1e-6)
+    # a slot the predicate leaves to the lane loop is the lane loop's to
+    # the bit, in a program that has both folds
+    np.testing.assert_array_equal(got[~takes], lanes[~takes])
+
+
+# the primitives of one kernel call, counted on PR 34's tree (d2744e8):
+# name -> ((C, Hq, Hkv, dtype, block_length), counts)
+_LANE_LOOP_PROGRAMS = {
+    "chat_c16": ((16, 2, 2, jnp.float32, 1), {
+        "add": 7, "broadcast_in_dim": 10, "cond": 3,
+        "convert_element_type": 6, "div": 2, "eq": 2, "exp": 2, "gather": 1,
+        "get": 10, "iota": 2, "jit": 2, "le": 1, "lt": 3, "max": 3,
+        "min": 1, "mul": 8, "pallas_call": 1, "program_id": 2,
+        "reduce_max": 1, "reduce_sum": 3, "reshape": 1, "select_n": 2,
+        "sub": 4, "swap": 7, "while": 1}),
+    "decode_c1": ((1, 8, 1, jnp.bfloat16, 1), {
+        "add": 7, "broadcast_in_dim": 12, "cond": 3,
+        "convert_element_type": 10, "div": 2, "eq": 2, "exp": 2,
+        "gather": 1, "get": 10, "iota": 2, "jit": 2, "le": 1, "lt": 3,
+        "max": 3, "min": 1, "mul": 8, "pallas_call": 1, "program_id": 2,
+        "reduce_max": 1, "reduce_sum": 3, "reshape": 3, "select_n": 2,
+        "sub": 4, "swap": 7, "while": 1})}
+
+
+@pytest.mark.parametrize("name", sorted(_LANE_LOOP_PROGRAMS))
+def test_a_geometry_the_predicate_rejects_traces_the_program_it_did(name):
+    """`folds_by_dot`'s static half: the dense family's chunk (C 16, one
+    query head a kv head, float32) and every one-token program trace the
+    lane loop alone, primitive for primitive what PR 34's tree traced;
+    the same heads at a chunk the predicate accepts trace a second
+    output and the products beside it."""
+    from test_afmoe_serving import _primitives
+
+    from paddle_tpu.fluid.ops.pallas_kernels import paged_attention as pa
+
+    (c, hq, hkv, dtype, block), want = _LANE_LOOP_PROGRAMS[name]
+    assert not pa.folds_by_dot(c, hq // hkv, dtype)
+
+    def count(c):
+        q = jnp.zeros((2, c, hq, 8), dtype)
+        pages = jnp.zeros((16, 4, hkv, 8), dtype)
+        return dict(_primitives(jax.make_jaxpr(
+            lambda *a: pa._paged_attention_pallas(
+                *a[:5], q_lens=a[5], interpret=True, block_length=block))(
+            q, pages, pages, jnp.zeros((2, 3), jnp.int32),
+            jnp.array([5, 9]), jnp.array([1, min(c, 4)])).jaxpr))
+
+    assert count(c) == want
+    wide = pa.DOT_MIN_ROWS[jnp.dtype(dtype).name] * hkv // hq
+    assert pa.folds_by_dot(wide, hq // hkv, dtype)
+    got = count(wide)
+    assert got["dot_general"] == 2 * hkv and "dot_general" not in want
+
+
 # --- compiled for a TPU v5e without one ----------------------------------
 # The cases above run the kernels INTERPRETED; Mosaic, the compiler the
 # chip uses, has never seen them. libtpu can describe a v5e topology to a
@@ -535,28 +704,38 @@ def _on(dev_or_sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
 
-@pytest.mark.parametrize("chunk,hq,hkv,dtype,block_length", [
-    (1, 16, 16, jnp.float32, 1), (16, 16, 16, jnp.float32, 1),
-    (4, 32, 4, jnp.bfloat16, 4), (16, 32, 4, jnp.bfloat16, 4)],
-    ids=["chat_c1", "chat_c16", "block_c4", "block_c16"])
+@pytest.mark.parametrize("chunk,hq,hkv,dtype,block_length,width,dot", [
+    (1, 16, 16, jnp.float32, 1, 64, False),
+    (16, 16, 16, jnp.float32, 1, 64, False),
+    (4, 32, 4, jnp.bfloat16, 4, 64, True),
+    (16, 32, 4, jnp.bfloat16, 4, 64, True),
+    (1, 32, 4, jnp.bfloat16, 1, 576, False),
+    (64, 32, 4, jnp.bfloat16, 1, 576, True)],
+    ids=["chat_c1", "chat_c16", "block_c4", "block_c16", "trinity_c1",
+         "trinity_c64"])
 def test_paged_kernel_compiles_for_v5e(v5e, chunk, hq, hkv, dtype,
-                                       block_length):
+                                       block_length, width, dot):
     """The served attention geometries, heads of 128 and page 16. The
     dense family's: 16 query / 16 kv heads, float32 q and pools, causal
     — single-token decode (C=1) and a prefill chunk (C=16). The block
     model's: 32 query / 4 kv heads, bfloat16 q and pools, blocks of 4 —
-    a block pass (C=4) and a prefill chunk (C=16)."""
+    a block pass (C=4) and a prefill chunk (C=16). The full layer of
+    ``trinity_mini_longmix`` (ISSUE 35): the same heads, causal, a table
+    of 576 columns, a decode token and a prefill chunk of 64 lanes.
+    ``dot``: whether the program holds the dot fold beside the lane loop
+    (``folds_by_dot``'s static half), and so a second output."""
     from paddle_tpu.fluid.ops.pallas_kernels.paged_attention import (
-        _paged_attention_pallas)
+        _paged_attention_pallas, folds_by_dot)
 
-    b, w, d, ps, pages = 16, 64, 128, 16, 128
+    assert folds_by_dot(chunk, hq // hkv, dtype) == dot
+    b, d, ps, pages = 16, 128, 16, 128
     d0 = v5e[0]
     jax.jit(lambda q, k, v, t, n, m: _paged_attention_pallas(
         q, k, v, t, n, q_lens=m, block_length=block_length)).lower(
         _on(d0, (b, chunk, hq, d), dtype),
         _on(d0, (pages, ps, hkv, d), dtype),
         _on(d0, (pages, ps, hkv, d), dtype),
-        _on(d0, (b, w), jnp.int32), _on(d0, (b,), jnp.int32),
+        _on(d0, (b, width), jnp.int32), _on(d0, (b,), jnp.int32),
         _on(d0, (b,), jnp.int32)).compile()
 
 
@@ -566,10 +745,12 @@ def test_windowed_paged_kernel_compiles_for_v5e(v5e, window_cols, chunk):
     """The window layers' geometry of ``trinity_mini_longmix`` (ISSUE 34):
     32 query / 4 kv heads of 128, bfloat16, window 2048 over a table of
     133 columns that starts at ``table_starts``, a decode token and a
-    prefill chunk of 64 lanes."""
+    prefill chunk of 64 lanes (whose program holds the dot fold,
+    ISSUE 35, beside the lane loop the decode token's is left with)."""
     from paddle_tpu.fluid.ops.pallas_kernels.paged_attention import (
-        _paged_attention_pallas)
+        _paged_attention_pallas, folds_by_dot)
 
+    assert folds_by_dot(chunk, 32 // 4, jnp.bfloat16) == (chunk == 64)
     b, d, ps, pages = 16, 128, 16, 256
     d0 = v5e[0]
     text = jax.jit(lambda q, k, v, t, n, m, s: _paged_attention_pallas(

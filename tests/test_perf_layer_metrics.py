@@ -5,7 +5,8 @@ where tokens are chosen, data only, read from the program's histogram
 cell (ISSUE 30): `block_tokens_per_pass`, `moe_load_max_over_mean`,
 `moe_experts_roofline`, `moe_route_share_pct`, `paged_attn_block_roofline`;
 and `paged_attn_grid_live_pct` (ISSUE 31: data only, the program's histogram
-`serving.decode.attn_grid_live_pct`).
+`serving.decode.attn_grid_live_pct`) and `paged_attn_dot_fold_pct` (ISSUE 35:
+data only, `serving.decode.attn_dot_fold_pct`, the benchmark's newest entry).
 
 The benchmark's own tests live in `perf/tests` and are not collected by
 the tier-1 command; this case is, so that a tree whose BENCHMARK.json no
@@ -26,11 +27,13 @@ from perf.lib.loader import Benchmark  # noqa: E402
 NAME, CELL = "sched_device_choice_pct", "xglm17b_chat"
 HISTOGRAM = "serving.decode.device_choice_pct"
 # the data-only metrics read from a histogram's mean in both serving cells:
-# name -> (histogram, layer); ISSUE 31's is the benchmark's newest entry
+# name -> (histogram, layer); ISSUE 35's is the benchmark's newest entry
 HISTOGRAM_AVG = {
     NAME: (HISTOGRAM, "scheduler"),
     "paged_attn_grid_live_pct": ("serving.decode.attn_grid_live_pct",
-                                 "kernels")}
+                                 "kernels"),
+    "paged_attn_dot_fold_pct": ("serving.decode.attn_dot_fold_pct",
+                                "kernels")}
 
 
 @pytest.fixture(scope="module")
@@ -44,7 +47,7 @@ def test_a_histogram_metric_loads_and_reads_the_histograms_avg(bench, name):
     bench.check_files()
     entry, = [m for m in bench.doc["per_layer"] if m["name"] == name]
     # ISSUE 34 appended its two cells to the list, and its four metrics
-    # behind the last of these
+    # behind ISSUE 31's; ISSUE 35's stands behind those, the last
     assert entry == {
         "name": name, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": layer,
@@ -55,7 +58,8 @@ def test_a_histogram_metric_loads_and_reads_the_histograms_avg(bench, name):
         "paged_attn_grid_live_pct") == 18
     assert [m["name"] for m in bench.doc["per_layer"]][19:] == [
         "kv_prefix_hit_pct", "paged_attn_window_roofline",
-        "attn_window_skip_pct", "kv_window_held_pct"]
+        "attn_window_skip_pct", "kv_window_held_pct",
+        "paged_attn_dot_fold_pct"]
     # data only: no reader of its own, and the training cell is not asked
     assert not os.path.exists(bench.path("layer_metrics", name + ".py"))
     assert name not in [m["name"] for m, _d in
