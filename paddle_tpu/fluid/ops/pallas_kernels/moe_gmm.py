@@ -35,7 +35,8 @@ rounded once), with no float32 round trip between.
 Rows are independent: what an unvisited or dead row holds, NaN included,
 reaches no other row. Inside a visited row tile the rows of no group are
 written as zeros; a row tile never visited is never written (the caller
-masks by ``counts``, as ``moe_layer`` does with ``routed``).
+never lets such a row reach a result: ``moe_layer`` masks a dead lane's
+gathered rows after its combine).
 
 ``moe_route`` names the implementation as ``paged_route`` does for the
 attention: the kernel on a TPU (``use_pallas_kernels``) at widths that are

@@ -18,10 +18,10 @@ softmax, and the final logits in float32. K and V go to the pool AFTER
 QK-norm and rotary. The experts are DROPLESS: the ``T * k`` assignments
 are sorted by expert, grouped products run over the ragged groups
 (``pallas_kernels/moe_gmm.py``: the Pallas kernel on a TPU,
-``jax.lax.ragged_dot`` elsewhere), and the results are scatter-added back
-with the renormalised weights. There is no capacity, no dropped token and no
-``[T, E, C]`` one-hot (``parallel/moe.py`` stays what the Fluid training
-path uses).
+``jax.lax.ragged_dot`` elsewhere), and each token gathers its rows back
+through the inverse of the sort and sums them with the renormalised
+weights. There is no capacity, no dropped token and no ``[T, E, C]``
+one-hot (``parallel/moe.py`` stays what the Fluid training path uses).
 """
 from __future__ import annotations
 
@@ -315,12 +315,14 @@ def moe_layer(h, lp, valid, spec, impl: Optional[str] = None, *,
     (``score(h, lp, spec) -> (w [T, k] float32, idx [T, k] int32)``: which
     ``experts_per_token`` of ``n_experts`` a token meets and how each
     weighs; ``softmax_scores`` here, ``afmoe.sigmoid_scores``); the sort,
-    the grouped products and the scatter-add are one. ``valid`` [T] marks
-    the live lanes; a dead lane is routed nowhere, weighs nothing and is
-    not counted. ``counts`` sums to ``valid.sum() * experts_per_token``.
-    ``impl`` is the step's ``attention_impl``: ``"reference"`` names XLA's
-    grouped product as it names the attention's reference
-    (``moe_gmm.moe_route``)."""
+    the grouped products and the combine are one. The combine is a gather:
+    each token takes its ``k`` rows back through the inverse of the sort
+    and sums them with its weights, so no row is scattered. ``valid`` [T]
+    marks the live lanes; a dead lane is routed nowhere, is not counted
+    and reads exact zeros. ``counts`` sums to ``valid.sum() *
+    experts_per_token``. ``impl`` is the step's ``attention_impl``:
+    ``"reference"`` names XLA's grouped product as it names the
+    attention's reference (``moe_gmm.moe_route``)."""
     import jax
     import jax.numpy as jnp
 
@@ -332,12 +334,13 @@ def moe_layer(h, lp, valid, spec, impl: Optional[str] = None, *,
         w, idx = score(h, lp, spec)
         # dead lanes sort behind every expert's group (sentinel E)
         idx = jnp.where(valid[:, None], idx, e).reshape(t * k)
-        w = jnp.where(valid[:, None], w, 0.0).reshape(t * k)
         order = jnp.argsort(idx, stable=True)
-        counts = jnp.zeros((e + 1,), jnp.int32).at[idx].add(1)[:e]
-        token = order // k                                   # [T*k]
-        routed = idx[order] < e
-        xs = h[token]
+        # pos[t, j]: the sorted row that holds assignment (t, j), the
+        # inverse of ``order``
+        pos = jnp.argsort(order).reshape(t, k)
+        counts = jnp.sum(idx[:, None] == jnp.arange(e)[None, :], axis=0,
+                         dtype=jnp.int32)
+        xs = h[order // k]
     with jax.named_scope("decoder.moe.experts"):
         # grouped products over the ragged groups: rows [sum(counts[:j]),
         # sum(counts[:j+1])) meet expert j's matrices. The gated product
@@ -345,10 +348,13 @@ def moe_layer(h, lp, valid, spec, impl: Optional[str] = None, *,
         act = grouped_dot(xs, lp["up"], counts, gate=lp["gate"], impl=impl)
         y = grouped_dot(act, lp["down"], counts, impl=impl)  # [T*k, d] f32
     with jax.named_scope("decoder.moe.combine"):
-        # rows behind the last group belong to no expert and hold whatever
-        # the product left there: exact zeros from here on
-        y = jnp.where(routed[:, None], y * w[order][:, None], 0.0)
-        out = jnp.zeros((t, h.shape[1]), jnp.float32).at[token].add(y)
+        # each token gathers its k rows and sums them weighed, in order of
+        # j. A dead lane's rows lie behind the last group and hold whatever
+        # the product left there, NaN included: masked after the sum
+        out = w[:, :1] * y[pos[:, 0]]
+        for j in range(1, k):
+            out = out + w[:, j:j + 1] * y[pos[:, j]]
+        out = jnp.where(valid[:, None], out, 0.0)
     return out, counts
 
 

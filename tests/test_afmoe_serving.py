@@ -148,7 +148,8 @@ def test_the_family_is_found_by_name_and_round_trips():
 # --- one dropless expert layer --------------------------------------------
 
 def _old_sdar_moe_layer(h, lp, valid, spec):
-    """``moe_layer`` as PR 33 had it, scoring inside: what the shared
+    """``moe_layer`` with the family's scoring written inside, as it was
+    before the model supplied it, and the gather combine: what the shared
     layer with ``softmax_scores`` must equal to the bit."""
     from paddle_tpu.fluid.ops.pallas_kernels.moe_gmm import grouped_dot
 
@@ -159,16 +160,16 @@ def _old_sdar_moe_layer(h, lp, valid, spec):
     if spec.norm_topk_prob:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     idx = jnp.where(valid[:, None], idx, e).reshape(t * k)
-    w = jnp.where(valid[:, None], w, 0.0).reshape(t * k)
     order = jnp.argsort(idx, stable=True)
+    pos = jnp.argsort(order).reshape(t, k)
     counts = jnp.zeros((e + 1,), jnp.int32).at[idx].add(1)[:e]
-    token = order // k
-    routed = idx[order] < e
-    xs = h[token]
+    xs = h[order // k]
     act = grouped_dot(xs, lp["up"], counts, gate=lp["gate"])
     y = grouped_dot(act, lp["down"], counts)
-    y = jnp.where(routed[:, None], y * w[order][:, None], 0.0)
-    return jnp.zeros((t, h.shape[1]), jnp.float32).at[token].add(y), counts
+    out = w[:, :1] * y[pos[:, 0]]
+    for j in range(1, k):
+        out = out + w[:, j:j + 1] * y[pos[:, j]]
+    return jnp.where(valid[:, None], out, 0.0), counts
 
 
 def test_sdar_moes_expert_layer_is_unchanged_to_the_bit():

@@ -18,10 +18,12 @@ from paddle_tpu.checkpoint.decoder import expected_decoder_tensors
 from paddle_tpu.fluid.flags import FLAGS, set_flags
 from paddle_tpu.fluid.ops.pallas_kernels import paged_attention as pa
 from paddle_tpu.fluid.ops.pallas_kernels.moe_gmm import moe_route
+from paddle_tpu.models.afmoe import TINY_CONFIG as AFMOE_TINY
+from paddle_tpu.models.afmoe import AfmoeSpec, sigmoid_scores
 from paddle_tpu.models.decoders import (DecoderSpec, spec_from_dict,
                                         validate_draft_spec)
 from paddle_tpu.models.sdar_moe import (TINY_CONFIG, SdarMoeSpec, moe_layer,
-                                        sdar_moe_step)
+                                        sdar_moe_step, softmax_scores)
 from paddle_tpu.serving.decode import DecodeEngine
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -254,6 +256,78 @@ def test_routing_is_dropless_and_equals_the_dense_all_experts_formula(
     np.testing.assert_allclose(got[np.asarray(valid)],
                                dense[np.asarray(valid)], atol=2e-5)
     assert not got[~np.asarray(valid)].any()         # dead lanes: zeros
+
+
+def scoring_case(scoring, impl):
+    """``(spec, layer params, score, context)`` of one scoring in front of
+    ``moe_layer`` on one route of the grouped products: this family's
+    softmax, or ``afmoe``'s sigmoid scores with its ``route_scale``."""
+    config, forced = experts_case(impl)
+    if scoring == "softmax":
+        spec = tiny_spec(config=config)
+        return spec, _layer_params(spec), softmax_scores, forced
+    spec = AfmoeSpec.from_config(
+        dict(AFMOE_TINY, hidden_size=config["hidden_size"],
+             moe_intermediate_size=config["moe_intermediate_size"]),
+        dtype="float32", seed=3)
+    lp = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32),
+                      spec.seeded_arrays()["layer1"])
+    return spec, lp, sigmoid_scores, forced
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "gmm_interpret"])
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_the_expert_layer_lowers_with_no_scatter(scoring, impl):
+    """Route and combine gather: the lowered layer holds no scatter, at a
+    ``T * k`` of four 64-row tiles of the kernel."""
+    spec, lp, score, forced = scoring_case(scoring, impl)
+    t = 100
+    h = jnp.ones((t, spec.d_model), jnp.float32)
+    valid = jnp.arange(t) % 6 != 5
+    with forced:
+        text = jax.jit(lambda h, lp, v: moe_layer(h, lp, v, spec,
+                                                  score=score)).lower(
+            h, lp, valid).as_text()
+    assert "sort" in text and "gather" in text
+    assert "scatter" not in text
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "gmm_interpret"])
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_rows_behind_the_last_group_never_reach_the_output(
+        ref, monkeypatch, scoring, impl):
+    """The grouped products leave the rows of no group holding whatever a
+    row tile held: planted NaN there, the output is finite, a dead lane
+    reads exact zeros and a live lane the dense all-experts formula."""
+    from paddle_tpu.fluid.ops.pallas_kernels import moe_gmm
+
+    spec, lp, score, forced = scoring_case(scoring, impl)
+    real = moe_gmm.grouped_dot
+
+    def nan_behind_the_groups(lhs, rhs, counts, **kw):
+        y = real(lhs, rhs, counts, **kw)
+        rows = jnp.arange(y.shape[0])[:, None]
+        return jnp.where(rows < jnp.sum(counts), y, jnp.nan).astype(y.dtype)
+
+    monkeypatch.setattr(moe_gmm, "grouped_dot", nan_behind_the_groups)
+    rng = np.random.RandomState(11)
+    t = 96                                           # 3 row tiles at k 2
+    h = jnp.asarray(rng.randn(t, spec.d_model), jnp.float32)
+    valid = np.arange(t) % 6 != 5
+    with forced:
+        out, counts = jax.jit(lambda h, lp, v: moe_layer(
+            h, lp, v, spec, score=score))(h, lp, jnp.asarray(valid))
+    got = np.asarray(out)
+    assert np.isfinite(got).all()
+    assert not got[~valid].any()
+    k = spec.experts_per_token
+    assert int(np.asarray(counts).sum()) == valid.sum() * k
+    w, idx = (np.asarray(a) for a in score(h, lp, spec))
+    dense_w = np.zeros((t, spec.n_experts), np.float32)
+    np.put_along_axis(dense_w, idx, w, axis=-1)
+    dense = np.asarray(ref.experts(h, jnp.asarray(dense_w), lp["gate"],
+                                   lp["up"], lp["down"]))
+    np.testing.assert_allclose(got[valid], dense[valid], atol=2e-5)
 
 
 # --- the step through the cache against the reference's full pass ----------
