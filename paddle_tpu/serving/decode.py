@@ -173,6 +173,25 @@ _m_step_ms = _metrics.histogram("serving.decode.step_ms")
 # the end of a wait for work), so the three add up to the step period.
 _m_sample_ms = _metrics.histogram("serving.decode.sample_ms")
 _m_sched_ms = _metrics.histogram("serving.decode.sched_ms")
+# the device call's host stretches, one observation a target dispatch
+# of a round each: dispatch_ms is the serving.decode.dispatch
+# span's (the jitted call with its host arguments, the pools' rebind, the
+# ids' copy to the host started, the draft's shadow call), release_ms the
+# serving.decode.release span's (the step's device outputs let go).
+# host_gap_ms is the host's stretch between two target steps: from the
+# moment the newest dispatched step's ids are on the host to the return
+# of the next dispatch (release, answer, the hand-over to the woken
+# clients, admit, prepare, plan, build and dispatch; the device starts
+# the step inside the dispatch, and the latency from its last operation
+# to the ids on the host lies outside the stretch). It
+# reads 0 where the step before was not waited for (its chunks all ended
+# inside their prompts: the device still held it), and a wait for work
+# restarts it, as it restarts the round's clock. Other device calls
+# between two target dispatches (page copies, restores, a host-route
+# row's fetch, the propose/verify substep, the embed lane) lie inside it
+_m_dispatch_ms = _metrics.histogram("serving.decode.dispatch_ms")
+_m_release_ms = _metrics.histogram("serving.decode.release_ms")
+_m_host_gap_ms = _metrics.histogram("serving.decode.host_gap_ms")
 # where a token was chosen (ISSUE 29), one increment a chosen token: by
 # the step's own program (``choose_tokens``: greedy, and temperature > 0
 # with top_k 0) or on the host from one fetched logits row (top_k > 0, a
@@ -431,7 +450,7 @@ def _dot_fold_pct(chunk: int, rep: int, pool_dtype, q_lens, kv_lens, *,
 
 
 def _call_work(slots: int, chunk: int, width: int, q_lens,
-               kv_lens, block: int = 1, *, page_size: int,
+               kv_lens, block: int = 1, *,
                window: Optional[int] = None) -> Dict[str, int]:
     """``serving.decode.device_call``'s args: the compiled buckets of
     one step call and the sums its attention work follows from,
@@ -443,10 +462,7 @@ def _call_work(slots: int, chunk: int, width: int, q_lens,
     ``4 * heads * head_dim * attn_pairs`` operations and reads
     ``2 * kv_heads * head_dim * kv_tokens`` K/V elements, beside
     ``2 * heads * head_dim * q_tokens`` of q and out. Dead slots are
-    0/0 and add nothing. ``kv_pages`` (pages that hold a key in view)
-    and ``q_lanes`` (``slots * chunk``) set what is walked beside what
-    is live: ``slots * width`` table columns and ``q_lanes`` query
-    lanes against ``kv_pages`` and ``q_tokens``.
+    0/0 and add nothing.
 
     A model with WINDOW layers (ISSUE 34, ``window`` keys a lane) has the
     sums by kind as well, a layer of each: ``kv_tokens_full`` /
@@ -458,9 +474,7 @@ def _call_work(slots: int, chunk: int, width: int, q_lens,
     q, kv = q_lens.astype(np.int64), kv_lens.astype(np.int64)
     out = {"slots": slots, "chunk": chunk, "width": width,
            "q_tokens": int(q.sum()), "kv_tokens": int(kv.sum()),
-           "attn_pairs": int(((2 * kv - q + block) * q // 2).sum()),
-           "kv_pages": _live_pages(kv_lens, page_size),
-           "q_lanes": slots * chunk}
+           "attn_pairs": int(((2 * kv - q + block) * q // 2).sum())}
     if window is not None:
         # the first lane sees ``first`` keys; ``low`` lanes see fewer
         # than a window's (an arithmetic run), the rest a window's
@@ -1064,6 +1078,11 @@ class DecodeEngine:
         # host clock — where the last one ended, or where a wait for
         # work did (serving.decode.sched_ms)
         self._t_round = 0.0
+        # scheduler-thread only: when the newest dispatched step's ids
+        # came to the host, or where a wait for work ended; None while
+        # the device still holds a step nobody waited for
+        # (serving.decode.host_gap_ms)
+        self._t_back: Optional[float] = 0.0
         self._compiled_shapes: set = set()  # guarded-by: _step_mu
         self._g_depth = _metrics.gauge(
             f"serving.decode.queue_depth.{self.name}.v{self.version}")
@@ -2006,8 +2025,9 @@ class DecodeEngine:
         # the admit span starts BEFORE the condition is taken (so it is
         # entered and left by hand): getting the condition back from
         # the clients the last step woke is the scheduler's time. The
-        # round's host clock (_t_round) runs on from the last round's
-        # end; only a wait for work, below, restarts it
+        # round's host clock (_t_round) and the device's host gap
+        # (_t_back) run on from the last round; only a wait for work,
+        # below, restarts them
         admit = _tracing.span("serving.decode.admit")
         admit.__enter__()
         # lint: allow-blocking — Condition.wait on the engine's own
@@ -2031,13 +2051,13 @@ class DecodeEngine:
                 self._cond.wait(0.05 if (self._queue
                                          or self._embed_queue)
                                 else None)
-                self._t_round = time.perf_counter()
+                self._t_round = self._t_back = time.perf_counter()
                 admit = _tracing.span("serving.decode.admit")
                 admit.__enter__()
 
-    def _loop(self):  # lint: allow-unguarded(_t_round) — this thread's
-        # own clock: nobody else reads or writes it
-        self._t_round = time.perf_counter()
+    def _loop(self):  # lint: allow-unguarded(_t_round, _t_back) — this
+        # thread's own clocks: nobody else reads or writes them
+        self._t_round = self._t_back = time.perf_counter()
         while True:
             nxt = self._next_live()
             if nxt is None:
@@ -2798,10 +2818,11 @@ class DecodeEngine:
             live, grants = self._prepare(live)
         if not live:
             return
-        rnd = (self._plan_blocks if self._block > 1
-               else self._plan_causal)(live, grants)
-        w_bucket = _bucket_for(self._width_ladder,
-                               max(s.pages_held for s in live))
+        with _tracing.span("serving.decode.plan"):
+            rnd = (self._plan_blocks if self._block > 1
+                   else self._plan_causal)(live, grants)
+            w_bucket = _bucket_for(self._width_ladder,
+                                   max(s.pages_held for s in live))
         t0 = time.perf_counter()
         # one decode step joins the OLDEST live request's trace (a span
         # has one parent); per-slot request spans live in the server
@@ -2829,37 +2850,56 @@ class DecodeEngine:
                 # dispatch of the jitted step to the chosen ids on the
                 # host; the logits stay on the device
                 with _tracing.span("serving.decode.device_call") as sp:
+                    with _tracing.span("serving.decode.dispatch"):
+                        t_call = time.perf_counter()
+                        # the draw's position is lens, each slot's new
+                        # token's absolute index in its sequence: the
+                        # (seed, position) pair that makes sampling
+                        # independent of batch composition AND chunking
+                        out, rnd.logits = self._run_step_arrays(
+                            *arrays, temperature=temperature, seed=seed,
+                            **rnd.call_kw)
+                        if not isinstance(out, dict):
+                            out = {"ids": out}
+                        if rnd.reads:
+                            for name in out:
+                                out[name].copy_to_host_async()
+                        if self._spec_k:
+                            # the draft shadows every prefill chunk so
+                            # its mirrored pool tracks the committed
+                            # sequence (its choice is discarded; its
+                            # watermark advances in the answer phase)
+                            self._run("draft", *arrays)
+                        t_sent = time.perf_counter()
+                    _m_dispatch_ms.observe((t_sent - t_call) * 1e3)
+                    _m_host_gap_ms.observe(
+                        0.0 if self._t_back is None
+                        else (t_sent - self._t_back) * 1e3)
+                    # worked out while the device runs the step
                     self._note_call(sp, s_bucket, c_bucket, w_bucket,
                                     arrays[2], arrays[4], rnd.call_args)
-                    # the draw's position is lens, each slot's new
-                    # token's absolute index in its sequence: the (seed,
-                    # position) pair that makes sampling independent of
-                    # batch composition AND chunking
-                    out, rnd.logits = self._run_step_arrays(
-                        *arrays, temperature=temperature, seed=seed,
-                        **rnd.call_kw)
-                    if not isinstance(out, dict):
-                        out = {"ids": out}
-                    if rnd.reads:
-                        for name in out:
-                            out[name].copy_to_host_async()
-                    if self._spec_k:
-                        # the draft shadows every prefill chunk so its
-                        # mirrored pool tracks the committed sequence
-                        # (its choice is discarded; its watermark
-                        # advances in the answer phase with pos)
-                        self._run("draft", *arrays)
-                    if self._await_ids(out["ids"], rnd.reads) is not None:
-                        rnd.out = {k: np.asarray(a) for k, a in out.items()}
-                        if "expert_counts" in rnd.out:
-                            sp.set_arg("moe_experts_touched", int(
-                                (rnd.out["expert_counts"] > 0).sum()))
+                    with _tracing.span("serving.decode.wait"):
+                        if self._await_ids(out["ids"],
+                                           rnd.reads) is not None:
+                            rnd.out = {k: np.asarray(a)
+                                       for k, a in out.items()}
+                            if sp.live and "expert_counts" in rnd.out:
+                                sp.set_arg("moe_experts_touched", int(
+                                    (rnd.out["expert_counts"] > 0).sum()))
+                    # the newest step's ids are on the host: the device
+                    # holds nothing from here to the next dispatch,
+                    # unless this step was not waited for
+                    t_back = time.perf_counter()
+                    self._t_back = t_back if rnd.out else None
                     # the device's copies of what was fetched go HERE,
                     # inside the call's stretch, not when this frame
                     # does: letting them go after the answer phase cost
                     # 0.6 ms between a round's wake-up and the next
                     # round's admit (PERF.md section 6, PR 32)
-                    del out
+                    with _tracing.span("serving.decode.release"):
+                        del out
+                        _m_release_ms.observe(
+                            (time.perf_counter() - t_back) * 1e3)
             if rnd.spec:
                 rnd.spec_out = self._spec_substep(rnd.spec, w_bucket)
         step_s = time.perf_counter() - t0
@@ -2875,58 +2915,65 @@ class DecodeEngine:
         # with it or the two sides can each answer the same request.
         # The span opens before the condition is taken: the wait for it
         # (clients reading their streams hold it) is the phase's time
-        with _tracing.span("serving.decode.answer"), self._cond:
-            self._n_steps += 1
-            for s, g in zip(live, grants):
-                if s.req.ev.is_set():
-                    # already answered — stop(drain=False) raced this
-                    # step and failed the request (or it was canceled);
-                    # don't double-answer or count a completion/token
-                    done.append(s)
-                    continue
-                s.steps += 1
-                if self._block > 1:
-                    finished = self._answer_block(s, g, rnd)
-                elif id(s) in rnd.spec_out:
-                    finished = self._answer_spec(s, rnd)
-                else:
-                    finished = self._answer_plain(s, g, rnd)
-                self._retire_locked(s, finished, now, done, notes)
-            chosen = n["device"] + n["host"]
-            if n["proposed"]:
-                _m_spec_proposed.inc(n["proposed"])
-                _m_spec_accepted.inc(n["accepted"])
-                _m_spec_rejected.inc(n["proposed"] - n["accepted"])
-            if n["passes"]:
-                _m_block_passes.inc(n["passes"])
-                _m_block_tokens_per_pass.observe(n["device"] / n["passes"])
+        with _tracing.span("serving.decode.answer"):
+            with self._cond:
+                self._n_steps += 1
+                for s, g in zip(live, grants):
+                    if s.req.ev.is_set():
+                        # already answered — stop(drain=False) raced this
+                        # step and failed the request (or it was canceled);
+                        # don't double-answer or count a completion/token
+                        done.append(s)
+                        continue
+                    s.steps += 1
+                    if self._block > 1:
+                        finished = self._answer_block(s, g, rnd)
+                    elif id(s) in rnd.spec_out:
+                        finished = self._answer_spec(s, rnd)
+                    else:
+                        finished = self._answer_plain(s, g, rnd)
+                    self._retire_locked(s, finished, now, done, notes)
+                chosen = n["device"] + n["host"]
+                if n["proposed"]:
+                    _m_spec_proposed.inc(n["proposed"])
+                    _m_spec_accepted.inc(n["accepted"])
+                    _m_spec_rejected.inc(n["proposed"] - n["accepted"])
+                if n["passes"]:
+                    _m_block_passes.inc(n["passes"])
+                    _m_block_tokens_per_pass.observe(n["device"] / n["passes"])
+                    if n["device"]:
+                        _m_block_committed.inc(n["device"])
+                if n["dropped"]:
+                    _m_block_dropped.inc(n["dropped"])
                 if n["device"]:
-                    _m_block_committed.inc(n["device"])
-            if n["dropped"]:
-                _m_block_dropped.inc(n["dropped"])
-            if n["device"]:
-                _m_device_choices.inc(n["device"])
-            if n["host"]:
-                _m_host_choices.inc(n["host"])
-            if chosen:
-                _m_tokens.inc(chosen)
-                _m_device_choice_pct.observe(100.0 * n["device"] / chosen)
-            counts = (rnd.out or {}).get("expert_counts")
-            if counts is not None and counts.sum():
-                _m_moe_load.observe(float(
-                    (counts.max(axis=1) / np.maximum(
-                        counts.mean(axis=1), 1e-9)).max()))
-            # ONE wake-up delivers a round's tokens, in order
-            self._close_round_locked(done, chosen > 0, notes)
-            # the round's host clock, still inside the span and the
-            # condition: once that is released the woken clients run,
-            # and what the scheduler then waits belongs to the next
-            # round's admit
-            t_end = time.perf_counter()
-            _m_sample_ms.observe(rnd.sample_s * 1e3)
-            _m_sched_ms.observe(
-                (t_end - self._t_round - step_s - rnd.sample_s) * 1e3)
-            self._t_round = t_end
+                    _m_device_choices.inc(n["device"])
+                if n["host"]:
+                    _m_host_choices.inc(n["host"])
+                if chosen:
+                    _m_tokens.inc(chosen)
+                    _m_device_choice_pct.observe(100.0 * n["device"] / chosen)
+                counts = (rnd.out or {}).get("expert_counts")
+                if counts is not None and counts.sum():
+                    _m_moe_load.observe(float(
+                        (counts.max(axis=1) / np.maximum(
+                            counts.mean(axis=1), 1e-9)).max()))
+                # ONE wake-up delivers a round's tokens, in order
+                self._close_round_locked(done, chosen > 0, notes)
+                # the round's host clock, still inside the span and the
+                # condition: once that is released the woken clients run,
+                # and what the scheduler then waits belongs to the next
+                # round's admit
+                t_end = time.perf_counter()
+                _m_sample_ms.observe(rnd.sample_s * 1e3)
+                _m_sched_ms.observe(
+                    (t_end - self._t_round - step_s - rnd.sample_s) * 1e3)
+                self._t_round = t_end
+            # the step's logits (the host route's rows came from them)
+            # are let go HERE, inside the phase and once the woken
+            # clients may run, so that freeing their device buffer
+            # (0.25-0.4 ms a round on a TPU v5e at 16 x 256k float32)
+            # is a named stretch and not a gap before the next admit
+            rnd.logits = None
 
     def _emit_locked(self, s: _Slot, tok: int, rnd: _Round,
                      route: str) -> bool:
@@ -3068,11 +3115,11 @@ class DecodeEngine:
 
     def _note_call(self, sp, slots: int, chunk: int, width: int, q_lens,
                    kv_lens, more: Dict[str, int]):
-        """What a step call that runs the attention records before it
-        is dispatched: the share of its ``slots x width`` grid that
-        holds a live page and the share of its folds the kernel's dot
-        fold takes, always, and on the ``device_call`` span where
-        one is live ``_call_work``'s args and the round's own
+        """What a step call that runs the attention records once it is
+        dispatched, while the device runs it: the share of its ``slots x
+        width`` grid that holds a live page and the share of its folds
+        the kernel's dot fold takes, always, and on the ``device_call``
+        span where one is live ``_call_work``'s args and the round's own
         (``more``)."""
         ps = self.cache.page_size
         _m_attn_grid_live.observe(
@@ -3083,8 +3130,7 @@ class DecodeEngine:
             if slots in self._kernel_slots else 0.0)
         if sp.live or self._window is not None:
             work = _call_work(slots, chunk, width, q_lens, kv_lens,
-                              self._block, page_size=ps,
-                              window=self._window)
+                              self._block, window=self._window)
             if work.get("attn_pairs_full"):
                 _m_window_skip.observe(
                     100.0 - 100.0 * work["attn_pairs_window"]

@@ -386,7 +386,7 @@ def test_no_window_traces_the_program_it_always_did(impl):
 
 def test_a_calls_window_sums_follow_the_mask():
     q, kv = np.array([1, 4, 0, 3]), np.array([30, 10, 0, 5])
-    work = _call_work(4, 4, 8, q, kv, page_size=PS, window=WINDOW)
+    work = _call_work(4, 4, 8, q, kv, window=WINDOW)
     pairs = keys = causal = 0
     for n_q, n_kv in zip(q, kv):
         seen = set()
@@ -401,8 +401,7 @@ def test_a_calls_window_sums_follow_the_mask():
     assert work["kv_tokens_window"] == keys
     assert work["attn_pairs_full"] == work["attn_pairs"] == causal
     assert work["kv_tokens_full"] == work["kv_tokens"] == kv.sum()
-    assert "attn_pairs_window" not in _call_work(4, 4, 8, q, kv,
-                                                 page_size=PS)
+    assert "attn_pairs_window" not in _call_work(4, 4, 8, q, kv)
 
 
 # --- a cache that knows layer kinds ---------------------------------------

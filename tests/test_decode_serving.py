@@ -811,7 +811,33 @@ def test_reserve_at_admission_holds_exactly_under_chunking():
 
 # --- the scheduler round's spans and host time (ISSUE 27) ---------------
 
-_ROUND = ("admit", "prepare", "step", "answer")
+_ROUND = ("admit", "prepare", "plan", "step", "answer")
+# the device call's three stretches, in order
+_CALL = ["dispatch", "wait", "release"]
+
+
+def _short(e):
+    return e["name"][len("serving.decode."):]
+
+
+def _nest(events):
+    """Events of one thread, by start and the longer first, as a forest
+    of ``(event, children)``: each child inside its parent, none
+    overlapping its sibling."""
+    top, stack = [], []
+    for e in events:
+        while stack and stack[-1][0]["end"] <= e["start"]:
+            stack.pop()
+        node = (e, [])
+        if stack:
+            parent, kids = stack[-1]
+            assert e["end"] <= parent["end"], (_short(e), _short(parent))
+            assert not kids or kids[-1][0]["end"] <= e["start"]
+            kids.append(node)
+        else:
+            top.append(node)
+        stack.append(node)
+    return top
 
 
 def _drive(eng, requests, together=False):
@@ -874,10 +900,10 @@ def _fed(prompt_len, max_new, chunk=4, alone=True):
 def test_round_spans_in_order_on_the_profilers_clock(profiler_session,
                                                      together):
     """Under a profiler session (the ring off) the scheduler thread's
-    line holds a round's seven spans in order: admit, prepare, step
-    (build then device_call inside it), answer (sample inside it), none
-    overlapping its sibling; device_call's args add up to the work the
-    submitted prompts need."""
+    line holds a round's spans in order: admit, prepare, plan, step
+    (build then device_call inside it, and inside that dispatch, wait,
+    release), answer (sample inside it), none overlapping its sibling;
+    device_call's args add up to the work the submitted prompts need."""
     eng = _engine(max_seq_len=16)
     requests = [([1, 2, 3, 4, 5, 6], dict(max_new_tokens=3, top_k=8,
                                           temperature=1.0, seed=3)),
@@ -888,38 +914,32 @@ def test_round_spans_in_order_on_the_profilers_clock(profiler_session,
     eng.stop()
     events = profiler_session.stop(prefix="serving.decode.")
     line, = {e["line"] for e in events}         # the scheduler's thread
-    short = lambda e: e["name"][len("serving.decode."):]
-    top, inside = [], {}
-    for e in events:            # by start, the longer first
-        if top and e["start"] < top[-1]["end"]:
-            assert e["end"] <= top[-1]["end"], (short(e), short(top[-1]))
-            kids = inside.setdefault(id(top[-1]), [])
-            assert not kids or kids[-1]["end"] <= e["start"]
-            kids.append(e)
-        else:
-            top.append(e)
+    top = _nest(events)
     # whole rounds; an admit pass that found nothing to run (the engine
     # idle between two requests, and at the end) is followed by another
-    names = [short(e) for e in top]
+    names = [_short(e) for e, _kids in top]
     rounds = [n for i, n in enumerate(names)
               if not (n == "admit" and names[i + 1:i + 2] != ["prepare"])]
     n_rounds = names.count("step")
     assert n_rounds >= 5
     assert rounds == list(_ROUND) * n_rounds, names
     calls = []
-    for e in top:
-        kids = [short(k) for k in inside.get(id(e), [])]
-        if short(e) == "step":
-            assert kids == ["build", "device_call"]
-            calls.append(inside[id(e)][1]["args"])
-        elif short(e) == "answer":
-            assert set(kids) <= {"sample"}
+    for e, kids in top:
+        if _short(e) == "step":
+            assert [_short(k) for k, _ in kids] == ["build", "device_call"]
+            call, inside = kids[1]
+            assert [_short(k) for k, _ in inside] == _CALL
+            assert not kids[0][1] and not any(g for _k, g in inside)
+            calls.append(call["args"])
+        elif _short(e) == "answer":
+            assert {_short(k) for k, _ in kids} <= {"sample"}
+            assert not any(g for _k, g in kids)
         else:
             assert kids == []
     # the top_k request's tokens and the first_topk sort took the host
     # route, inside sample spans; a slot whose token the step's program
     # chose (greedy here) opens none
-    n_sample = sum(short(e) == "sample" for e in events)
+    n_sample = sum(_short(e) == "sample" for e in events)
     assert n_sample == 3 + 1
     want = [_fed(len(p), kw["max_new_tokens"], alone=not together)
             for p, kw in requests]
@@ -939,8 +959,9 @@ def test_round_spans_in_order_on_the_profilers_clock(profiler_session,
 def test_grid_live_histogram_and_span_args_read_what_the_build_sent(
         profiler_session):
     """serving.decode.attn_grid_live_pct observes once a step call, span
-    or no span, and ``kv_pages`` / ``q_lanes`` of device_call say what
-    the call's grid walks beside what is live (ISSUE 31). One request
+    or no span, and device_call's args say what the build sent: the
+    compiled buckets beside the live sums (what the grid walks empty is
+    the histogram's, not an arg's). One request
     alone, page 4, chunk 4: a prompt of 6 goes in as 4 + 2, then two
     decode steps — keys in view 4, 6, 7, 8 on 1, 2, 2, 2 pages."""
     eng = _engine(max_seq_len=16)
@@ -949,10 +970,10 @@ def test_grid_live_histogram_and_span_args_read_what_the_build_sent(
     events = profiler_session.stop(prefix="serving.decode.device_call")
     calls = [e["args"] for e in events]
     assert [c["kv_tokens"] for c in calls] == [4, 6, 7, 8]
-    assert [c["kv_pages"] for c in calls] == [1, 2, 2, 2]
     assert [c["q_tokens"] for c in calls] == [4, 2, 1, 1]
-    assert [c["q_lanes"] for c in calls] == [4, 4, 1, 1]
-    assert all(c["q_lanes"] == c["slots"] * c["chunk"] for c in calls)
+    assert [c["chunk"] for c in calls] == [4, 4, 1, 1]
+    assert all(set(c) == {"slots", "chunk", "width", "q_tokens",
+                          "kv_tokens", "attn_pairs"} for c in calls)
     # the request holds 3 pages (6 + 3 tokens): width bucket 4, one slot
     assert {(c["slots"], c["width"]) for c in calls} == {(1, 4)}
     # no session open, the histogram alone: two prompts of 5 and 3 in
@@ -968,6 +989,161 @@ def test_grid_live_histogram_and_span_args_read_what_the_build_sent(
             [(1, 4), (2, 4), (2, 4), (2, 4), (1 + 1, 4), (2 + 1, 4)]]
     assert live["sum"] == pytest.approx(sum(want))
     assert (live["min"], live["max"]) == (25.0, 75.0)
+
+
+# --- the device call's stretches and the device's host gap --------------
+
+def test_the_device_calls_stretches_nest_on_both_clocks(profiler_session):
+    """With the ring on AND a profiler session collecting, each clock
+    holds the round's plan between prepare and step, and dispatch, wait
+    and release in that order inside device_call, on the scheduler's
+    thread; device_call keeps its args."""
+    from paddle_tpu.observability import tracing
+
+    eng = _engine(max_seq_len=16)
+    tracing.trace_reset()
+    tracing.trace_enable()
+    try:
+        profiler_session.start()
+        _drive(eng, [([1, 2, 3, 4, 5, 6], dict(max_new_tokens=3)),
+                     ([7, 8], dict(max_new_tokens=2))], together=True)
+        eng.stop()
+        events = profiler_session.stop(prefix="serving.decode.")
+        ring = [e for e in tracing.trace_events()
+                if e["name"].startswith("serving.decode.")]
+    finally:
+        tracing.trace_disable()
+        tracing.trace_reset()
+    ring = sorted(({"name": e["name"], "start": e["ts"],
+                    "end": e["ts"] + e["dur"], "line": e["tid"],
+                    "args": e.get("args", {})} for e in ring),
+                  key=lambda e: (e["start"], -e["end"]))
+    for clock in (events, ring):
+        assert len({e["line"] for e in clock}) == 1  # the scheduler's
+        top = _nest(clock)
+        names = [_short(e) for e, _kids in top]
+        n_steps = names.count("step")
+        assert n_steps >= 3
+        assert names.count("plan") == n_steps
+        for i, n in enumerate(names):
+            if n == "plan":
+                assert names[i - 1:i + 2] == ["prepare", "plan", "step"]
+        calls = [kids[1] for e, kids in top if _short(e) == "step"]
+        assert len(calls) == n_steps
+        for call, inside in calls:
+            assert _short(call) == "device_call"
+            assert [_short(k) for k, _ in inside] == _CALL
+            assert {"slots", "chunk", "width", "q_tokens", "kv_tokens",
+                    "attn_pairs"} <= set(call["args"])
+    # the ring's own parentage says the same: each stretch's parent is
+    # the device call
+    by_id = {e["args"]["span_id"]: _short(e) for e in ring}
+    for e in ring:
+        if _short(e) in _CALL:
+            assert by_id[e["args"]["parent_span_id"]] == "device_call"
+
+
+def _observed(monkeypatch, name):
+    """Every observation of the engine's histogram ``_m_<name>``, in
+    order."""
+    from paddle_tpu.serving import decode
+
+    seen, hist = [], getattr(decode, "_m_" + name)
+    real = hist.observe
+    monkeypatch.setattr(hist, "observe",
+                        lambda v: (seen.append(v), real(v)))
+    return seen
+
+
+def test_the_call_histograms_observe_once_a_target_step(monkeypatch):
+    """serving.decode.dispatch_ms, .release_ms and .host_gap_ms observe
+    once a step that dispatches the target program, as step_ms does;
+    the host gap holds the dispatch, so it is never the shorter of the
+    two where it is not 0."""
+    gaps = _observed(monkeypatch, "host_gap_ms")
+    sent = _observed(monkeypatch, "dispatch_ms")
+    eng = _engine(max_seq_len=16)
+    _drive(eng, [([1, 2, 3, 4, 5, 6], dict(max_new_tokens=4)),
+                 ([7, 8], dict(max_new_tokens=3))])
+    _drive(eng, [([3, 1, 4], dict(max_new_tokens=5)),
+                 ([9] * 9, dict(max_new_tokens=2))], together=True)
+    eng.stop()
+    snap = metrics.snapshot("serving.decode.")
+    steps = snap["serving.decode.steps"]
+    assert steps > 10
+    for name in ("dispatch_ms", "release_ms", "host_gap_ms", "step_ms"):
+        h = snap["serving.decode." + name]
+        assert h["count"] == steps, name
+        assert h["min"] >= 0.0
+    assert snap["serving.decode.dispatch_ms"]["min"] > 0.0
+    assert len(gaps) == len(sent) == steps
+    assert sum(g > 0 for g in gaps) > steps // 2
+    # a gap ends where its round's dispatch does and began before it
+    assert all(g >= d for g, d in zip(gaps, sent) if g > 0)
+
+
+def test_a_steps_logits_are_let_go_inside_its_answer_span(monkeypatch):
+    """A target step's logits stay on the device through the answer phase
+    (the host route's rows come from them) and are let go inside that
+    round's serving.decode.answer span, not when the round's frame ends
+    after it: the k-th step's logits go inside the k-th answer span."""
+    import weakref
+
+    from paddle_tpu.observability import tracing
+
+    gone, armed = [], []
+    real = DecodeEngine._run_step_arrays
+
+    def run(self, *args, **kw):
+        ids, logits = real(self, *args, **kw)
+        if armed:                                # not warm()'s calls
+            weakref.finalize(logits, lambda: gone.append(
+                (time.perf_counter() - tracing._EPOCH) * 1e6))
+        return ids, logits
+
+    monkeypatch.setattr(DecodeEngine, "_run_step_arrays", run)
+    eng = _engine(max_seq_len=16)
+    tracing.trace_reset()
+    tracing.trace_enable()
+    armed.append(True)
+    try:
+        _drive(eng, [([1, 2, 3, 4, 5, 6], dict(max_new_tokens=4)),
+                     ([7, 8], dict(max_new_tokens=3))], together=True)
+        eng.stop()
+        answers = sorted((e["ts"], e["ts"] + e["dur"])
+                         for e in tracing.trace_events()
+                         if e["name"] == "serving.decode.answer")
+    finally:
+        tracing.trace_disable()
+        tracing.trace_reset()
+    assert len(gone) == len(answers) > 3
+    for t, (start, end) in zip(sorted(gone), answers):
+        assert start <= t <= end
+
+
+def test_the_host_gap_is_0_behind_an_unread_step_and_skips_the_idle_wait(
+        monkeypatch):
+    """A prompt of 9 at chunk 4 goes in as 4, 4, 1: the first two chunks
+    end inside the prompt and nobody waits for them, so the round after
+    each reads a gap of 0 (the device still held a step); the rest read
+    more than 0. An engine idle for a second before a request does not
+    count the idle second in that request's first gap (the second prompt
+    shares no prefix with the first: no page of it is cached)."""
+    gaps = _observed(monkeypatch, "host_gap_ms")
+    eng = _engine(max_seq_len=16)
+    time.sleep(1.0)                              # idle from the start
+    _drive(eng, [(list(range(1, 10)), dict(max_new_tokens=3))])
+    first = list(gaps)
+    time.sleep(1.0)                              # idle between requests
+    _drive(eng, [(list(range(11, 20)), dict(max_new_tokens=3))])
+    eng.stop()
+    second = gaps[len(first):]
+    # 4 + 4 (unread), 1 (the first token), two decode steps
+    for run in (first, second):
+        assert len(run) == 5
+        assert run[1] == run[2] == 0.0
+        assert all(g > 0.0 for g in (run[0], run[3], run[4])), run
+        assert run[0] < 500.0            # not the idle second
 
 
 def test_dot_fold_share_counts_folds_by_the_kernels_predicate():

@@ -6,13 +6,14 @@ cell (ISSUE 30): `block_tokens_per_pass`, `moe_load_max_over_mean`,
 `moe_experts_roofline`, `moe_route_share_pct`, `paged_attn_block_roofline`;
 and `paged_attn_grid_live_pct` (ISSUE 31: data only, the program's histogram
 `serving.decode.attn_grid_live_pct`) and `paged_attn_dot_fold_pct` (ISSUE 35:
-data only, `serving.decode.attn_dot_fold_pct`, the benchmark's newest entry).
+data only, `serving.decode.attn_dot_fold_pct`).
 
 The benchmark's own tests live in `perf/tests` and are not collected by
 the tier-1 command; this case is, so that a tree whose BENCHMARK.json no
 longer loads with the metric fails here. ISSUE 34's two cells
 (`xglm17b_docqa`, `trinity_mini_longmix`), its configuration, its four
-metrics and its operations file are the last section.
+metrics and its operations file have a section; the last holds the three
+that read the device call's host stretches in every serving cell.
 """
 import os
 import sys
@@ -27,7 +28,7 @@ from perf.lib.loader import Benchmark  # noqa: E402
 NAME, CELL = "sched_device_choice_pct", "xglm17b_chat"
 HISTOGRAM = "serving.decode.device_choice_pct"
 # the data-only metrics read from a histogram's mean in both serving cells:
-# name -> (histogram, layer); ISSUE 35's is the benchmark's newest entry
+# name -> (histogram, layer)
 HISTOGRAM_AVG = {
     NAME: (HISTOGRAM, "scheduler"),
     "paged_attn_grid_live_pct": ("serving.decode.attn_grid_live_pct",
@@ -47,7 +48,8 @@ def test_a_histogram_metric_loads_and_reads_the_histograms_avg(bench, name):
     bench.check_files()
     entry, = [m for m in bench.doc["per_layer"] if m["name"] == name]
     # ISSUE 34 appended its two cells to the list, and its four metrics
-    # behind ISSUE 31's; ISSUE 35's stands behind those, the last
+    # behind the grid's live share; the dot fold's share behind those, and
+    # the three metrics of the device call's stretches behind it
     assert entry == {
         "name": name, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": layer,
@@ -56,7 +58,7 @@ def test_a_histogram_metric_loads_and_reads_the_histograms_avg(bench, name):
                       "trinity_mini_longmix"]}
     assert [m["name"] for m in bench.doc["per_layer"]].index(
         "paged_attn_grid_live_pct") == 18
-    assert [m["name"] for m in bench.doc["per_layer"]][19:] == [
+    assert [m["name"] for m in bench.doc["per_layer"]][19:24] == [
         "kv_prefix_hit_pct", "paged_attn_window_roofline",
         "attn_window_skip_pct", "kv_window_held_pct",
         "paged_attn_dot_fold_pct"]
@@ -252,14 +254,15 @@ def test_a_device_calls_pairs_follow_the_models_mask(block, q, kv):
     from paddle_tpu.serving.decode import _call_work
 
     work = _call_work(2, q, 8, np.array([q, 0]), np.array([kv, 0]),
-                      block=block, page_size=8)
+                      block=block)
     lanes = range(kv - q, kv)
     assert work["attn_pairs"] == sum(
         min(kv, (i // block + 1) * block) for i in lanes)
     assert (work["q_tokens"], work["kv_tokens"]) == (q, kv)
-    # what is walked beside what is live: 2 x 8 table columns of which
-    # the live slot's pages hold a key, and 2 x q lanes of which q do
-    assert (work["kv_pages"], work["q_lanes"]) == (-(-kv // 8), 2 * q)
+    # the compiled buckets beside the sums, and nothing else: what the
+    # grid walks empty is the histogram serving.decode.attn_grid_live_pct's
+    assert work == {"slots": 2, "chunk": q, "width": 8, "q_tokens": q,
+                    "kv_tokens": kv, "attn_pairs": work["attn_pairs"]}
 
 
 def test_the_trace_reduction_tells_the_paged_kernel_from_the_grouped_products(
@@ -511,3 +514,68 @@ def test_the_program_registers_what_issue_34s_metrics_read():
     for name in ("serving.kv.window.pages_released",
                  "serving.prefix.cached_tokens"):
         assert name in snap
+
+
+# --- the device call's host stretches, in every serving cell --------------
+
+SERVING = ["xglm17b_chat", "sdar30b_blockgen", DOCQA, LONGMIX]
+# name -> (unit, layer, histogram, statistic or None for a python reader)
+PR39 = {"serve_dispatch_ms": ("ms", "model step",
+                              "serving.decode.dispatch_ms", "p50"),
+        "sched_release_ms": ("ms", "scheduler",
+                             "serving.decode.release_ms", "p50"),
+        "sched_host_gap_pct": ("%", "scheduler",
+                               "serving.decode.host_gap_ms", None)}
+
+
+@pytest.mark.parametrize("name", sorted(PR39))
+def test_a_stretch_metric_loads_and_reads_what_its_file_says(bench, name):
+    unit, layer, histogram, stat = PR39[name]
+    bench.check_files()
+    entry, = [m for m in bench.doc["per_layer"] if m["name"] == name]
+    assert entry == {"name": name, "unit": unit, "better": "lower",
+                     "source": "program_span", "layer": layer,
+                     "moves": "serve_tokens_per_s", "workloads": SERVING}
+    # appended, in this order, behind every metric that was there
+    assert [m["name"] for m in bench.doc["per_layer"]][-3:] == [
+        "serve_dispatch_ms", "sched_release_ms", "sched_host_gap_pct"]
+    assert name not in [m["name"] for m, _d in
+                        bench.per_layer("resnet50_train")]
+    for cell in SERVING:
+        assert entry["moves"] in bench.end_to_end(cell)
+        (found, desc), = [(m, d) for m, d in bench.per_layer(cell)
+                          if m["name"] == name]
+        assert found is entry
+        assert (desc["name"], desc["unit"], desc["layer"], desc["source"],
+                desc["moves"]) == (name, unit, layer, "program_span",
+                                   "serve_tokens_per_s")
+    h = {"count": 171, "sum": 855.0, "avg": 5.0, "min": 0.0, "max": 9.0,
+         "p50": 4.75}
+    facts = {"histograms": {histogram: h}, "window_s": 51.0,
+             "trace": None}
+    if stat is None:
+        assert desc["reader"] == "python"
+        # 855 ms of host gap in a window of 51 s
+        assert bench.read_layer_metric(entry, desc, facts) == \
+            pytest.approx(100.0 * 855.0 / 51000.0)
+    else:
+        assert (desc["reader"], desc["histogram"], desc["stat"]) == (
+            "histogram", histogram, stat)
+        assert not os.path.exists(bench.path("layer_metrics", name + ".py"))
+        assert bench.read_layer_metric(entry, desc, facts) == 4.75
+    # the parent's case: a program without the histogram, or a window
+    # that observed nothing, gives nothing to read and does not raise
+    assert bench.read_layer_metric(entry, desc, {
+        "histograms": {}, "window_s": 51.0}) is None
+    assert bench.read_layer_metric(entry, desc, {
+        "histograms": {histogram: dict(h, count=0)},
+        "window_s": 51.0}) is None
+
+
+def test_the_program_registers_what_the_stretch_metrics_read():
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.serving import decode  # noqa: F401  (registers them)
+
+    snap = metrics.snapshot("serving.decode.")
+    for _unit, _layer, histogram, _stat in PR39.values():
+        assert isinstance(snap[histogram], dict)
