@@ -91,14 +91,22 @@ the device looks like is decided in one place each:
     buckets and refuses a feed past its slot's reservation;
   - the ROUND (``_step``): prepare -> PLAN (per slot: which pass, what
     it feeds, whether anybody reads its choice) -> build -> device call
-    -> ANSWER (per slot, by the pass it ran) -> retire -> notify. What a
-    causal slot and a block slot differ in is the plan and the answer
-    (``_plan_causal`` / ``_plan_blocks``; ``_answer_plain`` /
-    ``_answer_spec`` / ``_answer_block``), chosen by the model's block
-    length and the slot's state; buckets, spans, dispatch, waiting,
-    timing, retirement and the wake-up are written once. The embed lane
-    keeps its own slot list and scoring and shares the builder, the run
-    function and the retire/notify tail.
+    -> ANSWER (per slot, by the pass it ran) -> retire -> notify, ONE
+    STEP AHEAD: round n+1 is planned and dispatched before
+    round n's ids reach the host, and round n is answered after it.
+    What the plan knows without the answer it counts (positions,
+    grants, who reaches ``max_new``, a block's pass); the token a
+    decoding slot feeds and a block's lanes the device feeds from round
+    n (``feed_tokens``). A round waits for the answer before it where
+    that answer is the host's to give: a token the host chooses, a
+    draft's propose/verify, a preemption (``_waits_first``,
+    ``_reclaim_for_growth``). What a causal slot and a block slot differ
+    in is the plan and the answer (``_plan_causal`` / ``_plan_blocks``;
+    ``_answer_plain`` / ``_answer_spec`` / ``_answer_block``), chosen by
+    the model's block length and the slot's state; buckets, spans,
+    dispatch, waiting, timing, retirement and the wake-up are written
+    once. The embed lane keeps its own slot list and scoring and shares
+    ``_build_arrays``, the run function and the retire/notify tail.
 
 LAYER KINDS (ISSUE 34). The engine asks the model what each layer keeps
 of a sequence (``layer_kinds``: ``"full"`` or ``"window"``, and the
@@ -146,7 +154,8 @@ from .kv_cache import GARBAGE_PAGE, HostSpillStore, PagedKvCache
 __all__ = ["DecoderSpec", "DecodeEngine", "build_decoder_params",
            "seeded_decoder_arrays", "decoder_step",
            "decoder_step_chunked", "width_ladder", "sample_token",
-           "choose_tokens", "choose_block", "validate_draft_spec"]
+           "choose_tokens", "choose_block", "feed_tokens",
+           "validate_draft_spec"]
 
 _log = get_logger("serving")
 
@@ -158,7 +167,8 @@ _m_tokens = _metrics.counter("serving.decode.tokens")
 _m_overloads = _metrics.counter("serving.decode.overloads")
 _m_deadline_miss = _metrics.counter("serving.decode.deadline_misses")
 _m_cancels = _metrics.counter("serving.decode.cancels")
-# one inc per DISTINCT (slots, width) shape the step compiles — after
+# one inc per DISTINCT (slots, width) shape the step compiles, and per
+# shape of the host route's row fetch and of the device feed — after
 # warm() this must never move again (the tier-1 churn guard pins it)
 _m_compiles = _metrics.counter("serving.decode.compiles")
 _m_step_ms = _metrics.histogram("serving.decode.step_ms")
@@ -184,14 +194,19 @@ _m_sched_ms = _metrics.histogram("serving.decode.sched_ms")
 # clients, admit, prepare, plan, build and dispatch; the device starts
 # the step inside the dispatch, and the latency from its last operation
 # to the ids on the host lies outside the stretch). It
-# reads 0 where the step before was not waited for (its chunks all ended
-# inside their prompts: the device still held it), and a wait for work
-# restarts it, as it restarts the round's clock. Other device calls
+# reads 0 where the step went ahead of the one before (that step's ids
+# were not yet on the host: the device still held it), and a wait for
+# work restarts it, as it restarts the round's clock. Other device calls
 # between two target dispatches (page copies, restores, a host-route
-# row's fetch, the propose/verify substep, the embed lane) lie inside it
+# row's fetch, the propose/verify substep, the embed lane) lie inside it.
+# ahead_pct observes, once a target dispatch, 100 where the step was
+# dispatched before the ids of the step before it were on the host, and
+# 0 where the host had them first (a synchronous round, or the first
+# step after a wait for work)
 _m_dispatch_ms = _metrics.histogram("serving.decode.dispatch_ms")
 _m_release_ms = _metrics.histogram("serving.decode.release_ms")
 _m_host_gap_ms = _metrics.histogram("serving.decode.host_gap_ms")
+_m_ahead_pct = _metrics.histogram("serving.decode.ahead_pct")
 # where a token was chosen (ISSUE 29), one increment a chosen token: by
 # the step's own program (``choose_tokens``: greedy, and temperature > 0
 # with top_k 0) or on the host from one fetched logits row (top_k > 0, a
@@ -418,6 +433,49 @@ def choose_block(logits, temperature, seed, positions, masked, n_unmask):
         rank = jnp.sum(ahead, axis=-1)                   # [S, B]
         unmask = masked & (rank < n_unmask[:, None])
     return x0, conf, unmask
+
+
+def feed_tokens(tokens, src, ids, masked=None, unmask=None, prev_tokens=None,
+                prev_masked=None):
+    """The next step's input where the host does not yet hold what it
+    feeds: the step before it chose that on the device. ``tokens [S, C]``
+    int32 are the host-built rows; ``src [S]`` int32 names, a row, the
+    row of the step before whose answer it continues from (-1: the host
+    filled the row, a prefill chunk or a new admission); ``ids`` is what
+    that step's program handed back. A causal row takes ``ids[src]`` at
+    lane 0, the token it decodes. A block model's (``masked [S, B]``
+    given, with that step's ``unmask``, its input ``prev_tokens`` and
+    ``prev_masked``) carries on with its block: the lanes that pass
+    unmasked take its choices, the rest keep their input, and a lane
+    stays masked where it was and was not unmasked. Returns ``(tokens,
+    masked)``, ``masked`` None for a causal model."""
+    import jax.numpy as jnp
+
+    take = src >= 0
+    row = jnp.maximum(src, 0)
+    if masked is None:
+        return tokens.at[:, 0].set(
+            jnp.where(take, ids[row], tokens[:, 0])), None
+    b = ids.shape[1]
+    opened = unmask[row]
+    block = jnp.where(opened, ids[row], prev_tokens[row, :b])
+    carry = take[:, None]
+    return (tokens.at[:, :b].set(jnp.where(carry, block, tokens[:, :b])),
+            jnp.where(carry, prev_masked[row] & ~opened, masked))
+
+
+_feed_jit = None
+
+
+def _feed_program():
+    """``feed_tokens`` jitted once a process: every engine's calls of one
+    shape share its compiled program."""
+    global _feed_jit
+    if _feed_jit is None:
+        import jax
+
+        _feed_jit = jax.jit(feed_tokens)
+    return _feed_jit
 
 
 def _live_pages(kv_lens, page_size: int) -> int:
@@ -713,9 +771,14 @@ class _Round:
     """One decoding round between its plan and its answers (the
     scheduler thread's own): which slots ride the target program's call
     and what each feeds, what the call handed back, and what the slots'
-    answers tally for the round's counters."""
+    answers tally for the round's counters. The next round is planned
+    and dispatched before this one is answered, from what the plan
+    knows: how many tokens each slot is owed, which end by ``max_new``,
+    and a block's masked lanes left."""
 
-    def __init__(self):
+    def __init__(self, live: List[_Slot], grants: List[int]):
+        # the slots answered at this round, with their grants
+        self.live, self.grants = live, grants
         # the plan: the call's slots in row order, each row's (start
         # position, tokens) feed, a block model's pass a row, and the
         # decoding slots a draft proposes for (they ride no row)
@@ -724,20 +787,40 @@ class _Round:
         self.feeds: List[Tuple[int, Any]] = []
         self.kinds: List[str] = []
         self.spec: List[_Slot] = []
-        # whether a slot of the call reads its choice (a call whose
-        # chunks all end inside their prompts is not waited for)
+        # a row's source in the round before it (-1: the host fills the
+        # row; else that round's row whose choice this row feeds on)
+        self.src: List[int] = []
+        # whether a slot of the call reads its choice, and whether one
+        # reads a token the HOST chooses (the next round then waits)
         self.reads = False
+        self.host = False
         self.prefill_toks = 0
+        # tokens the answer will emit a slot, {id(slot): n}, as far as
+        # max_new lets; the slots that reach max_new with them; a block
+        # slot's masked lanes left after its denoise pass, {id(slot): n};
+        # the blocks the plan opened, {row: (block, masked)}; the rows
+        # whose logits row the answer reads
+        self.owed: Dict[int, int] = {}
+        self.ends: set = set()
+        self.left: Dict[int, int] = {}
+        self.opened: Dict[int, Tuple[List[int], List[bool]]] = {}
+        self.wanted: List[int] = []
         # a block program's further arrays, a row a slot of the bucket,
         # and the device_call span's further args
         self.call_kw: Dict[str, np.ndarray] = {}
         self.call_args: Dict[str, int] = {}
-        # the call: what it answered, on the host once somebody reads it
-        # (``ids`` and whatever else the program hands back), its logits
-        # on the device, and the substep's {id(slot): (committed, k_eff,
-        # accepted)}
+        # the call: what the program handed back, on the device until the
+        # answer collects it (``dev``) and on the host once somebody
+        # reads it (``out``: ``ids`` and whatever else the program hands
+        # back); the tokens and masked lanes it was fed (the next round's
+        # block rows carry on from them); its logits and the wanted rows
+        # of them on the device; and the substep's {id(slot): (committed,
+        # k_eff, accepted)}
+        self.dev: Optional[Dict[str, Any]] = None
+        self.fed: Tuple[Any, Any] = (None, None)
         self.out: Optional[Dict[str, np.ndarray]] = None
         self.logits = None
+        self.rows_out: Dict[int, Any] = {}
         self.spec_out: Dict[int, Tuple[List[int], int, int]] = {}
         # the answers' tallies: tokens chosen on the "device" / the
         # "host", draft tokens "proposed" / "accepted", a block model's
@@ -1078,9 +1161,12 @@ class DecodeEngine:
         # host clock — where the last one ended, or where a wait for
         # work did (serving.decode.sched_ms)
         self._t_round = 0.0
+        # scheduler-thread only: the round's seconds in step_ms's stretch
+        # and in waits for a round's ids, which sched_ms leaves out
+        self._waited = 0.0
         # scheduler-thread only: when the newest dispatched step's ids
         # came to the host, or where a wait for work ended; None while
-        # the device still holds a step nobody waited for
+        # the device holds a step whose ids the host has not collected
         # (serving.decode.host_gap_ms)
         self._t_back: Optional[float] = 0.0
         self._compiled_shapes: set = set()  # guarded-by: _step_mu
@@ -1236,9 +1322,13 @@ class DecodeEngine:
             lambda logits, i: jax.lax.dynamic_index_in_dim(
                 logits, i, 0, keepdims=False))  # guarded-by: _step_mu
         self._row_shapes: set = set()  # guarded-by: _step_mu
-        # the ids of a step nobody read a token of, still on the device
-        # (_await_ids); the scheduler thread's own
-        self._unread = None
+        # the shapes the device feed (feed_tokens) has been called at,
+        # counted as the row fetch's are
+        self._feed_shapes: set = set()  # guarded-by: _step_mu
+        # the newest dispatched round, not yet answered: the next round is
+        # planned and dispatched before it is (_step); the scheduler
+        # thread's own
+        self._pending: Optional[_Round] = None
         # serializes warm() (caller thread) against live steps (the
         # scheduler thread): read-pools -> step -> rebind must be
         # atomic or concurrent rebinds silently drop KV writes
@@ -1377,16 +1467,26 @@ class DecodeEngine:
         speculative churn still performs zero post-warm compiles.
         Every program chooses its tokens itself (all-greedy here: the
         sampling arrays are data, not shape), and the row fetch of the
-        host route warms once a slot count."""
+        host route warms once a slot count. The device feed of a step
+        that goes ahead of the one before it (``feed_tokens``) warms for
+        every pair of the two calls' (slots, chunk) buckets, and every
+        target triple runs again with its tokens (a block model's masked
+        lanes too) from that feed, the form such a step hands them in."""
         with _tracing.span("serving.decode.warmup", model=self.name,
                            version=self.version):
+            calls: Dict[Tuple[int, int], _Round] = {}
             for s in self._slot_ladder:
                 for w in self._width_ladder:
                     def dead(c):
                         return self._build_arrays((), (), s, c, w)
 
                     for c in self._chunk_ladder:
-                        _ids, logits = self._run_step_arrays(*dead(c))
+                        arrays = dead(c)
+                        out, logits = self._run_step_arrays(*arrays)
+                        calls[s, c] = call = _Round([], [])
+                        call.dev = out if isinstance(out, dict) \
+                            else {"ids": out}
+                        call.fed = (arrays[0], self._dead_masked(s))
                     if self._spec_k:
                         _ids, lanes = self._run(
                             "verify", *dead(self._verify_lanes))
@@ -1406,6 +1506,30 @@ class DecodeEngine:
                 self._fetch_row(logits, 0)
                 if self._spec_k:
                     self._fetch_row(lanes, 0)
+            # a block round carries on from a round that was host-built or
+            # itself fed from the device: both forms of the inputs it
+            # reads, so a second sweep feeds from the first one's outputs
+            fed = {}
+            for _sweep in range(2 if self._block > 1 else 1):
+                for (s, c), call in calls.items():
+                    src = np.full(s, -1, np.int32)
+                    for prev in calls.values():
+                        fed[s, c] = self._feed(call.fed[0], src, prev,
+                                               self._dead_masked(s))
+                for key, call in calls.items():
+                    call.fed = fed[key]
+            for (s, c), (tokens, masked) in fed.items():
+                for w in self._width_ladder:
+                    arrays = self._build_arrays((), (), s, c, w)
+                    self._run_step_arrays(tokens, *arrays[1:],
+                                          **({} if masked is None
+                                             else {"masked": masked}))
+
+    def _dead_masked(self, rows: int):
+        """A block program's ``masked`` for a call of ``rows`` dead rows
+        (None for a causal model, whose program takes none)."""
+        return (np.zeros((rows, self._block), bool) if self._block > 1
+                else None)
 
     def submit(self, prompt: Sequence[int], max_new_tokens: int = 16,
                deadline_ms: Optional[float] = None,
@@ -1935,17 +2059,20 @@ class DecodeEngine:
                 queue[:] = [r for r in queue if r not in late]
                 self._g_depth.set(len(self._queue))
 
-    def _admit_locked(self):
+    def _admit_locked(self, ending: frozenset = frozenset()):
         """Move queued requests into free slots. Continuous mode admits
         whenever a slot is free — INTO the in-flight batch; drain mode
-        (the bench baseline) only refills an empty batch. A request
+        (the bench baseline) only refills an empty batch. The slots in
+        ``ending`` reach ``max_new`` in the round not yet answered: they
+        ride no further round, so theirs count as free. A request
         whose reservation was surrendered (preempted victims sit at the
         queue FRONT, demoted reservations wherever they were) must
         re-reserve first; a refusal leaves it queued — completions and
         cache evictions free the pages it is waiting for."""
-        if not self._continuous and self._slots:
+        riding = sum(1 for s in self._slots if s not in ending)
+        if not self._continuous and riding:
             return
-        while self._queue and len(self._slots) < self._max_slots:
+        while self._queue and riding < self._max_slots:
             req = self._queue[0]
             if req.ev.is_set():
                 # canceled / expired while queued — already failed
@@ -2001,6 +2128,7 @@ class DecodeEngine:
             slot.steps = req.carry_steps
             slot.first_token_steps = req.carry_fts
             self._slots.append(slot)
+            riding += 1
             _m_admitted.inc()
             _m_queue_wait.observe((time.monotonic() - req.t_enq) * 1e3)
         # embed admission: its own slot lane, capped by the same ladder
@@ -2020,14 +2148,16 @@ class DecodeEngine:
         self._g_live.set(len(self._slots))
         self._g_embed.set(len(self._embed_slots))
 
-    def _next_live(self
+    def _next_live(self, ending: Optional[frozenset]
                    ) -> Optional[Tuple[List[_Slot], List[_EmbedSlot]]]:
         # the admit span starts BEFORE the condition is taken (so it is
         # entered and left by hand): getting the condition back from
         # the clients the last step woke is the scheduler's time. The
         # round's host clock (_t_round) and the device's host gap
         # (_t_back) run on from the last round; only a wait for work,
-        # below, restarts them
+        # below, restarts them. ``ending`` is None with no round waiting
+        # for its answer; else the slots that round ends, and the
+        # scheduler does not wait for work: it has that answer to give
         admit = _tracing.span("serving.decode.admit")
         admit.__enter__()
         # lint: allow-blocking — Condition.wait on the engine's own
@@ -2035,13 +2165,15 @@ class DecodeEngine:
         with self._cond:
             while True:
                 self._drop_expired_locked(time.monotonic())
-                self._admit_locked()
+                self._admit_locked(ending or frozenset())
                 admit.__exit__(None, None, None)
                 if self._slots or self._embed_slots:
                     return list(self._slots), list(self._embed_slots)
                 if self._stopping and not self._queue \
                         and not self._embed_queue:
                     return None
+                if ending is not None:
+                    return [], []
                 # no live slots here implies the queues are (almost
                 # always) empty too — admission can't fail with every
                 # slot free — so idle blocks untimed on submit()/stop()
@@ -2052,6 +2184,7 @@ class DecodeEngine:
                                          or self._embed_queue)
                                 else None)
                 self._t_round = self._t_back = time.perf_counter()
+                self._waited = 0.0
                 admit = _tracing.span("serving.decode.admit")
                 admit.__enter__()
 
@@ -2059,25 +2192,37 @@ class DecodeEngine:
         # thread's own clocks: nobody else reads or writes them
         self._t_round = self._t_back = time.perf_counter()
         while True:
-            nxt = self._next_live()
+            pend = self._pending
+            nxt = self._next_live(None if pend is None
+                                  else frozenset(pend.ends))
             if nxt is None:
+                # retiring: the round still waiting is answered (a request
+                # stop(drain=False) failed is dropped there)
+                self._settle()
                 return
             live, elive = nxt
             try:
                 if live:
                     self._step(live)
+                else:
+                    self._settle()
                 if elive:
                     # the embed lane runs AFTER the decode step each
                     # round: decode tokens never stall behind scoring,
                     # and a mixed churn interleaves the two lanes 1:1
                     self._embed_step(elive)
                     # the embed lane's time is no decode round's
-                    self._t_round = time.perf_counter()
+                    self._t_round, self._waited = time.perf_counter(), 0.0
             except BaseException as e:  # a broken step fails ITS slots
                 _log.error("decode step on %s v%d failed: %s: %s",
                            self.name, self.version, type(e).__name__, e)
                 err = (e if isinstance(e, ServingError) else
                        ServingError(f"{type(e).__name__}: {e}"))
+                # the round waiting for its answer goes with it: its
+                # slots are failed below with the rest
+                if pend is not None:
+                    live = live + [s for s in pend.live if s not in live]
+                self._pending = None
                 with self._cond:
                     for s in live + elive:
                         if not s.req.ev.is_set():
@@ -2251,35 +2396,102 @@ class DecodeEngine:
                          lens, temperature=temperature, seed=seed,
                          masked=masked, n_unmask=n_unmask)
 
-    def _await_ids(self, ids, chooses: bool):  # lint: allow-unguarded(_unread)
-        """The plain step's chosen ids ``[B]`` on the host, where a slot
-        of the step reads its token from them. A step whose chunks all
-        end inside their prompts chooses nothing anybody reads: the
-        scheduler does not wait for it, so the host's share of the next
-        round hides behind it. It waits for the one before instead, so
-        that at most two steps are ever queued on the device and a
-        cancel or a new request is never more than a step late.
-        ``_unread`` is the scheduler thread's own."""
-        unread, self._unread = self._unread, None
-        if chooses:
-            return np.asarray(ids)
-        if unread is not None:
-            unread.block_until_ready()
-        self._unread = ids
-        return None
+    def _feed(self, tokens, src, prev: _Round, masked=None):
+        """``feed_tokens`` for a call whose rows ``src`` names carry on
+        from ``prev``'s answer, which the host does not hold yet: what
+        that call handed back and was fed, still on the device. Counted
+        as a compiled shape the first time a shape is called, as the row
+        fetch is (``warm()`` calls every one)."""
+        args = (tokens, src, prev.dev["ids"])
+        if masked is not None:
+            args += (masked, prev.dev["unmask"]) + tuple(prev.fed)
+        key = tuple(np.shape(a) for a in args)
+        with self._step_mu:
+            if key not in self._feed_shapes:
+                self._feed_shapes.add(key)
+                _m_compiles.inc()
+            return _feed_program()(*args)
 
-    def _fetch_row(self, logits, row: int) -> np.ndarray:
-        """Row ``row`` of a step's logits on the host: ``[vocab]`` of
-        the plain step's and the draft's, ``[lanes, vocab]`` of the
-        verify's. The host route's only transfer: one row, not the
-        batch. Counted like a step shape (``serving.decode.compiles``
-        pins it after warm) but kept out of ``stats()``'s
-        ``compiled_shapes``, which are the steps'."""
+    def _row_async(self, logits, row: int):
+        """Row ``row`` of a step's logits, its copy to the host started:
+        ``[vocab]`` of the plain step's and the draft's, ``[lanes,
+        vocab]`` of the verify's and a block model's. The host route's
+        only transfer: one row, not the batch. Counted like a step shape
+        (``serving.decode.compiles`` pins it after warm) but kept out of
+        ``stats()``'s ``compiled_shapes``, which are the steps'."""
         with self._step_mu:
             if logits.shape not in self._row_shapes:
                 self._row_shapes.add(logits.shape)
                 _m_compiles.inc()
-            return np.asarray(self._row_fn(logits, np.int32(row)))
+            out = self._row_fn(logits, np.int32(row))
+        out.copy_to_host_async()
+        return out
+
+    def _fetch_row(self, logits, row: int) -> np.ndarray:
+        """Row ``row`` of a step's logits on the host (``_row_async``)."""
+        return np.asarray(self._row_async(logits, row))
+
+    def _collect(self, rnd: _Round, sp=None):
+        """Wait for a dispatched round's ids: on the host where a slot of
+        it reads its choice (``rnd.out``), else only done on the device,
+        so that at most two steps are ever queued. ``sp`` is the device
+        call's span that waits, which records the per-expert counts'
+        touched sets."""
+        if rnd.dev is None:
+            return
+        if rnd.reads:
+            rnd.out = {k: np.asarray(a) for k, a in rnd.dev.items()}
+            if sp is not None and sp.live and "expert_counts" in rnd.out:
+                sp.set_arg("moe_experts_touched", int(
+                    (rnd.out["expert_counts"] > 0).sum()))
+        else:
+            rnd.dev["ids"].block_until_ready()
+
+    def _release(self, rnd: _Round):
+        """Let go of a collected round's device outputs: what the host
+        needs of them is on the host, and a round that carries on from
+        them was dispatched before they were collected."""
+        if rnd.dev is None:
+            return
+        with _tracing.span("serving.decode.release"):
+            t = time.perf_counter()
+            rnd.dev = None
+            rnd.fed = (None, None)
+            _m_release_ms.observe((time.perf_counter() - t) * 1e3)
+
+    def _settle(self):
+        """Answer the round waiting for its answer with no step queued
+        behind it: the round before one that cannot go ahead of it, the
+        last before the engine runs out of work, or the round a
+        preemption would take a slot of. Its ids are waited for, and from
+        then on the device holds nothing until the next dispatch."""
+        rnd, self._pending = self._pending, None
+        if rnd is None:
+            return
+        t0 = time.perf_counter()
+        with _tracing.span("serving.decode.wait"):
+            self._collect(rnd)
+        self._t_back = time.perf_counter()
+        self._release(rnd)
+        self._waited += time.perf_counter() - t0
+        self._answer(rnd)
+
+    def _waits_first(self, pend: _Round, live: List[_Slot]) -> bool:
+        """Whether the round about to be planned must have ``pend``'s
+        answer first, from what the slots carry: a token of it that the
+        host chooses (``_draws_on_host``), a draft's propose/verify in
+        it, or one about to be (a decoding slot, under a draft, whose
+        proposals start from the token ``pend`` chooses)."""
+        return pend.host or bool(pend.spec) or (self._spec_k and any(
+            not s.req.ev.is_set() and s.pos >= len(s.req.prompt)
+            and s.req.mask is None for s in live))
+
+    @staticmethod
+    def _due(s: _Slot, pend: Optional[_Round]) -> int:
+        """The tokens a slot will have produced once every dispatched
+        round is answered (``eos_id`` aside)."""
+        return len(s.req.produced) + (
+            pend.owed.get(id(s), 0) if pend is not None else 0)
 
     def _prepare(self, live: List[_Slot]
                  ) -> Tuple[List[_Slot], List[int]]:
@@ -2414,8 +2626,10 @@ class DecodeEngine:
         newest QUEUED reservation first (it has no computed work to
         lose — admission re-reserves it later), else preempt the
         newest live slot other than the grower (spill + requeue at the
-        front). Mutates ``live`` in place when it preempts. False =
-        nothing left to take."""
+        front). A victim spills what its answered tokens wrote: a round
+        still waiting for its answer is answered first, and the slots it
+        retires leave ``live`` (the caller asks again). Mutates ``live``
+        in place. False = nothing left to take."""
         with self._cond:
             for req in reversed(self._queue):
                 if req.ev.is_set() or req.needs_alloc:
@@ -2428,6 +2642,11 @@ class DecodeEngine:
                 req.needs_alloc = True
                 _m_demotions.inc()
                 return True
+        if self._pending is not None:
+            self._settle()
+            with self._cond:
+                live[:] = [s for s in live if s in self._slots]
+            return True
         victim = None
         for s in reversed(live):
             if s is grower or s.req.ev.is_set():
@@ -2722,23 +2941,49 @@ class DecodeEngine:
             out[id(s)] = (committed, keff[i], accepted)
         return out
 
-    def _plan_causal(self, live: List[_Slot], grants: List[int]) -> _Round:
+    def _plan_causal(self, live: List[_Slot], grants: List[int],
+                     pend: Optional[_Round]) -> _Round:
         """A causal model's round: decoding slots with a draft attached
         ride the propose/verify substep; prefill chunks (and everything
         when speculation is off) ride the target program's chunked step,
-        each feeding its ``grant`` next tokens."""
-        rnd = _Round()
+        each feeding its ``grant`` next tokens from ``pos``, which moves
+        past them here. A decoding slot feeds the token ``pend`` chooses
+        from the device (``src``): ``pend`` is not answered yet."""
+        rnd = _Round(live, grants)
         for s, g in zip(live, grants):
-            if self._spec_k and not s.req.ev.is_set() \
-                    and s.pos >= len(s.req.prompt) and s.req.mask is None:
+            req = s.req
+            if self._spec_k and not req.ev.is_set() \
+                    and s.pos >= len(req.prompt) and req.mask is None:
                 rnd.spec.append(s)
                 continue
-            rnd.row_of[id(s)] = len(rnd.rows)
+            row = len(rnd.rows)
+            rnd.row_of[id(s)] = row
             rnd.rows.append(s)
-            rnd.feeds.append((s.pos, s.tokens_at(s.pos, g)))
-            if s.pos < len(s.req.prompt):
+            if s.pos < len(req.prompt) + len(req.produced):
+                rnd.feeds.append((s.pos, s.tokens_at(s.pos, g)))
+                rnd.src.append(-1)
+            else:
+                # a canceled slot rides through the garbage table
+                rnd.feeds.append((s.pos, [0] * g))
+                rnd.src.append(-1 if req.ev.is_set()
+                               else pend.row_of[id(s)])
+            if s.pos < len(req.prompt):
                 rnd.prefill_toks += g
-            rnd.reads |= s.pos + g >= len(s.req.prompt)
+            s.pos += g
+            if s.pos < len(req.prompt):
+                continue
+            # the newest lane's choice is read: one token owed
+            rnd.reads = True
+            rnd.owed[id(s)] = 1
+            due = self._due(s, pend)
+            if due + 1 >= req.max_new:
+                rnd.ends.add(s)
+            if self._draws_on_host(req):
+                rnd.host = True
+                rnd.wanted.append(row)
+            elif req.want_topk and not due:
+                # the first generated token: its row gives first_topk
+                rnd.wanted.append(row)
         per_token = self.spec.moe_assignments_per_token
         if per_token:
             rnd.n["assignments"] = per_token * sum(
@@ -2746,18 +2991,21 @@ class DecodeEngine:
             rnd.call_args = {"moe_assignments": rnd.n["assignments"]}
         return rnd
 
-    def _open_block(self, s: _Slot):
-        """Open the block at ``[s.pos, s.pos + B)``: the tokens that are
-        known there (the ``P mod B`` prompt tokens left over by the
-        prefill of whole blocks, in the first generated block) stay
-        unmasked, every other lane is masked."""
-        known = len(s.req.prompt) + len(s.req.produced)
+    def _open_block(self, s: _Slot, known: int
+                    ) -> Tuple[List[int], List[bool]]:
+        """The block at ``[s.pos, s.pos + B)`` as it opens, ``(tokens,
+        masked)``: the tokens the sequence holds there (the ``P mod B``
+        prompt tokens left over by the prefill of whole blocks, in the
+        first generated block) stay unmasked, every other lane is masked.
+        ``known``: the sequence's tokens once every dispatched round is
+        answered."""
         lanes = range(s.pos, s.pos + self._block)
-        s.masked = [p >= known for p in lanes]
-        s.block = [self.spec.mask_token_id if m else s.token_at(p)
-                   for p, m in zip(lanes, s.masked)]
+        masked = [p >= known for p in lanes]
+        return ([self.spec.mask_token_id if m else s.token_at(p)
+                 for p, m in zip(lanes, masked)], masked)
 
-    def _plan_blocks(self, live: List[_Slot], grants: List[int]) -> _Round:
+    def _plan_blocks(self, live: List[_Slot], grants: List[int],
+                     pend: Optional[_Round]) -> _Round:
         """The round of a model that generates by diffusion over blocks
         (ISSUE 30). Slot by slot the ONE jitted call carries a prefill
         chunk of whole blocks, a DENOISE pass (the block with the mask
@@ -2766,45 +3014,95 @@ class DecodeEngine:
         lane is masked, the COMMIT pass (the B real tokens, whose K/V
         later blocks read), B lanes each. Every pass writes its lanes'
         K/V (write-before-attend; the commit pass's write is the one
-        that stands)."""
+        that stands). A pass that carries on from ``pend``'s denoise pass
+        of its block, not answered yet, takes its input block and masked
+        lanes from the device (``src``): a pass unmasks ``B /
+        denoise_steps`` lanes, so the host counts the lanes left, and so
+        the pass."""
         bl = self._block
-        rnd = _Round()
+        rnd = _Round(live, grants)
         rnd.rows = live
         rnd.row_of = {id(s): i for i, s in enumerate(live)}
         rows = _bucket_for(self._slot_ladder, len(live))
         masked = np.zeros((rows, bl), bool)
         n_unmask = np.zeros(rows, np.int32)
         for i, (s, g) in enumerate(zip(live, grants)):
-            if s.pos < len(s.req.prompt) // bl * bl:
-                kind, fed = "prefill", s.req.prompt[s.pos:s.pos + g]
+            req, start = s.req, s.pos
+            rnd.src.append(-1)
+            if start < len(req.prompt) // bl * bl:
+                kind, fed = "prefill", req.prompt[start:start + g]
                 rnd.prefill_toks += g
+                s.pos += g
+                rnd.kinds.append(kind)
+                rnd.feeds.append((start, fed))
+                continue
+            due = self._due(s, pend)
+            before = (pend.kinds[pend.row_of[id(s)]]
+                      if pend is not None and id(s) in pend.row_of
+                      else None)
+            if before == "denoise":
+                left, fed = pend.left[id(s)], [0] * bl
+                rnd.src[i] = pend.row_of[id(s)]
             else:
-                if s.block is None:
-                    self._open_block(s)
-                kind, fed = "denoise" if any(s.masked) else "commit", s.block
-                masked[i] = s.masked
-                if kind == "denoise":
-                    n_unmask[i] = bl // s.req.denoise_steps
+                if before is None and s.block is not None:
+                    block, lanes = s.block, s.masked
+                else:
+                    block, lanes = self._open_block(
+                        s, len(req.prompt) + due)
+                    rnd.opened[i] = (block, lanes)
+                fed, left = block, sum(lanes)
+                masked[i] = lanes
+            if left:
+                kind = "denoise"
+                n_unmask[i] = bl // req.denoise_steps
+                rnd.left[id(s)] = max(0, left - int(n_unmask[i]))
+                if req.want_topk and req.first_topk is None \
+                        and before != "denoise":
+                    # the first pass of the first block: its row gives
+                    # first_topk
+                    rnd.wanted.append(i)
+            else:
+                # the block's tokens past what the sequence holds, as far
+                # as max_new lets
+                kind = "commit"
+                known = len(req.prompt) + due
+                owed = min(bl - max(0, known - start), req.max_new - due)
+                rnd.owed[id(s)] = owed
+                if due + owed >= req.max_new:
+                    rnd.ends.add(s)
+                s.pos += bl
             rnd.kinds.append(kind)
-            rnd.feeds.append((s.pos, fed))
+            rnd.feeds.append((start, fed))
         n_kind = {k + "_slots": rnd.kinds.count(k)
                   for k in ("prefill", "denoise", "commit")}
         rnd.n["passes"] = n_kind["denoise_slots"] + n_kind["commit_slots"]
         rnd.n["assignments"] = (sum(grants)
                                 * self.spec.moe_assignments_per_token)
-        # a step of prefill chunks alone is not waited for
+        # a step of prefill chunks alone chooses nothing anybody reads
         rnd.reads = rnd.n["passes"] > 0
         rnd.call_kw = {"masked": masked, "n_unmask": n_unmask}
         rnd.call_args = dict(n_kind, moe_assignments=rnd.n["assignments"])
         return rnd
 
     def _step(self, live: List[_Slot]):
-        """ONE decoding round, whatever the model: prepare -> plan (per
-        slot: which pass, what it feeds, whether anybody reads its
-        choice) -> build -> device call (-> the propose/verify substep
-        where a draft is attached) -> answer (per slot, by the pass it
-        ran) -> retire -> notify. A causal slot and a block slot differ
-        in the plan and the answer; the rest is written here, once."""
+        """ONE decoding round, whatever the model, ONE STEP AHEAD of its
+        answers: prepare -> plan (per slot: which pass, what it feeds,
+        whether anybody reads its choice) -> build -> device call (-> the
+        propose/verify substep where a draft is attached) -> wait for
+        the round before it -> answer THAT round (per slot, by the pass
+        it ran) -> retire -> notify. This round is answered once the next
+        one is dispatched, so the device has it queued while the host
+        answers and plans. The plan counts what it knows before the
+        answer (positions, grants, who reaches ``max_new``, a block's
+        pass); what it does not — the token a decoding slot feeds, a
+        block's lanes — the device feeds from the round before
+        (``feed_tokens``). A round has the answer of the one before it
+        first where the host chooses a token of that round, a draft
+        proposes (``_waits_first``) or a preemption would take one of
+        its slots (``_reclaim_for_growth``). A slot that reaches
+        ``eos_id`` rides the round after as a lane whose answer is
+        dropped. A causal slot and a block slot differ in the plan and
+        the answer; the rest is written here, once."""
         # named chaos seam for the SCHEDULER cadence: a
         # `delay@serving.decode.step:*=0.004` plan simulates a slow
         # decoder (long-context model, contended chip) so streaming/
@@ -2812,15 +3110,26 @@ class DecodeEngine:
         # a fast engine; `error@` fails the step's slots like any other
         # step failure. Zero cost with no plan installed.
         _faults.fire("serving.decode.step")
+        pend = self._pending
+        if pend is not None and self._waits_first(pend, live):
+            self._settle()
+            with self._cond:
+                live = [s for s in live if s in self._slots]
+        elif pend is not None:
+            # what reaches max_new in pend rides no further round
+            live = [s for s in live if s not in pend.ends]
         # restore-before-step, COW copies, demand-mode growth (may
         # preempt/demote — the returned live list is authoritative)
-        with _tracing.span("serving.decode.prepare"):
-            live, grants = self._prepare(live)
+        if live:
+            with _tracing.span("serving.decode.prepare"):
+                live, grants = self._prepare(live)
         if not live:
+            self._settle()
             return
+        pend = self._pending
         with _tracing.span("serving.decode.plan"):
             rnd = (self._plan_blocks if self._block > 1
-                   else self._plan_causal)(live, grants)
+                   else self._plan_causal)(live, grants, pend)
             w_bucket = _bucket_for(self._width_ladder,
                                    max(s.pages_held for s in live))
         t0 = time.perf_counter()
@@ -2847,66 +3156,76 @@ class DecodeEngine:
                     # token too: garbage nobody reads
                     temperature, seed = self._slot_sampling(rnd.rows,
                                                             s_bucket)
-                # dispatch of the jitted step to the chosen ids on the
-                # host; the logits stay on the device
+                    src = np.full(s_bucket, -1, np.int32)
+                    src[:len(rnd.src)] = rnd.src
+                # dispatch of the jitted step, then the round before it
+                # to its ids on the host; the logits stay on the device
                 with _tracing.span("serving.decode.device_call") as sp:
                     with _tracing.span("serving.decode.dispatch"):
                         t_call = time.perf_counter()
+                        tokens, kw = arrays[0], dict(rnd.call_kw)
+                        if (src >= 0).any():
+                            tokens, fed_masked = self._feed(
+                                tokens, src, pend, kw.get("masked"))
+                            if fed_masked is not None:
+                                kw["masked"] = fed_masked
+                        rnd.fed = (tokens, kw.get("masked"))
                         # the draw's position is lens, each slot's new
                         # token's absolute index in its sequence: the
                         # (seed, position) pair that makes sampling
                         # independent of batch composition AND chunking
                         out, rnd.logits = self._run_step_arrays(
-                            *arrays, temperature=temperature, seed=seed,
-                            **rnd.call_kw)
-                        if not isinstance(out, dict):
-                            out = {"ids": out}
+                            tokens, *arrays[1:], temperature=temperature,
+                            seed=seed, **kw)
+                        rnd.dev = (out if isinstance(out, dict)
+                                   else {"ids": out})
                         if rnd.reads:
-                            for name in out:
-                                out[name].copy_to_host_async()
+                            for a in rnd.dev.values():
+                                a.copy_to_host_async()
+                        # the host route's rows, queued behind the step
+                        # and before the next one
+                        for row in rnd.wanted:
+                            rnd.rows_out[row] = self._row_async(
+                                rnd.logits, row)
                         if self._spec_k:
                             # the draft shadows every prefill chunk so
                             # its mirrored pool tracks the committed
                             # sequence (its choice is discarded; its
                             # watermark advances in the answer phase)
-                            self._run("draft", *arrays)
+                            self._run("draft", tokens, *arrays[1:])
                         t_sent = time.perf_counter()
                     _m_dispatch_ms.observe((t_sent - t_call) * 1e3)
+                    _m_ahead_pct.observe(0.0 if pend is None else 100.0)
                     _m_host_gap_ms.observe(
                         0.0 if self._t_back is None
                         else (t_sent - self._t_back) * 1e3)
+                    # the device holds this step until a later round
+                    # collects its ids
+                    self._t_back = None
                     # worked out while the device runs the step
                     self._note_call(sp, s_bucket, c_bucket, w_bucket,
                                     arrays[2], arrays[4], rnd.call_args)
-                    with _tracing.span("serving.decode.wait"):
-                        if self._await_ids(out["ids"],
-                                           rnd.reads) is not None:
-                            rnd.out = {k: np.asarray(a)
-                                       for k, a in out.items()}
-                            if sp.live and "expert_counts" in rnd.out:
-                                sp.set_arg("moe_experts_touched", int(
-                                    (rnd.out["expert_counts"] > 0).sum()))
-                    # the newest step's ids are on the host: the device
-                    # holds nothing from here to the next dispatch,
-                    # unless this step was not waited for
-                    t_back = time.perf_counter()
-                    self._t_back = t_back if rnd.out else None
-                    # the device's copies of what was fetched go HERE,
-                    # inside the call's stretch, not when this frame
-                    # does: letting them go after the answer phase cost
-                    # 0.6 ms between a round's wake-up and the next
-                    # round's admit (PERF.md section 6, PR 32)
-                    with _tracing.span("serving.decode.release"):
-                        del out
-                        _m_release_ms.observe(
-                            (time.perf_counter() - t_back) * 1e3)
+                    if pend is not None:
+                        # the round this step went ahead of: its ids
+                        with _tracing.span("serving.decode.wait"):
+                            self._collect(pend, sp)
+                        self._release(pend)
             if rnd.spec:
                 rnd.spec_out = self._spec_substep(rnd.spec, w_bucket)
         step_s = time.perf_counter() - t0
+        self._waited += step_s
         self._observe_step(step_s, len(live), rnd.prefill_toks)
+        if rnd.n["assignments"]:
+            _m_moe_assignments.inc(rnd.n["assignments"])
+        self._pending = rnd
+        if pend is not None:
+            self._answer(pend)
+
+    def _answer(self, rnd: _Round):
+        """The answer phase of a round whose ids are on the host: each
+        slot's answer by the pass it ran, retire, the round's counters,
+        and ONE wake-up for its tokens."""
         n = rnd.n
-        if n["assignments"]:
-            _m_moe_assignments.inc(n["assignments"])
         now = time.monotonic()
         done: List[_Slot] = []
         notes: Dict[int, int] = {}
@@ -2918,10 +3237,11 @@ class DecodeEngine:
         with _tracing.span("serving.decode.answer"):
             with self._cond:
                 self._n_steps += 1
-                for s, g in zip(live, grants):
+                for s, g in zip(rnd.live, rnd.grants):
                     if s.req.ev.is_set():
                         # already answered — stop(drain=False) raced this
-                        # step and failed the request (or it was canceled);
+                        # step and failed the request (or it was canceled,
+                        # or it ended at eos_id in the round before);
                         # don't double-answer or count a completion/token
                         done.append(s)
                         continue
@@ -2965,15 +3285,16 @@ class DecodeEngine:
                 # round's admit
                 t_end = time.perf_counter()
                 _m_sample_ms.observe(rnd.sample_s * 1e3)
-                _m_sched_ms.observe(
-                    (t_end - self._t_round - step_s - rnd.sample_s) * 1e3)
-                self._t_round = t_end
+                _m_sched_ms.observe((t_end - self._t_round - self._waited
+                                     - rnd.sample_s) * 1e3)
+                self._t_round, self._waited = t_end, 0.0
             # the step's logits (the host route's rows came from them)
             # are let go HERE, inside the phase and once the woken
             # clients may run, so that freeing their device buffer
             # (0.25-0.4 ms a round on a TPU v5e at 16 x 256k float32)
             # is a named stretch and not a gap before the next admit
             rnd.logits = None
+            rnd.rows_out = {}
 
     def _emit_locked(self, s: _Slot, tok: int, rnd: _Round,
                      route: str) -> bool:
@@ -2991,11 +3312,13 @@ class DecodeEngine:
     def _answer_plain(self, s: _Slot, g: int, rnd: _Round) -> bool:
         """The answer of a causal slot that rode the target program's
         call: its chunk of ``g`` (>= 1: every live slot progresses) is
-        written, and once the prompt is through its newest lane's token
-        is the program's own choice or the host route's."""
-        s.pos += g
+        written from its feed's start, and once the prompt is through its
+        newest lane's token is the program's own choice or the host
+        route's."""
+        row = rnd.row_of[id(s)]
+        end = rnd.feeds[row][0] + g
         if self._prefix_on and not s.req.published and \
-                s.pos >= len(s.req.prompt):
+                end >= len(s.req.prompt):
             # prompt K/V fully on-device as of THIS step: publish the
             # prompt pages into the prefix index (metadata only; from
             # here they are immutable — this sequence only ever writes
@@ -3006,23 +3329,21 @@ class DecodeEngine:
         if self._spec_k:
             # the draft shadowed this prefill chunk lane for lane — its
             # watermark advances in lockstep
-            s.dpos = s.pos
-        if s.pos < len(s.req.prompt):
+            s.dpos = end
+        if end < len(s.req.prompt):
             return False
         # row is the slot's newest lane (the step unembeds only lane
         # q_len-1): prompt token P-1 when the chunk just finished
         # prefill, else the decode token; ids[row] is the program's own
-        # choice there, greedy or drawn by (seed, s.pos)
-        row = rnd.row_of[id(s)]
+        # choice there, greedy or drawn by (seed, end)
         tok, mask_done, route = int(rnd.out["ids"][row]), False, "device"
         topk = s.req.want_topk and s.req.first_topk is None
         if topk or self._draws_on_host(s.req):
-            # the host route: this slot's row, fetched alone, then
-            # today's numpy choice
+            # the host route: this slot's row, copied alone behind its
+            # step (``rnd.wanted``), then today's numpy choice
             with rnd.sampling():
                 tok, mask_done = self._sample(
-                    s.req, self._fetch_row(rnd.logits, row), tok, s.pos,
-                    topk)
+                    s.req, np.asarray(rnd.rows_out[row]), tok, end, topk)
             route = "host"
         return self._emit_locked(s, tok, rnd, route) or mask_done
 
@@ -3070,11 +3391,13 @@ class DecodeEngine:
         written; a denoise pass unmasks the lanes the program marked,
         at its choices; the commit pass answers up to B tokens at once —
         fewer at ``max_new`` or at ``eos_id``, the rest of the block is
-        dropped — and ``pos`` advances by B."""
+        dropped. The pass's input is the block the plan opened, else the
+        one the slot's last answer left."""
         i = rnd.row_of[id(s)]
         if rnd.kinds[i] == "prefill":
-            s.pos += g
             return False
+        start = rnd.feeds[i][0]
+        block, masked = rnd.opened.get(i, (s.block, s.masked))
         if rnd.kinds[i] == "denoise":
             req = s.req
             ids, unmask = rnd.out["ids"][i], rnd.out["unmask"][i]
@@ -3082,26 +3405,26 @@ class DecodeEngine:
                 # the first pass's first masked lane: its order of the
                 # best tokens, from one fetched row
                 with rnd.sampling():
-                    lane = s.masked.index(True)
                     req.first_topk = _top_order(
-                        self._fetch_row(rnd.logits, i)[lane], req.want_topk)
+                        np.asarray(rnd.rows_out[i])[masked.index(True)],
+                        req.want_topk)
             if req.passes is not None:
                 req.passes.append({
-                    "pos": int(s.pos),
-                    "input": [int(t) for t in s.block],
-                    "masked": list(s.masked),
+                    "pos": int(start),
+                    "input": [int(t) for t in block],
+                    "masked": list(masked),
                     "ids": [int(t) for t in ids],
                     "confidence": [float(c)
                                    for c in rnd.out["confidence"][i]],
                     "unmasked": [bool(u) for u in unmask]})
-            for j in np.flatnonzero(unmask):
-                s.block[j] = int(ids[j])
-                s.masked[j] = False
+            s.block = [int(ids[j]) if unmask[j] else t
+                       for j, t in enumerate(block)]
+            s.masked = [m and not unmask[j] for j, m in enumerate(masked)]
             return False
         # commit: the block's tokens that are not the prompt's, in
         # order, as far as max_new and eos let
         known = len(s.req.prompt) + len(s.req.produced)
-        fresh = s.block[max(0, known - s.pos):]
+        fresh = block[max(0, known - start):]
         taken, finished = 0, False
         for tok in fresh:
             taken += 1
@@ -3109,7 +3432,6 @@ class DecodeEngine:
                 finished = True
                 break
         rnd.n["dropped"] += len(fresh) - taken
-        s.pos += self._block
         s.block = s.masked = None
         return finished
 

@@ -103,7 +103,7 @@ def _decode_token(eng):
 def _block_pass(eng, masked_lanes):
     def opened(prompt, pos):
         s = _slot(eng, prompt, pos=pos)
-        eng._open_block(s)
+        s.block, s.masked = eng._open_block(s, len(s.req.prompt))
         if not masked_lanes:            # every lane filled: the commit
             s.block = [7 + j for j in range(4)]
             s.masked = [False] * 4

@@ -42,6 +42,7 @@ swings 604-836s on this host — see memory/tier1-timing-margin).
 """
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -575,33 +576,34 @@ def test_first_topk_order_is_the_whole_rows_stable_sort(k):
         assert _top_order(row, k) == want.tolist()
 
 
-def test_a_step_nobody_reads_a_token_of_is_not_waited_for():
+def test_a_step_nobody_reads_a_token_of_is_not_waited_for(monkeypatch):
     """A step whose chunks all end inside their prompts hands back ids
-    nobody reads: the scheduler fetches nothing and goes on (at most two
-    such steps queued), and the answer is what an engine that waits for
-    every step gives."""
+    nobody reads: the next step is dispatched before it is done, as the
+    one after a step that reads is, and its ids never come to the host
+    (at most two steps queued); the answer is what an engine that waits
+    for every step gives."""
     prompt = list(range(1, 14))                 # 13 tokens, chunks of 4
-    waits = []
+    ahead = _observed(monkeypatch, "ahead_pct")
+    fetched = []
     eng = _engine(max_seq_len=24, num_pages=16)
     every = _engine(max_seq_len=24, num_pages=16, name="every")
     try:
-        real = eng._await_ids
+        real = eng._collect
 
-        def recorded(ids, chooses):
-            waits.append(chooses)
-            out = real(ids, chooses)
-            assert (out is None) == (not chooses)
-            assert (eng._unread is None) == chooses
-            return out
+        def recorded(rnd, sp=None):
+            real(rnd, sp)
+            fetched.append(rnd.out is not None)
 
-        eng._await_ids = recorded
-        real_every = every._await_ids
-        every._await_ids = lambda ids, chooses: real_every(ids, True)
+        eng._collect = recorded
+        every._waits_first = lambda pend, live: True
         kw = dict(max_new_tokens=5, temperature=0.8, seed=21)
         got = eng.generate(prompt, **kw)
-        # 4 + 4 + 4 inside the prompt, then its last token and 4 more
-        assert waits == [False] * 3 + [True] * 5
+        # 4 + 4 + 4 inside the prompt, then its last token and 4 more;
+        # every step but the first went ahead of the one before it
+        assert fetched == [False] * 3 + [True] * 5
+        assert ahead == [0.0] + [100.0] * 7
         assert got["tokens"] == every.generate(prompt, **kw)["tokens"]
+        assert ahead[8:] == [0.0] * 8
         assert got["tokens"] == eng.generate(prompt, **kw)["tokens"]
     finally:
         eng.stop()
@@ -617,8 +619,10 @@ def test_choice_counters_on_a_mixed_batch_and_no_compile_after_warm():
     eng = _engine(slots=[1, 2, 4], num_pages=64, max_seq_len=16)
     try:
         warm = metrics.counter("serving.decode.compiles").value()
-        # the step ladder, and one row-fetch program a slot count
-        assert warm == len(eng.stats()["compiled_shapes"]) + 3
+        # the step ladder, one row-fetch program a slot count, and the
+        # device feed of a call from the one before: slots x chunks x
+        # the slots before
+        assert warm == len(eng.stats()["compiled_shapes"]) + 3 + 3 * 2 * 3
         metrics.reset_metrics("serving.decode.")
         reqs = _drive(eng, [
             ([1, 2, 3], dict(max_new_tokens=6)),
@@ -811,9 +815,31 @@ def test_reserve_at_admission_holds_exactly_under_chunking():
 
 # --- the scheduler round's spans and host time (ISSUE 27) ---------------
 
-_ROUND = ("admit", "prepare", "plan", "step", "answer")
-# the device call's three stretches, in order
+# a round's top-level spans: admit; the answer of the round before it
+# where this one cannot go ahead of it (wait, release, answer); then the
+# plan and the step, and the answer of the round before it where the
+# step went ahead of that round
+_ROUND = re.compile(r"A(WRN)?(PLS(N)?)?")
+_LETTER = {"admit": "A", "wait": "W", "release": "R", "answer": "N",
+           "prepare": "P", "plan": "L", "step": "S"}
+# the device call's three stretches, in order: a call that went ahead of
+# the round before it waits for that round's ids inside it
 _CALL = ["dispatch", "wait", "release"]
+
+
+def _rounds(top):
+    """The scheduler thread's top-level spans, checked round by round
+    against ``_ROUND``: each step that went ahead (its device call holds
+    the wait) is followed by the answer it waited for, and every round
+    dispatched is answered once. Returns the rounds' strings."""
+    text = "".join(_LETTER[_short(e)] for e, _kids in top)
+    rounds = [m.group(0) for m in _ROUND.finditer(text)]
+    assert "".join(rounds) == text, text
+    steps = [kids for e, kids in top if _short(e) == "step"]
+    ahead = [[_short(k) for k, _ in kids[1][1]] == _CALL for kids in steps]
+    assert [bool(r.endswith("SN")) for r in rounds if "S" in r] == ahead
+    assert text.count("N") == text.count("S")
+    return rounds
 
 
 def _short(e):
@@ -917,18 +943,16 @@ def test_round_spans_in_order_on_the_profilers_clock(profiler_session,
     top = _nest(events)
     # whole rounds; an admit pass that found nothing to run (the engine
     # idle between two requests, and at the end) is followed by another
-    names = [_short(e) for e, _kids in top]
-    rounds = [n for i, n in enumerate(names)
-              if not (n == "admit" and names[i + 1:i + 2] != ["prepare"])]
-    n_rounds = names.count("step")
-    assert n_rounds >= 5
-    assert rounds == list(_ROUND) * n_rounds, names
+    rounds = _rounds(top)
+    assert sum("S" in r for r in rounds) >= 5
+    # the top_k request's rounds wait for the answer before them
+    assert any(r.startswith("AWRN") and "S" in r for r in rounds)
     calls = []
     for e, kids in top:
         if _short(e) == "step":
             assert [_short(k) for k, _ in kids] == ["build", "device_call"]
             call, inside = kids[1]
-            assert [_short(k) for k, _ in inside] == _CALL
+            assert [_short(k) for k, _ in inside] in (_CALL[:1], _CALL)
             assert not kids[0][1] and not any(g for _k, g in inside)
             calls.append(call["args"])
         elif _short(e) == "answer":
@@ -1032,15 +1056,22 @@ def test_the_device_calls_stretches_nest_on_both_clocks(profiler_session):
         assert len(calls) == n_steps
         for call, inside in calls:
             assert _short(call) == "device_call"
-            assert [_short(k) for k, _ in inside] == _CALL
+            assert [_short(k) for k, _ in inside] in (_CALL[:1], _CALL)
             assert {"slots", "chunk", "width", "q_tokens", "kv_tokens",
                     "attn_pairs"} <= set(call["args"])
-    # the ring's own parentage says the same: each stretch's parent is
-    # the device call
+        # every round but the first went ahead of the one before it; the
+        # last is answered with nothing queued behind it
+        assert [[_short(k) for k, _ in inside] for _c, inside in calls] \
+            == [_CALL[:1]] + [_CALL] * (n_steps - 1)
+        assert [r for r in _rounds(top) if r != "A"][-1] == "AWRN"
+    # the ring's own parentage says the same: a dispatch's parent is the
+    # device call, and so is a wait's and a release's but for the last
+    # round's, which no dispatch holds
     by_id = {e["args"]["span_id"]: _short(e) for e in ring}
-    for e in ring:
-        if _short(e) in _CALL:
-            assert by_id[e["args"]["parent_span_id"]] == "device_call"
+    parents = [by_id.get(e["args"].get("parent_span_id"))
+               for e in ring if _short(e) in _CALL]
+    assert parents.count(None) == 2
+    assert set(parents) == {"device_call", None}
 
 
 def _observed(monkeypatch, name):
@@ -1056,12 +1087,14 @@ def _observed(monkeypatch, name):
 
 
 def test_the_call_histograms_observe_once_a_target_step(monkeypatch):
-    """serving.decode.dispatch_ms, .release_ms and .host_gap_ms observe
-    once a step that dispatches the target program, as step_ms does;
-    the host gap holds the dispatch, so it is never the shorter of the
-    two where it is not 0."""
+    """serving.decode.dispatch_ms, .release_ms, .host_gap_ms and
+    .ahead_pct observe once a step that dispatches the target program,
+    as step_ms does; the host gap is 0 exactly where the step went ahead
+    of the one before it, and elsewhere holds the dispatch, so it is
+    never the shorter of the two."""
     gaps = _observed(monkeypatch, "host_gap_ms")
     sent = _observed(monkeypatch, "dispatch_ms")
+    ahead = _observed(monkeypatch, "ahead_pct")
     eng = _engine(max_seq_len=16)
     _drive(eng, [([1, 2, 3, 4, 5, 6], dict(max_new_tokens=4)),
                  ([7, 8], dict(max_new_tokens=3))])
@@ -1071,13 +1104,16 @@ def test_the_call_histograms_observe_once_a_target_step(monkeypatch):
     snap = metrics.snapshot("serving.decode.")
     steps = snap["serving.decode.steps"]
     assert steps > 10
-    for name in ("dispatch_ms", "release_ms", "host_gap_ms", "step_ms"):
+    for name in ("dispatch_ms", "release_ms", "host_gap_ms", "step_ms",
+                 "ahead_pct"):
         h = snap["serving.decode." + name]
         assert h["count"] == steps, name
         assert h["min"] >= 0.0
     assert snap["serving.decode.dispatch_ms"]["min"] > 0.0
-    assert len(gaps) == len(sent) == steps
-    assert sum(g > 0 for g in gaps) > steps // 2
+    assert len(gaps) == len(sent) == len(ahead) == steps
+    assert set(ahead) == {0.0, 100.0}
+    assert sum(a == 100.0 for a in ahead) > steps // 2
+    assert [g == 0.0 for g in gaps] == [a == 100.0 for a in ahead]
     # a gap ends where its round's dispatch does and began before it
     assert all(g >= d for g, d in zip(gaps, sent) if g > 0)
 
@@ -1124,11 +1160,13 @@ def test_a_steps_logits_are_let_go_inside_its_answer_span(monkeypatch):
 def test_the_host_gap_is_0_behind_an_unread_step_and_skips_the_idle_wait(
         monkeypatch):
     """A prompt of 9 at chunk 4 goes in as 4, 4, 1: the first two chunks
-    end inside the prompt and nobody waits for them, so the round after
-    each reads a gap of 0 (the device still held a step); the rest read
-    more than 0. An engine idle for a second before a request does not
-    count the idle second in that request's first gap (the second prompt
-    shares no prefix with the first: no page of it is cached)."""
+    end inside the prompt and nobody reads their ids, the third and the
+    two decode steps after it are read; every step after the first is
+    dispatched before the ids of the one before it are on the host, so
+    each reads a gap of 0 (the device still held a step). An engine idle
+    for a second before a request does not count the idle second in that
+    request's first gap (the second prompt shares no prefix with the
+    first: no page of it is cached)."""
     gaps = _observed(monkeypatch, "host_gap_ms")
     eng = _engine(max_seq_len=16)
     time.sleep(1.0)                              # idle from the start
@@ -1141,9 +1179,8 @@ def test_the_host_gap_is_0_behind_an_unread_step_and_skips_the_idle_wait(
     # 4 + 4 (unread), 1 (the first token), two decode steps
     for run in (first, second):
         assert len(run) == 5
-        assert run[1] == run[2] == 0.0
-        assert all(g > 0.0 for g in (run[0], run[3], run[4])), run
-        assert run[0] < 500.0            # not the idle second
+        assert run[1:] == [0.0] * 4, run
+        assert 0.0 < run[0] < 500.0      # not the idle second
 
 
 def test_dot_fold_share_counts_folds_by_the_kernels_predicate():

@@ -536,8 +536,11 @@ def test_a_stretch_metric_loads_and_reads_what_its_file_says(bench, name):
     assert entry == {"name": name, "unit": unit, "better": "lower",
                      "source": "program_span", "layer": layer,
                      "moves": "serve_tokens_per_s", "workloads": SERVING}
-    # appended, in this order, behind every metric that was there
-    assert [m["name"] for m in bench.doc["per_layer"]][-3:] == [
+    # appended, in this order, behind every metric that was there before
+    # them (what later PRs append follows them)
+    names = [m["name"] for m in bench.doc["per_layer"]]
+    at = names.index("serve_dispatch_ms")
+    assert names[at:at + 3] == [
         "serve_dispatch_ms", "sched_release_ms", "sched_host_gap_pct"]
     assert name not in [m["name"] for m, _d in
                         bench.per_layer("resnet50_train")]
@@ -579,3 +582,39 @@ def test_the_program_registers_what_the_stretch_metrics_read():
     snap = metrics.snapshot("serving.decode.")
     for _unit, _layer, histogram, _stat in PR39.values():
         assert isinstance(snap[histogram], dict)
+
+
+# --- the round that goes one step ahead, in every serving cell ------------
+
+def test_the_ahead_share_loads_and_reads_the_programs_histogram(bench):
+    """sched_ahead_pct: the mean of serving.decode.ahead_pct (100 a
+    target dispatch that went ahead of the step before it, else 0), in
+    the four serving cells, appended behind every metric that was there;
+    a program without the histogram gives nothing to read."""
+    bench.check_files()
+    names = [m["name"] for m in bench.doc["per_layer"]]
+    assert names.index("sched_ahead_pct") > names.index("sched_host_gap_pct")
+    entry, = [m for m in bench.doc["per_layer"]
+              if m["name"] == "sched_ahead_pct"]
+    assert entry == {"name": "sched_ahead_pct", "unit": "%",
+                     "better": "higher", "source": "program_counter",
+                     "layer": "scheduler", "moves": "serve_tokens_per_s",
+                     "workloads": SERVING}
+    assert "sched_ahead_pct" not in [m["name"] for m, _d in
+                                     bench.per_layer("resnet50_train")]
+    for cell in SERVING:
+        (found, desc), = [(m, d) for m, d in bench.per_layer(cell)
+                          if m["name"] == "sched_ahead_pct"]
+        assert found is entry
+    assert (desc["reader"], desc["histogram"], desc["stat"]) == (
+        "histogram", "serving.decode.ahead_pct", "avg")
+    h = {"count": 200, "sum": 19600.0, "avg": 98.0, "min": 0.0,
+         "max": 100.0, "p50": 100.0}
+    assert bench.read_layer_metric(entry, desc, {
+        "histograms": {"serving.decode.ahead_pct": h}}) == 98.0
+    assert bench.read_layer_metric(entry, desc, {"histograms": {}}) is None
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.serving import decode  # noqa: F401  (registers it)
+
+    assert isinstance(metrics.snapshot("serving.")[
+        "serving.decode.ahead_pct"], dict)

@@ -236,9 +236,12 @@ def test_spec_churn_zero_post_warm_compiles():
         warm = _ctr("serving.decode.compiles")
         # the step shapes, and the host route's row fetch: one program a
         # slot count for the newest-lane logits (the target's and the
-        # draft's alike) and one for the verify's lanes
-        assert warm == (len(on.stats()["compiled_shapes"])
-                        + 2 * len(on.slot_ladder))
+        # draft's alike) and one for the verify's lanes; and the device
+        # feed of a target call from the one before: slots x chunks x
+        # the slots before
+        n = len(on.slot_ladder)
+        assert warm == (len(on.stats()["compiled_shapes"]) + 2 * n
+                        + n * len(on.chunk_ladder) * n)
         rng = np.random.RandomState(5)
         # greedy, drawn by the programs, drawn on the host: all warmed
         how = [{}, dict(temperature=0.8, seed=9),
