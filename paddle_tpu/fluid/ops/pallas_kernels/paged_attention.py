@@ -76,6 +76,15 @@ Two FOLDS of a live page into that one online softmax (ISSUE 35), and
     more takes it: a prefill chunk, whose cost a page then does not grow
     with its lanes.
 
+A LATENT pool (``paged_latent_attention``): one array ``[P, page_size,
+D]`` a layer, ONE key a token shared by every query head (multi-query over
+a latent attention layer's compressed entry) whose first ``value_dim``
+columns are also its value. The page is ``[page_size, D]`` as it lies,
+with no head axis to tile (a ``[P, ps, 1, D]`` pool pads its unit head
+axis and is turned at every call), and every slot's lanes take the dot
+fold: all ``C * H`` query rows against the page in one product, whatever
+``q_len``, since a single head already gives a decoding lane ``H`` rows.
+
 A WINDOW (ISSUE 34, ``window=`` static; ``None`` = none, the programs
 that pass none are the programs they were): a query lane at position
 ``p`` sees keys in ``(p - window, p]``. The table of such a call STARTS
@@ -122,7 +131,8 @@ from ....observability import metrics as _metrics
 NEG_INF = -1e30
 
 __all__ = ["paged_attention", "paged_attention_reference",
-           "paged_route", "folds_by_dot"]
+           "paged_route", "folds_by_dot", "paged_latent_attention",
+           "paged_latent_attention_reference"]
 
 # trace-time routing counters (this function body runs once per
 # compiled shape, n_layers times per decoder trace — not per step):
@@ -664,3 +674,141 @@ def paged_attention(q, k_pages, v_pages, page_tables, kv_lens,
     return paged_attention_reference(q, k_pages, v_pages, page_tables,
                                      kv_lens, q_lens=q_lens, scale=scale,
                                      block_length=block_length, **more)
+
+
+# --- a latent pool: one key a token, multi-query -------------------------
+
+def paged_latent_attention_reference(q, pages, page_tables, kv_lens, *,
+                                     q_lens, scale: float, value_dim: int):
+    """Pure-jax oracle of ``paged_latent_attention``: gather the pages,
+    causal mask, dense softmax at HIGHEST. ``q [B, C, H, D]``, ``pages
+    [P, ps, D]``; returns ``[B, C, H, value_dim]`` in q's dtype."""
+    b, c, h, d = q.shape
+    _p, ps, _d = pages.shape
+    w = page_tables.shape[1]
+    keys = pages[page_tables].reshape(b, w * ps, d).astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("bchd,btd->bcht", q.astype(jnp.float32) * scale, keys,
+                   precision=hi)
+    lane = jnp.arange(c)[None, :]
+    limit = _key_limit(kv_lens[:, None], q_lens[:, None], lane, 1)
+    t = jnp.arange(w * ps)[None, None, :]
+    keep = ((t <= limit[:, :, None])
+            & (lane < q_lens[:, None])[:, :, None])[:, :, None, :]
+    s = jnp.where(keep, s, NEG_INF)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)) * keep
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.einsum("bcht,btv->bchv", p, keys[..., :value_dim], precision=hi)
+    return (o / jnp.maximum(l, jnp.finfo(jnp.float32).tiny)).astype(q.dtype)
+
+
+def _latent_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, page_ref,
+                   o_ref, m_sc, l_sc, acc_sc, *, scale, page_size, heads,
+                   value_dim):
+    """One (sequence b, table column w) grid step of the latent form: the
+    page's keys folded into the online softmax of all the slot's ``N = C
+    * H`` query rows at once (row ``c * H + h``), which lie along the
+    LANES of everything it keeps (scores ``[ps, N]``, statistics ``[1,
+    N]``, accumulator ``[value_dim, N]``), as ``_fold_dot`` has them.
+    Two products on the MXU a page, float32 accumulation, no operand
+    narrowed (``_mxu``). A page past ``kv_len`` runs one compare."""
+    b = pl.program_id(0)
+    w = pl.program_id(1)
+
+    @pl.when(w == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    kv_len = kv_lens_ref[b]
+    q_len = q_lens_ref[b]
+
+    @pl.when(w * page_size < kv_len)
+    def _fold():
+        page = page_ref[0]                                 # [ps, D]
+        n = acc_sc.shape[-1]
+        s = _mxu(page, q_ref[0], ((1,), (1,))) * scale     # [ps, N]
+        offs = w * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (page_size, 1), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) // heads
+        keep = (offs <= _key_limit(kv_len, q_len, lane, 1)) & (lane < q_len)
+        s = jnp.where(keep, s, NEG_INF)
+        m_old = m_sc[...]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new) * keep
+        m_sc[...] = m_new
+        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
+        # pv[v, n] = sum_p page[p, v] * p[p, n], the value the key's first
+        # value_dim columns
+        acc_sc[...] = acc_sc[...] * alpha + _mxu(
+            page[:, :value_dim], p, ((0,), (0,)))
+
+    @pl.when(w == pl.num_programs(1) - 1)
+    def _emit():
+        l = jnp.maximum(l_sc[...], jnp.finfo(jnp.float32).tiny)
+        o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "value_dim",
+                                             "interpret"))
+def _latent_call(q, pages, tables, kv_l, q_l, *, scale, value_dim,
+                 interpret):
+    b, c, h, d = q.shape
+    _p, ps, _d = pages.shape
+    w = tables.shape[1]
+    n = c * h
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, w),
+        in_specs=[pl.BlockSpec((1, n, d), lambda bb, ww, *_: (bb, 0, 0)),
+                  # THE paged read: the slot's w-th live page
+                  pl.BlockSpec((1, ps, d),
+                               lambda bb, ww, t, *_: (t[bb, ww], 0, 0))],
+        out_specs=pl.BlockSpec((1, value_dim, n),
+                               lambda bb, ww, *_: (bb, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((1, n), jnp.float32),
+                        pltpu.VMEM((1, n), jnp.float32),
+                        pltpu.VMEM((value_dim, n), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale, page_size=ps,
+                          heads=h, value_dim=value_dim),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, value_dim, n), q.dtype),
+        interpret=interpret,
+        # the kernel's name in the compiled program and a device trace
+        name="paged_latent_attention",
+    )(_live_columns(tables, kv_l, ps), kv_l, q_l, q.reshape(b, n, d), pages)
+    return out.reshape(b, value_dim, c, h).transpose(0, 2, 3, 1)
+
+
+def paged_latent_attention(q, pages, page_tables, kv_lens, *, q_lens,
+                           scale: float, value_dim: int,
+                           interpret: Optional[bool] = None,
+                           impl: Optional[str] = None):
+    """Causal attention of ``q [B, C, H, D]`` (``q_lens`` live lanes a
+    slot) over ONE key a token read through the page tables from a latent
+    pool ``pages [P, ps, D]``, the value the key's first ``value_dim``
+    columns: ``[B, C, H, value_dim]`` in q's dtype, dead lanes exact
+    zeros. Routed as ``paged_attention`` is (``paged_route``)."""
+    from ...flags import pallas_interpret
+
+    if pages.ndim != 3 or q.ndim != 4 or q.shape[-1] != pages.shape[-1]:
+        raise ValueError(f"a latent pool is [P, ps, D] and q [B, C, H, D]; "
+                         f"got pages {pages.shape}, q {q.shape}")
+    if not 0 < value_dim <= q.shape[-1]:
+        raise ValueError(f"value_dim {value_dim} outside (0, {q.shape[-1]}]")
+    args = (q, pages, page_tables.astype(jnp.int32),
+            kv_lens.astype(jnp.int32), q_lens.astype(jnp.int32))
+    if paged_route(q.shape[0], impl) == "paged_kernel":
+        _m_route_kernel.inc()
+        return _latent_call(*args, scale=float(scale),
+                            value_dim=int(value_dim),
+                            interpret=pallas_interpret() if interpret is None
+                            else bool(interpret))
+    _m_route_ref.inc()
+    return paged_latent_attention_reference(
+        *args[:4], q_lens=args[4], scale=float(scale),
+        value_dim=int(value_dim))

@@ -13,10 +13,11 @@ sharding rules ask, instead of each knowing one decoder's tensor names:
   ``pool_dtype``, ``param_dtype``   the dtypes of the paged K/V pools
                       and of the parameter tree
   ``layer_kinds``     what each layer keeps of a sequence, in layer
-                      order: ``"full"`` (every key) or ``"window"`` (the
-                      newest ``window`` keys); the engine holds a cache a
-                      kind (``serving/kv_cache.py``). ``all_full(n)`` is
-                      the answer of a model with no window
+                      order: ``"full"`` (every key), ``"window"`` (the
+                      newest ``window`` keys) or ``"state"`` (below);
+                      the engine holds a cache a kind
+                      (``serving/kv_cache.py``). ``all_full(n)`` is the
+                      answer of a model with no window
   ``window``          keys a window layer's query sees, its own among
                       them (``None`` where every layer is full)
   ``moe_assignments_per_token``   token-to-expert assignments a live
@@ -39,11 +40,24 @@ sharding rules ask, instead of each knowing one decoder's tensor names:
                       ``KindTables`` of the full kind's table, the window
                       kind's and the logical page the latter's rows
                       start at
+  ``"state"`` kind    a layer that keeps a FIXED-SIZE state a sequence
+                      (a linear-attention layer's recurrent state and its
+                      convolution's tail), never paged: ``slot_state``
+                      gives its arrays' ``(shape, dtype)`` without the row
+                      axis (second); the engine holds a row of each a live
+                      sequence and the step takes ``k_pool = (full kind's
+                      pool, state array 0)``, ``v_pool = (full kind's V or
+                      None, state array 1)`` and ``page_tables`` as a
+                      ``StateTables``
+  ``pool_values``     False where the full kind keeps one array a token
+                      and no V (a latent cache); True where absent
 
 The dense decoder the engine was built on (``DecoderSpec``) is one such
-model, unchanged in arithmetic; ``sdar_moe.SdarMoeSpec`` is another, and
+model, unchanged in arithmetic; ``sdar_moe.SdarMoeSpec`` is another,
 ``afmoe.AfmoeSpec`` (window and full layers, gated attention, sigmoid-
-routed experts beside a shared one) a third.
+routed experts beside a shared one) a third, and
+``kimi_linear.KimiLinearSpec`` (state and latent full layers, a chip's
+share of the experts) a fourth.
 """
 from __future__ import annotations
 
@@ -54,7 +68,7 @@ import numpy as np
 
 __all__ = ["DecoderSpec", "build_decoder_params", "seeded_decoder_arrays",
            "decoder_step", "decoder_step_chunked", "validate_draft_spec",
-           "spec_from_dict", "KindTables", "all_full"]
+           "spec_from_dict", "KindTables", "StateTables", "all_full"]
 
 
 class KindTables(NamedTuple):
@@ -68,6 +82,23 @@ class KindTables(NamedTuple):
     full: Any
     window: Any
     starts: Any
+
+    @property
+    def shape(self):
+        return self.full.shape
+
+
+class StateTables(NamedTuple):
+    """The tables of one call to a model with state layers: ``full [S,
+    W]`` (the full kind's page table, as every model's), ``rows [S]``
+    (each call row's row of the state kind's arrays; dead rows name the
+    engine's garbage row) and ``fresh [S]`` (1 where the row's sequence
+    starts in the call: its state starts from zero). ``shape`` is the
+    full table's."""
+
+    full: Any
+    rows: Any
+    fresh: Any
 
     @property
     def shape(self):
@@ -429,5 +460,9 @@ def spec_from_dict(d: Dict[str, Any]):
         from .afmoe import AfmoeSpec
 
         return AfmoeSpec.from_dict(d)
-    raise ValueError(f"unknown decoder family {family!r}; known: "
-                     f"{[DecoderSpec.family, 'sdar_moe', 'afmoe']}")
+    if family == "kimi_linear":
+        from .kimi_linear import KimiLinearSpec
+
+        return KimiLinearSpec.from_dict(d)
+    known = [DecoderSpec.family, "sdar_moe", "afmoe", "kimi_linear"]
+    raise ValueError(f"unknown decoder family {family!r}; known: {known}")

@@ -308,7 +308,7 @@ def softmax_scores(h, lp, spec: SdarMoeSpec):
 
 
 def moe_layer(h, lp, valid, spec, impl: Optional[str] = None, *,
-              score=softmax_scores):
+              score=softmax_scores, held: Optional[Tuple[int, int]] = None):
     """THE dropless expert layer of every served family, over ``h`` [T,
     d] (the normed hidden states, in the weights' dtype): ``(out [T, d]
     float32, counts [E] int32)``. The MODEL supplies the scoring
@@ -322,7 +322,15 @@ def moe_layer(h, lp, valid, spec, impl: Optional[str] = None, *,
     and reads exact zeros. ``counts`` sums to ``valid.sum() *
     experts_per_token``. ``impl`` is the step's ``attention_impl``:
     ``"reference"`` names XLA's grouped product as it names the
-    attention's reference (``moe_gmm.moe_route``)."""
+    attention's reference (``moe_gmm.moe_route``).
+
+    ``held = (lo, hi)``: this chip holds experts ``lo .. hi - 1`` of the
+    router's ``n_experts`` (expert parallelism; the expert weights are
+    ``[hi - lo, ...]``). The router keeps its width and its top-k and the
+    weights are renormalised over all k chosen; an assignment to an
+    expert held elsewhere is routed nowhere like a dead lane's and adds
+    exact zeros, and ``counts`` is over the held experts. ``None`` (every
+    expert held) traces the program it always was."""
     import jax
     import jax.numpy as jnp
 
@@ -332,6 +340,11 @@ def moe_layer(h, lp, valid, spec, impl: Optional[str] = None, *,
     e, k = spec.n_experts, spec.experts_per_token
     with jax.named_scope("decoder.moe.route"):
         w, idx = score(h, lp, spec)
+        here = None
+        if held is not None:
+            lo, hi = int(held[0]), int(held[1])
+            here = valid[:, None] & (idx >= lo) & (idx < hi)    # [T, k]
+            idx, e = jnp.where(here, idx - lo, hi - lo), hi - lo
         # dead lanes sort behind every expert's group (sentinel E)
         idx = jnp.where(valid[:, None], idx, e).reshape(t * k)
         order = jnp.argsort(idx, stable=True)
@@ -351,9 +364,17 @@ def moe_layer(h, lp, valid, spec, impl: Optional[str] = None, *,
         # each token gathers its k rows and sums them weighed, in order of
         # j. A dead lane's rows lie behind the last group and hold whatever
         # the product left there, NaN included: masked after the sum
-        out = w[:, :1] * y[pos[:, 0]]
-        for j in range(1, k):
-            out = out + w[:, j:j + 1] * y[pos[:, j]]
+        if here is None:
+            out = w[:, :1] * y[pos[:, 0]]
+            for j in range(1, k):
+                out = out + w[:, j:j + 1] * y[pos[:, j]]
+        else:
+            # an expert held elsewhere left its row behind the last group
+            # too: each term is masked before the sum
+            out = jnp.where(here[:, :1], w[:, :1] * y[pos[:, 0]], 0.0)
+            for j in range(1, k):
+                out = out + jnp.where(here[:, j:j + 1],
+                                      w[:, j:j + 1] * y[pos[:, j]], 0.0)
         out = jnp.where(valid[:, None], out, 0.0)
     return out, counts
 

@@ -27,7 +27,7 @@ This engine does CONTINUOUS batching over FIXED compiled shapes:
     generated token per decoding slot (ISSUE 10, chunked prefill):
     sequences still in their prompt are granted chunks of it — causal
     within the chunk, all slots sharing a per-step token BUDGET of
-    ``prefill_chunk`` prompt tokens — while sequences past their
+    ``prefill_budget`` prompt tokens — while sequences past their
     prompt consume their previously sampled token, all in the SAME
     compiled mixed batch (Sarathi-style). A P-token prompt completes
     prefill in ``ceil(P / prefill_chunk)`` steps instead of P, so
@@ -125,6 +125,19 @@ kind's as wide as that and starting at the window's first page
 end (the prefix cache, a draft's mirrored pool, the embed lane) and a mesh
 refuse such a model by the field's name.
 
+A model with STATE layers (``"state"``: a linear-attention layer's fixed-size
+recurrent state and convolution tail, ``models/kimi_linear.py``) keeps them
+in a ``SlotStateCache`` beside the full kind's pool: a row of its arrays a
+live sequence, taken at admission (from a slot that ends in the round not
+yet answered where none is free), given back with the pages, donated into
+each step and returned like a pool, never paged. The step starts a row from
+zero where ``_build_arrays`` marks its sequence as starting in the call
+(``StateTables.fresh``); a preemption spills the row beside the pages and
+the restore writes it back bitwise. Such a model's
+full kind may keep one array a token and no V (``pool_values`` False: a
+latent cache). The prefix cache, speculation, the embed lane and a mesh are
+refused for it by name, as for window layers.
+
 Lifecycle mirrors the one-shot engine so the SAME ModelRegistry
 hot-swaps decoders: ``stop(drain=True)`` finishes every admitted
 sequence then drops params/pools/compiled steps (executables release
@@ -149,7 +162,8 @@ from ..observability.log import get_logger
 from .engine import bucket_for as _bucket_for, resolve_bucket_spec
 from .errors import (DeadlineExceeded, EngineRetired, RequestTooLarge,
                      ServerOverloaded, ServingError)
-from .kv_cache import GARBAGE_PAGE, HostSpillStore, PagedKvCache
+from .kv_cache import (GARBAGE_PAGE, HostSpillStore, PagedKvCache,
+                       SlotStateCache)
 
 __all__ = ["DecoderSpec", "DecodeEngine", "build_decoder_params",
            "seeded_decoder_arrays", "decoder_step",
@@ -301,6 +315,11 @@ _m_moe_load = _metrics.histogram("serving.decode.moe.load_max_over_mean")
 # lie behind the window (0 until a sequence passes it)
 _m_window_held = _metrics.histogram("serving.kv.window.held_pct")
 _m_window_skip = _metrics.histogram("serving.decode.attn_window_skip_pct")
+# state layers: state_resets counts the sequences whose state a step
+# started from zero (a first chunk), state_spills
+# the state rows a preemption carried to the host beside the pages
+_m_state_resets = _metrics.counter("serving.decode.state_resets")
+_m_state_spills = _metrics.counter("serving.decode.state_spills")
 
 
 # --- the pluggable decoder model ----------------------------------------
@@ -309,7 +328,7 @@ _m_window_skip = _metrics.histogram("serving.decode.attn_window_skip_pct")
 # by. The dense decoder this engine was built on is one such model and
 # keeps its names here.
 from ..models.decoders import (DecoderSpec, KindTables,  # noqa: E402,F401
-                               build_decoder_params,
+                               StateTables, build_decoder_params,
                                decoder_step, decoder_step_chunked,
                                seeded_decoder_arrays, spec_from_dict,
                                validate_draft_spec)
@@ -857,6 +876,7 @@ class DecodeEngine:
                  max_seq_len: Optional[int] = None,
                  max_queue: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
+                 prefill_budget: Optional[int] = None,
                  continuous: bool = True,
                  params: Optional[Dict[str, Any]] = None,
                  prefix_cache: Optional[bool] = None,
@@ -924,6 +944,32 @@ class DecodeEngine:
                 f"'num_window_pages' sizes the window kind's pool; "
                 f"decoder '{name}' (family {spec.family!r}) has no window "
                 f"layer")
+        # state layers: a sequence's state is a function of EVERY token
+        # before it and is carried, not recomputed. A shared prefix (its
+        # pages hold no state), a draft's rollback (the state cannot
+        # un-see a rejected token), the embed lane's own slots and the
+        # mesh's sharded pools are refused by name as for window layers
+        self._stateful = "state" in spec.layer_kinds
+        if self._stateful and self._window is not None:
+            # the state kind's arrays take the step's second pool
+            # arguments, which the window kind's pool holds
+            raise ValueError(
+                f"decoder '{name}' has window and state layers (family "
+                f"{spec.family!r}): one engine serves one of the two "
+                f"kinds beside its full layers")
+        if self._stateful:
+            for field, given in (("draft_spec", draft_spec is not None),
+                                 ("spec_k", bool(spec_k)),
+                                 ("embeddings", bool(embeddings)),
+                                 ("prefix_cache", bool(prefix_cache)),
+                                 ("mesh", bool(mesh))):
+                if given:
+                    raise ValueError(
+                        f"decoder '{name}' has state layers (family "
+                        f"{spec.family!r}): '{field}' is not carried "
+                        f"through for a sequence whose state is carried "
+                        f"from step to step")
+            prefix_cache, mesh = False, ""
         # mesh-sharded serving (ISSUE 15): one replica SPANS chips.
         # `mesh` is a MeshSpec / axes dict / "tp=2" string (None reads
         # FLAGS['serving_mesh_axes']; '' = single-chip, bit-identical
@@ -1030,7 +1076,8 @@ class DecodeEngine:
             page_size=ps, num_pages=npages, dtype=spec.pool_dtype,
             label=f"{self.name}.v{self.version}",
             prefix_cache=self._prefix_on,
-            mesh=self._mesh, shard_spec=self._pool_spec())
+            mesh=self._mesh, shard_spec=self._pool_spec(),
+            values=getattr(spec, "pool_values", True))
         # host refuge for preempted sequences' pages (kv_spill_dir
         # moves it to disk); cleared at retirement — leaks nothing
         self._spill = HostSpillStore(
@@ -1059,6 +1106,19 @@ class DecodeEngine:
         # passes ride C=block_length), steps carrying a prefill grant
         # ride the C=chunk shapes
         self._chunk_ladder = sorted({self._block, self._prefill_chunk})
+        # the prompt tokens a step grants over all its prefill slots;
+        # each slot's grant stays within the chunk. Left unset it is the
+        # chunk, so that the slot that holds the budget takes it all. A
+        # wider budget fills the lanes that a step of the chunk's shape
+        # computes anyway: many sequences in their prompts at once then
+        # each take up to a chunk a step, not one token
+        self._prefill_budget = (self._prefill_chunk if prefill_budget is None
+                                else int(prefill_budget))
+        if self._prefill_budget < self._prefill_chunk:
+            raise ValueError(
+                f"prefill_budget {self._prefill_budget} is under the "
+                f"chunk ({self._prefill_chunk}): the slot that holds the "
+                f"budget could never take a whole chunk")
         # the WINDOW kind's cache (ISSUE 34), over an allocator of its
         # own: a pair, as a draft's pool beside its target's, and not one
         # pool with a kind axis, because the two kinds hold different
@@ -1084,8 +1144,16 @@ class DecodeEngine:
                 spec.head_dim, page_size=ps, num_pages=wpages,
                 dtype=spec.pool_dtype,
                 label=f"{self.name}.v{self.version}.window")
-        self._hbm_bytes = self.cache.hbm_bytes + (
-            self._wcache.hbm_bytes if self._wcache is not None else 0)
+        # the STATE kind's arrays: a row for each slot the ladder allows
+        # and the garbage row, the model's per-sequence state at a fixed
+        # size, counted in hbm_bytes beside the pools
+        self._scache = (SlotStateCache(
+            spec.slot_state, self._max_slots + 1,
+            label=f"{self.name}.v{self.version}.state")
+            if self._stateful else None)
+        self._hbm_bytes = self.cache.hbm_bytes + sum(
+            c.hbm_bytes for c in (self._wcache, self._scache)
+            if c is not None)
         # speculative decoding (ISSUE 14): a small DRAFT decoder
         # proposes spec_k tokens per decoding slot per round; the
         # target verifies all k+1 positions in ONE chunked call. The
@@ -1361,6 +1429,10 @@ class DecodeEngine:
         return self._prefill_chunk
 
     @property
+    def prefill_budget(self) -> int:
+        return self._prefill_budget
+
+    @property
     def chunk_ladder(self) -> List[int]:
         return list(self._chunk_ladder)
 
@@ -1374,6 +1446,11 @@ class DecodeEngine:
     def window_cache(self) -> Optional[PagedKvCache]:
         """The window kind's cache (None: every layer is full)."""
         return self._wcache
+
+    @property
+    def state_cache(self) -> Optional[SlotStateCache]:
+        """The state kind's arrays (None: the model has no state layer)."""
+        return self._scache
 
     @property
     def spec_k(self) -> int:
@@ -1927,6 +2004,8 @@ class DecodeEngine:
                 self._draft_cache = None
             if self._wcache is not None:
                 self._wcache.release()
+            if self._scache is not None:
+                self._scache.release_all()
             self.cache.release()
         # any spills that survived the drain (preempted sequences the
         # retirement failed) die with the engine — files included
@@ -1957,6 +2036,7 @@ class DecodeEngine:
                 "slots": list(self._slot_ladder),
                 "table_widths": list(self._width_ladder),
                 "prefill_chunk": self._prefill_chunk,
+                "prefill_budget": self._prefill_budget,
                 "chunk_ladder": list(self._chunk_ladder),
                 "page_size": self.cache.page_size,
                 "max_seq_len": self.max_seq_len,
@@ -1979,6 +2059,9 @@ class DecodeEngine:
                 "kv_window": (self._wcache.allocator.stats()
                               if self._wcache is not None else None),
                 "window": self._window,
+                # state layers: the state kind's rows beside the pools
+                "state": (self._scache.stats()
+                          if self._scache is not None else None),
                 "kv_hbm_bytes": self.hbm_bytes,
                 "queue_depth": len(self._queue),
                 "live": len(self._slots),
@@ -2033,6 +2116,8 @@ class DecodeEngine:
         self.cache.allocator.free(seq_id)
         if self._wcache is not None:
             self._wcache.allocator.free(seq_id)
+        if self._scache is not None:
+            self._scache.release(seq_id)
 
     def _fail_locked(self, req: _DecodeRequest, err: BaseException):
         self._free_pages(req.seq_id)
@@ -2100,6 +2185,15 @@ class DecodeEngine:
                 except ServerOverloaded:
                     break
                 req.needs_alloc = False
+            if self._scache is not None:
+                # a state row beside the pages; a slot that ends in the
+                # round not yet answered rides no further round, so its
+                # row may be handed on
+                try:
+                    self._scache.assign(req.seq_id, [s.req.seq_id
+                                                     for s in ending])
+                except ServerOverloaded:
+                    break
             self._queue.pop(0)
             slot = _Slot(req,
                          self.cache.allocator.held_pages(req.seq_id))
@@ -2324,6 +2418,12 @@ class DecodeEngine:
                         seq_ids, min(w_bucket, self._wwidth),
                         rows=s_bucket),
                     self._wcache.allocator.table_starts(seq_ids, s_bucket))
+            elif self._scache is not None:
+                # each row's row of the state kind's arrays beside it, and
+                # whether its sequence starts here (its state from zero)
+                tables = StateTables(
+                    tables, self._scache.rows_array(seq_ids, s_bucket),
+                    ((starts == 0) & (q_lens > 0)).astype(np.int32))
         return tokens, positions, q_lens, tables, lens
 
     def _run(self, tag: str, tokens, positions, q_lens, tables, lens, *,
@@ -2351,10 +2451,10 @@ class DecodeEngine:
             prog.steps.inc()
             params, cache = ((self._draft_params, self._draft_cache)
                              if prog.draft else (self._params, self.cache))
-            # a model with window layers takes and gives each pool as the
-            # pair of its kinds (it has no draft)
-            kinds = ((cache,) if self._wcache is None
-                     else (cache, self._wcache))
+            # a model with window or state layers takes and gives each
+            # pool as the pair of its kinds (it has no draft)
+            second = self._wcache or self._scache
+            kinds = (cache,) if second is None else (cache, second)
             rows, args = len(tokens), ()
             if prog.sampled:
                 args = ((np.zeros(rows, np.float32),
@@ -2506,7 +2606,7 @@ class DecodeEngine:
         answered the same way. Returns the (possibly shrunk) live list
         and its grants."""
         cows: List[Tuple[int, int]] = []
-        restores, wrestores = [], []
+        restores, wrestores, srestores = [], [], []
         spills: Dict[int, Any] = {}
         for s in live:
             if s.pending_restore:
@@ -2527,7 +2627,14 @@ class DecodeEngine:
                 spill = spills.get(s.req.seq_id)
                 if spill is not None:
                     pages = self.cache.allocator.pages_of(s.req.seq_id)
-                    restores.append((pages[:spill[0].shape[1]], spill))
+                    if self._scache is not None:
+                        # the pool's one array (no V), then the state row
+                        restores.append((pages[:spill[0].shape[1]],
+                                         (spill[0], None)))
+                        srestores.append((
+                            self._scache.row_of(s.req.seq_id), spill[1:]))
+                    else:
+                        restores.append((pages[:spill[0].shape[1]], spill))
                     if self._wcache is not None:
                         wpages = self._wcache.allocator.pages_of(
                             s.req.seq_id)
@@ -2557,6 +2664,8 @@ class DecodeEngine:
                             pages, spill[2], spill[3])
                 for wpages, wk, wv in wrestores:
                     self._wcache.scatter_pages(wpages, wk, wv)
+                for row, values in srestores:
+                    self._scache.scatter_row(row, values)
         pages_for = self.cache.allocator.pages_for_tokens
         while True:
             grants = self._grants(live)
@@ -2689,6 +2798,13 @@ class DecodeEngine:
                         arrays = arrays + self._wcache.gather_pages(
                             self._wcache.allocator.pages_of(req.seq_id)[
                                 :n_keep - victim.wfirst])
+                    if self._scache is not None:
+                        # the state row, which holds every token up to
+                        # pos: no round is in flight (a preemption waits
+                        # for the round before it)
+                        arrays = arrays + self._scache.gather_row(
+                            self._scache.row_of(req.seq_id))
+                        _m_state_spills.inc()
                 # put (disk-backed spills savez) stays outside the
                 # step mutex, same as the pop side in _prepare
                 self._spill.put(req.seq_id, *arrays)
@@ -2731,8 +2847,9 @@ class DecodeEngine:
         """Token-budget scheduling (Sarathi-style, ISSUE 10): every
         slot past its prompt gets its one decode token unconditionally
         — in-flight decodes NEVER stall behind a prompt — while slots
-        still in prefill share a per-step budget of ``prefill_chunk``
-        prompt tokens, granted in slot order. Every prefill slot is
+        still in prefill share a per-step budget of ``prefill_budget``
+        prompt tokens (the chunk unless set wider), granted in slot
+        order, no slot more than a chunk. Every prefill slot is
         guaranteed at least one token per step (at ``prefill_chunk=1``
         this is bitwise the PR 6 one-token-per-slot schedule; no slot
         ever starves), so the budget caps the CHUNKS, not progress. A
@@ -2744,7 +2861,7 @@ class DecodeEngine:
         mode growth in ``_prepare`` covers the whole speculative write
         range before the round runs; like decode tokens, speculative
         lanes are never budgeted against prefill."""
-        budget = self._prefill_chunk
+        budget, chunk = self._prefill_budget, self._prefill_chunk
         grants = []
         bl = self._block
         for s in live:
@@ -2756,13 +2873,13 @@ class DecodeEngine:
                 remaining = len(s.req.prompt) // bl * bl - s.pos
                 g = bl
                 if remaining > 0:
-                    g = max(bl, min(remaining, budget) // bl * bl)
+                    g = max(bl, min(remaining, budget, chunk) // bl * bl)
                     budget = max(0, budget - g)
                 grants.append(g)
                 continue
             remaining_prompt = len(s.req.prompt) - s.pos
             if remaining_prompt > 0:
-                g = max(1, min(remaining_prompt, budget))
+                g = max(1, min(remaining_prompt, budget, chunk))
                 budget = max(0, budget - g)
             else:
                 g = 1 + self._k_eff(s)
@@ -2969,6 +3086,8 @@ class DecodeEngine:
                                else pend.row_of[id(s)])
             if s.pos < len(req.prompt):
                 rnd.prefill_toks += g
+            if s.pos == 0 and self._scache is not None:
+                _m_state_resets.inc()
             s.pos += g
             if s.pos < len(req.prompt):
                 continue
@@ -2989,6 +3108,9 @@ class DecodeEngine:
             rnd.n["assignments"] = per_token * sum(
                 len(fed) for _start, fed in rnd.feeds)
             rnd.call_args = {"moe_assignments": rnd.n["assignments"]}
+        if self._scache is not None:
+            # the state rows the call's recurrence reads and writes
+            rnd.call_args["state_rows"] = len(rnd.rows)
         return rnd
 
     def _open_block(self, s: _Slot, known: int
@@ -3604,11 +3726,11 @@ class DecodeEngine:
         # serving.decode.step); the workload layer's per-kind site
         # (serving.workload.embed) lives at the dispatch boundary
         _faults.fire("serving.decode.embed")
-        budget = self._prefill_chunk
+        budget, chunk = self._prefill_budget, self._prefill_chunk
         grants = []
         for s in live:
             remaining = len(s.req.prompt) - s.pos
-            g = max(1, min(remaining, budget))
+            g = max(1, min(remaining, budget, chunk))
             budget = max(0, budget - g)
             grants.append(g)
         w_bucket = _bucket_for(self._width_ladder,

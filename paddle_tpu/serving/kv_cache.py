@@ -143,19 +143,22 @@ def _page_move_fns() -> Dict[str, Any]:
         # the .inc() calls run at TRACE time only: each fires once per
         # compiled shape, never per call — that IS the compiled-once
         # evidence the satellite test pins
+        # a pool with no V (a latent cache) passes v as None: the moves
+        # run on k alone
         def copy_kv(k, v, src, dst):
             _m_pagemove_compiles.inc()
             return (k.at[:, dst].set(k[:, src]),
-                    v.at[:, dst].set(v[:, src]))
+                    None if v is None else v.at[:, dst].set(v[:, src]))
 
         def gather_kv(k, v, idx):
             _m_pagemove_compiles.inc()
-            return k[:, idx], v[:, idx]
+            return k[:, idx], None if v is None else v[:, idx]
 
         def scatter_kv(k, v, idx, ks, vs):
             _m_pagemove_compiles.inc()
             return (k.at[:, idx].set(ks.astype(k.dtype)),
-                    v.at[:, idx].set(vs.astype(v.dtype)))
+                    None if v is None else v.at[:, idx].set(
+                        vs.astype(v.dtype)))
 
         _PAGE_MOVE["copy"] = jax.jit(
             copy_kv, donate_argnums=(0, 1) if donate else ())
@@ -973,7 +976,7 @@ class PagedKvCache:
                  *, page_size: int, num_pages: int, dtype=None,
                  label: Optional[str] = None, prefix_cache: bool = False,
                  allocator: Optional[PageAllocator] = None,
-                 mesh=None, shard_spec=None):
+                 mesh=None, shard_spec=None, values: bool = True):
         import jax.numpy as jnp
 
         self.num_layers = int(num_layers)
@@ -997,8 +1000,17 @@ class PagedKvCache:
                                            label=label,
                                            prefix_cache=prefix_cache)
         self.dtype = jnp.float32 if dtype is None else dtype
-        shape = (self.num_layers, int(num_pages), int(page_size),
-                 self.num_kv_heads, self.head_dim)
+        # ``values=False``: a LATENT cache, one array a token and no V pool
+        # (its values are its keys' first columns), one key shared by
+        # every query head: ``[layers, pages, page_size, head_dim]``, no
+        # head axis; v stays None through every step, move and spill
+        self.values = bool(values)
+        if not self.values and self.num_kv_heads != 1:
+            raise ValueError(f"a pool with no V holds one key a token, "
+                             f"got num_kv_heads={num_kv_heads}")
+        shape = ((self.num_layers, int(num_pages), int(page_size))
+                 + ((self.num_kv_heads,) if self.values else ())
+                 + (self.head_dim,))
         # mesh-sharded pools (ISSUE 15): one decode replica spans chips
         # with the pool sharded over the kv-head axis — hbm_bytes stays
         # the GLOBAL budget, each chip holds 1/|axis| of it. `sharding`
@@ -1013,7 +1025,8 @@ class PagedKvCache:
 
             self.sharding = NamedSharding(mesh, shard_spec)
         self.k = jnp.zeros(shape, self.dtype, device=self.sharding)
-        self.v = jnp.zeros(shape, self.dtype, device=self.sharding)
+        self.v = (jnp.zeros(shape, self.dtype, device=self.sharding)
+                  if self.values else None)
 
     @property
     def page_size(self) -> int:
@@ -1026,13 +1039,15 @@ class PagedKvCache:
     @property
     def hbm_bytes(self) -> int:
         """The preallocated KV budget: fixed at construction."""
-        return 2 * int(np.prod(self.k.shape)) * self.k.dtype.itemsize
+        return ((1 + self.values) * int(np.prod(self.k.shape))
+                * self.k.dtype.itemsize)
 
     def rebind(self, k, v):
         """Adopt the pools a decode step returned. Shape-checked: the
         whole point is that the footprint NEVER changes."""
-        if tuple(k.shape) != tuple(self.k.shape) or \
-                tuple(v.shape) != tuple(self.v.shape):
+        if tuple(k.shape) != tuple(self.k.shape) or (v is None) != (
+                self.v is None) or (v is not None and tuple(v.shape)
+                                    != tuple(self.v.shape)):
             raise ValueError(
                 f"decode step changed the pool shape: "
                 f"{tuple(self.k.shape)} -> {tuple(k.shape)}")
@@ -1046,7 +1061,8 @@ class PagedKvCache:
 
             if getattr(k, "sharding", None) != self.sharding:
                 k = jax.device_put(k, self.sharding)
-            if getattr(v, "sharding", None) != self.sharding:
+            if v is not None and getattr(v, "sharding",
+                                         None) != self.sharding:
                 v = jax.device_put(v, self.sharding)
         self.k = k
         self.v = v
@@ -1068,20 +1084,20 @@ class PagedKvCache:
         self.k, self.v = _page_move_fns()["copy"](self.k, self.v,
                                                   srcs, dsts)
 
-    def gather_pages(self, pages: Sequence[int]
-                     ) -> Tuple[np.ndarray, np.ndarray]:
+    def gather_pages(self, pages: Sequence[int]) -> Tuple[np.ndarray, ...]:
         """Read page contents to host (preemption spill): bitwise
         copies of ``[layers, len(pages), page_size, heads, dim]`` via
         the jitted batched gather (one executable per page count, not
-        one whole-pool slice per call)."""
+        one whole-pool slice per call): ``(k, v)``, or ``(k,)`` of a
+        pool with no V."""
         if self.k is None:
             raise ServingError("KV pools released — engine retired")
         idx = np.asarray(list(pages), np.int32)
         k, v = _page_move_fns()["gather"](self.k, self.v, idx)
-        return np.asarray(k), np.asarray(v)
+        return (np.asarray(k),) + (() if v is None else (np.asarray(v),))
 
     def scatter_pages(self, pages: Sequence[int], k: np.ndarray,
-                      v: np.ndarray):
+                      v: Optional[np.ndarray] = None):
         """Write spilled page contents back (preemption restore) —
         the bitwise inverse of ``gather_pages``, into a possibly
         DIFFERENT set of physical pages (the table rebinds; content,
@@ -1110,3 +1126,169 @@ class PagedKvCache:
         self.k = None
         self.v = None
         self.allocator.retire()
+
+
+# the state kind's bytes on the device (the engine's slot-state arrays),
+# set where they are made and zeroed where they are dropped
+_g_state_bytes = _metrics.gauge("serving.decode.state_bytes")
+
+_state_move_mu = threading.Lock()
+_STATE_MOVE: Dict[str, Any] = {}  # guarded-by: _state_move_mu
+
+
+def _state_move_fns() -> Dict[str, Any]:
+    """One state row to the host and back, jitted once a shape (the row is
+    data), donated in place on a TPU as the page moves are."""
+    with _state_move_mu:
+        if _STATE_MOVE:
+            return dict(_STATE_MOVE)
+        import jax
+
+        donate = jax.default_backend() == "tpu"
+
+        def gather_row(arrays, row):
+            return tuple(a[:, row] for a in arrays)
+
+        def scatter_row(arrays, row, values):
+            return tuple(a.at[:, row].set(x.astype(a.dtype))
+                         for a, x in zip(arrays, values))
+
+        _STATE_MOVE["gather"] = jax.jit(gather_row)
+        _STATE_MOVE["scatter"] = jax.jit(
+            scatter_row, donate_argnums=(0,) if donate else ())
+        return dict(_STATE_MOVE)
+
+
+class SlotStateCache:
+    """The STATE kind's arrays: what a layer keeps of a sequence at a
+    FIXED size whatever its length (a linear-attention layer's recurrent
+    state and its convolution's tail), one ROW a live sequence, never
+    paged.
+
+    The model describes the arrays (``slot_state``: ``(shape, dtype)``
+    each, without the row axis, which goes second); they are allocated
+    ONCE with ``rows`` rows, row 0 the garbage row that dead call rows
+    name (a sequence never holds it, so what they write there is read by
+    nobody). ``k`` and ``v`` are the first and second array: the names
+    the engine threads a kind's arrays through a step by, beside the full
+    kind's pools, rebinding them after each step as a pool's.
+
+    A sequence takes a row when it takes a slot (``assign``; a row whose
+    sequence rides no further round may be handed on, ``assign(...,
+    donors=)``) and gives it back when its pages go (``release``);
+    ``gather_row`` / ``scatter_row`` spill a preempted sequence's row and
+    restore it bitwise into whatever row it gets next. The engine
+    serializes every device touch with its steps, as the page moves'."""
+
+    def __init__(self, layout, rows: int, label: Optional[str] = None):
+        import jax.numpy as jnp
+
+        if rows < 2:
+            raise ValueError(f"a slot-state cache needs the garbage row "
+                             f"and one more, got rows={rows}")
+        self.label = label
+        self.layout = tuple((tuple(int(d) for d in shape), str(dtype))
+                            for shape, dtype in layout)
+        if len(self.layout) != 2:
+            raise ValueError(f"the state kind is two arrays (they ride "
+                             f"a step as k and v), got {len(self.layout)}")
+        self.arrays = tuple(
+            jnp.zeros(shape[:1] + (int(rows),) + shape[1:], dtype)
+            for shape, dtype in self.layout)
+        self._mu = threading.Lock()
+        self._free = list(range(int(rows) - 1, 0, -1))  # guarded-by: _mu
+        self._row: Dict[int, int] = {}  # guarded-by: _mu
+        self._hbm_bytes = int(sum(a.size * a.dtype.itemsize
+                                  for a in self.arrays))
+        _g_state_bytes.set(self._hbm_bytes)
+
+    @property
+    def k(self):
+        return self.arrays[0] if self.arrays else None
+
+    @property
+    def v(self):
+        return self.arrays[1] if self.arrays else None
+
+    @property
+    def rows(self) -> int:
+        return int(self.arrays[0].shape[1])
+
+    @property
+    def hbm_bytes(self) -> int:
+        """Both arrays' bytes: fixed at construction."""
+        return self._hbm_bytes
+
+    def rebind(self, k, v):
+        """Adopt the arrays a step returned (shape-checked, as a pool's)."""
+        for new, old in zip((k, v), self.arrays):
+            if tuple(new.shape) != tuple(old.shape):
+                raise ValueError(f"decode step changed a state array's "
+                                 f"shape: {tuple(old.shape)} -> "
+                                 f"{tuple(new.shape)}")
+        self.arrays = (k, v)
+
+    def assign(self, seq_id: int, donors: Sequence[int] = ()) -> int:
+        """A row for ``seq_id``: a free one, else the row of the first
+        sequence in ``donors`` that holds one (it rides no further round;
+        the device runs the rounds in order, so the new sequence's first
+        round, which starts from a zero state, follows the donor's last)."""
+        with self._mu:
+            if self._free:
+                row = self._free.pop()
+            else:
+                donor = next((d for d in donors if d in self._row), None)
+                if donor is None:
+                    raise ServerOverloaded(
+                        f"slot-state rows exhausted ({self.label})")
+                row = self._row.pop(donor)
+            self._row[int(seq_id)] = row
+            return row
+
+    def release(self, seq_id: int) -> bool:
+        """Give ``seq_id``'s row back (idempotent)."""
+        with self._mu:
+            row = self._row.pop(int(seq_id), None)
+            if row is not None:
+                self._free.append(row)
+            return row is not None
+
+    def row_of(self, seq_id: int) -> int:
+        """``seq_id``'s row, 0 (the garbage row) where it holds none."""
+        with self._mu:
+            return self._row.get(int(seq_id), 0)
+
+    def rows_array(self, seq_ids: Sequence[int], n: int) -> np.ndarray:
+        """``[n]`` int32: the rows of ``seq_ids``, the garbage row past
+        them."""
+        out = np.zeros(int(n), np.int32)
+        with self._mu:
+            for i, sid in enumerate(seq_ids):
+                out[i] = self._row.get(int(sid), 0)
+        return out
+
+    def stats(self) -> Dict[str, int]:
+        with self._mu:
+            used = len(self._row)
+        return {"rows": self.rows - 1, "rows_used": used,
+                "bytes": self._hbm_bytes}
+
+    def gather_row(self, row: int) -> Tuple[np.ndarray, ...]:
+        """Row ``row`` of every array on the host, bitwise."""
+        if not self.arrays:
+            raise ServingError("state arrays released — engine retired")
+        return tuple(np.asarray(a) for a in _state_move_fns()["gather"](
+            self.arrays, np.int32(row)))
+
+    def scatter_row(self, row: int, values: Sequence[np.ndarray]):
+        """Write a spilled row back into row ``row`` (the inverse of
+        ``gather_row``)."""
+        if not self.arrays:
+            raise ServingError("state arrays released — engine retired")
+        self.arrays = _state_move_fns()["scatter"](
+            self.arrays, np.int32(row), tuple(values))
+
+    def release_all(self):
+        """Drop the arrays (engine retirement) so HBM frees."""
+        self.arrays = ()
+        _g_state_bytes.set(0)

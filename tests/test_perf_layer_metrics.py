@@ -26,6 +26,9 @@ sys.path.insert(0, ROOT)
 from perf.lib.loader import Benchmark  # noqa: E402
 
 NAME, CELL = "sched_device_choice_pct", "xglm17b_chat"
+# the cell of the kimi-linear configuration, appended to the serving lists
+# whose readers hold for it
+KIMI = "kimi_linear_longgen"
 HISTOGRAM = "serving.decode.device_choice_pct"
 # the data-only metrics read from a histogram's mean in both serving cells:
 # name -> (histogram, layer)
@@ -49,13 +52,17 @@ def test_a_histogram_metric_loads_and_reads_the_histograms_avg(bench, name):
     entry, = [m for m in bench.doc["per_layer"] if m["name"] == name]
     # ISSUE 34 appended its two cells to the list, and its four metrics
     # behind the grid's live share; the dot fold's share behind those, and
-    # the three metrics of the device call's stretches behind it
+    # the three metrics of the device call's stretches behind it. The
+    # kimi cell is appended where the program's counter holds for its
+    # latent kernel (not the dot fold's: that kernel folds every lane by
+    # products, whatever the counter's predicate says)
     assert entry == {
         "name": name, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": layer,
         "moves": "serve_tokens_per_s",
         "workloads": [CELL, "sdar30b_blockgen", "xglm17b_docqa",
-                      "trinity_mini_longmix"]}
+                      "trinity_mini_longmix"]
+        + ([] if name == "paged_attn_dot_fold_pct" else [KIMI])}
     assert [m["name"] for m in bench.doc["per_layer"]].index(
         "paged_attn_grid_live_pct") == 18
     assert [m["name"] for m in bench.doc["per_layer"]][19:24] == [
@@ -164,6 +171,9 @@ def test_a_new_metric_loads_and_reads_what_its_file_says(bench, name):
     cells = ([NEW_CELL] if name in ("block_tokens_per_pass",
                                     "paged_attn_block_roofline")
              else [NEW_CELL, "trinity_mini_longmix"])
+    # and the kimi cell to the two that read nothing of the experts' sizes
+    if name in ("moe_load_max_over_mean", "moe_route_share_pct"):
+        cells.append(KIMI)
     assert entry == {"name": name, "unit": unit, "better": better,
                      "source": source, "layer": layer, "moves": moves,
                      "workloads": cells}
@@ -369,7 +379,7 @@ def test_the_two_cells_and_the_configuration_are_appended(bench):
     bench.check_files()
     assert [w["name"] for w in bench.doc["workloads"]] == [
         "xglm17b_chat", "resnet50_train", "sdar30b_blockgen", DOCQA,
-        LONGMIX]
+        LONGMIX, KIMI]
     assert all(w["chips"] == 1 for w in bench.doc["workloads"])
     entry = bench.doc["configs"][3]
     assert (entry["name"], entry["reduced"]) == (
@@ -518,7 +528,7 @@ def test_the_program_registers_what_issue_34s_metrics_read():
 
 # --- the device call's host stretches, in every serving cell --------------
 
-SERVING = ["xglm17b_chat", "sdar30b_blockgen", DOCQA, LONGMIX]
+SERVING = ["xglm17b_chat", "sdar30b_blockgen", DOCQA, LONGMIX, KIMI]
 # name -> (unit, layer, histogram, statistic or None for a python reader)
 PR39 = {"serve_dispatch_ms": ("ms", "model step",
                               "serving.decode.dispatch_ms", "p50"),
