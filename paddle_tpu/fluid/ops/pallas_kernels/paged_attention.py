@@ -82,8 +82,13 @@ a latent attention layer's compressed entry) whose first ``value_dim``
 columns are also its value. The page is ``[page_size, D]`` as it lies,
 with no head axis to tile (a ``[P, ps, 1, D]`` pool pads its unit head
 axis and is turned at every call), and every slot's lanes take the dot
-fold: all ``C * H`` query rows against the page in one product, whatever
+fold: all ``C * H`` query rows against the keys in one product, whatever
 ``q_len``, since a single head already gives a decoding lane ``H`` rows.
+Its grid step folds a BLOCK of ``latent_pages_per_step`` pages (128 keys,
+never more than the table's width) and not one page: the grid is ``(B,
+ceil(W / k))``, the pool stays in HBM where it lies, and the kernel copies
+each live page of a block by its own DMA, the next live block's while it
+folds this one (``_latent_kernel``).
 
 A WINDOW (ISSUE 34, ``window=`` static; ``None`` = none, the programs
 that pass none are the programs they were): a query lane at position
@@ -132,7 +137,7 @@ NEG_INF = -1e30
 
 __all__ = ["paged_attention", "paged_attention_reference",
            "paged_route", "folds_by_dot", "paged_latent_attention",
-           "paged_latent_attention_reference"]
+           "paged_latent_attention_reference", "latent_pages_per_step"]
 
 # trace-time routing counters (this function body runs once per
 # compiled shape, n_layers times per decoder trace — not per step):
@@ -702,35 +707,91 @@ def paged_latent_attention_reference(q, pages, page_tables, kv_lens, *,
     return (o / jnp.maximum(l, jnp.finfo(jnp.float32).tiny)).astype(q.dtype)
 
 
-def _latent_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, page_ref,
-                   o_ref, m_sc, l_sc, acc_sc, *, scale, page_size, heads,
-                   value_dim):
-    """One (sequence b, table column w) grid step of the latent form: the
-    page's keys folded into the online softmax of all the slot's ``N = C
-    * H`` query rows at once (row ``c * H + h``), which lie along the
-    LANES of everything it keeps (scores ``[ps, N]``, statistics ``[1,
-    N]``, accumulator ``[value_dim, N]``), as ``_fold_dot`` has them.
-    Two products on the MXU a page, float32 accumulation, no operand
-    narrowed (``_mxu``). A page past ``kv_len`` runs one compare."""
-    b = pl.program_id(0)
-    w = pl.program_id(1)
+# keys a grid step of the latent kernel folds: a block of whole pages
+LATENT_BLOCK_KEYS = 128
 
-    @pl.when(w == 0)
+
+def latent_pages_per_step(page_size: int, width: int) -> int:
+    """Pages of a slot's table the latent kernel folds a grid step: as
+    many as make ``LATENT_BLOCK_KEYS`` keys, never more than the table's
+    ``width`` columns. The kernel and
+    ``serving.decode.attn_latent_steps_live_pct`` both ask here."""
+    return max(1, min(LATENT_BLOCK_KEYS // page_size, width))
+
+
+def _latent_kernel(tables_ref, kv_lens_ref, q_lens_ref, first_ref, after_ref,
+                   q_ref, pool_ref, o_ref, buf, sems, m_sc, l_sc, acc_sc, *,
+                   scale, page_size, pages, heads, value_dim):
+    """One (sequence b, block j) grid step of the latent form: the
+    block's ``pages`` pages, ``bk = pages * page_size`` keys, folded into
+    the online softmax of all the slot's ``N = C * H`` query rows at once
+    (row ``c * H + h``), which lie along the LANES of everything it keeps
+    (scores ``[bk, N]``, statistics ``[1, N]``, accumulator ``[value_dim,
+    N]``), as ``_fold_dot`` has them. Two products on the MXU a block,
+    float32 accumulation, no operand narrowed (``_mxu``). A block past
+    ``kv_len`` runs one compare.
+
+    The pool stays in HBM: each live page of a block is copied by its own
+    DMA into one half of a double buffer ``buf [2, pages, ps, D]``, with a
+    semaphore a page. The live blocks of a call form one chain, slot by
+    slot (``first_ref[b]`` is the chain's index of slot b's first block,
+    ``after_ref[b]`` the next slot with a live block, B where none is): a
+    live block starts the copies of the next one into the other half
+    before it waits for its own, and the chain's first starts its own.
+    Pages past ``kv_len`` are neither copied nor waited for, and their
+    rows are zeroed before the fold, so whatever the buffer held there
+    cannot reach the output."""
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    nb = pl.num_programs(0)
+    bk = pages * page_size
+    kv_len = kv_lens_ref[b]
+    q_len = q_lens_ref[b]
+
+    def _copies(bb, jj, half):
+        """The block's page copies, each with whether its page is live."""
+        out = []
+        for i in range(pages):
+            col = jj * pages + i
+            out.append((col * page_size < kv_lens_ref[bb],
+                        pltpu.make_async_copy(pool_ref.at[tables_ref[bb, col]],
+                                              buf.at[half, i],
+                                              sems.at[half, i])))
+        return out
+
+    def _start(bb, jj, half):
+        for live, copy in _copies(bb, jj, half):
+            pl.when(live)(copy.start)
+
+    @pl.when(j == 0)
     def _init():
         m_sc[...] = jnp.full_like(m_sc, NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    kv_len = kv_lens_ref[b]
-    q_len = q_lens_ref[b]
-
-    @pl.when(w * page_size < kv_len)
+    @pl.when(j * bk < kv_len)
     def _fold():
-        page = page_ref[0]                                 # [ps, D]
+        link = first_ref[b] + j
+        half = link % 2
+
+        @pl.when(link == 0)
+        def _first():
+            _start(b, j, half)
+
+        more = (j + 1 < pl.num_programs(1)) & ((j + 1) * bk < kv_len)
+        next_b = jnp.where(more, b, after_ref[b])
+
+        @pl.when(next_b < nb)
+        def _next():
+            _start(next_b, jnp.where(more, j + 1, 0), 1 - half)
+
+        for live, copy in _copies(b, j, half):
+            pl.when(live)(copy.wait)
         n = acc_sc.shape[-1]
-        s = _mxu(page, q_ref[0], ((1,), (1,))) * scale     # [ps, N]
-        offs = w * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)
+        offs = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+        keys = buf[half].reshape(bk, -1)                   # [bk, D]
+        keys = jnp.where(offs < kv_len, keys, jnp.zeros_like(keys))
+        s = _mxu(keys, q_ref[0], ((1,), (1,))) * scale     # [bk, N]
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) // heads
         keep = (offs <= _key_limit(kv_len, q_len, lane, 1)) & (lane < q_len)
         s = jnp.where(keep, s, NEG_INF)
@@ -740,12 +801,12 @@ def _latent_kernel(tables_ref, kv_lens_ref, q_lens_ref, q_ref, page_ref,
         p = jnp.exp(s - m_new) * keep
         m_sc[...] = m_new
         l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
-        # pv[v, n] = sum_p page[p, v] * p[p, n], the value the key's first
+        # pv[v, n] = sum_t keys[t, v] * p[t, n], the value the key's first
         # value_dim columns
         acc_sc[...] = acc_sc[...] * alpha + _mxu(
-            page[:, :value_dim], p, ((0,), (0,)))
+            keys[:, :value_dim], p, ((0,), (0,)))
 
-    @pl.when(w == pl.num_programs(1) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
         l = jnp.maximum(l_sc[...], jnp.finfo(jnp.float32).tiny)
         o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
@@ -759,28 +820,48 @@ def _latent_call(q, pages, tables, kv_l, q_l, *, scale, value_dim,
     _p, ps, _d = pages.shape
     w = tables.shape[1]
     n = c * h
+    k = latent_pages_per_step(ps, w)
+    nj = pl.cdiv(w, k)
+    # the table padded to whole blocks. A column past a slot's kv_len is
+    # never read from the pool: the kernel copies live pages only, so the
+    # table goes in as it is (no _live_columns gather)
+    tables = jnp.pad(tables, ((0, 0), (0, nj * k - w)), mode="edge")
+    # the chain of live blocks (_latent_kernel): each slot's first link
+    # and the next slot that has one
+    blocks = jnp.minimum(pl.cdiv(kv_l, k * ps), nj)
+    first = jnp.cumsum(blocks) - blocks
+    slot = jnp.arange(b, dtype=jnp.int32)
+    after = jax.lax.cummin(jnp.where(blocks > 0, slot, b), reverse=True)
+    after = jnp.concatenate([after[1:], jnp.full((1,), b, jnp.int32)])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, w),
-        in_specs=[pl.BlockSpec((1, n, d), lambda bb, ww, *_: (bb, 0, 0)),
-                  # THE paged read: the slot's w-th live page
-                  pl.BlockSpec((1, ps, d),
-                               lambda bb, ww, t, *_: (t[bb, ww], 0, 0))],
+        num_scalar_prefetch=5,
+        grid=(b, nj),
+        in_specs=[pl.BlockSpec((1, n, d), lambda bb, jj, *_: (bb, 0, 0)),
+                  # THE paged read: the pool where it lies, copied page by
+                  # page by the kernel
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, value_dim, n),
-                               lambda bb, ww, *_: (bb, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((1, n), jnp.float32),
+                               lambda bb, jj, *_: (bb, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, k, ps, d), pages.dtype),
+                        pltpu.SemaphoreType.DMA((2, k)),
+                        pltpu.VMEM((1, n), jnp.float32),
                         pltpu.VMEM((1, n), jnp.float32),
                         pltpu.VMEM((value_dim, n), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_latent_kernel, scale=scale, page_size=ps,
-                          heads=h, value_dim=value_dim),
+                          pages=k, heads=h, value_dim=value_dim),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, value_dim, n), q.dtype),
+        # a copy started in one grid step is waited for in a later one:
+        # the steps run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         # the kernel's name in the compiled program and a device trace
         name="paged_latent_attention",
-    )(_live_columns(tables, kv_l, ps), kv_l, q_l, q.reshape(b, n, d), pages)
+    )(tables, kv_l, q_l, first.astype(jnp.int32), after, q.reshape(b, n, d),
+      pages)
     return out.reshape(b, value_dim, c, h).transpose(0, 2, 3, 1)
 
 

@@ -248,6 +248,12 @@ _m_attn_grid_live = _metrics.histogram("serving.decode.attn_grid_live_pct")
 # the call's, by the kernel's own predicate; 0 for a call whose attention
 # is not the kernel's
 _m_attn_dot_fold = _metrics.histogram("serving.decode.attn_dot_fold_pct")
+# the latent kernel's grid, one observation a step call of a model with a
+# latent pool: 100 x the (slot, block) grid steps that fold a live page
+# over slot bucket x ceil(width bucket / k), k pages a block as
+# ``latent_pages_per_step`` says (the kernel asks it too)
+_m_attn_latent_steps = _metrics.histogram(
+    "serving.decode.attn_latent_steps_live_pct")
 # chunked prefill (ISSUE 10): prompt tokens consumed via prefill
 # grants, per-step grant totals (prices the token-budget policy next
 # to the occupancy/fragmentation gauges), and how many scheduler steps
@@ -500,6 +506,18 @@ def _feed_program():
 def _live_pages(kv_lens, page_size: int) -> int:
     """Pages that hold a key a step call's slots see."""
     return int((-(-kv_lens.astype(np.int64) // page_size)).sum())
+
+
+def _latent_steps_live_pct(slots: int, width: int, kv_lens,
+                           page_size: int) -> float:
+    """100 x the share of the latent kernel's ``slots x ceil(width / k)``
+    grid steps that fold a live page, a step a block of ``k`` pages."""
+    from ..fluid.ops.pallas_kernels.paged_attention import (
+        latent_pages_per_step)
+
+    k = latent_pages_per_step(page_size, width)
+    return 100.0 * _live_pages(kv_lens, k * page_size) / (
+        slots * -(-width // k))
 
 
 def _dot_fold_pct(chunk: int, rep: int, pool_dtype, q_lens, kv_lens, *,
@@ -1037,6 +1055,9 @@ class DecodeEngine:
                                spec.pool_dtype)
         self._attn_layers = (spec.layer_kinds.count("full"),
                              spec.layer_kinds.count("window"))
+        # a latent pool (one array a token, no V) is read by the latent
+        # kernel, whose grid steps are blocks of pages
+        self._latent_pool = not getattr(spec, "pool_values", True)
         if self._mesh is not None:
             _log.info("decode %s.v%d spans mesh %s: attention takes %s "
                       "(the Pallas paged kernel has no SPMD form)",
@@ -3562,9 +3583,10 @@ class DecodeEngine:
         """What a step call that runs the attention records once it is
         dispatched, while the device runs it: the share of its ``slots x
         width`` grid that holds a live page and the share of its folds
-        the kernel's dot fold takes, always, and on the ``device_call``
-        span where one is live ``_call_work``'s args and the round's own
-        (``more``)."""
+        the kernel's dot fold takes, always; the share of the latent
+        kernel's grid steps that fold a live block, where the model has a
+        latent pool; and on the ``device_call`` span where one is live
+        ``_call_work``'s args and the round's own (``more``)."""
         ps = self.cache.page_size
         _m_attn_grid_live.observe(
             100.0 * _live_pages(kv_lens, ps) / (slots * width))
@@ -3572,6 +3594,9 @@ class DecodeEngine:
             chunk, *self._fold_geometry, q_lens, kv_lens, page_size=ps,
             window=self._window, layers=self._attn_layers)
             if slots in self._kernel_slots else 0.0)
+        if self._latent_pool:
+            _m_attn_latent_steps.observe(
+                _latent_steps_live_pct(slots, width, kv_lens, ps))
         if sp.live or self._window is not None:
             work = _call_work(slots, chunk, width, q_lens, kv_lens,
                               self._block, window=self._window)

@@ -206,6 +206,20 @@ def test_kda_recurrence_carries_chunks_resets_reused_rows_and_skips_dead(
         np.testing.assert_array_equal(np.asarray(st2[1, r]), keep[1, r])
 
 
+def _un_absorbed(entries, q_nope, q_pe, w_uk, w_uv, scale):
+    """One query lane's attention over the latent ``entries`` it sees,
+    each head with its own key and value made from the latent (``c
+    W_uk``, ``c W_uv`` and the shared ``k_pe``): ``[heads, dv]``."""
+    rank = w_uk.shape[0]
+    k_nope = np.einsum("tk,khn->thn", entries[:, :rank], w_uk)
+    v = np.einsum("tk,khv->thv", entries[:, :rank], w_uv)
+    score = (np.einsum("hn,thn->ht", q_nope, k_nope)
+             + np.einsum("hr,tr->ht", q_pe, entries[:, rank:])) * scale
+    prob = np.exp(score - score.max(-1, keepdims=True))
+    prob /= prob.sum(-1, keepdims=True)
+    return np.einsum("ht,thv->hv", prob, v)
+
+
 def test_the_latent_kernel_is_the_un_absorbed_attention():
     """The absorbed form through the latent pool (one key a token, values
     its first ``rank`` columns) against each head's own key and value made
@@ -232,17 +246,9 @@ def test_the_latent_kernel_is_the_un_absorbed_attention():
     got = np.einsum("bchk,khv->bchv", np.asarray(a), w_uv)
     for s in (0, 2):
         entries = lat[tables[s]].reshape(-1, rank + rope)[:kv[s]]
-        k_nope = np.einsum("tk,khn->thn", entries[:, :rank], w_uk)
-        v = np.einsum("tk,khv->thv", entries[:, :rank], w_uv)
         for j in range(ql[s]):
-            pos = kv[s] - ql[s] + j
-            score = (np.einsum("hn,thn->ht", q_nope[s, j],
-                               k_nope[:pos + 1])
-                     + np.einsum("hr,tr->ht", q_pe[s, j],
-                                 entries[:pos + 1, rank:])) * scale
-            prob = np.exp(score - score.max(-1, keepdims=True))
-            prob /= prob.sum(-1, keepdims=True)
-            want = np.einsum("ht,thv->hv", prob, v[:pos + 1])
+            want = _un_absorbed(entries[:kv[s] - ql[s] + j + 1],
+                                q_nope[s, j], q_pe[s, j], w_uk, w_uv, scale)
             np.testing.assert_allclose(got[s, j], want, rtol=1e-4,
                                        atol=1e-4)
     assert not got[1].any() and not got[2, 1:].any()
@@ -251,6 +257,130 @@ def test_the_latent_kernel_is_the_un_absorbed_attention():
         jnp.asarray(kv), q_lens=jnp.asarray(ql), scale=scale,
         value_dim=rank)
     np.testing.assert_allclose(np.asarray(a), np.asarray(ref), atol=1e-5)
+
+
+# id: page size, table width, chunk, kv_lens, q_lens, pool dtype. The
+# kernel folds k = latent_pages_per_step(page size, width) pages a grid
+# step: 8 at page 16, 16 at page 8, 32 at page 4, never more than width
+LATENT_BLOCK_CASES = {
+    # 10 columns in blocks of 8: the table padded to 16; 150 keys end
+    # mid-page in the second block, 160 on a page's end
+    "width_not_a_multiple_of_k": (16, 10, 4, [150, 160, 37], [4, 1, 2],
+                                  "float32"),
+    # 5 columns and k 5: one block of the whole table
+    "width_under_k": (16, 5, 4, [70, 33, 16], [3, 1, 4], "float32"),
+    # blocks of 16 pages of 8: 203 keys end 3 rows into a page, 75 into
+    # the second block; 300 in the third
+    "mid_page_and_mid_block": (8, 40, 4, [203, 77, 300], [4, 2, 1],
+                               "float32"),
+    # three blocks of 128 keys: 200 keys leave the last one dead, 129 the
+    # second with one key
+    "last_block_dead": (16, 24, 4, [200, 16, 129], [4, 1, 4], "float32"),
+    "dead_slot": (16, 8, 4, [0, 100, 0], [0, 4, 0], "float32"),
+    "decode_c1": (16, 12, 1, [191, 1, 0, 64], [1, 1, 0, 1], "float32"),
+    "chunk": (4, 40, 8, [150, 8, 129], [8, 8, 3], "float32"),
+    # the served dtype: the products' bfloat16 operands and p's three
+    # terms (_mxu) over a block of 128 rows
+    "bfloat16": (16, 10, 4, [150, 57, 128], [4, 1, 3], "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("case", list(LATENT_BLOCK_CASES))
+def test_the_block_kernel_is_the_reference_and_the_un_absorbed_attention(
+        case):
+    """The latent kernel, k pages a grid step, in interpret mode against
+    ``paged_latent_attention_reference`` and against each head's own key
+    and value made from the latent. Every pool row at or past a slot's
+    ``kv_len`` (the rest of its last page, the pages of its dead columns)
+    and the garbage page hold NaN in the kernel's pool and not in the
+    reference's: none of it may reach the output. Dead lanes and a dead
+    slot are exact zeros."""
+    ps, width, c, kv, ql, dtype = LATENT_BLOCK_CASES[case]
+    kv, ql = np.array(kv), np.array(ql)
+    b, h, nope, rope, rank, dv, d = len(kv), 4, 8, 8, 16, 8, 32
+    rng = np.random.RandomState(sum(map(ord, case)))
+    p = b * width + 1
+    lat = rng.randn(p, ps, rank + rope).astype(np.float32)
+    pages = np.zeros((p, ps, d), np.float32)
+    pages[..., :rank + rope] = lat
+    pages = np.asarray(jnp.asarray(pages, dtype).astype(jnp.float32))
+    lat = pages[..., :rank + rope]
+    w_uk, w_uv = rng.randn(rank, h, nope), rng.randn(rank, h, dv)
+    q_nope, q_pe = rng.randn(b, c, h, nope), rng.randn(b, c, h, rope)
+    q = np.zeros((b, c, h, d), np.float32)
+    q[..., :rank] = np.einsum("bchn,khn->bchk", q_nope, w_uk)
+    q[..., rank:rank + rope] = q_pe
+    q = jnp.asarray(q, dtype)
+    tables = (rng.permutation(p - 1)[:b * width] + 1).reshape(b, width)
+    fouled = pages.copy()
+    fouled[0] = np.nan
+    for s in range(b):
+        rows = fouled[tables[s]].reshape(-1, d)
+        rows[kv[s]:] = np.nan
+        fouled[tables[s]] = rows.reshape(width, ps, d)
+    scale = (nope + rope) ** -0.5
+    args = (jnp.asarray(tables, jnp.int32), jnp.asarray(kv, jnp.int32),
+            jnp.asarray(ql, jnp.int32))
+    a = np.asarray(pa._latent_call(
+        q, jnp.asarray(fouled, dtype), *args, scale=scale, value_dim=rank,
+        interpret=True).astype(jnp.float32))
+    want = np.asarray(pa.paged_latent_attention_reference(
+        q, jnp.asarray(pages, dtype), *args[:2], q_lens=args[2],
+        scale=scale, value_dim=rank).astype(jnp.float32))
+    assert a.shape == (b, c, h, rank) and np.isfinite(a).all()
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(a, want, atol=tol, rtol=tol)
+    dead = np.arange(c)[None, :] >= ql[:, None]
+    assert not a[dead].any()
+    if dtype != "float32":
+        return      # the absorbed query was rounded: the reference alone
+    got = np.einsum("bchk,khv->bchv", a, w_uv)
+    for s in range(b):
+        entries = lat[tables[s]].reshape(-1, rank + rope)
+        for j in range(ql[s]):
+            np.testing.assert_allclose(got[s, j], _un_absorbed(
+                entries[:kv[s] - ql[s] + j + 1], q_nope[s, j], q_pe[s, j],
+                w_uk, w_uv, scale), rtol=1e-4, atol=1e-4)
+
+
+def test_latent_pages_per_step_follows_page_size_and_width():
+    """k pages a grid step make 128 keys, capped at the table's width."""
+    k = pa.latent_pages_per_step
+    assert [k(16, 1024), k(16, 10), k(16, 5), k(8, 40), k(4, 40), k(4, 3),
+            k(32, 1024), k(128, 7), k(256, 7), k(16, 1)] == [
+        8, 8, 5, 16, 32, 3, 4, 1, 1, 1]
+
+
+def test_the_latent_steps_counter_reads_the_block_grid(shared):
+    """``serving.decode.attn_latent_steps_live_pct`` by hand, then as the
+    engine records it: one observation a step call of a model with a
+    latent pool, the share of the latent kernel's (slot, block) steps
+    that fold a live page."""
+    from paddle_tpu.serving.decode import _latent_steps_live_pct
+
+    # page 16, width 10: k 8, two blocks a slot; 150 keys take both, 128
+    # one, 129 both, 0 none: 5 of 8
+    kv = np.array([150, 0, 128, 129])
+    assert _latent_steps_live_pct(4, 10, kv, 16) == 62.5
+    # width 1024: 128 blocks a slot; 1 + 1 + 74 blocks of 128 slots x 128
+    assert _latent_steps_live_pct(128, 1024, np.array(
+        [1, 128, 9400] + [0] * 125), 16) == pytest.approx(
+        100.0 * 76 / (128 * 128))
+    spec, params = shared
+    name = "serving.decode.attn_latent_steps_live_pct"
+    before = metrics.snapshot(name)[name]
+    eng = engine(spec, 4, params)
+    try:
+        seen = _watched(eng)
+        req = eng.submit(list(range(1, 11)), max_new_tokens=6)
+        assert req.ev.wait(300) and req.error is None
+    finally:
+        eng.stop(drain=False)
+    after = metrics.snapshot(name)[name]
+    want = [_latent_steps_live_pct(2, c["width"], c["lens"], PS)
+            for c in seen]
+    assert after["count"] - before["count"] == len(seen) > 0
+    assert after["sum"] - before["sum"] == pytest.approx(sum(want))
 
 
 def test_the_witness_rounds_less_than_the_control(ref, shared):
@@ -307,7 +437,8 @@ def _watched(eng):
         ids, logits = real(*args, **kw)
         seen.append({"q": np.array(args[2]), "lens": np.array(args[4]),
                      "logits": np.asarray(logits),
-                     "rows": np.array(args[3].rows)})
+                     "rows": np.array(args[3].rows),
+                     "width": args[3].full.shape[1]})
         return ids, logits
 
     eng._run_step_arrays = wrapped
@@ -464,7 +595,7 @@ def test_the_benchmark_finds_the_cell_its_runner_and_its_metrics():
         "serve_tokens_per_s", "token_gap_p95_ms", "setup_s"]
     names = [m["name"] for m, _d in bench.per_layer("kimi_linear_longgen")]
     for name in ("kda_state_roofline", "paged_attn_latent_roofline",
-                 "kda_step_share_pct"):
+                 "kda_step_share_pct", "paged_attn_latent_steps_live_pct"):
         assert name in names
 
 

@@ -764,6 +764,30 @@ def test_windowed_paged_kernel_compiles_for_v5e(v5e, window_cols, chunk):
     assert "paged_attention" in text
 
 
+@pytest.mark.parametrize("chunk", [1, 32], ids=["kimi_c1", "kimi_c32"])
+def test_latent_paged_kernel_compiles_for_v5e(v5e, chunk):
+    """The latent form at ``kimi_linear_longgen``'s geometry: 128 slots of
+    32 heads, entries of 640 bfloat16 (512 of them the value), pages of
+    16, a table of 1024 columns folded 8 pages a grid step, and the pool
+    of 131073 pages (2.7 GB) handed to the kernel where it lies: no
+    temporary as large as the pool."""
+    from paddle_tpu.fluid.ops.pallas_kernels.paged_attention import (
+        _latent_call, latent_pages_per_step)
+
+    assert latent_pages_per_step(16, 1024) == 8
+    b, h, d, ps, pages, w = 128, 32, 640, 16, 131073, 1024
+    d0 = v5e[0]
+    compiled = jax.jit(lambda q, pool, t, n, m: _latent_call(
+        q, pool, t, n, m, scale=192 ** -0.5, value_dim=512,
+        interpret=False)).lower(
+        _on(d0, (b, chunk, h, d), jnp.bfloat16),
+        _on(d0, (pages, ps, d), jnp.bfloat16),
+        _on(d0, (b, w), jnp.int32), _on(d0, (b,), jnp.int32),
+        _on(d0, (b,), jnp.int32)).compile()
+    assert "paged_latent_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < pages * ps * d
+
+
 @pytest.mark.parametrize("rows,f", [(512, 768), (2048, 768), (128, 1024),
                                     (8192, 1024)],
                          ids=["block_c4", "block_c16", "afmoe_c1",
